@@ -22,8 +22,10 @@ DESC = {
                    "histogram→split-gain kernel; TPU-specific extension)",
     "compile_cache_dir": "persistent XLA compilation cache directory so "
                          "repeated/resumed runs skip the warmup compile "
-                         "tax ('' = the /tmp default, 'off' disables; "
-                         "LIGHTGBM_TPU_COMPILE_CACHE env wins; "
+                         "tax ('' = one fixed directory inside the "
+                         "checkout, 'off' disables; where "
+                         "JAX_COMPILATION_CACHE_DIR is set it places the "
+                         "cache and a directory given here is ignored; "
                          "docs/OBSERVABILITY.md §Warmup & compile caching)",
     "row_buckets": "pad training rows up a shared shape ladder "
                    "(utils/compile_cache.py bucket_rows; zero row_weight "

@@ -310,8 +310,9 @@ _DEFAULTS: Dict[str, Any] = {
                                 # env wins; load in Perfetto)
     # warmup tax (utils/compile_cache.py; docs/OBSERVABILITY.md)
     "compile_cache_dir": "",   # persistent XLA compile cache dir ("" = the
-                               # /tmp default, "off" disables;
-                               # LIGHTGBM_TPU_COMPILE_CACHE env wins)
+                               # fixed in-checkout default, "off"
+                               # disables; JAX_COMPILATION_CACHE_DIR,
+                               # where set, places the cache instead)
     "row_buckets": True,       # pad training rows up a shared shape ladder
                                # (zero row_weight, bit-identical trees) so
                                # train_step/grow_tree programs are shared

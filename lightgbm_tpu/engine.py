@@ -53,8 +53,9 @@ def train(params, train_set, num_boost_round=100,
     # -- persistent XLA compile cache (utils/compile_cache.py): applied
     # BEFORE any device work so the training programs themselves are
     # covered — repeated/resumed runs load executables from disk instead
-    # of paying the 34-321 s warmup tax again.  On by default;
-    # compile_cache_dir=off disables, LIGHTGBM_TPU_COMPILE_CACHE wins.
+    # of paying the warmup tax again.  On by default; where
+    # JAX_COMPILATION_CACHE_DIR is set it places the cache;
+    # compile_cache_dir=off (or LIGHTGBM_TPU_COMPILE_CACHE=off) disables.
     from .utils import compile_cache as _compile_cache
     _compile_cache.setup(params.get("compile_cache_dir") or None)
     # -- deep observability (lightgbm_tpu/obs/, docs/OBSERVABILITY.md):

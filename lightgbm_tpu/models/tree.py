@@ -73,8 +73,7 @@ class Tree:
         already shrunk; ``shrinkage`` records the rate like Tree::Shrinkage.
 
         Accepts device or host arrays; device pytrees are fetched with ONE
-        transfer (13 per-field transfers were ~160ms/iter over a remote
-        device link)."""
+        transfer instead of 13 per-field round-trips)."""
         import jax
         tree_arrays = jax.device_get(tree_arrays)
         num_leaves = int(tree_arrays.num_leaves)

@@ -1,8 +1,9 @@
 """Process-wide account of every XLA compilation.
 
-BENCH_r02-r05 showed training throughput flat while warmup swung 34-321 s
-of XLA compiles — and nothing could say WHICH programs compiled, for
-which shapes, or how long each took.  This module is that account:
+Early bench rounds showed training throughput flat while warmup swung
+34-321 s of XLA compiles (PERF.md, "Carried over") — and nothing could
+say WHICH programs compiled, for which shapes, or how long each took.
+This module is that account:
 
 - ``instrumented_jit(fn, program=...)`` wraps a function in ``jax.jit``
   (or wraps an already-jitted callable) and detects each compilation the
@@ -219,8 +220,6 @@ def _cost_analysis(fn, args: tuple,
         ca = fn.lower(*args, **kwargs).compile().cost_analysis()
     except Exception:
         return None
-    if isinstance(ca, (list, tuple)):      # older jax: one dict per device
-        ca = ca[0] if ca else None
     if not isinstance(ca, dict):
         return None
 
@@ -250,15 +249,11 @@ class InstrumentedJit:
     caused it.
 
     Compile detection reads the jit's executable-cache size before/after
-    each call (the ``CountingJit`` technique, now shared); jax builds
-    without the private ``_cache_size`` API fall back to counting
-    distinct abstract-shape keys — the same signal wherever shapes are
-    the only specialization axis."""
+    each call (the ``CountingJit`` technique, now shared)."""
 
     def __init__(self, fn: Callable, program: str):
         self._fn = fn
         self.program = str(program)
-        self._seen_keys: set = set()
 
     # underlying-jit passthroughs (so stacked wrappers keep detecting,
     # and callers can inspect the lowered program — e.g. the donation
@@ -266,14 +261,8 @@ class InstrumentedJit:
     def lower(self, *args, **kwargs):
         return self._fn.lower(*args, **kwargs)
 
-    def _cache_size(self) -> Optional[int]:
-        probe = getattr(self._fn, "_cache_size", None)
-        if probe is None:
-            return None
-        try:
-            return int(probe())
-        except Exception:  # pragma: no cover - jax internals moved
-            return None
+    def _cache_size(self) -> int:
+        return int(self._fn._cache_size())
 
     def _dispatch(self, *args, **kwargs):
         """The one seam every instrumented dispatch passes through —
@@ -312,13 +301,7 @@ class InstrumentedJit:
         t0 = time.perf_counter()
         out = self._call_guarded(*args, **kwargs)
         dt = time.perf_counter() - t0
-        after = self._cache_size()
-        if after is not None:
-            compiled = before is not None and after > before
-        else:  # pragma: no cover - fallback for jax without _cache_size
-            key = abstract_shapes(args, kwargs, limit=64)
-            compiled = key not in self._seen_keys
-            self._seen_keys.add(key)
+        compiled = self._cache_size() > before
         if compiled:
             cost = None
             if devprof.ENABLED:

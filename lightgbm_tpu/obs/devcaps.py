@@ -8,10 +8,9 @@ running.  This module is that table — small, static, and overridable:
 - TPU entries are the vendor-published per-chip peak dense (bf16)
   FLOP/s and HBM bandwidth.  ``jax.local_devices()[0].device_kind``
   strings ("TPU v4", "TPU v5 lite", ...) select the row by substring.
-- the CPU entry is an order-of-magnitude NOMINAL (a few AVX cores),
-  because there is no one honest number for "a CPU" — it exists so the
-  roofline column renders on the CPU tier-1 path at all.  For real CPU
-  rooflines, override.
+- a device that is not in the table (the CPU backend among them) has no
+  peak: ``capabilities`` returns None for both numbers and the roofline
+  column stays empty rather than rendering against a guess.
 - ``LIGHTGBM_TPU_PEAK_FLOPS`` / ``LIGHTGBM_TPU_PEAK_BYTES_PER_SEC``
   env vars override both numbers for any platform (measured-peak
   calibration beats any table).
@@ -43,9 +42,6 @@ _TABLE: Dict[str, tuple] = {
     "tpu v5p": (459.0e12, 2765.0e9),
     "tpu v5": (459.0e12, 2765.0e9),
     "tpu v6e": (918.0e12, 1640.0e9),
-    # nominal modern-host order of magnitude, NOT a measurement: renders
-    # the roofline column on CPU runs; override via env for real numbers
-    "cpu": (1.0e11, 2.0e10),
 }
 
 
@@ -86,8 +82,6 @@ def capabilities(device: Any = None) -> Dict[str, Any]:
     for sub in _TABLE:
         if sub in key and len(sub) > len(best):
             best = sub
-    if not best and platform.lower() in _TABLE:
-        best = platform.lower()
     if best:
         flops, bw = _TABLE[best]
         source = "table"

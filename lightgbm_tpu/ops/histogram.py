@@ -20,24 +20,16 @@ Values accumulated per (feature, bin): (sum_gradients, sum_hessians, count)
 
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
 
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:  # backend not initialised yet
-        return False
+from ..utils import device
 
 
 def children_histograms(bins, grad, hess, weight, leaf_id,
                         parent_leaf, right_leaf, max_bin: int):
     """Platform dispatcher: Pallas MXU kernel on TPU (14x the XLA
     scatter there), scatter-add elsewhere (CPU tests, small data)."""
-    if _on_tpu():
+    if device.on_tpu():
         from .pallas_histogram import children_histograms_pallas
         return children_histograms_pallas(bins, grad, hess, weight, leaf_id,
                                           parent_leaf, right_leaf, max_bin)
@@ -47,7 +39,7 @@ def children_histograms(bins, grad, hess, weight, leaf_id,
 
 def root_histogram(bins, grad, hess, weight, max_bin: int):
     """Platform dispatcher for the root (all-rows) histogram."""
-    if _on_tpu():
+    if device.on_tpu():
         from .pallas_histogram import root_histogram_pallas
         return root_histogram_pallas(bins, grad, hess, weight, max_bin)
     return build_root_histogram(bins, grad, hess, weight, max_bin)
@@ -78,7 +70,7 @@ def children_split_candidates(bins, grad, hess, weight, leaf_id,
         return per_feature_candidates(hists, totals[:, 0], totals[:, 1],
                                       totals[:, 2], num_bin, is_cat,
                                       feat_mask, params)
-    if _on_tpu():
+    if device.on_tpu():
         from .pallas_histogram import fused_children_split_candidates_pallas
         raw = fused_children_split_candidates_pallas(
             bins, grad, hess, weight, leaf_id, parent_leaf, right_leaf,

@@ -49,18 +49,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..utils import device
+
 # 24-bit fixed point: values quantized to round(x / scale * 2^QBITS),
 # |q| <= 2^QBITS, decomposed into 3 balanced radix-256 int8 digits.
 QBITS = 22
 _DIGIT_W = (65536.0, 256.0, 1.0)
 NUM_STREAMS = 9  # 3 values (g, h, w) x 3 digits
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
 
 
 def compute_scales(g, h, w):
@@ -179,7 +174,7 @@ def digit_histogram_scatter(bins_rm, digits, max_bin: int):
 
 def digit_histogram(bins_rm, digits, max_bin: int):
     """Platform dispatcher for the all-rows digit histogram."""
-    if _on_tpu():
+    if device.on_tpu():
         return digit_histogram_pallas(bins_rm, digits, max_bin)
     return digit_histogram_scatter(bins_rm, digits, max_bin)
 
@@ -238,7 +233,7 @@ def leaf_histogram(bins_rm, digits, mask, count, max_bin: int,
             gathered_bins = jnp.take(bins_rm, idx, axis=0)      # [size, F]
             gathered_dig = jnp.take(digits, idx, axis=0)        # [size, 9]
             gathered_dig = jnp.where(valid[:, None], gathered_dig, 0)
-            if _on_tpu():
+            if device.on_tpu():
                 return digit_histogram_pallas(gathered_bins, gathered_dig, B)
             return digit_histogram_scatter(gathered_bins, gathered_dig, B)
         return branch

@@ -24,8 +24,8 @@ in place (the suffix IS the tail of the window, all-equal keys,
 stability).  The lane packing assumes uint8 bins (max_bin <= 256);
 GBDT._make_grow_fn routes uint16 datasets to the cached learner instead.
 
-Alternatives measured and rejected on TPU (tools/probe_primitives.py,
-docs/BENCH_NOTES_r03.md): XLA row gathers run ~12-200 ns/row (lowered
+Alternatives measured and rejected on TPU (tools/probe_primitives.py;
+PERF.md, "Carried over", dead ends): XLA row gathers run ~12-200 ns/row (lowered
 per-index), so permutation-only layouts that gather payloads on demand
 are 2x SLOWER end-to-end; the 12-operand bitonic sort at ~6 ms per 1M
 rows remains the fastest stable partition XLA offers.
@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 
 from ..obs.compile_ledger import instrumented_jit
+from ..utils import device
 from . import leafhist
 from .grow import GrowParams, TreeArrays
 from .split import BestSplit, find_best_split, leaf_output, K_MIN_SCORE
@@ -72,10 +73,10 @@ def _size_classes(n: int, smallest: int = 8192):
     """Power-of-two window classes covering [1, n].
 
     A x4-spaced ladder was tried for compile time and REVERTED: it saved
-    no measurable warmup (remote-compile latency dominates and is now
-    hidden by the persistent compilation cache, utils/compile_cache.py —
-    applied by every entry point since round 7, not just bench.py) but
-    cost ~5% throughput in sort padding (docs/BENCH_NOTES_r03.md).
+    no measurable warmup on the installation of the time and cost ~5%
+    throughput in sort padding (PERF.md, "Carried over").  Warm runs
+    hide the compile behind the persistent compilation cache
+    (utils/compile_cache.py, applied by every entry point).
 
     Callers pass the row-BUCKETED N (utils/compile_cache.py
     bucket_rows via models/gbdt.py), so the classes — and with them the
@@ -213,7 +214,7 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
                 jnp.uint8).reshape(Psz, -1)[:, :9], jnp.int8)
         ch_dig = jnp.where(
             jnp.arange(Psz, dtype=jnp.int32)[:, None] < scnt, ch_dig, 0)
-        if leafhist._on_tpu():
+        if device.on_tpu():
             return leafhist.digit_histogram_pallas(ch_bins, ch_dig, B)
         return leafhist.digit_histogram_scatter(ch_bins, ch_dig, B)
 

@@ -51,6 +51,15 @@ from ..utils.compile_cache import bucket_rows
 from .split import SplitParams, per_feature_scan
 
 
+# What the TPU compiler (jax 0.9.0 Pallas lowering) says to
+# ``fused_children_split_candidates_pallas``: the ``jnp.cumsum`` of
+# split.py's ``per_feature_scan``, traced inside the kernel, has no
+# lowering.  GBDT refuses ``serial_grow=fused`` on a TPU with these words
+# (models/gbdt.py); tests/test_tpu_compile.py pins that they stay true.
+FUSED_GAIN_TPU_REFUSAL = ("Unimplemented primitive in Pallas TPU lowering "
+                          "for KernelType.TC: cumsum")
+
+
 def _padded_rows(n: int, n_blk: int) -> int:
     """Rows padded up the SHARED bucket ladder, then to a whole number
     of kernel blocks — so the padded shape is common to every row count

@@ -13,12 +13,13 @@ and the [K, n_blk] output.
 The walk is recast as a *path-consistency matmul* so it runs on the MXU
 instead of as serial gathers (Mosaic has no cheap dynamic gather):
 
-- ``fsel`` [KT*(M+1), F] one-hot split-feature rows turn the row block's
+- ``fsel`` [KT*Mp, F] one-hot split-feature rows (Mp = the M nodes plus
+  one dummy, padded to whole 8-row tiles) turn the row block's
   bins [F, n] into every node's comparison operand in one exact f32
   matmul (``fbin = fsel @ bins``; bin codes < 2^24 are exact in f32).
 - each node compares once (``fbin <= thr`` numeric, ``== thr``
   categorical) giving c = ±1 for all nodes simultaneously.
-- ``paths`` [KT, L, M+1] holds each leaf's ancestor signs (+1 = left
+- ``paths`` [KT, Lp, Mp] holds each leaf's ancestor signs (+1 = left
   edge on the leaf's path, -1 = right) with column M = -depth against a
   constant dummy node whose comparison is always +1.  For the leaf a row
   actually reaches, every ancestor comparison agrees with its sign, so
@@ -31,21 +32,26 @@ instead of as serial gathers (Mosaic has no cheap dynamic gather):
   SAME Kahan-compensation order as ``predict_binned_forest``.
 
 Linear forests (docs/LINEAR_TREES.md) fold the per-leaf affine epilogue
-into the same pass: ``aff`` [KT, L, F] is the dense per-leaf coefficient
+into the same pass: ``aff`` [KT, Lp, F] is the dense per-leaf coefficient
 matrix, the epilogue is ``sum_l sel[l] * (aff_t @ xt)[l]`` (ROADMAP item
 7(c) — no second program, no second HBM round trip).
 
-Bin-space quantization rides the same layout: thresholds live in the
-uint8/16 cut-bin domain (``thr`` stores cut-table indices in the
-narrowest dtype that fits ``nan_bin``), binned inputs arrive already
-quantized, and raw inputs bucketize ONCE per row block inside the
-kernel against the VMEM-resident cut tables — the same
+Bin-space quantization rides the same layout: thresholds are cut-table
+INDICES (``thr``, small exact integers held as f32 — a ``[.., 1]`` column
+pads to a full lane tile in VMEM whatever its dtype, and the chip's
+compiler has no unsigned-narrow -> f32 cast), binned inputs arrive in
+the narrowest unsigned dtype that fits ``nan_bin`` and widen through
+int32 in the kernel, and raw inputs bucketize ONCE per row block inside
+the kernel against the VMEM-resident cut tables — the same
 ``searchsorted(side='left')`` predicate as the XLA raw program, f32
 compares and all.  Leaves may be stored bf16 (``serve_quantize_leaves``)
 — the accumulation stays f32 Kahan either way.
 
 ``interpret=True`` runs the kernel in the Pallas interpreter, which is
-how CPU tier-1 pins fused == gather parity (like ``pallas_histogram``).
+how CPU tier-1 pins fused == gather parity (like ``pallas_histogram``);
+what the chip's compiler accepts is pinned by tests/test_tpu_compile.py
+(every dynamic row offset here is a whole number of 8-row tiles, no
+slice starts at a dynamic lane, no select is between boolean vectors).
 Entry points are deliberately UN-jitted: serve/forest.py traces them
 inside its own bucket-keyed CountingJit programs
 (``predict_forest_walk`` / ``serve_forest_walk``), exactly like
@@ -62,15 +68,9 @@ import numpy as np
 from ..utils.log import LightGBMError
 
 
-def on_tpu() -> bool:
-    """True when jax dispatches to a TPU backend (mirrors
-    ops/histogram.py's platform probe; import-safe on CPU-only hosts)."""
-    try:
-        import jax
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
-
+# f32 tile height on the chip: every dynamic row offset the kernel takes
+# is a multiple of this (see build_walk_tables)
+_SUBLANE = 8
 
 # ---------------------------------------------------------------------------
 # host-side operand builders (freeze-time, numpy)
@@ -122,43 +122,54 @@ def bin_index_dtype(nan_bin: int):
     return np.int32
 
 
-def build_walk_tables(sf, sb, ic, lc, rc, lv, num_features: int,
-                      nan_bin: int):
+def _round_up(x: int, m: int) -> int:
+    return -(-int(x) // m) * m
+
+
+def build_walk_tables(sf, sb, ic, lc, rc, lv, num_features: int):
     """Stacked [K, T, M] / [K, T, L] SoA forest -> fused-walk operands.
 
+    The node axis (M splits + 1 dummy) and the leaf axis are padded to
+    whole 8-row sublane tiles (``Mp``, ``Lp``): the kernel slices one
+    tree's rows at the dynamic offset ``tree * Mp``, and the chip's
+    compiler accepts a dynamic sublane offset only when it is provably
+    tile-aligned.  Pad nodes are all-zero rows (they compare +1 against a
+    zero path column), pad leaves carry the never-selected +1 bias.
+
     Returns ``(fsel, thr, icat, paths, lv_flat)``:
-      fsel  [KT*(M+1), F] f32 one-hot split features (dummy row = 0)
-      thr   [KT*(M+1), 1] u8/u16/i32 cut-bin thresholds (dummy = 0)
-      icat  [KT*(M+1), 1] f32 categorical-node flags
-      paths [KT, L, M+1]  f32 per-leaf ancestor signs / -depth column
-      lv    [KT, L]       f32 leaf values, class-major tree order
+      fsel  [KT*Mp, F]    f32 one-hot split features (dummy/pad rows = 0)
+      thr   [KT*Mp, 1]    f32 cut-bin threshold indices (exact small ints)
+      icat  [KT*Mp, 1]    f32 categorical-node flags
+      paths [KT, Lp, Mp]  f32 per-leaf ancestor signs / -depth column
+      lv    [KT, 1, Lp]   f32 leaf values, class-major tree order
     """
     K, T, M = sf.shape
     L = M + 1
-    Mp = M + 1
+    Lp = _round_up(L, _SUBLANE)
+    Mp = _round_up(M + 1, _SUBLANE)
     KT = K * T
-    dt = bin_index_dtype(nan_bin)
     fsel = np.zeros((KT * Mp, num_features), np.float32)
-    thr = np.zeros((KT * Mp, 1), dt)
+    thr = np.zeros((KT * Mp, 1), np.float32)
     icat = np.zeros((KT * Mp, 1), np.float32)
-    paths = np.zeros((KT, L, Mp), np.float32)
-    lvf = np.zeros((KT, L), np.float32)
+    paths = np.zeros((KT, Lp, Mp), np.float32)
+    lvf = np.zeros((KT, 1, Lp), np.float32)
     for k in range(K):
         for t in range(T):
             tt = k * T + t
             base = tt * Mp
             fsel[base + np.arange(M), sf[k, t]] = 1.0
-            thr[base:base + M, 0] = sb[k, t].astype(dt)
+            thr[base:base + M, 0] = sb[k, t]
             icat[base:base + M, 0] = ic[k, t]
             _leaf_paths(lc[k, t], rc[k, t], M, paths[tt])
-            lvf[tt] = lv[k, t]
+            lvf[tt, 0, :L] = lv[k, t]
     return fsel, thr, icat, paths, lvf
 
 
 def build_affine_tables(lcf, lft, num_features: int) -> np.ndarray:
-    """[K, T, L, Kf] sparse leaf coeff/feat stacks -> dense [KT, L, F]
+    """[K, T, L, Kf] sparse leaf coeff/feat stacks -> dense [KT, Lp, F]
     per-leaf affine matrices (duplicate feature slots sum, matching the
-    gather epilogue's ``(lcf * vals).sum``)."""
+    gather epilogue's ``(lcf * vals).sum``; the leaf axis is padded to
+    ``Lp`` with zero rows like ``build_walk_tables``)."""
     K, T, L, Kf = lcf.shape
     F = num_features
     A = np.zeros((K * T * L, F), np.float32)
@@ -167,7 +178,8 @@ def build_affine_tables(lcf, lft, num_features: int) -> np.ndarray:
     coefs = lcf.reshape(-1).astype(np.float32)
     valid = feats >= 0
     np.add.at(A, (rows[valid], feats[valid]), coefs[valid])
-    return A.reshape(K * T, L, F)
+    A = A.reshape(K * T, L, F)
+    return np.pad(A, ((0, 0), (0, _round_up(L, _SUBLANE) - L), (0, 0)))
 
 
 def walk_vmem_bytes(num_class: int, trees_per_class: int, num_leaves: int,
@@ -179,24 +191,26 @@ def walk_vmem_bytes(num_class: int, trees_per_class: int, num_leaves: int,
     lane = 128
 
     def pad(x: int) -> int:
-        return -(-max(int(x), 1) // lane) * lane
+        return _round_up(max(int(x), 1), lane)
 
     K, T = max(num_class, 1), max(trees_per_class, 1)
     L = max(num_leaves, 2)
-    Mp = L           # (L - 1) nodes + 1 dummy
-    F, C = num_features, max_cuts
+    # L leaves, and (L - 1) nodes + 1 dummy: both tile-padded to the same
+    Lp = Mp = _round_up(L, _SUBLANE)
+    F, C = num_features, _round_up(max_cuts, _SUBLANE)
     KT = K * T
     b = 0
     b += 4 * KT * Mp * pad(F)            # fsel
     b += 2 * 4 * KT * Mp * lane          # thr + icat ([.., 1] lanes pad)
-    b += 4 * KT * L * pad(Mp)            # paths
-    b += 4 * KT * pad(L)                 # lv (bf16 stores less; bound f32)
-    b += 4 * 2 * F * pad(C)              # bnd + cats (raw variant)
+    b += 4 * KT * Lp * pad(Mp)           # paths
+    b += 4 * KT * _SUBLANE * pad(Lp)     # lv ([1, Lp] slab = one tile row)
+    b += 4 * 2 * C * pad(F)              # bnd + cats (raw variant, [C, F])
     b += 4 * F * lane                    # is_cat column
     if linear:
-        b += 4 * KT * L * pad(F)         # aff
-    # per-block transients: bins/x row block, fbin/cmp, sel/S, epilogue
-    b += 4 * pad(n_blk) * (4 * F + 4 * Mp + 4 * L)
+        b += 4 * KT * Lp * pad(F)        # aff
+    # per-block transients: bins/x row block, fbin/cmp, sel/S, epilogue,
+    # and the raw variant's [C, n] per-feature compare matrix
+    b += 4 * pad(n_blk) * (4 * F + 4 * Mp + 4 * Lp + 2 * C)
     return int(b)
 
 
@@ -204,7 +218,7 @@ def walk_vmem_bytes(num_class: int, trees_per_class: int, num_leaves: int,
 # the kernel
 
 def _class_walk(fsel_ref, thr_ref, icat_ref, paths_ref, lv_ref, aff_ref,
-                bins_f, xt, out_ref, *, K: int, T: int, L: int, Mp: int,
+                bins_f, xt, out_ref, *, K: int, T: int, Mp: int,
                 n_blk: int):
     """Per-class Kahan scan over trees: the compensation order mirrors
     ``predict_binned_forest`` exactly, so per-tree contributions (which
@@ -223,22 +237,25 @@ def _class_walk(fsel_ref, thr_ref, icat_ref, paths_ref, lv_ref, aff_ref,
         def tree_body(t, carry, k=k):
             acc, comp = carry
             tt = k * T + t
-            base = tt * Mp
+            # Mp is a whole number of sublane tiles, so the offset is
+            # provably aligned — the only dynamic row slice Mosaic takes
+            base = pl.multiple_of(tt * Mp, _SUBLANE)
             fsel_t = fsel_ref[pl.ds(base, Mp), :]          # [Mp, F]
             fbin = dot(fsel_t, bins_f)                     # [Mp, n] exact
-            thr_t = thr_ref[pl.ds(base, Mp), :].astype(jnp.float32)
+            thr_t = thr_ref[pl.ds(base, Mp), :]            # [Mp, 1]
             icat_t = icat_ref[pl.ds(base, Mp), :]
-            go = jnp.where(icat_t > 0, fbin == thr_t, fbin <= thr_t)
-            cmp = jnp.where(go, 1.0, -1.0).astype(jnp.float32)
-            p_t = paths_ref[pl.ds(tt, 1), :, :].reshape(L, Mp)
-            s = dot(p_t, cmp)                              # [L, n] exact
-            sel = (s == 0.0).astype(jnp.float32)
-            lv_t = lv_ref[pl.ds(tt, 1), :].astype(jnp.float32)  # [1, L]
+            # numeric (<=) vs categorical (==) as +-1 f32 arithmetic:
+            # the chip's compiler has no select between boolean vectors
+            le = jnp.where(fbin <= thr_t, 1.0, -1.0).astype(jnp.float32)
+            eq = jnp.where(fbin == thr_t, 1.0, -1.0).astype(jnp.float32)
+            cmp = le + icat_t * (eq - le)
+            p_t = paths_ref[tt]                            # [Lp, Mp]
+            s = dot(p_t, cmp)                              # [Lp, n] exact
+            sel = jnp.where(s == 0.0, 1.0, 0.0).astype(jnp.float32)
+            lv_t = lv_ref[tt].astype(jnp.float32)          # [1, Lp]
             val = dot(lv_t, sel)                           # [1, n]
             if aff_ref is not None:
-                a_t = aff_ref[pl.ds(tt, 1), :, :].reshape(
-                    L, fsel_ref.shape[1])
-                z = dot(a_t, xt)                           # [L, n]
+                z = dot(aff_ref[tt], xt)                   # [Lp, n]
                 val = val + jnp.sum(sel * z, axis=0, keepdims=True)
             y = val - comp
             tot = acc + y
@@ -250,14 +267,12 @@ def _class_walk(fsel_ref, thr_ref, icat_ref, paths_ref, lv_ref, aff_ref,
         out_ref[k:k + 1, :] = acc
 
 
-def _walk_kernel(*refs, K: int, T: int, L: int, Mp: int, n_blk: int,
-                 raw: bool, linear: bool, nan_bin: int, max_cuts: int):
+def _walk_kernel(*refs, K: int, T: int, Mp: int, n_blk: int,
+                 raw: bool, linear: bool, nan_bin: int):
     """Grid: (row_blocks,).  Forest operands use constant index maps, so
     they stay VMEM-resident across the whole grid; only the row block
     and output move per step."""
-    import jax
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
 
     it = iter(refs)
     fsel_ref, thr_ref, icat_ref, paths_ref, lv_ref = (
@@ -270,41 +285,47 @@ def _walk_kernel(*refs, K: int, T: int, L: int, Mp: int, n_blk: int,
         x_ref = next(it) if linear else None
     aff_ref = next(it) if linear else None
     out_ref = next(it)
+    bins_scr = next(it) if raw else None
 
     if raw:
         # bucketize ONCE per row block against the VMEM cut tables: the
         # same f32 searchsorted(side='left') predicate as the XLA raw
         # program (count of cuts strictly below the value), NaN -> the
         # nan bin, categorical miss -> the nan bin (routes identically
-        # to the gather path's -1: neither ever equals a threshold)
+        # to the gather path's -1: neither ever equals a threshold).
+        # One feature per (static) step: its cuts are a [C, 1] column of
+        # the transposed tables against the feature's [1, n] row, so no
+        # slice ever starts at a dynamic lane.
         x = x_ref[:, :]
-        isnan = jnp.isnan(x)
+        isnan = x != x
         safe = jnp.where(isnan, 0.0, x)
         iv = safe.astype(jnp.int32)
-
-        def bin_step(c, carry):
-            nacc, cacc, hacc = carry
-            b = bnd_ref[:, pl.ds(c, 1)]
-            cv = cats_ref[:, pl.ds(c, 1)]
-            nacc = nacc + (b < safe).astype(jnp.float32)
-            cacc = cacc + (cv < iv).astype(jnp.float32)
-            hacc = hacc + (cv == iv).astype(jnp.float32)
-            return nacc, cacc, hacc
-
-        z = jnp.zeros_like(safe)
-        nacc, cacc, hacc = jax.lax.fori_loop(0, max_cuts, bin_step,
-                                             (z, z, z))
+        bnd_t = bnd_ref[:, :]                              # [C, F] f32
+        cats_t = cats_ref[:, :]                            # [C, F] i32
         nanb = jnp.float32(nan_bin)
-        nbin = jnp.where(isnan, nanb, nacc)
-        cbin = jnp.where((hacc > 0) & ~isnan, cacc, nanb)
-        bins_f = jnp.where(iscol_ref[:, :] > 0, cbin, nbin)
+
+        def count(mask):
+            return jnp.sum(jnp.where(mask, 1.0, 0.0).astype(jnp.float32),
+                           axis=0, keepdims=True)          # [1, n]
+
+        for f in range(x.shape[0]):
+            xr, ivr = safe[f:f + 1, :], iv[f:f + 1, :]
+            cv = cats_t[:, f:f + 1]
+            nbin = count(bnd_t[:, f:f + 1] < xr)
+            cbin = jnp.where(count(cv == ivr) > 0, count(cv < ivr), nanb)
+            # f32 select on the [1, 1] column flag (not a boolean select)
+            isc = iscol_ref[f:f + 1, :]
+            bins_scr[f:f + 1, :] = nbin + isc * (cbin - nbin)
+        bins_f = jnp.where(isnan, nanb, bins_scr[:, :])
         xt = safe if linear else None
     else:
-        bins_f = bins_ref[:, :].astype(jnp.float32)
+        # u8/u16 bin codes widen through int32: the chip's compiler has
+        # no direct unsigned-narrow -> f32 cast
+        bins_f = bins_ref[:, :].astype(jnp.int32).astype(jnp.float32)
         xt = x_ref[:, :] if linear else None
 
     _class_walk(fsel_ref, thr_ref, icat_ref, paths_ref, lv_ref, aff_ref,
-                bins_f, xt, out_ref, K=K, T=T, L=L, Mp=Mp, n_blk=n_blk)
+                bins_f, xt, out_ref, K=K, T=T, Mp=Mp, n_blk=n_blk)
 
 
 def _pad_cols(a, width: int):
@@ -314,7 +335,7 @@ def _pad_cols(a, width: int):
 
 
 def _run_walk(tables, grid_args, grid_dtypes, const_args, *,
-              num_class: int, raw: bool, nan_bin: int, max_cuts: int,
+              num_class: int, raw: bool, nan_bin: int,
               aff=None, n_blk: int, interpret: bool):
     """Shared pallas_call assembly for both variants.  ``tables`` are
     the pinned forest operands, ``grid_args`` the per-row-block inputs
@@ -324,17 +345,18 @@ def _run_walk(tables, grid_args, grid_dtypes, const_args, *,
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     fsel, thr, icat, paths, lv = tables
-    KT, L, Mp = paths.shape
+    KT, _, Mp = paths.shape
     K = num_class
     if KT % K:
         raise LightGBMError(
             f"walk tables carry {KT} trees, not a multiple of "
             f"num_class={K}")
     T = KT // K
-    B = grid_args[0].shape[1]
-    Bp = -(-max(B, 1) // n_blk) * n_blk
+    F, B = grid_args[0].shape
+    Bp = _round_up(max(B, 1), n_blk)
     grid_args = [_pad_cols(jnp.asarray(a, dt), Bp)
                  for a, dt in zip(grid_args, grid_dtypes)]
 
@@ -357,13 +379,15 @@ def _run_walk(tables, grid_args, grid_dtypes, const_args, *,
         operands.append(aff)
 
     out = pl.pallas_call(
-        functools.partial(_walk_kernel, K=K, T=T, L=L, Mp=Mp, n_blk=n_blk,
-                          raw=raw, linear=linear, nan_bin=nan_bin,
-                          max_cuts=max_cuts),
+        functools.partial(_walk_kernel, K=K, T=T, Mp=Mp, n_blk=n_blk,
+                          raw=raw, linear=linear, nan_bin=nan_bin),
         grid=(Bp // n_blk,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((K, n_blk), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((K, Bp), jnp.float32),
+        # the raw variant assembles its [F, n] bin block row by row
+        scratch_shapes=([pltpu.VMEM((F, n_blk), jnp.float32)]
+                        if raw else []),
         interpret=interpret,
     )(*operands)
     return out[:, :B]
@@ -376,7 +400,7 @@ def forest_walk(fsel, thr, icat, paths, lv, bins, *, num_class: int,
 
     ``bins`` [F, B] cut-bin codes in the forest's quantized bin domain
     (u8/u16/i32; categorical misses already remapped to ``nan_bin``).
-    Linear forests pass ``aff`` [KT, L, F] and ``xt`` [F, B] f32
+    Linear forests pass ``aff`` [KT, Lp, F] and ``xt`` [F, B] f32
     NaN-imputed covariates.  Returns [num_class, B] f32 raw scores."""
     grid_args, grid_dtypes = [bins], [bins.dtype]
     if aff is not None:
@@ -385,24 +409,29 @@ def forest_walk(fsel, thr, icat, paths, lv, bins, *, num_class: int,
         grid_dtypes.append(jnp.float32)
     return _run_walk((fsel, thr, icat, paths, lv), grid_args, grid_dtypes,
                      (), num_class=num_class, raw=False, nan_bin=nan_bin,
-                     max_cuts=0, aff=aff, n_blk=n_blk, interpret=interpret)
+                     aff=aff, n_blk=n_blk, interpret=interpret)
 
 
 def forest_walk_raw(fsel, thr, icat, paths, lv, bnd, cats, is_cat_col, X,
-                    *, num_class: int, nan_bin: int, max_cuts: int,
+                    *, num_class: int, nan_bin: int,
                     aff=None, n_blk: int = 128, interpret: bool = False):
     """Fused bucketize-and-walk on raw floats (the serving hot path).
 
     ``X`` [F, B] f32 raw features (NaN allowed), ``bnd`` [F, C] f32
     numeric cut values (+inf pad), ``cats`` [F, C] i32 category codes
     (sentinel pad), ``is_cat_col`` [F, 1] f32 flags.  Rows bucketize
-    once per row block inside the kernel.  Returns [num_class, B] f32
-    raw scores."""
+    once per row block inside the kernel, which reads the cut tables
+    transposed ([C, F], cut axis padded to whole sublane tiles with the
+    same never-counted pad values).  Returns [num_class, B] f32 raw
+    scores."""
     import jax.numpy as jnp
+    bnd = jnp.asarray(bnd, jnp.float32)
+    cats = jnp.asarray(cats, jnp.int32)
+    pad = _round_up(bnd.shape[1], _SUBLANE) - bnd.shape[1]
+    bnd_t = jnp.pad(bnd.T, ((0, pad), (0, 0)), constant_values=jnp.inf)
+    cats_t = jnp.pad(cats.T, ((0, pad), (0, 0)),
+                     constant_values=np.iinfo(np.int32).max)
     return _run_walk((fsel, thr, icat, paths, lv), [X], [jnp.float32],
-                     (jnp.asarray(bnd, jnp.float32),
-                      jnp.asarray(cats, jnp.int32),
-                      jnp.asarray(is_cat_col, jnp.float32)),
+                     (bnd_t, cats_t, jnp.asarray(is_cat_col, jnp.float32)),
                      num_class=num_class, raw=True, nan_bin=nan_bin,
-                     max_cuts=max_cuts, aff=aff, n_blk=n_blk,
-                     interpret=interpret)
+                     aff=aff, n_blk=n_blk, interpret=interpret)

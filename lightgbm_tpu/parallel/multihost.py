@@ -335,7 +335,6 @@ def maybe_initialize_distributed(config) -> bool:
     if pid is None:
         log.fatal("Could not find the local machine in machine_list_file; "
                   "set LIGHTGBM_TPU_PROCESS_ID explicitly")
-    _maybe_enable_cpu_collectives()
     host, port = machines[0]
     log.info("jax.distributed: coordinator %s:%d, process %d/%d",
              host, port, pid, num_machines)
@@ -350,34 +349,6 @@ def maybe_initialize_distributed(config) -> bool:
         timeout_s=timeout_s)
     _maybe_start_watchdog(config, machines, pid)
     return True
-
-
-def _maybe_enable_cpu_collectives() -> None:
-    """Multi-process collectives on the CPU backend need a cross-process
-    implementation (gloo); the default has none, and the gap surfaces
-    only mid-round as "Multiprocess computations aren't implemented on
-    the CPU backend".  Opt in automatically when the run EXPLICITLY
-    targets cpu (``JAX_PLATFORMS=cpu`` / the ``jax_platforms`` option —
-    how CPU rigs are driven here), so reference multi-machine confs work
-    from the CLI.  A machine whose platform is left to autodetection is
-    not touched: we cannot know the backend without initializing it."""
-    import jax
-    platforms = (os.environ.get("JAX_PLATFORMS", "")
-                 or str(getattr(jax.config, "jax_platforms", None) or ""))
-    if "cpu" not in [p.strip() for p in platforms.split(",")]:
-        return
-    try:
-        # not a plain attribute on this jax build; the raw option table is
-        cur = getattr(jax.config, "values", {}).get(
-            "jax_cpu_collectives_implementation")
-    except Exception:  # pragma: no cover - option renamed/removed
-        return
-    if cur in (None, "", "none"):
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-            log.info("cpu backend: enabling gloo cross-process collectives")
-        except Exception as e:  # pragma: no cover - jax build drift
-            log.warning("could not enable gloo cpu collectives: %s", e)
 
 
 def _maybe_start_watchdog(config, machines: List[Tuple[str, int]],

@@ -57,7 +57,7 @@ from typing import Dict, Optional, Sequence, Tuple
 import numpy as np
 
 from .. import obs
-from ..utils import timetag
+from ..utils import device, log, timetag
 from ..utils.log import LightGBMError
 from .batcher import BucketLadder, CountingJit, pad_rows
 
@@ -345,11 +345,10 @@ class CompiledForest:
         the kernel only on TPU and only when the pinned operands fit the
         VMEM budget (``LIGHTGBM_TPU_WALK_VMEM_BYTES``, default 8 MiB of
         the ~16 MiB/core)."""
-        from ..ops.pallas_walk import on_tpu
         req = self.serve_walk_requested
         if req != "auto":
             return req
-        if not on_tpu():
+        if not device.on_tpu():
             return "gather"
         budget = int(os.environ.get("LIGHTGBM_TPU_WALK_VMEM_BYTES",
                                     8 << 20))
@@ -359,11 +358,11 @@ class CompiledForest:
         """Freeze-time fused-walk operands + per-strategy programs."""
         import jax.numpy as jnp
         from ..ops.pallas_walk import (bin_index_dtype, build_affine_tables,
-                                       build_walk_tables, on_tpu)
+                                       build_walk_tables)
 
         sf, sb, ic, lc, rc, lv = stacked
         fsel, thr, icat, paths, lvf = build_walk_tables(
-            sf, sb, ic, lc, rc, lv, self.num_features, int(self._nan_bin))
+            sf, sb, ic, lc, rc, lv, self.num_features)
         self._bin_dtype = bin_index_dtype(int(self._nan_bin))
         self.leaf_dtype = "float32"
         lv_dtype = jnp.float32
@@ -375,7 +374,7 @@ class CompiledForest:
             # the named fallback counter records why.
             lv_q = np.asarray(jnp.asarray(lvf, jnp.bfloat16)
                               .astype(jnp.float32))
-            per_tree = np.abs(lv_q - lvf).max(axis=1)
+            per_tree = np.abs(lv_q - lvf).max(axis=(1, 2))
             bound = float(per_tree.reshape(
                 self.num_class, self.trees_per_class).sum(axis=1).max()
                 if per_tree.size else 0.0)
@@ -394,7 +393,16 @@ class CompiledForest:
             self._walk_aff_dev = jnp.asarray(aff)
         self._is_cat_col_dev = jnp.asarray(
             self._is_cat_feat.astype(np.float32)[:, None])
-        self._walk_interpret = not on_tpu()
+        # off the chip an explicit serve_walk=fused runs the kernel in
+        # the Pallas interpreter (how the CPU tests pin parity) — said
+        # once and reported by info(), never a silent stand-in
+        self._walk_interpret = not device.on_tpu()
+        if self._walk_interpret:
+            log.warn_once(
+                "serve_walk_interpreted",
+                "serve_walk=fused without a TPU: the walk kernel runs in "
+                "the Pallas interpreter (correctness only, not a serving "
+                "speed)")
         obs.devprof.transfer(
             "h2d", "forest",
             sum(int(a.nbytes) for a in self._walk_dev)
@@ -443,7 +451,6 @@ class CompiledForest:
         from ..ops.pallas_walk import forest_walk_raw
 
         nan_bin = int(self._nan_bin)
-        max_cuts = int(self.max_cuts)
         K = self.num_class
         interp = self._walk_interpret
 
@@ -451,8 +458,8 @@ class CompiledForest:
             fsel, thr, icat, paths, lv = walk_dev
             raw = forest_walk_raw(fsel, thr, icat, paths, lv, bnd, cats,
                                   iscol, X.T, num_class=K,
-                                  nan_bin=nan_bin, max_cuts=max_cuts,
-                                  aff=aff, interpret=interp)
+                                  nan_bin=nan_bin, aff=aff,
+                                  interpret=interp)
             raw = jnp.where(mask[None, :], raw, 0.0)
             out = self._transform(raw)
             out = jnp.where(mask[None, :], out, 0.0)
@@ -835,6 +842,7 @@ class CompiledForest:
         }
         if self.walk_strategy == "fused":
             out["walk_vmem_bytes"] = int(self.walk_vmem_bytes())
+            out["walk_interpreted"] = bool(self._walk_interpret)
             out["leaf_dtype"] = self.leaf_dtype
             out["bin_dtype"] = np.dtype(self._bin_dtype).name
         if self.device is not None:

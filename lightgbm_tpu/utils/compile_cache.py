@@ -1,20 +1,25 @@
 """Persistent XLA compilation cache + shared training row buckets.
 
-BENCH_r02-r05 measured 34-321 s of XLA compiles per training run for
-IDENTICAL code — the warmup tax the compile ledger (obs/compile_ledger.py)
-made attributable in round 6.  Two levers kill most of it, both owned
-here so every entry point (engine.train, the CLI, bench.py's two modes)
-configures them identically instead of copy-pasting ``jax.config.update``
-blocks:
+Identical code used to pay minutes of XLA compiles on every training run
+(PERF.md, "Carried over") — the warmup tax the compile ledger
+(obs/compile_ledger.py) made attributable in round 6.  Two levers kill
+most of it, both owned here so every entry point (engine.train, the CLI,
+bench.py, chip_smoke.py, the tools) configures them identically instead
+of copy-pasting ``jax.config.update`` blocks:
 
 - ``setup()`` points JAX's persistent compilation cache at a directory,
   so a repeated or resumed run loads compiled executables from disk
-  instead of re-invoking XLA.  Precedence: the
-  ``LIGHTGBM_TPU_COMPILE_CACHE`` env var wins over the
-  ``compile_cache_dir`` config param, which wins over JAX's own
-  ``JAX_COMPILATION_CACHE_DIR``, which wins over the baked-in default
-  (``/tmp/lightgbm_tpu_jax_cache``).  The cache is ON by default — a
-  value of ``off``/``none``/``0`` disables it.
+  instead of re-invoking XLA.  The directory is placed from OUTSIDE:
+  where ``JAX_COMPILATION_CACHE_DIR`` is set the cache is there and
+  nothing in the code names another (a directory given through the
+  ``compile_cache_dir`` param is ignored with one warning).  Where it is
+  not set: the ``compile_cache_dir`` param if the user gave one, else one
+  fixed directory inside the checkout (``DEFAULT_CACHE_DIR``, derived
+  from this package's own path — the path is part of the cache key, so
+  it never carries a temporary name, a pid or a time).  The cache is ON
+  by default; ``compile_cache_dir`` or the ``LIGHTGBM_TPU_COMPILE_CACHE``
+  env var set to ``off``/``none``/``0`` disables it (the env var is an
+  off-switch only — it no longer names a directory).
 
 - ``bucket_rows()`` maps a row count onto a small ladder of shared
   shapes, the training-side counterpart of ``serve/batcher.py``'s
@@ -41,8 +46,11 @@ from __future__ import annotations
 import os
 from typing import Optional
 
-ENV_DIR = "LIGHTGBM_TPU_COMPILE_CACHE"
-DEFAULT_CACHE_DIR = "/tmp/lightgbm_tpu_jax_cache"
+ENV_SWITCH = "LIGHTGBM_TPU_COMPILE_CACHE"   # off-switch only
+JAX_ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_compile_cache")
 
 # Below this compile time XLA skips the disk write; 1.0 s keeps every
 # program that meaningfully contributes to the warmup tax (the default
@@ -59,18 +67,30 @@ _configured_dir: Optional[str] = None
 def resolve_dir(cache_dir: Optional[str] = None) -> Optional[str]:
     """Effective cache directory for a run, or None when disabled.
 
-    ``LIGHTGBM_TPU_COMPILE_CACHE`` env > ``cache_dir`` argument (the
-    ``compile_cache_dir`` param) > ``JAX_COMPILATION_CACHE_DIR`` env >
-    ``DEFAULT_CACHE_DIR``.  Any level may disable with an off-value."""
-    for value in (os.environ.get(ENV_DIR, ""),
-                  str(cache_dir or ""),
-                  os.environ.get("JAX_COMPILATION_CACHE_DIR", ""),
-                  DEFAULT_CACHE_DIR):
-        value = value.strip()
-        if not value:
-            continue
-        return None if value.lower() in _OFF_VALUES else value
-    return None  # pragma: no cover - DEFAULT_CACHE_DIR is never empty
+    An off-value in ``LIGHTGBM_TPU_COMPILE_CACHE``, in ``cache_dir`` (the
+    ``compile_cache_dir`` param) or in ``JAX_COMPILATION_CACHE_DIR``
+    disables.  Otherwise ``JAX_COMPILATION_CACHE_DIR`` when set, else
+    ``cache_dir`` when given, else ``DEFAULT_CACHE_DIR``."""
+    from . import log
+    switch = os.environ.get(ENV_SWITCH, "").strip()
+    param = str(cache_dir or "").strip()
+    placed = os.environ.get(JAX_ENV_DIR, "").strip()
+    if any(v.lower() in _OFF_VALUES for v in (switch, param, placed) if v):
+        return None
+    if switch:
+        log.warn_once(
+            "compile_cache_env_dir",
+            "%s=%s ignored: the variable only switches the cache off; "
+            "place the cache with %s or compile_cache_dir",
+            ENV_SWITCH, switch, JAX_ENV_DIR)
+    if placed:
+        if param and param != placed:
+            log.warn_once(
+                "compile_cache_param_dir",
+                "compile_cache_dir=%s ignored: %s=%s places the cache",
+                param, JAX_ENV_DIR, placed)
+        return placed
+    return param or DEFAULT_CACHE_DIR
 
 
 def setup(cache_dir: Optional[str] = None,
@@ -95,18 +115,10 @@ def setup(cache_dir: Optional[str] = None,
                 "full compiles)", path)
             path = None
     import jax
-    try:
-        jax.config.update("jax_compilation_cache_dir", path)
-        if path is not None:
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              float(min_compile_seconds))
-    except Exception as exc:  # pragma: no cover - jax without the flags
-        from . import log
-        log.warn_once("compile_cache_setup",
-                      "persistent compilation cache unavailable on this "
-                      "jax build (%s); every run pays full compiles", exc)
-        _configured_dir = None
-        return None
+    jax.config.update("jax_compilation_cache_dir", path)
+    if path is not None:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          float(min_compile_seconds))
     _configured_dir = path
     return path
 

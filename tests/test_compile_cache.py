@@ -81,28 +81,73 @@ def test_bucket_rows_collapses_nearby_sizes():
 # setup(): one helper for every entry point
 
 
-def test_resolve_dir_precedence(monkeypatch):
-    monkeypatch.delenv(compile_cache.ENV_DIR, raising=False)
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    assert compile_cache.resolve_dir() == compile_cache.DEFAULT_CACHE_DIR
-    assert compile_cache.resolve_dir("/x") == "/x"
-    assert compile_cache.resolve_dir("off") is None
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/jaxdir")
-    assert compile_cache.resolve_dir() == "/jaxdir"
-    assert compile_cache.resolve_dir("/x") == "/x"
-    monkeypatch.setenv(compile_cache.ENV_DIR, "/envdir")
-    assert compile_cache.resolve_dir("/x") == "/envdir"
-    monkeypatch.setenv(compile_cache.ENV_DIR, "none")
-    assert compile_cache.resolve_dir("/x") is None
+_DEFAULT = object()   # "the fixed directory inside the checkout"
+
+# (JAX_COMPILATION_CACHE_DIR, LIGHTGBM_TPU_COMPILE_CACHE, param) -> dir
+_PRECEDENCE = {
+    "unset-default": (None, None, None, _DEFAULT),
+    "unset-param": (None, None, "/x", "/x"),
+    "unset-param-off": (None, None, "off", None),
+    "unset-switch-off": (None, "none", "/x", None),
+    "unset-switch-dir-ignored": (None, "/envdir", None, _DEFAULT),
+    "jax-alone": ("/jaxdir", None, None, "/jaxdir"),
+    "jax-beats-param": ("/jaxdir", None, "/x", "/jaxdir"),
+    "jax-beats-switch-dir": ("/jaxdir", "/envdir", "/x", "/jaxdir"),
+    "jax-param-off": ("/jaxdir", None, "off", None),
+    "jax-switch-off": ("/jaxdir", "0", "/x", None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PRECEDENCE))
+def test_resolve_dir_precedence(monkeypatch, case):
+    """The cache is placed from outside: JAX_COMPILATION_CACHE_DIR, where
+    set, wins over the parameter and the repo's own variable (which only
+    switches off); unset, the parameter, else the in-checkout default."""
+    jax_dir, switch, param, want = _PRECEDENCE[case]
+    for name, value in ((compile_cache.JAX_ENV_DIR, jax_dir),
+                        (compile_cache.ENV_SWITCH, switch)):
+        if value is None:
+            monkeypatch.delenv(name, raising=False)
+        else:
+            monkeypatch.setenv(name, value)
+    if want is _DEFAULT:
+        want = compile_cache.DEFAULT_CACHE_DIR
+    assert compile_cache.resolve_dir(param) == want
+    assert compile_cache.resolve_dir(param) == want      # stable per call
+
+
+def test_default_dir_is_fixed_inside_the_checkout():
+    """No temporary name, pid or time in the default: the path is part of
+    the cache key, so two processes must resolve the same directory —
+    inside the checkout, beside the package."""
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_cache.DEFAULT_CACHE_DIR == os.path.join(
+        repo, ".jax_compile_cache")
+    env = {k: v for k, v in os.environ.items()
+           if k not in (compile_cache.JAX_ENV_DIR, compile_cache.ENV_SWITCH)}
+    env["PYTHONPATH"] = repo
+    code = ("from lightgbm_tpu.utils import compile_cache as c; "
+            "print(c.resolve_dir())")
+    outs = {subprocess.run([sys.executable, "-c", code], env=env,
+                           capture_output=True, text=True, check=True,
+                           timeout=120).stdout.strip() for _ in range(2)}
+    assert outs == {compile_cache.DEFAULT_CACHE_DIR}
 
 
 def test_setup_applies_and_disables(tmp_path, monkeypatch):
-    monkeypatch.delenv(compile_cache.ENV_DIR, raising=False)
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv(compile_cache.ENV_SWITCH, raising=False)
+    monkeypatch.delenv(compile_cache.JAX_ENV_DIR, raising=False)
     d = str(tmp_path / "cache")
     assert compile_cache.setup(d) == d
     assert compile_cache.configured_dir() == d
     assert jax.config.jax_compilation_cache_dir == d
+    # placed from outside: the variable wins over the parameter
+    placed = str(tmp_path / "placed")
+    monkeypatch.setenv(compile_cache.JAX_ENV_DIR, placed)
+    assert compile_cache.setup(d) == placed
+    assert jax.config.jax_compilation_cache_dir == placed
     assert compile_cache.setup("off") is None
     assert compile_cache.configured_dir() is None
 

@@ -246,6 +246,9 @@ def test_auto_resolves_gather_off_tpu_and_info_reports():
     assert info["walk_vmem_bytes"] > 0
     assert info["bin_dtype"] == "uint8"       # max_bin 255 fits u8 bins
     assert info["leaf_dtype"] == "float32"    # quantize not requested
+    # off the chip an explicit fused walk is the interpreter, and says so
+    assert info["walk_interpreted"] is True
+    assert "walk_interpreted" not in auto.info()
 
 
 def test_serve_walk_param_plumbs_from_config():

@@ -12,7 +12,7 @@ read-only mount with two missing-#include fixes:
     cmake -S /tmp/refsrc -B /tmp/refbuild -DCMAKE_BUILD_TYPE=Release
     cmake --build /tmp/refbuild -j    # binary lands at /tmp/refsrc/lightgbm
 
-Measured 2026-07-30 on this box (recorded in docs/BENCH_NOTES_r02.md):
+Measured 2026-07-30 on an earlier installation (PERF.md, "Carried over"):
 reference training auc @40 iters = 0.838636, ours matched within 1e-4.
 """
 

@@ -1,0 +1,154 @@
+"""The Pallas kernels of the main paths, compiled for a DESCRIBED TPU v5e
+(no chip attached; nothing runs).
+
+Tier-1 runs every kernel with ``interpret=True`` on the CPU, which says
+nothing about what the chip's compiler accepts: kernels that passed every
+interpreter test were refused for unaligned slices, missing casts and
+missing primitives (PERF.md, "On the chip, PR 24").  The TPU compiler is
+installed here and compiles for a topology that is only described, so a
+few real-width compiles guard every later PR at no chip time.  A compile
+that passes is not a chip run — ``chip_smoke.py`` is.
+
+The topology is described inside a module-scoped fixture, never while a
+module is imported: only one process may load the TPU library at a time,
+and under pytest-xdist every worker imports every test file.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+
+F, B = 28, 255                      # the recorded training widths
+T, L, CUTS = 40, 31, 255            # bench.py --mode predict's forest
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def spec(topo):
+    """``spec(shape, dtype)`` -> a ShapeDtypeStruct placed on the first
+    described chip.  The persistent compile cache is off while the module
+    runs: what is compiled for a described chip is written to it but
+    cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    cc.reset_cache()
+
+
+def _assert_kernel_compiles(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_digit_histogram_kernel_compiles(spec):
+    """The default (ordered) grower's histogram kernel at one size class:
+    4096 rows is the smallest child window (ops/ordered_grow.py P8)."""
+    from lightgbm_tpu.ops import leafhist
+    _assert_kernel_compiles(
+        lambda b, d: leafhist.digit_histogram_pallas(b, d, B),
+        spec((4096, F), jnp.uint8), spec((4096, 9), jnp.int8))
+
+
+def test_children_histograms_kernel_compiles(spec):
+    """The parallel learners' two-children histogram kernel."""
+    from lightgbm_tpu.ops.pallas_histogram import children_histograms_pallas
+    n = 8192
+    text = children_histograms_pallas.lower(
+        spec((F, n), jnp.uint8), spec((n,), jnp.float32),
+        spec((n,), jnp.float32), spec((n,), jnp.float32),
+        spec((n,), jnp.int32), 1, 3, max_bin=B).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def _chain_forest_tables():
+    """Walk tables of T left-deep L-leaf trees over F features."""
+    from lightgbm_tpu.ops import pallas_walk
+    m = L - 1
+    rng = np.random.RandomState(0)
+    sf = rng.randint(0, F, size=(1, T, m)).astype(np.int32)
+    sb = rng.randint(0, CUTS, size=(1, T, m)).astype(np.int32)
+    ic = np.zeros((1, T, m), bool)
+    lc = np.zeros((1, T, m), np.int32)
+    rc = np.zeros((1, T, m), np.int32)
+    for i in range(m):
+        lc[:, :, i] = i + 1 if i + 1 < m else ~m
+        rc[:, :, i] = ~i
+    lv = rng.normal(size=(1, T, L)).astype(np.float32)
+    return pallas_walk.build_walk_tables(sf, sb, ic, lc, rc, lv, F)
+
+
+@pytest.mark.parametrize("bucket", [16, 256])
+@pytest.mark.parametrize("variant", ["binned", "raw"])
+def test_forest_walk_kernel_compiles(spec, variant, bucket):
+    """Both serve-walk variants (``serve_walk=fused``, and ``auto`` on a
+    TPU) at the smallest and a mid serve bucket."""
+    from lightgbm_tpu.ops import pallas_walk
+    nan_bin = CUTS + 1
+    tables = tuple(spec(a.shape, a.dtype) for a in _chain_forest_tables())
+    if variant == "binned":
+        _assert_kernel_compiles(
+            lambda *a: pallas_walk.forest_walk(*a, num_class=1,
+                                               nan_bin=nan_bin),
+            *tables,
+            spec((F, bucket), pallas_walk.bin_index_dtype(nan_bin)))
+    else:
+        _assert_kernel_compiles(
+            lambda *a: pallas_walk.forest_walk_raw(*a, num_class=1,
+                                                   nan_bin=nan_bin),
+            *tables, spec((F, CUTS), jnp.float32),
+            spec((F, CUTS), jnp.int32), spec((F, 1), jnp.float32),
+            spec((F, bucket), jnp.float32))
+
+
+def test_fused_gain_kernel_is_refused_with_the_quoted_words(spec):
+    """``serial_grow=fused`` is fenced off on a TPU (models/gbdt.py) with
+    the compiler's own words; they must stay the compiler's."""
+    from lightgbm_tpu.ops import pallas_histogram as ph
+    from lightgbm_tpu.ops.split import SplitParams
+    n = 8192
+    with pytest.raises(NotImplementedError) as exc:
+        ph.fused_children_split_candidates_pallas.lower(
+            spec((F, n), jnp.uint8), spec((n,), jnp.float32),
+            spec((n,), jnp.float32), spec((n,), jnp.float32),
+            spec((n,), jnp.int32), 1, 3, spec((2, 3), jnp.float32),
+            spec((F,), jnp.int32), spec((F,), jnp.bool_),
+            spec((F,), jnp.bool_), max_bin=B,
+            params=SplitParams(min_data_in_leaf=50)).compile()
+    assert ph.FUSED_GAIN_TPU_REFUSAL in str(exc.value)
+
+
+def test_serial_grow_fused_fails_at_construction_on_tpu(monkeypatch):
+    """... and a booster asking for it on a TPU is refused when it is
+    built, by name, never switched to another grower."""
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.ops.pallas_histogram import FUSED_GAIN_TPU_REFUSAL
+    from lightgbm_tpu.utils import device
+    rng = np.random.RandomState(0)
+    X = rng.normal(size=(400, 4))
+    y = (X[:, 0] > 0).astype(np.float64)
+    params = {"objective": "binary", "num_leaves": 4, "verbose": -1,
+              "serial_grow": "fused"}
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    with pytest.raises(device.KernelRefusedOnTPU) as exc:
+        lgb.Booster(params=params, train_set=lgb.Dataset(X, label=y))
+    assert FUSED_GAIN_TPU_REFUSAL in str(exc.value)

@@ -48,12 +48,8 @@ def run(num_data, bagging):
 
 
 def main():
-    import jax
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.environ.get("JAX_COMPILATION_CACHE_DIR",
-                       "/tmp/lightgbm_tpu_jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 5.0)
+    from lightgbm_tpu.utils import compile_cache
+    compile_cache.setup()
     rows = int(sys.argv[1]) if len(sys.argv) > 1 else 1_000_000
     dt_full, auc_full = run(rows, bagging=False)
     dt_bag, auc_bag = run(rows, bagging=True)

@@ -7,7 +7,7 @@ named baseline and fail loudly on a throughput regression.
 result object or the driver envelope whose ``tail``/``parsed`` fields
 hold it).  This tool makes those files actionable:
 
-    python tools/bench_regress.py --baseline BENCH_r05.json \
+    python tools/bench_regress.py --baseline /tmp/bench_base.json \
         --candidate /tmp/bench_new.json --threshold 5
 
 exits 0 when the candidate's ``value`` is within ``--threshold`` percent
@@ -27,18 +27,18 @@ like; for even older baselines the value is recovered from the
 ``warmup_s=...`` field of the driver envelope's tail comment.  The warm
 number rides along in the verdict uninspected.  Lower warmup is always fine — the gate is
 one-sided, like the throughput gate.  Mind that warmup variance dwarfs
-throughput variance (34-321 s across BENCH_r02-r05 for identical code:
-remote-AOT service load + persistent-cache hits); gate wide, or pin the
-environment first.  Intended CI shape
+throughput variance (34-321 s across four early rounds for identical
+code: cold compiles against persistent-cache hits; PERF.md, "Carried
+over"); gate wide, or pin the environment first.  Intended CI shape
 once a TPU runner exists (docs/OBSERVABILITY.md §Benchmark regression
 gate):
 
     python bench.py > /tmp/bench_new.json
-    python tools/bench_regress.py --baseline BENCH_r05.json \
+    python tools/bench_regress.py --baseline /tmp/bench_base.json \
         --candidate /tmp/bench_new.json --threshold 10
 
-Mind the variance notes in docs/BENCH_NOTES_r03.md: the shared device
-measured 5.9-7.5 it/s for identical code across a day, so gate with a
+Mind the variance recorded in PERF.md ("Carried over"): an earlier
+installation measured 5.9-7.5 it/s for identical code across a day, so gate with a
 threshold wider than the observed window spread (the JSON's ``spread``
 tail comment) or on a quiet runner.
 

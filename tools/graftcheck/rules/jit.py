@@ -4,8 +4,8 @@
   ``obs/compile_ledger.py`` (the one sanctioned wrapper).  Every repo
   jit must route through ``obs.instrumented_jit`` / ``CountingJit`` so
   its compiles land in the compile ledger; raw sites are exactly the
-  blind spots BENCH_r02-r05 could not attribute (34-321s of warmup with
-  no program names).  A site whose jit is wrapped by a CountingJit one
+  blind spots the early bench rounds could not attribute (34-321s of
+  warmup with no program names).  A site whose jit is wrapped by a CountingJit one
   level up is still flagged — waive it with an inline suppression so
   the indirection is visible and counted.
 - ``jit-closure`` — ``jax.jit``/``instrumented_jit`` applied to a
