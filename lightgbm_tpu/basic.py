@@ -197,7 +197,11 @@ class Dataset:
             data, self.feature_name, self.categorical_feature = \
                 _data_from_pandas(data, self.feature_name,
                                   self.categorical_feature)
-            data = _to_dense(data)
+            from . import obs
+            with obs.span("Bin::apply"):
+                # the float64 widening of the whole matrix is part of
+                # applying the bins (io/dataset.py reads the wide copy)
+                data = _to_dense(data)
 
         feature_name = (None if self.feature_name == "auto"
                         else list(self.feature_name))
@@ -554,13 +558,13 @@ class Booster:
         return dd.host_score().reshape(-1)
 
     def __eval_at(self, data_idx: int, name: str, feval=None):
-        from .utils import timetag
+        from . import obs
         b = self._booster
         out = []
         metrics = (b.train_metrics if data_idx == 0
                    else b.valid_metrics[data_idx - 1])
         dd = b.train_data if data_idx == 0 else b.valid_data[data_idx - 1]
-        with timetag.scope("GBDT::metric"):
+        with obs.span("GBDT::metric"):
             score = dd.host_score()
             for m in metrics:
                 for mname, v in zip(m.names, m.eval(score)):

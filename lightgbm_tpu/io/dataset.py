@@ -213,7 +213,12 @@ class BinnedDataset:
                     ) -> "BinnedDataset":
         """Bin a raw [N, F] float matrix (dataset_loader.cpp:656-820 flow:
         sample rows -> per-feature FindBin -> extract features)."""
-        data = np.ascontiguousarray(np.asarray(data, dtype=np.float64))
+        from .. import obs
+        with obs.span("Bin::apply"):
+            # the float64 widening is part of applying the bins: every
+            # column read below reads the widened copy (a no-op when
+            # basic.py's _to_dense already widened it, under this name)
+            data = np.ascontiguousarray(np.asarray(data, dtype=np.float64))
         if data.ndim != 2:
             raise ValueError("data must be 2-D [num_data, num_features]")
         num_data, num_features = data.shape
@@ -230,21 +235,22 @@ class BinnedDataset:
 
         # Row sampling for bin construction (config bin_construct_sample_cnt,
         # dataset_loader.cpp sample_cnt default 200k).
-        rng = np.random.RandomState(data_random_seed)
-        if num_data > bin_construct_sample_cnt:
-            sample_idx = np.sort(rng.choice(num_data, bin_construct_sample_cnt,
-                                            replace=False))
-            sample = data[sample_idx]
-        else:
-            sample = data
-        total_sample_cnt = sample.shape[0]
+        with obs.span("Bin::sample"):
+            rng = np.random.RandomState(data_random_seed)
+            if num_data > bin_construct_sample_cnt:
+                sample_idx = np.sort(rng.choice(
+                    num_data, bin_construct_sample_cnt, replace=False))
+                sample = data[sample_idx]
+            else:
+                sample = data
 
-        per_real = build_mappers_from_sample(
-            sample, num_data, max_bin=max_bin,
-            min_data_in_bin=min_data_in_bin,
-            min_data_in_leaf=min_data_in_leaf,
-            categorical_features=cat, ignore_features=ignored,
-            predefined_mappers=predefined_mappers)
+        with obs.span("Bin::find_bin"):
+            per_real = build_mappers_from_sample(
+                sample, num_data, max_bin=max_bin,
+                min_data_in_bin=min_data_in_bin,
+                min_data_in_leaf=min_data_in_leaf,
+                categorical_features=cat, ignore_features=ignored,
+                predefined_mappers=predefined_mappers)
         self.real_to_inner = np.full(num_features, -1, dtype=np.int64)
         mappers: List[BinMapper] = []
         used: List[int] = []
@@ -271,13 +277,14 @@ class BinnedDataset:
         dtype = _bins_dtype(mappers, self.bundle_plan)
         feature_bins = (lambda inner:
                         mappers[inner].value_to_bin(data[:, used[inner]]))
-        if self.bundle_plan is not None:
-            self.bins = self.bundle_plan.encode_columns(
-                feature_bins, num_data, dtype)
-        else:
-            self.bins = np.zeros((len(used), num_data), dtype=dtype)
-            for inner in range(len(used)):
-                self.bins[inner] = feature_bins(inner).astype(dtype)
+        with obs.span("Bin::apply"):
+            if self.bundle_plan is not None:
+                self.bins = self.bundle_plan.encode_columns(
+                    feature_bins, num_data, dtype)
+            else:
+                self.bins = np.zeros((len(used), num_data), dtype=dtype)
+                for inner in range(len(used)):
+                    self.bins[inner] = feature_bins(inner).astype(dtype)
 
         if keep_raw and used:
             # feature-major like ``bins`` so the linear-fit gather reads
@@ -297,10 +304,11 @@ class BinnedDataset:
         # bookkeeping at bin time; serialized with the model artifact.
         if used:
             from ..obs.drift import DataFingerprint
-            self.data_fingerprint = DataFingerprint.from_training(
-                mappers, used, self.feature_names, data,
-                np.asarray(label, np.float64) if label is not None
-                else None)
+            with obs.span("Bin::fingerprint"):
+                self.data_fingerprint = DataFingerprint.from_training(
+                    mappers, used, self.feature_names, data,
+                    np.asarray(label, np.float64) if label is not None
+                    else None)
         return self
 
     def create_valid(self, data: np.ndarray, label=None) -> "BinnedDataset":

@@ -417,18 +417,24 @@ def _build_shared_train_step(objective, num_class: int, guard: bool,
     the grown leaf values, the fitted delta replaces the grower's
     constant delta, and the packed transfer grows the (feat, coeff)
     vectors.  ``linear=None`` leaves the trace — and the registry key —
-    byte-identical to the pre-linear program."""
+    byte-identical to the pre-linear program.
+
+    Every operation traced here sits under one leaf phase of
+    obs/phases.py ROUND_PHASES (the ordered grower scopes its own), so
+    a profiler window reduces to named phases (obs/devtrace.py)."""
     fused_comm = SerialComm(leaf_cache=False, fused_gain=True)
     nocache_comm = SerialComm(leaf_cache=False)
 
     def step_fn(score, feat_masks, row_weight, lr, bins, num_bin, is_cat,
                 grad_arrays, bins_rm, bins_words, bundle, raw=None):
-        grad, hess = objective.gradients_with(grad_arrays, score)
-        ok = (_all_finite(grad, hess) if guard else jnp.asarray(True))
+        with jax.named_scope("gradients"):
+            grad, hess = objective.gradients_with(grad_arrays, score)
+            ok = (_all_finite(grad, hess) if guard else jnp.asarray(True))
         outs = []
         for cls in range(num_class):
-            args = (bins, num_bin, is_cat, feat_masks[cls], grad[cls],
-                    hess[cls], row_weight, lr)
+            with jax.named_scope("gradients"):
+                args = (bins, num_bin, is_cat, feat_masks[cls], grad[cls],
+                        hess[cls], row_weight, lr)
             if kind == "ordered":
                 # the leaf-ordered grower has no column decode; kind
                 # selection guarantees bundle is None here
@@ -450,13 +456,18 @@ def _build_shared_train_step(objective, num_class: int, guard: bool,
                 ta, coeff, feat, delta, fb = fit_leaf_models(
                     ta, bins, is_cat, raw, grad[cls], hess[cls],
                     row_weight, lr, linear, bundle=bundle)
-                score = score.at[cls].add(delta)
-                outs.append((pack_tree_arrays(ta)
-                             + pack_linear(coeff, feat, fb),
-                             ta, delta, (coeff, feat)))
+                with jax.named_scope("score_update"):
+                    score = score.at[cls].add(delta)
+                with jax.named_scope("pack_tree"):
+                    packed = pack_tree_arrays(ta) \
+                        + pack_linear(coeff, feat, fb)
+                outs.append((packed, ta, delta, (coeff, feat)))
             else:
-                score = score.at[cls].add(delta)
-                outs.append((pack_tree_arrays(ta), ta, delta))
+                with jax.named_scope("score_update"):
+                    score = score.at[cls].add(delta)
+                with jax.named_scope("pack_tree"):
+                    packed = pack_tree_arrays(ta)
+                outs.append((packed, ta, delta))
         return score, outs, ok
     return step_fn
 
@@ -592,10 +603,11 @@ class GBDT:
                              else self.num_data)
         self._linear = self._setup_linear(cfg, train_set)
         self._check_memory_budget(cfg, train_set)
-        self.train_data = _DeviceData(train_set, self.num_class,
-                                      with_row_major=True,
-                                      padded_rows=self._padded_rows,
-                                      with_raw=self._linear is not None)
+        with obs.span("Dataset::to_device"):
+            self.train_data = _DeviceData(
+                train_set, self.num_class, with_row_major=True,
+                padded_rows=self._padded_rows,
+                with_raw=self._linear is not None)
         self.valid_data: List[_DeviceData] = []
         self.valid_metrics: List[List[Metric]] = []
         self.train_metrics = self._make_metrics(cfg, train_set)
@@ -1185,10 +1197,11 @@ class GBDT:
         self._linear = self._setup_linear(cfg, train_set)
         self._check_memory_budget(cfg, train_set)
         self._valid_mem_bytes = valid_bytes
-        self.train_data = _DeviceData(train_set, self.num_class,
-                                      with_row_major=True,
-                                      padded_rows=self._padded_rows,
-                                      with_raw=self._linear is not None)
+        with obs.span("Dataset::to_device"):
+            self.train_data = _DeviceData(
+                train_set, self.num_class, with_row_major=True,
+                padded_rows=self._padded_rows,
+                with_raw=self._linear is not None)
         self.train_metrics = self._make_metrics(cfg, train_set)
         self._init_row_state()
         self._full_feat_mask = jnp.ones(self.num_features, bool)
@@ -1244,12 +1257,13 @@ class GBDT:
                       "set's raw feature values (the per-leaf affine "
                       "epilogue reads them); create the valid set with "
                       "reference=train from an in-memory matrix")
-        dd = _DeviceData(valid_set, self.num_class,
-                         padded_rows=(
-                             compile_cache.bucket_rows(valid_set.num_data)
+        with obs.span("Dataset::to_device"):
+            dd = _DeviceData(
+                valid_set, self.num_class,
+                padded_rows=(compile_cache.bucket_rows(valid_set.num_data)
                              if self._row_buckets_enabled(self.config)
                              else valid_set.num_data),
-                         with_raw=self._linear is not None)
+                with_raw=self._linear is not None)
         # replay existing trees (continued training)
         for i, tree in enumerate(self.models):
             cls = i % self.num_class
@@ -1468,14 +1482,19 @@ class GBDT:
 
         @obs.instrumented_jit(program="train_step")
         def step_fn(score, feat_masks, row_weight, lr, view):
-            grad, hess = obj_grad(score)
-            ok = (_all_finite(grad, hess) if guard else jnp.asarray(True))
+            with jax.named_scope("gradients"):
+                grad, hess = obj_grad(score)
+                ok = (_all_finite(grad, hess) if guard
+                      else jnp.asarray(True))
             outs = []
             for cls in range(num_class):
                 ta, _, delta = grow(view, num_bin, is_cat, feat_masks[cls],
                                     grad[cls], hess[cls], row_weight, lr)
-                score = score.at[cls].add(delta)
-                outs.append((pack_tree_arrays(ta), ta, delta))
+                with jax.named_scope("score_update"):
+                    score = score.at[cls].add(delta)
+                with jax.named_scope("pack_tree"):
+                    packed = pack_tree_arrays(ta)
+                outs.append((packed, ta, delta))
             return score, outs, ok
         return step_fn
 
@@ -1505,7 +1524,7 @@ class GBDT:
             return
         self._pending_iter = None
         pend_idx, self._pending_iter_idx = self._pending_iter_idx, -1
-        with timetag.scope("GBDT::host_tree"):
+        with obs.span("GBDT::host_tree"):
             host = jax.device_get([packed for packed, _, _ in pend])
         obs.devprof.transfer(
             "d2h", "host_tree",
@@ -1567,11 +1586,17 @@ class GBDT:
         accumulator baseline captured at iteration start (None when the
         serializing TIMETAG mode is off — then only the honest async wall
         time is recorded)."""
+        report = None
         if self._trace is not None:
             self._trace.iter_end(it, sync=self.train_data.score)
+            report = self._trace.take_report()
         rec = self._telemetry
         if rec is None:
             return
+        if report is not None:
+            # the window closed on this round: its reduction to named
+            # device phases (obs/devtrace.py, device_phases.json)
+            rec.note(it, device_phases=report)
         phases = {}
         if tt0 is not None:
             now = timetag.get_timings()
@@ -1773,7 +1798,7 @@ class GBDT:
         tt0 = (timetag.get_timings()
                if rec is not None and timetag.ENABLED else None)
         if self._trace is not None:
-            self._trace.iter_begin(it)
+            self._trace.iter_begin(it, sync=self.train_data.score)
         # The fused step computes gradients INSIDE the jit and never calls
         # the _gradients / _transform_host_gradients hooks, so it only
         # applies when this instance uses the base implementations of ALL
@@ -1815,12 +1840,12 @@ class GBDT:
         cur = []
         if fused:
             # standard objective: ONE device dispatch for the whole round
-            with timetag.scope("GBDT::bagging"):
+            with obs.span("GBDT::bagging"):
                 row_weight = self._bagging_mask(self.iter_)
             if self._train_step is None:
                 self._train_step = self._make_train_step()
             feat_masks = self._feature_masks_all()
-            with timetag.scope("GBDT::tree") as tt:
+            with obs.span("GBDT::tree") as tt:
                 self.train_data.score, outs, gh_ok = self._train_step(
                     self.train_data.score, feat_masks, row_weight, lr_dev,
                     view)
@@ -1840,7 +1865,7 @@ class GBDT:
                     packed, tree_arrays, delta = out[0], out[1], out[2]
                     lin = out[3] if len(out) > 3 else None
                     vdeltas = []
-                    with timetag.scope("GBDT::valid_score") as tt:
+                    with obs.span("GBDT::valid_score") as tt:
                         for dd in self.valid_data:
                             vd = self._device_tree_delta(dd, tree_arrays,
                                                          lin)
@@ -1854,7 +1879,7 @@ class GBDT:
             # LGBT_NO_FUSED_STEP.  Gradients BEFORE the bagging mask:
             # GOSS._gradients draws this round's sample and the mask read
             # must see it (gbdt.cpp Bagging-before-Boosting ordering).
-            with timetag.scope("GBDT::boosting") as tt:
+            with obs.span("GBDT::boosting") as tt:
                 if grad is None or hess is None:
                     grad, hess = self._gradients()
                 else:
@@ -1877,12 +1902,12 @@ class GBDT:
                 # caught BEFORE growing: the poisoned round skips the
                 # whole tree pass, not just its bookkeeping
                 poisoned = "gradients/hessians"
-            with timetag.scope("GBDT::bagging"):
+            with obs.span("GBDT::bagging"):
                 row_weight = self._bagging_mask(self.iter_)
             classes = range(self.num_class) if poisoned is None else ()
             for cls in classes:
                 feat_mask = self._feature_mask()
-                with timetag.scope("GBDT::tree") as tt:
+                with obs.span("GBDT::tree") as tt:
                     tree_arrays, leaf_id, delta = self._grow_fn(
                         view, self.num_bin, self.is_cat,
                         feat_mask, grad[cls], hess[cls], row_weight, lr_dev)
@@ -1892,7 +1917,7 @@ class GBDT:
                     # batched per-leaf affine fit (models/linear.py):
                     # intercepts replace the grown leaf values and the
                     # fitted delta replaces the grower's constant delta
-                    with timetag.scope("Bin::linear_fit") as tt:
+                    with obs.span("Bin::linear_fit") as tt:
                         (tree_arrays, l_coeff, l_feat, delta,
                          l_fb) = _shared_linear_fit(self._linear)(
                             tree_arrays, view.bins, self.is_cat,
@@ -1900,12 +1925,12 @@ class GBDT:
                             row_weight, lr_dev, view.bundle)
                         lin = (l_coeff, l_feat)
                         tt.sync(delta)
-                with timetag.scope("GBDT::train_score") as tt:
+                with obs.span("GBDT::train_score") as tt:
                     self.train_data.score = self._score_add(
                         self.train_data.score, delta, cls, donate)
                     tt.sync(self.train_data.score)
                 vdeltas = []
-                with timetag.scope("GBDT::valid_score") as tt:
+                with obs.span("GBDT::valid_score") as tt:
                     for dd in self.valid_data:
                         vd = self._device_tree_delta(dd, tree_arrays, lin)
                         dd.score = self._score_add(dd.score, vd, cls,
@@ -2213,7 +2238,7 @@ class GBDT:
         cfg = self.config
         out_lines = []
         if cfg.is_training_metric and self.train_metrics:
-            with timetag.scope("GBDT::metric"):
+            with obs.span("GBDT::metric"):
                 score = self.train_data.host_score()
                 for m in self.train_metrics:
                     for name, v in zip(m.names, m.eval(score)):
@@ -2247,7 +2272,7 @@ class GBDT:
 
     def eval_metrics(self) -> Dict[str, Dict[str, float]]:
         """All current metric values, for callbacks/evals_result."""
-        with timetag.scope("GBDT::metric"):
+        with obs.span("GBDT::metric"):
             return self._eval_metrics_impl()
 
     def _eval_metrics_impl(self) -> Dict[str, Dict[str, float]]:

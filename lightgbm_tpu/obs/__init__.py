@@ -2,9 +2,9 @@
 JSONL event stream, collective-traffic accounting, and gated device trace
 capture.
 
-The only prior instrument, ``utils/timetag.py``, must *serialize the async
-pipeline* to attribute device time to a phase — a measurement mode that
-cannot stay on during real runs.  This subsystem is the opposite trade,
+The only prior instrument, the TIMETAG mode (``utils/timetag.py``), must
+*serialize the async pipeline* to attribute device time to a phase — a
+measurement mode that cannot stay on during real runs.  This subsystem is the opposite trade,
 in the spirit of XGBoost's GPU monitor counters (Mitchell & Frank,
 arXiv:1806.11248): cheap host-side bookkeeping that is always on, so
 every optimization round has a before/after phase breakdown instead of
@@ -21,13 +21,21 @@ one end-to-end number.  Pieces:
 - collective-traffic accounting lives on the comm strategies themselves
   (``parallel/comm.py`` ``traffic_per_tree``) — static shape math only,
   nothing added to the jitted path.
-- ``trace``: ``LIGHTGBM_TPU_TRACE_DIR`` (or the ``trace_dir`` config key)
-  wraps a window of boosting iterations in ``jax.profiler`` traces that
-  break down by the ``jax.named_scope`` phases annotated in
-  ``ops/grow.py`` / ``ops/ordered_grow.py``.
-- ``spans``: ``obs.span(name)`` / ``@obs.timed`` — always-on wall-time
-  histograms per phase (``span_series`` maps the ``phases.py`` taxonomy
-  onto metric names).
+- ``trace`` + ``devtrace``: ``LIGHTGBM_TPU_TRACE_DIR`` (or the
+  ``trace_dir`` config key) wraps a window of boosting iterations in a
+  ``jax.profiler`` trace.  The chip's trace carries no
+  ``jax.named_scope`` path, so the program joins the two itself: while
+  the capture is armed ``compile_ledger`` exports each compiled
+  program's ``{instruction -> leaf phase}`` map, and at window close
+  ``devtrace`` reduces the device events by it into
+  ``device_phases.json`` (ms a round per phase of ``phases.py``
+  ROUND_PHASES, compiler-inserted copies under the phase that causes
+  them, idle gaps named by host span).
+- ``spans``: ``obs.span(name)`` / ``@obs.timed`` — the one entry point
+  of a host phase: always-on wall-time histograms (``span_series`` maps
+  the ``phases.py`` taxonomy onto metric names) and a
+  ``jax.profiler.TraceAnnotation("lgbt:<name>")`` on the profiler's
+  clock.
 - ``prom`` + ``metrics_server``: Prometheus text exposition 0.0.4 over
   the registry, served at ``GET /metrics`` by the standalone training
   listener (``metrics_port`` / ``LIGHTGBM_TPU_METRICS_PORT``) and by
@@ -35,10 +43,11 @@ one end-to-end number.  Pieces:
 - ``report``: ``python -m lightgbm_tpu obs-report`` — offline summary
   of an ``--events-file`` stream (per-phase totals, slowest iterations,
   NaN/saturation incidents, collective traffic, eval trajectory), of a
-  compile ledger (``--compile=``), and of trace-event files
-  (``--traces``).
+  compile ledger (``--compile=``), of trace-event files (``--traces``)
+  and of a reduced device window (``--device-trace``).
 - ``compile_ledger``: process-wide account of every XLA compilation —
-  program name, abstract input shapes, wall seconds — captured by
+  program name, abstract input shapes, wall seconds, whether the
+  persistent cache served it — captured by
   ``instrumented_jit`` at the repo's own jit entry points, feeding
   ``compile_count``/``compile_seconds`` registry series and an
   append-only ``compile_ledger.jsonl``
@@ -60,7 +69,7 @@ one end-to-end number.  Pieces:
   (``trace_events_file``/``LIGHTGBM_TPU_TRACE_EVENTS``).
 """
 
-from . import devcaps, devprof, drift  # noqa: F401
+from . import devcaps, devprof, devtrace, drift  # noqa: F401
 from .compile_ledger import (InstrumentedJit, abstract_shapes,  # noqa: F401
                              instrumented_jit)
 from .events import SCHEMA_VERSION, EventRecorder, read_events  # noqa: F401
@@ -112,5 +121,5 @@ __all__ = [
     "instrumented_jit", "InstrumentedJit", "abstract_shapes",
     "TRACER", "trace_span", "trace_begin", "trace_end", "trace_link",
     "HOST_PHASES", "DEVICE_PHASES", "DEVICE_PARENT", "JITTED_HOST_PHASES",
-    "TRANSFER_PHASES", "devprof", "devcaps", "drift",
+    "TRANSFER_PHASES", "devprof", "devcaps", "devtrace", "drift",
 ]

@@ -24,6 +24,17 @@ This module is that account:
   names a path — to an append-only JSONL file, one line per compile,
   crash-safe by construction (each line is flushed as it happens).
 
+- every event says whether the persistent compilation cache served it
+  (``cache_hit``, from jax's own monitoring events), so cold and warm
+  compile seconds separate.
+- while an ``obs.TraceCapture`` is armed (``trace_dir``), a compile
+  event — or the first dispatch inside the window of a program compiled
+  earlier — also exports the program's PHASE MAP: the compiled text
+  parsed into ``{instruction name -> leaf phase}`` (obs/devtrace.py
+  ``phase_map``), written to ``<trace_dir>/phase_map.<program>.json``,
+  with ``ops_scoped`` / ``ops_unscoped`` added to the entry.  With no
+  capture armed nothing is lowered twice and nothing is parsed.
+
 Calls made while another jit is tracing are passed straight through
 (``jax.core.trace_state_clean``): an inner jit inlined into an outer
 trace is not a compilation of its own, and instrumenting it there would
@@ -40,7 +51,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
-from . import devprof, registry
+from . import devprof, devtrace, registry, trace
 
 ENV_PATH = "LIGHTGBM_TPU_COMPILE_LEDGER"
 
@@ -52,6 +63,25 @@ _lock = threading.Lock()
 _events: List[Dict[str, Any]] = []
 _dropped = 0
 _path: Optional[str] = os.environ.get(ENV_PATH, "").strip() or None
+
+# persistent-cache hits seen by jax's monitoring events; the listener is
+# registered at the first instrumented dispatch, not at import
+_cache_hits = 0
+_listening = False
+
+
+def _count_cache_hits() -> int:
+    global _listening
+    if not _listening:
+        import jax
+
+        def on_event(event: str, **_kw) -> None:
+            global _cache_hits
+            if event == "/jax/compilation_cache/cache_hits":
+                _cache_hits += 1
+        jax.monitoring.register_event_listener(on_event)
+        _listening = True
+    return _cache_hits
 
 
 def configure(path: Optional[str] = None) -> Optional[str]:
@@ -113,13 +143,18 @@ def summary(k: int = 5) -> Dict[str, Any]:
 
 
 def record(program: str, shapes: str, seconds: float,
-           cost: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
+           cost: Optional[Dict[str, Any]] = None,
+           cache_hit: Optional[bool] = None,
+           phase_map: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
     """Append one compile event; feeds the registry series and the JSONL
     sink.  Called by the instrumented jits — safe to call directly for
     compilations detected by other means.  ``cost`` is the program's
     static cost-analysis row (``_cost_analysis``); the three fields are
     present on every event — None when profiling was off or the backend
-    reported nothing — so ledger consumers see one schema."""
+    reported nothing — so ledger consumers see one schema.
+    ``cache_hit``: the persistent cache served the executable (None:
+    not known).  ``phase_map``: the counts of ``_export_phase_map``,
+    present only when a map was built."""
     global _dropped
     registry.inc("compile_count")
     registry.inc("compile_count_" + _sanitize(program))
@@ -133,7 +168,10 @@ def record(program: str, shapes: str, seconds: float,
         "flops": cost.get("flops"),
         "bytes_accessed": cost.get("bytes_accessed"),
         "output_bytes": cost.get("output_bytes"),
+        "cache_hit": cache_hit,
     }
+    if phase_map:
+        ev.update(phase_map)
     with _lock:
         ev["count"] = registry.get_counter("compile_count")
         if len(_events) < MAX_EVENTS:
@@ -202,11 +240,13 @@ def abstract_shapes(args: tuple, kwargs: Optional[dict] = None,
 def _in_trace() -> bool:
     """True while another jit is tracing this call (inner jits inline —
     not a compilation of their own)."""
-    import jax
     try:
-        return not jax.core.trace_state_clean()
-    except Exception:  # pragma: no cover - jax internals moved
-        return False
+        # jax 0.9 took trace_state_clean out of jax.core; without it every
+        # inner jit read as a top-level dispatch
+        from jax._src.core import trace_state_clean
+    except ImportError:  # pragma: no cover - jax internals moved
+        from jax.core import trace_state_clean
+    return not trace_state_clean()
 
 
 def _cost_analysis(fn, args: tuple,
@@ -240,6 +280,65 @@ def _cost_analysis(fn, args: tuple,
                               "bytes_accessed_output"),
     }
     return out if any(v is not None for v in out.values()) else None
+
+
+def _shape_structs(args: tuple, kwargs: dict):
+    """The call's arguments with every device array replaced by its
+    ``ShapeDtypeStruct``, taken BEFORE the call: the step donates its
+    score, and a donated array cannot be lowered from afterwards."""
+    import jax
+
+    def one(x):
+        if isinstance(x, jax.Array):
+            return jax.ShapeDtypeStruct(
+                x.shape, x.dtype, weak_type=x.weak_type,
+                sharding=x.sharding if x.committed else None)
+        return x
+    return jax.tree_util.tree_map(one, (args, kwargs))
+
+
+def _export_phase_map(fn, structs, capture,
+                      program: str) -> Optional[Dict[str, Any]]:
+    """Lower and AOT-compile ``fn`` at ``structs`` (served by the
+    persistent cache where there is one), parse the compiled text into
+    the program's phase map and hand it to the armed capture.  Returns
+    the counts for the ledger entry; None (one warning) when the backend
+    gives no text.
+
+    The persistent cache's key leaves debug info out, so it may serve an
+    executable compiled from the same operations under OTHER scopes: its
+    ``op_name``s are then not this program's.  The phases that only the
+    lowered module or only the compiled text holds are listed as
+    ``stale_phases`` and warned about (``devtrace.stale_phases``)."""
+    t0 = time.perf_counter()
+    try:
+        sargs, skwargs = structs
+        lowered = fn.lower(*sargs, **skwargs)
+        text = lowered.compile().as_text()
+        pm = devtrace.phase_map(text)
+        pm["stale_phases"] = devtrace.stale_phases(
+            text, lowered.as_text(debug_info=True))
+        if pm["stale_phases"]:
+            from ..utils import log
+            log.warning(
+                "phase map of %s: the compiled text and the program "
+                "disagree on phases %s: the persistent compilation cache "
+                "served an executable compiled under other scopes (its "
+                "key leaves metadata out); remove the entry, or run once "
+                "with JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY=1",
+                program, pm["stale_phases"])
+        capture.write_map(program, pm)
+    except Exception as e:
+        from ..utils import log
+        capture.write_map(program, None)      # tried: not again per call
+        log.warn_once("phase_map_" + program,
+                      "no phase map for program %s: %s: %s", program,
+                      type(e).__name__, e)
+        return None
+    return {"ops_scoped": pm["ops_scoped"],
+            "ops_unscoped": pm["ops_unscoped"],
+            "stale_phases": pm["stale_phases"],
+            "phase_map_s": round(time.perf_counter() - t0, 3)}
 
 
 class InstrumentedJit:
@@ -297,11 +396,18 @@ class InstrumentedJit:
         ledger event when the call compiled."""
         if _in_trace():
             return self._call_guarded(*args, **kwargs), False
+        capture = trace.armed()       # None unless trace_dir is set
+        structs = (_shape_structs(args, kwargs)
+                   if capture is not None else None)
         before = self._cache_size()
+        hits = _count_cache_hits()
         t0 = time.perf_counter()
         out = self._call_guarded(*args, **kwargs)
         dt = time.perf_counter() - t0
         compiled = self._cache_size() > before
+        pm = None
+        if capture is not None and capture.wants_map(self.program, compiled):
+            pm = _export_phase_map(self._fn, structs, capture, self.program)
         if compiled:
             cost = None
             if devprof.ENABLED:
@@ -309,7 +415,7 @@ class InstrumentedJit:
                 if cost:
                     devprof.note_cost(self.program, cost)
             record(self.program, abstract_shapes(args, kwargs), dt,
-                   cost=cost)
+                   cost=cost, cache_hit=_cache_hits > hits, phase_map=pm)
         return out, compiled
 
     def __call__(self, *args, **kwargs):

@@ -4,14 +4,27 @@
 Two taxonomies exist because the host and the device see different
 boundaries:
 
-- HOST_PHASES are ``timetag.scope("...")`` names: host wall-clock phases
-  of one boosting round, the reference's TIMETAG taxonomy
+- HOST_PHASES are ``obs.span("...")`` names: host wall-clock phases of
+  set-up and of one boosting round, the reference's TIMETAG taxonomy
   (gbdt.cpp:20-59 boosting/train_score/valid_score/metric/bagging/tree
-  plus the TPU port's host_tree materialization phase).
+  plus the TPU port's host_tree materialization phase and the binning
+  phases of ``Dataset.construct``).  Each also enters a
+  ``jax.profiler.TraceAnnotation("lgbt:" + name)`` (obs/spans.py), so a
+  profiler window holds them on the device trace's clock.
 - DEVICE_PHASES are ``jax.named_scope("...")`` names inside the jitted
-  growers (ops/grow.py, ops/ordered_grow.py), the reference's
-  serial_tree_learner.cpp:10-37 taxonomy (hist/find_split/split).  A
-  device trace captured via LIGHTGBM_TPU_TRACE_DIR groups ops by these.
+  programs.  The fused round (models/gbdt.py ``train_step`` over
+  ops/ordered_grow.py) is covered whole: every operation traced into it
+  sits under exactly one LEAF phase, the innermost declared scope of its
+  ``op_name`` (``leaf_phase`` below).  The chip's trace names a device
+  event by its HLO text and carries no scope path, so the program joins
+  the two itself: ``obs/compile_ledger.py`` exports
+  ``{instruction -> leaf phase}`` from the compiled text and
+  ``obs/devtrace.py`` reduces a profiler window by it.  ops/grow.py
+  keeps the reference's serial_tree_learner.cpp:10-37 three
+  (hist/find_split/split).
+- ROUND_PHASES is the top level of the fused round's taxonomy, in
+  program order.  PERF.md names metrics after these: a rename is a
+  ``benchmark`` issue's.
 - DEVICE_PARENT maps each device phase to the host phase whose dispatch
   contains it, so trace time can be attributed back to the host account.
 - JITTED_HOST_PHASES are the host phases whose time is device work; each
@@ -27,6 +40,14 @@ HOST_PHASES = frozenset({
     "Bin::linear_fit",    # per-stage batched leaf ridge solve
                           # (models/linear.py, docs/LINEAR_TREES.md;
                           # the fused path folds it into GBDT::tree)
+    # set-up: Dataset.construct (io/dataset.py) and the device upload
+    "Bin::sample",        # the bin-construct row sample and its columns
+    "Bin::find_bin",      # FindBin over the sample, feature by feature
+    "Bin::apply",         # raw matrix -> bin indices (float64 widening
+                          # of the matrix included)
+    "Bin::fingerprint",   # drift fingerprint (obs/drift.py): missing
+                          # rates and a second, exact binning of all rows
+    "Dataset::to_device",  # binned matrix, labels, word packing up
     "GBDT::iteration",    # whole boosting round (obs.span, always on)
     "GBDT::boosting",
     "GBDT::bagging",
@@ -60,9 +81,34 @@ HOST_PHASES = frozenset({
                           # the response path
 })
 
-DEVICE_PHASES = frozenset({
+# The fused round, top level, in program order (models/gbdt.py
+# _build_shared_train_step, ops/ordered_grow.py, ops/leafhist.py).
+ROUND_PHASES = (
+    "gradients",          # objective gradients, weighting, root sums
+    "layout",             # digit quantisation, word packing, the
+                          # compaction sort
+    "hist/root",          # root pass: combine, cache seed (the kernel
+                          # itself is hist/kernel, its feed hist/window)
+    "grow_loop",          # the fori_loop itself, best-leaf pick, the
+                          # size-class switch
+    "split/window_read",  # dynamic slices of the parent's window
+    "split/key",          # split column pick, go-right key, counts
+    "split/sort",         # the stable segment sort
+    "split/window_write",  # sorted window written back in place
+    "hist/window",        # slices and unpacking that feed the kernel
+    "hist/kernel",        # digit_histogram (Pallas) or its scatter twin
+    "hist/subtract",      # sibling subtraction and the cache update
+    "find_split",         # root and children
+    "leaf_table",         # node and leaf rows, TreeArrays
+    "leaf_delta",         # segment-to-leaf sort, searchsorted, scatter
+                          # back to row order, the out-of-bag walk
+    "score_update",
+    "pack_tree",
+)
+
+DEVICE_PHASES = frozenset(ROUND_PHASES) | frozenset({
+    # ops/grow.py (cached and parallel learners): the reference's three
     "hist",
-    "find_split",
     "split",
     # CompiledForest fused inference program (serve/forest.py)
     "bin_lookup",
@@ -74,8 +120,8 @@ DEVICE_PHASES = frozenset({
 })
 
 DEVICE_PARENT = {
+    **{p: "GBDT::tree" for p in ROUND_PHASES},
     "hist": "GBDT::tree",
-    "find_split": "GBDT::tree",
     "split": "GBDT::tree",
     "bin_lookup": "Predict::forest",
     "forest_walk": "Predict::forest",
@@ -98,6 +144,23 @@ TRANSFER_PHASES = frozenset({
     "forest",      # CompiledForest build / to_device weight placement
     "serve",       # serve-path request payloads (batcher/forest calls)
 })
+
+
+def leaf_phase(op_name):
+    """The leaf phase of an HLO ``op_name`` path
+    (``jit(step_fn)/grow_loop/while/body/.../split/sort/sort``): the
+    INNERMOST declared device phase on the path, the longer name where
+    two start at the same component (``hist/kernel`` over ``hist``).
+    The last component is the primitive and never a scope.  None when no
+    declared phase is on the path."""
+    path = "/" + str(op_name).rpartition("/")[0] + "/"
+    best, best_at = None, -1
+    for phase in DEVICE_PHASES:
+        at = path.rfind("/" + phase + "/")
+        if at > best_at or (at == best_at and at >= 0
+                            and len(phase) > len(best)):
+            best, best_at = phase, at
+    return best
 
 
 def sanitize(name):

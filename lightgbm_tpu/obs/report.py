@@ -36,6 +36,11 @@ Two sibling inputs ride the same CLI (docs/OBSERVABILITY.md):
   programs by estimated device seconds with roofline %, H2D/D2H bytes
   per phase, forced-sync cost (docs/OBSERVABILITY.md §Device-time
   attribution);
+- ``--device-trace <trace_dir>`` prints the ``device_phases.json`` that
+  ``obs/devtrace.py`` wrote when a ``trace_dir`` window closed (reducing
+  the directory's newest trace afresh when the file is not there): ms a
+  round per named device phase, inserted copies, unattributed events,
+  idle gaps by host span (docs/OBSERVABILITY.md §Device trace capture);
 - ``--drift`` prints the drift observatory's per-model offender table
   (PSI / missing-rate delta per feature, score PSI, window trajectory,
   sustained offenders).  Positional files may be registry-snapshot JSON
@@ -47,6 +52,7 @@ Two sibling inputs ride the same CLI (docs/OBSERVABILITY.md):
 from __future__ import annotations
 
 import json
+import os
 import sys
 from typing import Any, Dict, List, Optional, Sequence
 
@@ -603,11 +609,27 @@ def render_profile_table(rep: Dict[str, Any]) -> str:
     return "\n".join(out)
 
 
+def device_trace_report(trace_dir: str) -> Dict[str, Any]:
+    """``--device-trace``: the window's ``device_phases.json``, or a
+    fresh reduction of the directory's newest trace by the phase maps
+    beside it (rounds unknown: the whole window counts as one)."""
+    from . import devtrace
+    path = os.path.join(trace_dir, devtrace.REPORT_FILE)
+    if os.path.exists(path):
+        with open(path) as fh:
+            return json.load(fh)
+    rep = devtrace.reduce_dir(trace_dir)
+    if rep is None:
+        raise ValueError(f"no profiler trace under {trace_dir}")
+    return rep
+
+
 def main(argv: Optional[Sequence[str]] = None) -> int:
     """CLI entry: ``python -m lightgbm_tpu obs-report <events.jsonl ...>
     [--format=json|table] [--top=K] [--compile=<ledger.jsonl>]``,
     ``obs-report --traces <trace.json ...>``,
-    ``obs-report --profile [<registry_snapshot.json ...>]``, or
+    ``obs-report --profile [<registry_snapshot.json ...>]``,
+    ``obs-report --device-trace <trace_dir>``, or
     ``obs-report --drift [<snapshot_or_drift_stats.json ...>]``."""
     argv = list(sys.argv[1:] if argv is None else argv)
     fmt = "table"
@@ -616,6 +638,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     traces_mode = False
     profile_mode = False
     drift_mode = False
+    device_trace_mode = False
     paths: List[str] = []
     for tok in argv:
         if tok.startswith("--format="):
@@ -635,6 +658,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             profile_mode = True
         elif tok == "--drift":
             drift_mode = True
+        elif tok == "--device-trace":
+            device_trace_mode = True
         elif tok.startswith("-"):
             print(f"obs-report: unknown flag {tok!r}", file=sys.stderr)
             return 2
@@ -649,6 +674,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
               "       python -m lightgbm_tpu obs-report --profile "
               "[<registry_snapshot.json ...>] [--format=json|table] "
               "[--top=K]\n"
+              "       python -m lightgbm_tpu obs-report --device-trace "
+              "<trace_dir> [--format=json|table]\n"
               "       python -m lightgbm_tpu obs-report --drift "
               "[<snapshot_or_drift_stats.json ...>] "
               "[--format=json|table] [--top=K]",
@@ -659,7 +686,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
               file=sys.stderr)
         return 2
     try:
-        if drift_mode:
+        if device_trace_mode:
+            rep = device_trace_report(paths[0])
+        elif drift_mode:
             rep = drift_summary_from_files(paths, top_k=top_k)
         elif profile_mode:
             rep = profile_summary_from_files(paths, top_k=top_k)
@@ -675,6 +704,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         return 1
     if fmt == "json":
         print(json.dumps(rep, indent=2, sort_keys=True))
+    elif device_trace_mode:
+        from . import devtrace
+        print(devtrace.render(rep))
     elif drift_mode:
         print(render_drift_table(rep))
     elif profile_mode:

@@ -1,21 +1,24 @@
 """Unified wall-time spans feeding per-phase histograms.
 
-``span(name)`` is the always-on timer the metrics pipeline is built on:
+``span(name)`` is the always-on timer the metrics pipeline is built on,
+and the ONE place a host phase (obs/phases.py HOST_PHASES) is entered:
 it measures host wall clock between enter and exit and lands ONE
 histogram observe in the process registry under the series name
 ``phases.span_series(name)`` (``GBDT::tree`` ->
-``phase_seconds_gbdt_tree``).  Unlike ``utils/timetag.scope`` it never
-blocks on device values by default, so it can stay on in production —
-for async dispatches it honestly measures dispatch time, and the device
-side remains the trace capture's job.  The two instruments are unified:
+``phase_seconds_gbdt_tree``).  It never blocks on device values by
+default, so it can stay on in production — for async dispatches it
+honestly measures dispatch time, and the device side remains the trace
+capture's job.  Its other sinks:
 
+- the profiler's clock: every span enters a
+  ``jax.profiler.TraceAnnotation("lgbt:" + name)``, so a profiler window
+  (obs/trace.py) holds the host phases on the device trace's timeline
+  and ``obs/devtrace.py`` names each device idle gap by the span that
+  covers its start.  One enter and exit; a no-op outside a window.
 - when LIGHTGBM_TPU_TIMETAG is enabled, a span ALSO feeds the timetag
   accumulator for ``name`` (one account, two sinks) and honors
-  ``sync(x)`` requests exactly like ``timetag.scope`` — the serializing
-  measurement mode attributes device time to the span's phase;
-- ``timetag.scope`` itself mirrors every enabled measurement into the
-  same histogram series, so non-migrated scope sites populate the
-  distribution too.
+  ``sync(x)`` requests — the serializing measurement mode attributes
+  device time to the span's phase (utils/timetag.py keeps the account).
 
 ``timed(name)`` wraps a function in a span — decorator sugar for
 hot-path-free helpers (model export, report generation).
@@ -40,7 +43,7 @@ import time
 from contextlib import contextmanager
 from typing import Callable, Optional, Sequence
 
-from . import devprof, memwatch, phases, registry, tracing
+from . import devprof, devtrace, memwatch, phases, registry, tracing
 
 
 # span names are a small fixed set (the phase taxonomy); memoize the
@@ -58,7 +61,8 @@ def _series(name: str) -> str:
 class _SpanHandle:
     """Yielded by ``span``: ``sync(x)`` registers device values to block
     on before the clock stops — honored only under the serializing
-    TIMETAG mode, so production spans never force a host sync.
+    TIMETAG mode, so production spans never force a host sync (the
+    handle, and its reference to the value, die with the span).
     ``trace`` is the causal-tracing span handle (None unless the tracer
     is armed, obs/tracing.py)."""
 
@@ -76,7 +80,9 @@ class _SpanHandle:
 def span(name: str, buckets: Optional[Sequence[float]] = None,
          reg: Optional[registry.Registry] = None):
     """Time this block into the ``span_series(name)`` wall-time
-    histogram (and the timetag accumulator when that mode is on)."""
+    histogram, the profiler's trace (``lgbt:<name>``) and the timetag
+    accumulator when that mode is on."""
+    import jax
     from ..utils import timetag
     r = reg if reg is not None else registry.REGISTRY
     handle = _SpanHandle()
@@ -87,7 +93,8 @@ def span(name: str, buckets: Optional[Sequence[float]] = None,
         token = tracing.push(handle.trace)
     t0 = time.perf_counter()
     try:
-        yield handle
+        with jax.profiler.TraceAnnotation(devtrace.HOST_SPAN_PREFIX + name):
+            yield handle
     finally:
         if serialize and handle.value is not None:
             # counted sync (obs/devprof.py): the serializing TIMETAG

@@ -140,22 +140,27 @@ def digit_histogram_pallas(bins_rm, digits, max_bin: int, n_blk: int = 8192,
     S, F = bins_rm.shape
     B = -(-max_bin // 128) * 128
     nb = min(n_blk, S) if S % n_blk else n_blk
-    if S % nb:
-        pad = (-S) % nb
-        bins_rm = jnp.pad(bins_rm, ((0, pad), (0, 0)))
-        digits = jnp.pad(digits, ((0, pad), (0, 0)))
-        S += pad
-    out = pl.pallas_call(
-        functools.partial(_digit_hist_kernel, nb=nb, f_blk=F, bb=B),
-        grid=(S // nb,),
-        in_specs=[pl.BlockSpec((nb, F), lambda i: (i, 0)),
-                  pl.BlockSpec((nb, 9), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((F, 9, B), lambda i: (0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((F, 9, B), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((F, 9, B), jnp.int32)],
-        interpret=interpret,
-    )(bins_rm, digits)
-    return out[:, :, :max_bin]
+    with jax.named_scope("hist/kernel"):
+        if S % nb:
+            pad = (-S) % nb
+            bins_rm = jnp.pad(bins_rm, ((0, pad), (0, 0)))
+            digits = jnp.pad(digits, ((0, pad), (0, 0)))
+            S += pad
+        out = pl.pallas_call(
+            functools.partial(_digit_hist_kernel, nb=nb, f_blk=F, bb=B),
+            grid=(S // nb,),
+            in_specs=[pl.BlockSpec((nb, F), lambda i: (i, 0)),
+                      pl.BlockSpec((nb, 9), lambda i: (i, 0))],
+            out_specs=pl.BlockSpec((F, 9, B), lambda i: (0, 0, 0)),
+            out_shape=jax.ShapeDtypeStruct((F, 9, B), jnp.int32),
+            scratch_shapes=[pltpu.VMEM((F, 9, B), jnp.int32)],
+            interpret=interpret,
+            # a device event of the kernel reads %digit_histogram.N
+            # whatever function or lax.switch branch traced the call
+            # (obs/devtrace.py; benchmarks hist_ms_per_round)
+            name="digit_histogram",
+        )(bins_rm, digits)
+        return out[:, :, :max_bin]
 
 
 def digit_histogram_scatter(bins_rm, digits, max_bin: int):
@@ -163,13 +168,14 @@ def digit_histogram_scatter(bins_rm, digits, max_bin: int):
     by (feature, bin) accumulating the 9 digit streams in int32."""
     S, F = bins_rm.shape
     B = max_bin
-    feat = jnp.arange(F, dtype=jnp.int32)[None, :]             # [1, F]
-    seg = feat * B + bins_rm.astype(jnp.int32)                 # [S, F]
-    out = jnp.zeros((F * B, 9), jnp.int32)
-    vals = jnp.broadcast_to(digits.astype(jnp.int32)[:, None, :],
-                            (S, F, 9)).reshape(-1, 9)
-    out = out.at[seg.reshape(-1)].add(vals, mode="drop")
-    return out.reshape(F, B, 9).transpose(0, 2, 1)             # [F, 9, B]
+    with jax.named_scope("hist/kernel"):
+        feat = jnp.arange(F, dtype=jnp.int32)[None, :]         # [1, F]
+        seg = feat * B + bins_rm.astype(jnp.int32)             # [S, F]
+        out = jnp.zeros((F * B, 9), jnp.int32)
+        vals = jnp.broadcast_to(digits.astype(jnp.int32)[:, None, :],
+                                (S, F, 9)).reshape(-1, 9)
+        out = out.at[seg.reshape(-1)].add(vals, mode="drop")
+        return out.reshape(F, B, 9).transpose(0, 2, 1)         # [F, 9, B]
 
 
 def digit_histogram(bins_rm, digits, max_bin: int):

@@ -148,35 +148,43 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
     F, N = bins.shape
     sp = params.split_params()
 
-    if bins_rm is None:
-        bins_rm = bins.T
-    if bins_words is None:
-        bins_words = pack_u8_words(bins_rm)
+    # Every operation below sits under exactly one leaf phase of
+    # obs/phases.py ROUND_PHASES (the innermost scope wins): the scopes
+    # are metadata only, and obs/compile_ledger.py joins them to the
+    # chip's device events through the compiled text.
+    with jax.named_scope("layout"):
+        if bins_rm is None:
+            bins_rm = bins.T
+        if bins_words is None:
+            bins_words = pack_u8_words(bins_rm)
 
-    g = grad * row_weight
-    h = hess * row_weight
+    with jax.named_scope("gradients"):
+        g = grad * row_weight
+        h = hess * row_weight
 
-    root_g = jnp.sum(g)
-    root_h = jnp.sum(h)
-    root_c = jnp.sum(row_weight)
-
-    scales = leafhist.compute_scales(g, h, row_weight)
-    digits = leafhist.quantize_digits(g, h, row_weight, scales)  # [N, 9] i8
+        root_g = jnp.sum(g)
+        root_h = jnp.sum(h)
+        root_c = jnp.sum(row_weight)
 
     classes = _size_classes(N)
     PAD = classes[-1]          # windows may overrun the last segment
     W = len(bins_words)
 
-    # callers (GBDT._DeviceData) pre-pad the shared bin words once per
-    # dataset; pad here only when handed bare [N] words
-    bins_w = tuple(bw if bw.shape[0] >= N + PAD
-                   else jnp.pad(bw, (0, N + PAD - bw.shape[0]))
-                   for bw in bins_words)
-    root_cnt = jnp.int32(N)
-    dig_w = tuple(jnp.pad(dw, (0, PAD)) for dw in pack_u8_words(
-        jax.lax.bitcast_convert_type(digits, jnp.uint8)))
-    DW = len(dig_w)
-    row_ord = jnp.pad(jnp.arange(N, dtype=jnp.int32), (0, PAD))
+    with jax.named_scope("layout"):
+        scales = leafhist.compute_scales(g, h, row_weight)
+        digits = leafhist.quantize_digits(g, h, row_weight,
+                                          scales)       # [N, 9] i8
+
+        # callers (GBDT._DeviceData) pre-pad the shared bin words once
+        # per dataset; pad here only when handed bare [N] words
+        bins_w = tuple(bw if bw.shape[0] >= N + PAD
+                       else jnp.pad(bw, (0, N + PAD - bw.shape[0]))
+                       for bw in bins_words)
+        root_cnt = jnp.int32(N)
+        dig_w = tuple(jnp.pad(dw, (0, PAD)) for dw in pack_u8_words(
+            jax.lax.bitcast_convert_type(digits, jnp.uint8)))
+        DW = len(dig_w)
+        row_ord = jnp.pad(jnp.arange(N, dtype=jnp.int32), (0, PAD))
 
     if params.compact_inactive:
         # one stable sort per tree (over the REAL N rows only — the
@@ -184,36 +192,41 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
         # segment: every later window, partition sort, and histogram then
         # costs O(subsample), not O(N) — the reference's bag-subset
         # dataset switch (gbdt.cpp:271-278)
-        bag_key = (row_weight <= 0.0).astype(jnp.uint8)
-        ops0 = (bag_key,) + tuple(w[:N] for w in bins_w) \
-            + tuple(w[:N] for w in dig_w) + (row_ord[:N],)
-        sorted0 = jax.lax.sort(ops0, num_keys=1, is_stable=True)
+        with jax.named_scope("layout"):
+            bag_key = (row_weight <= 0.0).astype(jnp.uint8)
+            ops0 = (bag_key,) + tuple(w[:N] for w in bins_w) \
+                + tuple(w[:N] for w in dig_w) + (row_ord[:N],)
+            sorted0 = jax.lax.sort(ops0, num_keys=1, is_stable=True)
 
-        def _splice(full, head):
-            return jax.lax.dynamic_update_slice(full, head, (0,))
-        bins_w = tuple(_splice(f, h)
-                       for f, h in zip(bins_w, sorted0[1:1 + W]))
-        dig_w = tuple(_splice(f, h)
-                      for f, h in zip(dig_w, sorted0[1 + W:1 + W + DW]))
-        row_ord = _splice(row_ord, sorted0[-1])
-        root_cnt = jnp.sum((row_weight > 0.0).astype(jnp.int32))
+            def _splice(full, head):
+                return jax.lax.dynamic_update_slice(full, head, (0,))
+            bins_w = tuple(_splice(f, h)
+                           for f, h in zip(bins_w, sorted0[1:1 + W]))
+            dig_w = tuple(_splice(f, h)
+                          for f, h in zip(dig_w,
+                                          sorted0[1 + W:1 + W + DW]))
+            row_ord = _splice(row_ord, sorted0[-1])
+            root_cnt = jnp.sum((row_weight > 0.0).astype(jnp.int32))
 
     def hist_window(bw_tuple, dw_tuple, off, scnt, Psz: int):
         """[F, 9, B] digit sums over rows [off, off+Psz) of the packed
         layout, digit streams masked to the first scnt rows.  The ONE
         histogram formulation every call site shares (per-split child
         windows and the compacted root)."""
-        ch_bins = _unpack_words(
-            tuple(jax.lax.dynamic_slice(bw, (off,), (Psz,))
-                  for bw in bw_tuple), F)
-        ch_dig = jax.lax.bitcast_convert_type(
-            jax.lax.bitcast_convert_type(
-                jnp.stack(
-                    tuple(jax.lax.dynamic_slice(dw, (off,), (Psz,))
-                          for dw in dw_tuple), axis=1),
-                jnp.uint8).reshape(Psz, -1)[:, :9], jnp.int8)
-        ch_dig = jnp.where(
-            jnp.arange(Psz, dtype=jnp.int32)[:, None] < scnt, ch_dig, 0)
+        with jax.named_scope("hist/window"):
+            ch_bins = _unpack_words(
+                tuple(jax.lax.dynamic_slice(bw, (off,), (Psz,))
+                      for bw in bw_tuple), F)
+            ch_dig = jax.lax.bitcast_convert_type(
+                jax.lax.bitcast_convert_type(
+                    jnp.stack(
+                        tuple(jax.lax.dynamic_slice(dw, (off,), (Psz,))
+                              for dw in dw_tuple), axis=1),
+                    jnp.uint8).reshape(Psz, -1)[:, :9], jnp.int8)
+            ch_dig = jnp.where(
+                jnp.arange(Psz, dtype=jnp.int32)[:, None] < scnt,
+                ch_dig, 0)
+        # the kernel scopes itself (hist/kernel, ops/leafhist.py)
         if device.on_tpu():
             return leafhist.digit_histogram_pallas(ch_bins, ch_dig, B)
         return leafhist.digit_histogram_scatter(ch_bins, ch_dig, B)
@@ -227,47 +240,52 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
                           .astype(jnp.int32), len(hbs) - 1)
         return jax.lax.switch(cls, hbs, (off, scnt))
 
-    if params.compact_inactive:
-        # root histogram over the compacted ACTIVE prefix: cost tracks
-        # the subsample (inactive rows have zero digits either way)
-        sums_root = windowed_hist(jnp.int32(0), root_cnt)
-    else:
-        # root histogram over the initial (original-order) layout
-        sums_root = leafhist.digit_histogram(bins_rm, digits, B)
-    hist_root = leafhist.combine_digit_sums(sums_root, scales)
-    root_split = find_best_split(hist_root, root_g, root_h, root_c,
-                                 num_bin, is_cat, feat_mask,
-                                 jnp.asarray(True), sp)
-    cache = jnp.zeros((L, F, 9, B), jnp.int32).at[0].set(sums_root)
+    with jax.named_scope("hist/root"):
+        if params.compact_inactive:
+            # root histogram over the compacted ACTIVE prefix: cost
+            # tracks the subsample (inactive rows have zero digits
+            # either way)
+            sums_root = windowed_hist(jnp.int32(0), root_cnt)
+        else:
+            # root histogram over the initial (original-order) layout
+            sums_root = leafhist.digit_histogram(bins_rm, digits, B)
+        hist_root = leafhist.combine_digit_sums(sums_root, scales)
+    with jax.named_scope("find_split"):
+        root_split = find_best_split(hist_root, root_g, root_h, root_c,
+                                     num_bin, is_cat, feat_mask,
+                                     jnp.asarray(True), sp)
+    with jax.named_scope("hist/root"):
+        cache = jnp.zeros((L, F, 9, B), jnp.int32).at[0].set(sums_root)
 
-    root_f32 = jnp.stack([
-        root_split.gain, root_split.left_sum_g, root_split.left_sum_h,
-        root_split.left_count, root_g, root_h, root_c,
-        jnp.float32(0.0)])
-    leaf_f32 = jnp.full((L, 8), K_MIN_SCORE, jnp.float32) \
-        .at[:, 1:].set(0.0).at[0].set(root_f32)
-    root_i32 = jnp.array([0, 0, -1, 0, 0, 0, 0, 0], jnp.int32) \
-        .at[_LI["best_feat"]].set(root_split.feature) \
-        .at[_LI["best_bin"]].set(root_split.threshold) \
-        .at[_LI["cnt"]].set(root_cnt)
-    leaf_i32 = jnp.zeros((L, 8), jnp.int32) \
-        .at[:, _LI["parent"]].set(-1).at[0].set(root_i32)
-    empty_node = jnp.zeros((8,), jnp.int32).at[_ND["feature"]].set(-1)
-    node_i32 = jnp.broadcast_to(empty_node, (L - 1, 8))
+    with jax.named_scope("leaf_table"):
+        root_f32 = jnp.stack([
+            root_split.gain, root_split.left_sum_g, root_split.left_sum_h,
+            root_split.left_count, root_g, root_h, root_c,
+            jnp.float32(0.0)])
+        leaf_f32 = jnp.full((L, 8), K_MIN_SCORE, jnp.float32) \
+            .at[:, 1:].set(0.0).at[0].set(root_f32)
+        root_i32 = jnp.array([0, 0, -1, 0, 0, 0, 0, 0], jnp.int32) \
+            .at[_LI["best_feat"]].set(root_split.feature) \
+            .at[_LI["best_bin"]].set(root_split.threshold) \
+            .at[_LI["cnt"]].set(root_cnt)
+        leaf_i32 = jnp.zeros((L, 8), jnp.int32) \
+            .at[:, _LI["parent"]].set(-1).at[0].set(root_i32)
+        empty_node = jnp.zeros((8,), jnp.int32).at[_ND["feature"]].set(-1)
+        node_i32 = jnp.broadcast_to(empty_node, (L - 1, 8))
 
     def make_branch(P: int):
         def branch(ops):
             (bins_w, dig_w, row_ord, s, c, feat, tbin, cat, do_split) = ops
-            # TIMETAG phase names (serial_tree_learner.cpp:10-37) as trace
-            # annotations, mirroring ops/grow.py's cached learner: device
-            # traces captured via LIGHTGBM_TPU_TRACE_DIR group by these.
-            with jax.named_scope("split"):
+            # the reference's split phase (serial_tree_learner.cpp:
+            # 10-37), divided where its device time divides
+            with jax.named_scope("split/window_read"):
                 win_b = tuple(jax.lax.dynamic_slice(bw, (s,), (P,))
                               for bw in bins_w)
                 win_d = tuple(jax.lax.dynamic_slice(dw, (s,), (P,))
                               for dw in dig_w)
                 win_r = jax.lax.dynamic_slice(row_ord, (s,), (P,))
 
+            with jax.named_scope("split/key"):
                 word = feat // 4
                 byte = feat % 4
                 # dynamic word pick as a select chain (a lax.switch here
@@ -285,6 +303,7 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
                 key = jnp.where(do_split & inseg,
                                 go_r.astype(jnp.uint8), jnp.uint8(2))
 
+            with jax.named_scope("split/sort"):
                 operands = (key,) + win_b + win_d + (win_r,)
                 sorted_ops = jax.lax.sort(operands, num_keys=1,
                                           is_stable=True)
@@ -292,14 +311,12 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
                 sd = sorted_ops[1 + W:1 + W + DW]
                 sr = sorted_ops[-1]
 
+            with jax.named_scope("split/window_write"):
                 bins_w = tuple(jax.lax.dynamic_update_slice(bw, nb, (s,))
                                for bw, nb in zip(bins_w, sb))
                 dig_w = tuple(jax.lax.dynamic_update_slice(dw, nd, (s,))
                               for dw, nd in zip(dig_w, sd))
                 row_ord = jax.lax.dynamic_update_slice(row_ord, sr, (s,))
-
-                cnt_r = jnp.sum((go_r & inseg).astype(jnp.int32))
-                cnt_l = c - cnt_r
 
             # smaller child's histogram from its CONTIGUOUS slice; pad to
             # P/8 when the child is small enough (splits are often very
@@ -308,9 +325,12 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
             # packed-word kernel runs 3x slower per row (Mosaic keeps all
             # one-hot temporaries live under a dynamic grid, forcing tiny
             # blocks), so the static size-class structure stays.
-            small_left = cnt_l <= cnt_r
-            off = s + jnp.where(small_left, 0, cnt_l)
-            scnt = jnp.minimum(cnt_l, cnt_r)
+            with jax.named_scope("split/key"):
+                cnt_r = jnp.sum((go_r & inseg).astype(jnp.int32))
+                cnt_l = c - cnt_r
+                small_left = cnt_l <= cnt_r
+                off = s + jnp.where(small_left, 0, cnt_l)
+                scnt = jnp.minimum(cnt_l, cnt_r)
 
             def hist_at(Psz):
                 # NOTE: closes over the branch's SORTED bins_w/dig_w
@@ -318,7 +338,7 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
 
             P2 = max(P // 2, classes[0] // 2, 4096)
             P8 = max(P // 8, 4096)
-            with jax.named_scope("hist"):
+            with jax.named_scope("hist/window"):
                 if P8 < P2:
                     sums_small = jax.lax.cond(scnt <= P8, hist_at(P8),
                                               hist_at(P2), None)
@@ -328,7 +348,8 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
         return branch
 
     branches = [make_branch(P) for P in classes]
-    sizes_arr = jnp.asarray(classes, jnp.int32)
+    with jax.named_scope("grow_loop"):
+        sizes_arr = jnp.asarray(classes, jnp.int32)
 
     def step(k, carry):
         (num_leaves, stopped, leaf_f32, leaf_i32, node_i32, cache,
@@ -358,40 +379,45 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
             jax.lax.switch(cls, branches,
                            (bins_w, dig_w, row_ord, s, c, feat, tbin,
                             is_cat[feat], do_split))
+        # (what no scope names up to here is the loop's own: grow_loop,
+        # entered around the fori_loop below)
 
-        # --- split sums (exact reference decomposition) -----------------
-        parent_g = rb_f[_LF["total_g"]]
-        parent_h = rb_f[_LF["total_h"]]
-        parent_c = rb_f[_LF["total_c"]]
-        left_g = rb_f[_LF["best_left_g"]]
-        left_h = rb_f[_LF["best_left_h"]]
-        left_c = rb_f[_LF["best_left_c"]]
-        right_g = parent_g - left_g
-        right_h = parent_h - left_h
-        right_c = parent_c - left_c
-        left_val = leaf_output(left_g, left_h, sp.lambda_l1, sp.lambda_l2)
-        right_val = leaf_output(right_g, right_h, sp.lambda_l1, sp.lambda_l2)
+        with jax.named_scope("leaf_table"):
+            # --- split sums (exact reference decomposition) -------------
+            parent_g = rb_f[_LF["total_g"]]
+            parent_h = rb_f[_LF["total_h"]]
+            parent_c = rb_f[_LF["total_c"]]
+            left_g = rb_f[_LF["best_left_g"]]
+            left_h = rb_f[_LF["best_left_h"]]
+            left_c = rb_f[_LF["best_left_c"]]
+            right_g = parent_g - left_g
+            right_h = parent_h - left_h
+            right_c = parent_c - left_c
+            left_val = leaf_output(left_g, left_h, sp.lambda_l1,
+                                   sp.lambda_l2)
+            right_val = leaf_output(right_g, right_h, sp.lambda_l1,
+                                    sp.lambda_l2)
 
-        # --- node record + parent child-pointer fixup -------------------
-        node = k
-        p_safe = jnp.maximum(parent_node, 0)
-        rp = _row(node_i32, p_safe, 8)
-        was_left = rp[_ND["left"]] == ~best_leaf
-        upd_parent = do_split & (parent_node >= 0)
-        rp = rp.at[_ND["left"]].set(
-            jnp.where(upd_parent & was_left, node, rp[_ND["left"]]))
-        rp = rp.at[_ND["right"]].set(
-            jnp.where(upd_parent & ~was_left, node, rp[_ND["right"]]))
-        node_i32 = _put_row(node_i32, p_safe, rp)
-        new_node = jnp.stack([
-            rb_i[_LI["best_feat"]], tbin, _f2i(gain), ~best_leaf,
-            ~right_leaf, _f2i(rb_f[_LF["cur_value"]]),
-            parent_c.astype(jnp.int32), jnp.int32(0)])
-        node_i32 = _put_row(node_i32, node,
-                            jnp.where(do_split, new_node, empty_node))
+            # --- node record + parent child-pointer fixup ---------------
+            node = k
+            p_safe = jnp.maximum(parent_node, 0)
+            rp = _row(node_i32, p_safe, 8)
+            was_left = rp[_ND["left"]] == ~best_leaf
+            upd_parent = do_split & (parent_node >= 0)
+            rp = rp.at[_ND["left"]].set(
+                jnp.where(upd_parent & was_left, node, rp[_ND["left"]]))
+            rp = rp.at[_ND["right"]].set(
+                jnp.where(upd_parent & ~was_left, node, rp[_ND["right"]]))
+            node_i32 = _put_row(node_i32, p_safe, rp)
+            new_node = jnp.stack([
+                rb_i[_LI["best_feat"]], tbin, _f2i(gain), ~best_leaf,
+                ~right_leaf, _f2i(rb_f[_LF["cur_value"]]),
+                parent_c.astype(jnp.int32), jnp.int32(0)])
+            node_i32 = _put_row(node_i32, node,
+                                jnp.where(do_split, new_node, empty_node))
 
         # --- child histograms via exact sibling subtraction -------------
-        with jax.named_scope("hist"):
+        with jax.named_scope("hist/subtract"):
             sums_parent = cache[best_leaf]
             sums_large = sums_parent - sums_small
             sums_left = jnp.where(small_left, sums_small, sums_large)
@@ -413,78 +439,84 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
                 jnp.stack([left_h, right_h]), jnp.stack([left_c, right_c]),
                 num_bin, is_cat, feat_mask, can, sp)
 
-        def leaf_rows(ci, tot_g, tot_h, tot_c, val, seg_s, seg_c):
-            f32 = jnp.stack([
-                child_split.gain[ci], child_split.left_sum_g[ci],
-                child_split.left_sum_h[ci], child_split.left_count[ci],
-                tot_g, tot_h, tot_c, val])
-            i32 = jnp.stack([
-                child_split.feature[ci], child_split.threshold[ci],
-                node, depth + 1, seg_s, seg_c, jnp.int32(0), jnp.int32(0)])
-            return f32, i32
+        with jax.named_scope("leaf_table"):
+            def leaf_rows(ci, tot_g, tot_h, tot_c, val, seg_s, seg_c):
+                f32 = jnp.stack([
+                    child_split.gain[ci], child_split.left_sum_g[ci],
+                    child_split.left_sum_h[ci], child_split.left_count[ci],
+                    tot_g, tot_h, tot_c, val])
+                i32 = jnp.stack([
+                    child_split.feature[ci], child_split.threshold[ci],
+                    node, depth + 1, seg_s, seg_c, jnp.int32(0), jnp.int32(0)])
+                return f32, i32
 
-        lf, li = leaf_rows(0, left_g, left_h, left_c, left_val, s, cnt_l)
-        rf, ri = leaf_rows(1, right_g, right_h, right_c, right_val,
-                           s + cnt_l, c - cnt_l)
-        leaf_f32 = _put_row(leaf_f32, best_leaf,
-                            jnp.where(do_split, lf, rb_f))
-        leaf_i32 = _put_row(leaf_i32, best_leaf,
-                            jnp.where(do_split, li, rb_i))
-        leaf_f32 = _put_row(leaf_f32, right_leaf,
-                            jnp.where(do_split, rf, rr_f))
-        leaf_i32 = _put_row(leaf_i32, right_leaf,
-                            jnp.where(do_split, ri, rr_i))
-        num_leaves = num_leaves + jnp.where(do_split, 1, 0)
+            lf, li = leaf_rows(0, left_g, left_h, left_c, left_val, s, cnt_l)
+            rf, ri = leaf_rows(1, right_g, right_h, right_c, right_val,
+                               s + cnt_l, c - cnt_l)
+            leaf_f32 = _put_row(leaf_f32, best_leaf,
+                                jnp.where(do_split, lf, rb_f))
+            leaf_i32 = _put_row(leaf_i32, best_leaf,
+                                jnp.where(do_split, li, rb_i))
+            leaf_f32 = _put_row(leaf_f32, right_leaf,
+                                jnp.where(do_split, rf, rr_f))
+            leaf_i32 = _put_row(leaf_i32, right_leaf,
+                                jnp.where(do_split, ri, rr_i))
+            num_leaves = num_leaves + jnp.where(do_split, 1, 0)
         return (num_leaves, stopped, leaf_f32, leaf_i32, node_i32, cache,
                 bins_w, dig_w, row_ord)
 
-    carry = (jnp.asarray(1, jnp.int32), jnp.asarray(False),
-             leaf_f32, leaf_i32, node_i32, cache, bins_w, dig_w, row_ord)
-    (num_leaves, _, leaf_f32, leaf_i32, node_i32, _, _, _, row_ord) = \
-        jax.lax.fori_loop(0, L - 1, step, carry)
+    with jax.named_scope("grow_loop"):
+        carry = (jnp.asarray(1, jnp.int32), jnp.asarray(False),
+                 leaf_f32, leaf_i32, node_i32, cache, bins_w, dig_w,
+                 row_ord)
+    with jax.named_scope("grow_loop"):
+        (num_leaves, _, leaf_f32, leaf_i32, node_i32, _, _, _, row_ord) = \
+            jax.lax.fori_loop(0, L - 1, step, carry)
 
-    shrunk = leaf_f32[:, _LF["cur_value"]] * learning_rate
-    tree = TreeArrays(
-        num_leaves=num_leaves,
-        split_feature=node_i32[:, _ND["feature"]],
-        split_bin=node_i32[:, _ND["bin"]],
-        split_gain=_i2f(node_i32[:, _ND["gain"]]),
-        left_child=node_i32[:, _ND["left"]],
-        right_child=node_i32[:, _ND["right"]],
-        internal_value=_i2f(node_i32[:, _ND["value"]]),
-        internal_count=node_i32[:, _ND["count"]],
-        leaf_value=shrunk,
-        leaf_count=leaf_f32[:, _LF["total_c"]].astype(jnp.int32),
-        leaf_parent=leaf_i32[:, _LI["parent"]],
-        leaf_depth=leaf_i32[:, _LI["depth"]],
-    )
+    with jax.named_scope("leaf_table"):
+        shrunk = leaf_f32[:, _LF["cur_value"]] * learning_rate
+        tree = TreeArrays(
+            num_leaves=num_leaves,
+            split_feature=node_i32[:, _ND["feature"]],
+            split_bin=node_i32[:, _ND["bin"]],
+            split_gain=_i2f(node_i32[:, _ND["gain"]]),
+            left_child=node_i32[:, _ND["left"]],
+            right_child=node_i32[:, _ND["right"]],
+            internal_value=_i2f(node_i32[:, _ND["value"]]),
+            internal_count=node_i32[:, _ND["count"]],
+            leaf_value=shrunk,
+            leaf_count=leaf_f32[:, _LF["total_c"]].astype(jnp.int32),
+            leaf_parent=leaf_i32[:, _LI["parent"]],
+            leaf_depth=leaf_i32[:, _LI["depth"]],
+        )
 
-    # Per-position leaf assignment from the contiguous segments: the leaf
-    # owning position p is the one with the largest seg_start <= p.
-    leaf_iota = jnp.arange(L, dtype=jnp.int32)
-    live = (leaf_iota < num_leaves) & (leaf_i32[:, _LI["cnt"]] > 0)
-    sv = jnp.where(live, leaf_i32[:, _LI["start"]], jnp.int32(N))
-    sv_sorted, leaf_sorted = jax.lax.sort((sv, leaf_iota), num_keys=1,
-                                          is_stable=True)
-    pos = jnp.arange(N, dtype=jnp.int32)
-    seg = jnp.searchsorted(sv_sorted, pos, side="right") - 1
-    leaf_of_pos = leaf_sorted[seg]
-    # back to ORIGINAL row order: one scatter per tree
-    leaf_id = jnp.zeros(N, jnp.int32).at[row_ord[:N]].set(
-        leaf_of_pos, unique_indices=True)
-    output_delta = shrunk[leaf_id]
+    with jax.named_scope("leaf_delta"):
+        # Per-position leaf assignment from the contiguous segments: the
+        # leaf owning position p is the one with the largest seg_start <= p.
+        leaf_iota = jnp.arange(L, dtype=jnp.int32)
+        live = (leaf_iota < num_leaves) & (leaf_i32[:, _LI["cnt"]] > 0)
+        sv = jnp.where(live, leaf_i32[:, _LI["start"]], jnp.int32(N))
+        sv_sorted, leaf_sorted = jax.lax.sort((sv, leaf_iota), num_keys=1,
+                                              is_stable=True)
+        pos = jnp.arange(N, dtype=jnp.int32)
+        seg = jnp.searchsorted(sv_sorted, pos, side="right") - 1
+        leaf_of_pos = leaf_sorted[seg]
+        # back to ORIGINAL row order: one scatter per tree
+        leaf_id = jnp.zeros(N, jnp.int32).at[row_ord[:N]].set(
+            leaf_of_pos, unique_indices=True)
+        output_delta = shrunk[leaf_id]
 
-    if params.compact_inactive:
-        # zero-weight rows never entered a segment: route them through the
-        # tree like the reference's out-of-bag AddPredictionToScore
-        # (gbdt.cpp UpdateScore; cost ~ actual tree depth via the while
-        # walk in ops/predict.py)
-        from .predict import predict_binned_tree
-        pval, pleaf = predict_binned_tree(
-            tree.split_feature, tree.split_bin,
-            is_cat[jnp.maximum(tree.split_feature, 0)],
-            tree.left_child, tree.right_child, shrunk, bins, L)
-        active = row_weight > 0.0
-        leaf_id = jnp.where(active, leaf_id, pleaf)
-        output_delta = jnp.where(active, output_delta, pval)
+        if params.compact_inactive:
+            # zero-weight rows never entered a segment: route them through
+            # the tree like the reference's out-of-bag AddPredictionToScore
+            # (gbdt.cpp UpdateScore; cost ~ actual tree depth via the
+            # while walk in ops/predict.py)
+            from .predict import predict_binned_tree
+            pval, pleaf = predict_binned_tree(
+                tree.split_feature, tree.split_bin,
+                is_cat[jnp.maximum(tree.split_feature, 0)],
+                tree.left_child, tree.right_child, shrunk, bins, L)
+            active = row_weight > 0.0
+            leaf_id = jnp.where(active, leaf_id, pleaf)
+            output_delta = jnp.where(active, output_delta, pval)
     return tree, leaf_id, output_delta

@@ -225,6 +225,9 @@ def children_histograms_pallas(bins, grad, hess, weight, leaf_id,
         out_shape=jax.ShapeDtypeStruct((F, 6, B), jnp.float32),
         scratch_shapes=[pltpu.VMEM((F, 6, B), jnp.float32)],
         interpret=interpret,
+        # a device event of the kernel reads %children_histograms.N
+        # whatever function traced the call (obs/devtrace.py)
+        name="children_histograms",
     )(jnp.asarray([parent_leaf], jnp.int32),
       jnp.asarray([right_leaf], jnp.int32),
       bins, grad[None], hess[None], weight[None],
@@ -282,6 +285,9 @@ def fused_children_split_candidates_pallas(
         out_shape=jax.ShapeDtypeStruct((2, F, 8), jnp.float32),
         scratch_shapes=[pltpu.VMEM((F, 6, B), jnp.float32)],
         interpret=interpret,
+        # a device event of the kernel reads %children_histograms.N
+        # whatever function traced the call (obs/devtrace.py)
+        name="children_histograms",
     )(jnp.asarray([parent_leaf], jnp.int32),
       jnp.asarray([right_leaf], jnp.int32),
       jnp.asarray(totals, jnp.float32),

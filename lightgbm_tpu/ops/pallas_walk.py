@@ -389,6 +389,9 @@ def _run_walk(tables, grid_args, grid_dtypes, const_args, *,
         scratch_shapes=([pltpu.VMEM((F, n_blk), jnp.float32)]
                         if raw else []),
         interpret=interpret,
+        # a device event of the kernel reads %forest_walk.N whatever
+        # function traced the call (obs/devtrace.py)
+        name="forest_walk",
     )(*operands)
     return out[:, :B]
 

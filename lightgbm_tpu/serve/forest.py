@@ -57,7 +57,7 @@ from typing import Dict, Optional, Sequence, Tuple
 import numpy as np
 
 from .. import obs
-from ..utils import device, log, timetag
+from ..utils import device, log
 from ..utils.log import LightGBMError
 from .batcher import BucketLadder, CountingJit, pad_rows
 
@@ -673,7 +673,7 @@ class CompiledForest:
             bins = self.bin_rows(Xp)
             obs.devprof.transfer("h2d", "serve",
                                  int(np.asarray(bins).nbytes))
-            with timetag.scope("Predict::forest"):
+            with obs.span("Predict::forest"):
                 if self._has_linear:
                     # affine covariates: the same padded rows, NaN->0
                     # f32, [F, B] (docs/LINEAR_TREES.md)
@@ -704,7 +704,7 @@ class CompiledForest:
             Xp, mask = pad_rows(X[off:off + n], bucket)
             obs.devprof.transfer("h2d", "serve",
                                  int(Xp.nbytes) + int(mask.nbytes))
-            with timetag.scope("Predict::forest"):
+            with obs.span("Predict::forest"):
                 raw, out = self._dispatch_raw(bucket, Xp, mask)
             obs.devprof.transfer("d2h", "serve",
                                  int(raw.nbytes) + int(out.nbytes))

@@ -1,29 +1,31 @@
-"""TIMETAG-style phase profiling.
+"""TIMETAG-style phase account.
 
 The reference compiles scoped wall-clock accumulators under #ifdef TIMETAG
 (serial_tree_learner.cpp:10-37: init_train/init_split/hist/find_split/
 split; gbdt.cpp:20-59: boosting/train_score/valid_score/metric/bagging/
-tree) and prints the totals at shutdown.  Here the same phase taxonomy is
-kept, adapted to an async device:
+tree) and prints the totals at shutdown.  Here the same host taxonomy
+(obs/phases.py HOST_PHASES) has ONE entry point, ``obs.span``; this
+module keeps only the serializing mode's switch and its account:
 
-- ``scope(name, sync=...)`` — host wall-clock accumulator.  Enabled by
-  LIGHTGBM_TPU_TIMETAG=1; when ``sync`` is given the scope blocks on that
-  device value before stopping the clock, so device time is attributed to
-  the phase that produced it (this serializes the pipeline exactly like
-  the reference's TIMETAG builds perturb theirs — a measurement mode, not
-  a production mode).
-- jitted code carries ``jax.named_scope`` annotations with the same phase
-  names (ops/grow.py), so device-side traces captured with
-  jax.profiler.trace() break down by phase without any re-run.
+- ``ENABLED`` (LIGHTGBM_TPU_TIMETAG=1, or ``enable()``): while on, a span
+  given ``sync(x)`` blocks on that device value before stopping its
+  clock, so device time is attributed to the phase that produced it.
+  This serializes the pipeline exactly like the reference's TIMETAG
+  builds perturb theirs — a measurement mode, not a production mode.
+- ``add(name, seconds)`` / ``get_timings()``: the per-phase totals the
+  spans feed while the mode is on, printed at exit.
+
+Device time by phase without serializing anything is the trace window's
+job: jitted code carries ``jax.named_scope`` phases, the program exports
+the map from compiled instruction to phase, and ``obs/devtrace.py``
+reduces a profiler window by it (obs/trace.py).
 """
 
 from __future__ import annotations
 
 import atexit
 import os
-import time
 from collections import defaultdict
-from contextlib import contextmanager
 from typing import Dict
 
 from . import log
@@ -46,62 +48,6 @@ def add(name: str, seconds: float) -> None:
     the two instruments share one account."""
     _acc[name] += seconds
     _cnt[name] += 1
-
-
-class _Sync:
-    """Collects device values to block on when the scope closes."""
-
-    __slots__ = ("value",)
-
-    def __init__(self):
-        self.value = None
-
-    def sync(self, value) -> None:
-        self.value = value
-
-
-class _NoopSync:
-    """Disabled mode: must NOT retain the passed device buffers (a stored
-    reference would pin grad/score arrays in HBM for the process
-    lifetime)."""
-
-    __slots__ = ()
-
-    def sync(self, value) -> None:
-        pass
-
-
-_NOOP = _NoopSync()
-
-
-@contextmanager
-def scope(name: str):
-    """Accumulate wall time under ``name``.  The yielded object's
-    ``sync(x)`` registers device values to block on before the clock
-    stops, so async device work is attributed to the phase that produced
-    it."""
-    if not ENABLED:
-        yield _NOOP
-        return
-    s = _Sync()
-    t0 = time.perf_counter()
-    try:
-        yield s
-    finally:
-        if s.value is not None:
-            # counted sync (obs/devprof.py): this scope's serialization
-            # is visible in the profile it distorts
-            from ..obs import devprof
-            devprof.sync(s.value, source=name)
-        dt = time.perf_counter() - t0
-        _acc[name] += dt
-        _cnt[name] += 1
-        # mirror into the per-phase wall-time histogram (obs/spans.py):
-        # under the serializing TIMETAG mode, scope sites populate the
-        # same distribution series that obs.span feeds, so the phase
-        # account has one metrics namespace regardless of instrument
-        from ..obs import registry, spans
-        registry.observe(spans._series(name), dt)
 
 
 def get_timings() -> Dict[str, float]:

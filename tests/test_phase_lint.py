@@ -1,4 +1,4 @@
-"""tools/lint_phase_scopes.py as a tier-1 test: the host timetag phase
+"""tools/lint_phase_scopes.py as a tier-1 test: the host span phase
 taxonomy and the device named_scope taxonomy must both match
 lightgbm_tpu/obs/phases.py, so the two accounts can't silently drift."""
 
@@ -20,13 +20,41 @@ def test_phase_taxonomies_in_sync():
 
 
 def test_lint_recognizes_obs_span_sites():
-    """obs.span("X") counts as a host-phase user alongside
-    timetag.scope("X") — the always-on span API feeds the same account."""
+    """obs.span("X") is the one entry point of a host phase; the
+    timetag.scope form is gone and no longer counts."""
     lint = _load_lint()
     m = lint.SCOPE_RE.search('with obs.span("GBDT::iteration"):')
     assert m and m.group(1) == "GBDT::iteration"
-    m = lint.SCOPE_RE.search('with timetag.scope("GBDT::tree") as tt:')
+    m = lint.SCOPE_RE.search('with obs.span("GBDT::tree") as tt:')
     assert m and m.group(1) == "GBDT::tree"
+    assert not lint.SCOPE_RE.search('with timetag.scope("GBDT::tree"):')
+
+
+def test_lint_accepts_nested_device_phase_names(tmp_path, monkeypatch):
+    """The fused round's taxonomy nests with ``/`` (``split/sort``,
+    ``hist/kernel``): the rule reads such names whole, in every device
+    file, and still reports one that is not declared."""
+    lint = _load_lint()
+    m = lint.NAMED_RE.search('with jax.named_scope("split/window_read"):')
+    assert m and m.group(1) == "split/window_read"
+    assert {"models/gbdt.py", "ops/ordered_grow.py",
+            "ops/leafhist.py"} <= set(lint.DEVICE_FILES)
+    pkg = tmp_path / "lightgbm_tpu"
+    (pkg / "obs").mkdir(parents=True)
+    (pkg / "ops").mkdir()
+    real = (pathlib.Path(lint.__file__).resolve().parent.parent
+            / "lightgbm_tpu" / "obs" / "phases.py")
+    (pkg / "obs" / "phases.py").write_text(real.read_text())
+    (pkg / "ops" / "grow.py").write_text("")
+    (pkg / "ops" / "ordered_grow.py").write_text(
+        'with jax.named_scope("split/sort"):\n    pass\n'
+        'with jax.named_scope("split/rogue"):\n    pass\n')
+    monkeypatch.setattr(lint, "ROOT", tmp_path)
+    monkeypatch.setattr(lint, "PKG", pkg)
+    errors = lint.check()
+    assert any("split/rogue" in e for e in errors)
+    assert not any("'split/sort'" in e and "not declared" in e
+                   for e in errors)
 
 
 def test_lint_recognizes_trace_span_sites():
@@ -110,7 +138,7 @@ def test_lint_catches_undeclared_scope(tmp_path, monkeypatch):
                    / "lightgbm_tpu" / "obs" / "phases.py")
     (pkg / "obs" / "phases.py").write_text(real_phases.read_text())
     (pkg / "models.py").write_text(
-        'with timetag.scope("GBDT::rogue"):\n    pass\n')
+        'with obs.span("GBDT::rogue"):\n    pass\n')
     (pkg / "ops" / "grow.py").write_text(
         'with jax.named_scope("hist"):\n    pass\n'
         'with jax.named_scope("find_split"):\n    pass\n'

@@ -1,10 +1,13 @@
-"""TIMETAG phase profiling (utils/timetag.py): the reference's phase
-taxonomy (gbdt.cpp:20-59, serial_tree_learner.cpp:10-37) accumulated
-host-side with device sync, plus named_scope annotations in the grower."""
+"""TIMETAG phase account (utils/timetag.py) fed by ``obs.span``, the one
+entry point of a host phase: the reference's phase taxonomy (gbdt.cpp:
+20-59, serial_tree_learner.cpp:10-37) accumulated host-side with device
+sync while the serializing mode is on (ported one for one from the
+``timetag.scope`` cases that this file held before the scope went)."""
 
 import numpy as np
 
 import lightgbm_tpu as lgb
+from lightgbm_tpu import obs
 from lightgbm_tpu.utils import timetag
 
 
@@ -28,6 +31,10 @@ def test_phase_accumulators():
     # _make_train_step)
     for phase in ("GBDT::tree", "GBDT::valid_score", "GBDT::host_tree",
                   "GBDT::metric", "GBDT::bagging"):
+        assert phase in t and t[phase] >= 0.0, (phase, t)
+    # set-up is spanned too (io/dataset.py, models/gbdt.py)
+    for phase in ("Bin::sample", "Bin::find_bin", "Bin::apply",
+                  "Dataset::to_device"):
         assert phase in t and t[phase] >= 0.0, (phase, t)
     timetag.reset()
     assert timetag.get_timings() == {}
@@ -60,9 +67,25 @@ def test_phase_accumulators_custom_fobj():
     timetag.reset()
 
 
-def test_disabled_is_noop():
+def test_disabled_is_noop(monkeypatch):
+    """With the serializing mode off a span feeds no timetag account and
+    never blocks on the value handed to ``sync``."""
+    from lightgbm_tpu.obs import devprof
+    synced = []
+    monkeypatch.setattr(devprof, "sync",
+                        lambda value, source=None: synced.append(source))
     timetag.enable(False)
     timetag.reset()
-    with timetag.scope("x") as s:
+    with obs.span("GBDT::metric") as s:
         s.sync(np.zeros(3))
     assert timetag.get_timings() == {}
+    assert synced == []
+    timetag.enable(True)
+    try:
+        with obs.span("GBDT::metric") as s:
+            s.sync(np.zeros(3))
+        assert synced == ["GBDT::metric"]
+        assert "GBDT::metric" in timetag.get_timings()
+    finally:
+        timetag.enable(False)
+        timetag.reset()

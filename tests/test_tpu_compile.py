@@ -15,6 +15,7 @@ and under pytest-xdist every worker imports every test file.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -55,9 +56,19 @@ def spec(topo):
     cc.reset_cache()
 
 
-def _assert_kernel_compiles(fn, *args):
+def _assert_kernel_compiles(fn, *args, name=None):
+    """The kernel is in the compiled text and, where ``name`` is given,
+    its instruction carries the kernel's own name: a device event of it
+    then reads ``%<name>.N`` whatever function traced the call
+    (obs/devtrace.py, benchmarks/layer_metrics)."""
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    if name is not None:
+        kernels = [ln for ln in text.splitlines()
+                   if 'custom_call_target="tpu_custom_call"' in ln]
+        assert kernels and all(
+            re.match(rf"\s*(ROOT )?%{name}(\.\d+)? = ", ln)
+            for ln in kernels), [ln[:80] for ln in kernels]
 
 
 def test_digit_histogram_kernel_compiles(spec):
@@ -66,7 +77,8 @@ def test_digit_histogram_kernel_compiles(spec):
     from lightgbm_tpu.ops import leafhist
     _assert_kernel_compiles(
         lambda b, d: leafhist.digit_histogram_pallas(b, d, B),
-        spec((4096, F), jnp.uint8), spec((4096, 9), jnp.int8))
+        spec((4096, F), jnp.uint8), spec((4096, 9), jnp.int8),
+        name="digit_histogram")
 
 
 def test_children_histograms_kernel_compiles(spec):
@@ -78,6 +90,8 @@ def test_children_histograms_kernel_compiles(spec):
         spec((n,), jnp.float32), spec((n,), jnp.float32),
         spec((n,), jnp.int32), 1, 3, max_bin=B).compile().as_text()
     assert "tpu_custom_call" in text
+    assert re.search(r"%children_histograms(\.\d+)? = .*tpu_custom_call",
+                     text)
 
 
 def _chain_forest_tables():
@@ -110,14 +124,48 @@ def test_forest_walk_kernel_compiles(spec, variant, bucket):
             lambda *a: pallas_walk.forest_walk(*a, num_class=1,
                                                nan_bin=nan_bin),
             *tables,
-            spec((F, bucket), pallas_walk.bin_index_dtype(nan_bin)))
+            spec((F, bucket), pallas_walk.bin_index_dtype(nan_bin)),
+            name="forest_walk")
     else:
         _assert_kernel_compiles(
             lambda *a: pallas_walk.forest_walk_raw(*a, num_class=1,
                                                    nan_bin=nan_bin),
             *tables, spec((F, CUTS), jnp.float32),
             spec((F, CUTS), jnp.int32), spec((F, 1), jnp.float32),
-            spec((F, bucket), jnp.float32))
+            spec((F, bucket), jnp.float32), name="forest_walk")
+
+
+def test_ordered_grower_text_carries_the_phase_paths(spec, monkeypatch):
+    """The chip's trace names a device event by its instruction and hands
+    out no scope path; the compiled text does (``op_name``), which is
+    what the program's phase map (obs/devtrace.py) is parsed from.  The
+    ordered grower at ONE size class (8,192 rows; four features keep the
+    kernel's unroll short): the segment sort carries ``split/sort``, the
+    kernel its own name, and no operation is left under no phase."""
+    from lightgbm_tpu.obs import devtrace
+    from lightgbm_tpu.ops.grow import GrowParams
+    from lightgbm_tpu.ops.ordered_grow import grow_tree_ordered
+    from lightgbm_tpu.utils import device
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    n, f = 8192, 4
+    text = grow_tree_ordered.lower(
+        spec((f, n), jnp.uint8), spec((f,), jnp.int32),
+        spec((f,), jnp.bool_), spec((f,), jnp.bool_),
+        spec((n,), jnp.float32), spec((n,), jnp.float32),
+        spec((n,), jnp.float32), spec((), jnp.float32),
+        GrowParams(num_leaves=7, max_bin=B, min_data_in_leaf=50)
+    ).compile().as_text()
+    sorts = re.findall(r'%(sort[\w.\-]*) = .* sort\(.*op_name="([^"]*)"', text)
+    assert sorts and any(op.endswith("/split/sort/sort") for _, op in sorts)
+    pm = devtrace.phase_map(text)
+    assert pm["module"] == "jit_grow_tree_ordered"
+    kernels = [k for k, ph in pm["phases"].items() if ph == "hist/kernel"
+               and k.startswith("digit_histogram")]
+    assert kernels, sorted(pm["phases"])[:20]
+    assert {pm["phases"][name] for name, op in sorts
+            if op.endswith("/split/sort/sort")} == {"split/sort"}
+    assert pm["ops_unscoped"] == 0, pm["unscoped_op_names"]
+    assert pm["inserted"], "the chip's compiler inserts copies here"
 
 
 def test_fused_gain_kernel_is_refused_with_the_quoted_words(spec):

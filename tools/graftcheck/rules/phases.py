@@ -5,13 +5,14 @@ entry point still works and delegates here; its ``check()`` contract
 (a list of human-readable violation strings) is preserved verbatim for
 ``tests/test_phase_lint.py``.
 
-Checks (unchanged from the standalone lint):
+Checks:
 
-1. every ``timetag.scope("X")`` / ``obs.span`` / tracing-span literal
-   under the package is declared in HOST_PHASES, and every declared host
-   phase is used;
+1. every ``obs.span("X")`` / tracing-span literal under the package is
+   declared in HOST_PHASES, and every declared host phase is used
+   (``obs.span`` is the one entry point of a host phase);
 2. every ``jax.named_scope("X")`` in the jitted device files is declared
-   in DEVICE_PHASES, and vice versa;
+   in DEVICE_PHASES, and vice versa (names nest with ``/``:
+   ``split/sort``, ``hist/kernel``);
 3. DEVICE_PARENT maps every device phase onto a declared host phase and
    covers every JITTED_HOST_PHASE;
 4. every phase resolves through ``phases.span_series`` to a valid,
@@ -28,15 +29,17 @@ from typing import Dict, List, Optional
 from ..core import Finding, Project, family
 
 SCOPE_RE = re.compile(
-    r"(?:timetag\.scope|obs\.span|spans\.span"
+    r"(?:obs\.span|spans\.span"
     r"|obs\.trace_span|obs\.trace_begin|tracing\.span|TRACER\.(?:span|begin)"
     r")\(\s*[\"']([^\"']+)[\"']")
 NAMED_RE = re.compile(r"jax\.named_scope\(\s*[\"']([^\"']+)[\"']")
 SERIES_RE = re.compile(r"^phase_seconds_[a-z_][a-z0-9_]*$")
 
-# the jitted paths carrying the device taxonomy: the growers plus the
-# compiled-forest inference program (serve/forest.py)
-DEVICE_FILES = ("ops/grow.py", "ops/ordered_grow.py", "serve/forest.py")
+# the jitted paths carrying the device taxonomy: the fused round
+# (models/gbdt.py train_step, the growers, the histogram kernel's own
+# scope) plus the compiled-forest inference program (serve/forest.py)
+DEVICE_FILES = ("models/gbdt.py", "ops/grow.py", "ops/ordered_grow.py",
+                "ops/leafhist.py", "serve/forest.py")
 
 
 def _load_phases(pkg: pathlib.Path):
@@ -85,11 +88,11 @@ def scope_errors(root, pkg, project: Optional[Project] = None
     for name, sites in sorted(host_used.items()):
         if name not in phases.HOST_PHASES:
             errors.append(
-                f"timetag.scope({name!r}) in {sites} is not declared in "
+                f"obs.span({name!r}) in {sites} is not declared in "
                 f"obs/phases.py HOST_PHASES")
     for name in sorted(phases.HOST_PHASES - set(host_used)):
         errors.append(
-            f"HOST_PHASES declares {name!r} but no timetag.scope uses it")
+            f"HOST_PHASES declares {name!r} but no obs.span uses it")
 
     dev_used = _scan_texts(device_texts, NAMED_RE)
     for name, sites in sorted(dev_used.items()):

@@ -1,18 +1,21 @@
 #!/usr/bin/env python
-"""Static lint: keep the host (timetag) and device (named_scope) phase
+"""Static lint: keep the host (obs.span) and device (named_scope) phase
 taxonomies from drifting apart.
 
-``utils/timetag.py`` accumulates host wall-clock under
-``timetag.scope("GBDT::x")`` names; the jitted growers annotate device
-ops with ``jax.named_scope("x")`` so LIGHTGBM_TPU_TRACE_DIR traces break
-down by phase.  The two taxonomies only stay joinable (trace time
-attributed back to the host account) if both match the declarations in
-``lightgbm_tpu/obs/phases.py``.  Checks:
+``obs.span("GBDT::x")`` times a host phase (and enters it on the
+profiler's clock as ``lgbt:GBDT::x``); the jitted programs annotate
+device ops with ``jax.named_scope("x")``, which the program's phase map
+(obs/compile_ledger.py, obs/devtrace.py) joins to the device events of a
+LIGHTGBM_TPU_TRACE_DIR window.  The two taxonomies only stay joinable
+(trace time attributed back to the host account) if both match the
+declarations in ``lightgbm_tpu/obs/phases.py``.  Checks:
 
-1. every ``timetag.scope("X")`` literal under lightgbm_tpu/ is declared
-   in HOST_PHASES, and every declared host phase is used in code;
-2. every ``jax.named_scope("X")`` in the jitted growers (ops/grow.py,
-   ops/ordered_grow.py) is declared in DEVICE_PHASES, and vice versa;
+1. every ``obs.span("X")`` literal under lightgbm_tpu/ is declared in
+   HOST_PHASES, and every declared host phase is used in code;
+2. every ``jax.named_scope("X")`` in the jitted device files
+   (models/gbdt.py, ops/grow.py, ops/ordered_grow.py, ops/leafhist.py,
+   serve/forest.py) is declared in DEVICE_PHASES, and vice versa; names
+   nest with ``/`` (``split/sort``);
 3. DEVICE_PARENT maps every device phase onto a declared host phase, and
    every JITTED_HOST_PHASE is covered by at least one device phase —
    a rename on either side fails here instead of silently splitting the
@@ -23,11 +26,11 @@ attributed back to the host account) if both match the declarations in
    and the phase taxonomy cannot diverge, and no two phases can silently
    alias onto one series.
 
-``obs.span("X")`` sites count as host-phase users alongside
-``timetag.scope("X")`` — the span API is the always-on successor and
-feeds the same phase account (obs/spans.py).  So do the causal-tracing
-call forms (``obs.trace_span("X")`` / ``obs.trace_begin("X")``,
-obs/tracing.py): trace span names are the SAME taxonomy, so a name
+``obs.span("X")`` is the one entry point of a host phase
+(``timetag.scope`` is gone; obs/spans.py feeds its account).  The
+causal-tracing call forms count as users too
+(``obs.trace_span("X")`` / ``obs.trace_begin("X")``, obs/tracing.py):
+trace span names are the SAME taxonomy, so a name
 invented at a tracing call site fails here instead of minting an
 unregistered series.  The serving-fleet spans (``Serve::dispatch`` /
 ``Serve::reload`` / ``Serve::drain``, serve/fleet.py) and the
