@@ -90,7 +90,7 @@ ROUND_PHASES = (
     "hist/root",          # root pass: combine, cache seed (the kernel
                           # itself is hist/kernel, its feed hist/window)
     "grow_loop",          # the fori_loop itself, best-leaf pick, the
-                          # size-class switch
+                          # size-class dispatch (a chain of conds)
     "split/window_read",  # dynamic slices of the parent's window
     "split/key",          # split column pick, go-right key, counts
     "split/sort",         # the stable segment sort

@@ -12,9 +12,17 @@ Splitting leaf ``l`` then only touches its own segment:
   * the stable left/right partition is a segment-local 12-operand sort
     whose cost tracks the PARENT segment (padded to a power-of-two class),
     not N — sum over a tree ~ O(N * depth) instead of O(N * leaves),
-  * the smaller child's histogram kernel reads a contiguous slice,
+  * the smaller child's histogram kernel reads a contiguous slice of
+    the sorted window,
   * the sibling histogram comes from the exact int32 parent-cache
     subtraction (ops/leafhist.py).
+
+A step picks its size class through a chain of two-way ``lax.cond``s that
+thread the row lanes, NOT through one ``lax.switch``, and nothing reads a
+lane after its write-back: handed to an N-way conditional (or read again
+by a nested one) every carried lane was copied whole, per branch and per
+step, by the chip's compiler — 447 ms of a 2,073 ms round at 10.5M rows
+(PERF.md, PR 29; tests/test_tpu_compile.py holds the property).
 
 Row payloads travel through the sort as WORD-MAJOR i32 lanes (7 words of
 bins + 3 words of digits + original row id, each a separate 1-D array, so
@@ -209,23 +217,28 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
             root_cnt = jnp.sum((row_weight > 0.0).astype(jnp.int32))
 
     def hist_window(bw_tuple, dw_tuple, off, scnt, Psz: int):
-        """[F, 9, B] digit sums over rows [off, off+Psz) of the packed
-        layout, digit streams masked to the first scnt rows.  The ONE
-        histogram formulation every call site shares (per-split child
-        windows and the compacted root)."""
+        """[F, 9, B] digit sums over the scnt rows from ``off`` of the
+        packed layout, read as ONE Psz-row window with every other row's
+        digit streams masked to zero.  The window starts at ``off``, or
+        ends with the arrays where ``off + Psz`` would overrun them (a
+        sorted split window; the full lanes carry PAD spare rows).  The
+        ONE histogram formulation every call site shares (per-split
+        child windows and the compacted root)."""
         with jax.named_scope("hist/window"):
+            start = jnp.minimum(off, bw_tuple[0].shape[0] - Psz)
+            first = off - start
             ch_bins = _unpack_words(
-                tuple(jax.lax.dynamic_slice(bw, (off,), (Psz,))
+                tuple(jax.lax.dynamic_slice(bw, (start,), (Psz,))
                       for bw in bw_tuple), F)
             ch_dig = jax.lax.bitcast_convert_type(
                 jax.lax.bitcast_convert_type(
                     jnp.stack(
-                        tuple(jax.lax.dynamic_slice(dw, (off,), (Psz,))
+                        tuple(jax.lax.dynamic_slice(dw, (start,), (Psz,))
                               for dw in dw_tuple), axis=1),
                     jnp.uint8).reshape(Psz, -1)[:, :9], jnp.int8)
-            ch_dig = jnp.where(
-                jnp.arange(Psz, dtype=jnp.int32)[:, None] < scnt,
-                ch_dig, 0)
+            row = jnp.arange(Psz, dtype=jnp.int32)[:, None]
+            ch_dig = jnp.where((row >= first) & (row < first + scnt),
+                               ch_dig, 0)
         # the kernel scopes itself (hist/kernel, ops/leafhist.py)
         if device.on_tpu():
             return leafhist.digit_histogram_pallas(ch_bins, ch_dig, B)
@@ -329,21 +342,23 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
                 cnt_r = jnp.sum((go_r & inseg).astype(jnp.int32))
                 cnt_l = c - cnt_r
                 small_left = cnt_l <= cnt_r
-                off = s + jnp.where(small_left, 0, cnt_l)
+                off = jnp.where(small_left, 0, cnt_l)
                 scnt = jnp.minimum(cnt_l, cnt_r)
 
             def hist_at(Psz):
-                # NOTE: closes over the branch's SORTED bins_w/dig_w
-                return lambda _: hist_window(bins_w, dig_w, off, scnt, Psz)
+                # reads the SORTED WINDOW, not the lanes just written:
+                # a lane that this nested cond read after its write-back
+                # was copied whole around the write by the chip's compiler
+                return lambda win: hist_window(*win, off, scnt, Psz)
 
             P2 = max(P // 2, classes[0] // 2, 4096)
             P8 = max(P // 8, 4096)
             with jax.named_scope("hist/window"):
                 if P8 < P2:
                     sums_small = jax.lax.cond(scnt <= P8, hist_at(P8),
-                                              hist_at(P2), None)
+                                              hist_at(P2), (sb, sd))
                 else:
-                    sums_small = hist_at(P2)(None)
+                    sums_small = hist_at(P2)((sb, sd))
             return bins_w, dig_w, row_ord, cnt_l, small_left, sums_small
         return branch
 
@@ -375,10 +390,18 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
 
         cls = jnp.minimum(jnp.sum(c > sizes_arr).astype(jnp.int32),
                           len(branches) - 1)
-        bins_w, dig_w, row_ord, cnt_l, small_left, sums_small = \
-            jax.lax.switch(cls, branches,
-                           (bins_w, dig_w, row_ord, s, c, feat, tbin,
-                            is_cat[feat], do_split))
+        scalars = (s, c, feat, tbin, is_cat[feat], do_split)
+        # NOT a lax.switch: under an N-way conditional the chip's compiler
+        # copies every lane whole, per branch and per step (module
+        # docstring); a chain of two-way conds writes the lanes in place
+        st = (bins_w, dig_w, row_ord, jnp.int32(0), jnp.asarray(False),
+              jnp.zeros((F, 9, B), jnp.int32))
+        for i, branch in enumerate(branches):
+            st = jax.lax.cond(
+                cls == i,
+                lambda st, branch=branch: branch(st[:3] + scalars),
+                lambda st: st, st)
+        bins_w, dig_w, row_ord, cnt_l, small_left, sums_small = st
         # (what no scope names up to here is the loop's own: grow_loop,
         # entered around the fori_loop below)
 

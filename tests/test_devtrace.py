@@ -234,7 +234,28 @@ def test_render_is_a_table_of_the_report():
 
 # -- the fused round's taxonomy is closed ------------------------------------
 
-def test_train_step_has_no_unscoped_operation():
+@pytest.fixture
+def no_persistent_cache():
+    """``compile_cache_dir=off`` does not switch the cache off in a process
+    that has used it: jax decides once whether the persistent cache is in
+    use and keeps the directory it opened, and that is the checkout's
+    ``.jax_compile_cache/``, which every worker and every earlier run
+    writes to.  A worker that ran another file first was then served a
+    ``train_step`` of an older run (``cache_hit`` true, older op_names).
+    Switch it off where jax looks, and put it back after."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was_on = jax.config.jax_enable_compilation_cache
+    was_dir = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    jax.config.update("jax_compilation_cache_dir", was_dir)
+    cc.reset_cache()
+
+
+def test_train_step_has_no_unscoped_operation(no_persistent_cache):
     """(a) ``train_step`` lowered and compiled at a toy shape: every
     operation with an ``op_name`` of its own sits under a declared phase
     and every phase of the round's top level occurs."""
@@ -300,7 +321,8 @@ def test_span_is_on_the_profilers_clock(tmp_path):
     assert handle.trace is None            # the causal tracer is not armed
 
 
-def test_trace_window_writes_phase_maps_and_device_phases(tmp_path):
+def test_trace_window_writes_phase_maps_and_device_phases(
+        tmp_path, no_persistent_cache):
     """(e) a ``trace_dir`` window over a 6-round CPU train: the armed
     capture makes ``train_step``'s compile export its phase map, the
     window's close writes ``device_phases.json`` (no device plane on the

@@ -61,6 +61,56 @@ def test_ordered_matches_unordered(num_leaves, cat):
                                np.asarray(delta_ref), rtol=1e-6, atol=1e-7)
 
 
+@pytest.mark.parametrize("n,seed,overruns", [(40000, 0, False),
+                                             (64000, 3, True)])
+def test_ordered_matches_unordered_across_size_classes(n, seed, overruns):
+    """One 31-leaf tree whose splits take at least three different size
+    classes (40,000 rows: the root in the 65,536 class, then 32,768,
+    16,384 and 8,192), every ``TreeArrays`` field, ``leaf_id`` and the
+    score delta EXACTLY equal to ops/grow.py's: the dispatch hands the
+    row lanes through the classes a step does not take, and one that
+    dropped or reordered a lane there could hide behind a single-class
+    tree.  The second case holds a split whose smaller child lies so far
+    right in the sorted window that its histogram window would overrun
+    it (``hist_window`` then ends the window with the array)."""
+    from lightgbm_tpu.ops.ordered_grow import _size_classes
+    bins, num_bin, is_cat, feat_mask, g, h, w = _data(n=n, seed=seed,
+                                                      cat_feature=True)
+    params = GrowParams(num_leaves=31, max_bin=32, min_data_in_leaf=20,
+                        min_sum_hessian_in_leaf=1.0)
+    lr = jnp.float32(0.1)
+    t_ref, leaf_ref, delta_ref = grow_tree(bins, num_bin, is_cat, feat_mask,
+                                           g, h, w, lr, params)
+    t_ord, leaf_ord, delta_ord = grow_tree_ordered(
+        bins, num_bin, is_cat, feat_mask, g, h, w, lr, params)
+    assert int(t_ref.num_leaves) == 31
+
+    # the size class and the child window of every split, from the counts
+    classes = _size_classes(n)
+    counts = np.asarray(t_ref.internal_count)
+    leaf_counts = np.asarray(t_ref.leaf_count)
+    taken, overrun = set(), False
+    for node in range(30):
+        P = next((c for c in classes if counts[node] <= c), classes[-1])
+        left = int(np.asarray(t_ref.left_child)[node])
+        cnt_l = counts[left] if left >= 0 else leaf_counts[~left]
+        cnt_r = counts[node] - cnt_l
+        win = max(P // 8, 4096) if min(cnt_l, cnt_r) <= P // 8 else P // 2
+        taken.add(P)
+        overrun |= bool(cnt_l > cnt_r and cnt_l + win > P)
+    assert len(taken) >= 3, taken
+    assert overrun == overruns
+
+    for field in t_ref._fields:
+        np.testing.assert_array_equal(
+            np.asarray(getattr(t_ord, field)),
+            np.asarray(getattr(t_ref, field)), err_msg=field)
+    np.testing.assert_array_equal(np.asarray(leaf_ord),
+                                  np.asarray(leaf_ref))
+    np.testing.assert_array_equal(np.asarray(delta_ord),
+                                  np.asarray(delta_ref))
+
+
 def test_ordered_with_bagging_weights():
     bins, num_bin, is_cat, feat_mask, g, h, w = _data(n=9000)
     rng = np.random.RandomState(1)

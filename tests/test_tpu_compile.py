@@ -135,6 +135,23 @@ def test_forest_walk_kernel_compiles(spec, variant, bucket):
             spec((F, bucket), jnp.float32), name="forest_walk")
 
 
+def _ordered_grower_text(spec, monkeypatch, n):
+    """Compiled text of ``grow_tree_ordered`` at ``n`` rows (four features
+    keep the kernel's unroll short), 7 leaves, on the chip's kernel."""
+    from lightgbm_tpu.ops.grow import GrowParams
+    from lightgbm_tpu.ops.ordered_grow import grow_tree_ordered
+    from lightgbm_tpu.utils import device
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    f = 4
+    return grow_tree_ordered.lower(
+        spec((f, n), jnp.uint8), spec((f,), jnp.int32),
+        spec((f,), jnp.bool_), spec((f,), jnp.bool_),
+        spec((n,), jnp.float32), spec((n,), jnp.float32),
+        spec((n,), jnp.float32), spec((), jnp.float32),
+        GrowParams(num_leaves=7, max_bin=B, min_data_in_leaf=50)
+    ).compile().as_text()
+
+
 def test_ordered_grower_text_carries_the_phase_paths(spec, monkeypatch):
     """The chip's trace names a device event by its instruction and hands
     out no scope path; the compiled text does (``op_name``), which is
@@ -143,18 +160,7 @@ def test_ordered_grower_text_carries_the_phase_paths(spec, monkeypatch):
     kernel's unroll short): the segment sort carries ``split/sort``, the
     kernel its own name, and no operation is left under no phase."""
     from lightgbm_tpu.obs import devtrace
-    from lightgbm_tpu.ops.grow import GrowParams
-    from lightgbm_tpu.ops.ordered_grow import grow_tree_ordered
-    from lightgbm_tpu.utils import device
-    monkeypatch.setattr(device, "on_tpu", lambda: True)
-    n, f = 8192, 4
-    text = grow_tree_ordered.lower(
-        spec((f, n), jnp.uint8), spec((f,), jnp.int32),
-        spec((f,), jnp.bool_), spec((f,), jnp.bool_),
-        spec((n,), jnp.float32), spec((n,), jnp.float32),
-        spec((n,), jnp.float32), spec((), jnp.float32),
-        GrowParams(num_leaves=7, max_bin=B, min_data_in_leaf=50)
-    ).compile().as_text()
+    text = _ordered_grower_text(spec, monkeypatch, 8192)
     sorts = re.findall(r'%(sort[\w.\-]*) = .* sort\(.*op_name="([^"]*)"', text)
     assert sorts and any(op.endswith("/split/sort/sort") for _, op in sorts)
     pm = devtrace.phase_map(text)
@@ -166,6 +172,61 @@ def test_ordered_grower_text_carries_the_phase_paths(spec, monkeypatch):
             if op.endswith("/split/sort/sort")} == {"split/sort"}
     assert pm["ops_unscoped"] == 0, pm["unscoped_op_names"]
     assert pm["inserted"], "the chip's compiler inserts copies here"
+
+
+def _whole_lane_copies_in_grow_loop(text, lane):
+    """Names of the ``copy`` instructions whose result is a whole row
+    lane (``s32[lane]``) in the body of the grow loop (the ``while``
+    that reaches the segment sorts) or in any computation it calls.
+    ``copy-start``/``copy-done`` (the compiler's moves of a small lane
+    into ``S(1)``) are other opcodes and are not counted."""
+    from lightgbm_tpu.obs import devtrace
+    instrs = devtrace.parse_hlo(text)["instructions"]
+    by_comp = {}
+    for name, rec in instrs.items():
+        by_comp.setdefault(rec["comp"], []).append(name)
+
+    def reached(comp):
+        seen, todo = set(), [comp]
+        while todo:
+            c = todo.pop()
+            if c not in seen:
+                seen.add(c)
+                todo += [callee for name in by_comp.get(c, ())
+                         for _, callee in instrs[name]["called"]]
+        return seen
+
+    loops = [reached(dict(rec["called"])["body"])
+             for rec in instrs.values() if rec["opcode"] == "while"]
+    in_loop = set().union(*(comps for comps in loops if any(
+        (instrs[n]["op_name"] or "").endswith("/split/sort/sort")
+        for c in comps for n in by_comp.get(c, ()))))
+    assert in_loop, "no loop reaches the segment sorts"
+    whole = re.compile(rf"%([\w.\-]+) = s32\[{lane}\](\{{[^}}]*\}})? copy\(")
+    names = (m.group(1) for m in map(whole.search, text.splitlines()) if m)
+    return sorted(n for n in names if instrs[n]["comp"] in in_loop)
+
+
+def test_ordered_grower_copies_no_whole_lane_in_the_grow_loop(spec,
+                                                              monkeypatch):
+    """The grow loop carries the row lanes (bin words, digit words, row
+    order; ``s32[N + PAD]`` each) and a split step writes one window of
+    each with a ``dynamic_update_slice``.  Handed through an N-way
+    ``lax.switch`` over the size classes, the chip's compiler copied
+    every lane whole before that write in all branches but one (9 copy
+    instructions at this size, 447 ms of a 2,073 ms round at 10.5M rows:
+    PERF.md, PR 29); through the chain of two-way ``lax.cond``s it writes
+    in place.  32,768 rows take three size classes; the copies the
+    LOOP makes of its carry show only at 11M rows and are checked by
+    hand (PERF.md)."""
+    from lightgbm_tpu.ops.ordered_grow import _size_classes
+    n = 32768
+    classes = _size_classes(n)
+    assert len(classes) >= 3
+    text = _ordered_grower_text(spec, monkeypatch, n)
+    lane = n + classes[-1]
+    assert f"s32[{lane}]" in text and "dynamic-update-slice(" in text
+    assert _whole_lane_copies_in_grow_loop(text, lane) == []
 
 
 def test_fused_gain_kernel_is_refused_with_the_quoted_words(spec):
