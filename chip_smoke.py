@@ -342,26 +342,35 @@ def multichip_phase(rows: int, seed: int, chips: int, rounds: int = 5):
     # models/gbdt.py warns and grows serially when it finds fewer devices
     check(g._parallel_grow_active, "chips: the parallel learner is not "
           "active (fell back to serial)")
-    for name, arr in (("binned matrix", g.train_data.bins),
-                      ("scores", g.train_data.score)):
+    # placement by shard (models/gbdt.py _DeviceData): every array of the
+    # resident training state is one equal block on each device
+    td = g.train_data
+    for name, arr in (("binned matrix", td.bins),
+                      ("row-major bins", td.bins_rm),
+                      ("bin words", td.bins_words[0]),
+                      ("scores", td.score),
+                      ("labels", g._grad_arrays["label"])):
         shards = arr.addressable_shards
         check(len(shards) == chips
               and len({s.device for s in shards}) == chips
-              and all(s.data.shape[-1] == rows // chips for s in shards),
-              f"chips: {name} is not one row block on each of {chips} "
+              and all(s.data.size * chips == arr.size for s in shards),
+              f"chips: {name} is not one equal block on each of {chips} "
               f"devices: {[(str(s.device), s.data.shape) for s in shards]}")
-    # the learner asks for a reduce-scatter of the histograms; the chip's
-    # compiler may serve it as an all-reduce plus a slice, so either counts
+    # leaf-ordered shards: one all-reduce of digit sums a split step (the
+    # kernel is the serial learner's digit_histogram)
     text = compiled_step_text(par)
-    obs = {"reduce_scatter": text.count(" reduce-scatter("),
-           "all_reduce": text.count(" all-reduce("),
-           "custom_calls": text.count("tpu_custom_call")}
-    say(f"chips: one shard of bins and scores on each of {chips} devices; "
-        f"compiled step holds {obs['reduce_scatter']} reduce-scatter, "
-        f"{obs['all_reduce']} all-reduce, {obs['custom_calls']} "
-        "tpu_custom_call")
-    check(obs["reduce_scatter"] + obs["all_reduce"] > 0,
-          "chips: the compiled step holds no reduce-scatter/all-reduce")
+    obs = {"all_reduce": text.count(" all-reduce("),
+           "custom_calls": text.count("tpu_custom_call"),
+           "comm_calls_per_tree": g._comm_traffic_totals[0]}
+    say(f"chips: one block of bins, words, scores and labels on each of "
+        f"{chips} devices; compiled step holds {obs['all_reduce']} "
+        f"all-reduce, {obs['custom_calls']} tpu_custom_call; "
+        f"{obs['comm_calls_per_tree']} collective calls a tree")
+    check(obs["all_reduce"] > 0,
+          "chips: the compiled step holds no all-reduce")
+    check("digit_histogram" in text,
+          "chips: the sharded step does not run the leaf-ordered grower's "
+          "kernel")
 
     t0 = time.time()
     ser, ser_obs = train_once(lgb, PARAMS, dataset, rounds, 1)

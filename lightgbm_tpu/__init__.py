@@ -3,8 +3,8 @@
 A from-scratch re-design of the LightGBM v2 feature set for TPU hardware:
 histogram construction and leaf-wise split search run as fused XLA/Pallas
 programs over a `jax.sharding.Mesh`; the reference's socket/MPI collective
-layer (src/network/) is replaced by XLA collectives (psum/psum_scatter/
-all_gather) inside shard_map.
+layer (src/network/) is replaced by XLA collectives (psum/all_gather)
+inside shard_map.
 """
 
 __version__ = "0.1.0"

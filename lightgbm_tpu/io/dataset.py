@@ -20,6 +20,7 @@ from typing import Any, Dict, List, Optional, Sequence
 import numpy as np
 
 from ..utils import log
+from ..utils.threads import map_features
 from .binning import BinMapper, CATEGORICAL, NUMERICAL
 from .bundling import BundlePlan, plan_bundles
 
@@ -283,8 +284,10 @@ class BinnedDataset:
                     feature_bins, num_data, dtype)
             else:
                 self.bins = np.zeros((len(used), num_data), dtype=dtype)
-                for inner in range(len(used)):
+
+                def fill(inner):
                     self.bins[inner] = feature_bins(inner).astype(dtype)
+                map_features(fill, range(len(used)), num_data)
 
         if keep_raw and used:
             # feature-major like ``bins`` so the linear-fit gather reads

@@ -190,7 +190,7 @@ class _DeviceData:
     def __init__(self, dataset: BinnedDataset, num_models: int,
                  with_row_major: bool = False,
                  padded_rows: Optional[int] = None,
-                 with_raw: bool = False):
+                 with_raw: bool = False, mesh=None):
         self.dataset = dataset
         self.num_data = dataset.num_data
         self.padded_rows = max(int(padded_rows or 0), dataset.num_data)
@@ -198,13 +198,24 @@ class _DeviceData:
         bins_np = dataset.bins if pad == 0 else \
             np.pad(dataset.bins, ((0, 0), (0, pad)))
         h2d_xfers, h2d_bytes = 1, int(bins_np.nbytes)
+        # ``mesh`` (a row-sharded learner in one process): every device
+        # receives its own row block straight from the host, so a chip
+        # never holds more than its shard — not even while loading
+        self.mesh = mesh
+        put_cols = jnp.asarray if mesh is None else \
+            functools.partial(_put_row_blocks, mesh)
         # Native uint8/uint16 on device (int32 would 4x the HBM footprint
         # and the histogram kernel's read traffic).
-        self.bins = jnp.asarray(bins_np)
+        self.bins = put_cols(bins_np)
         # Row-major copy for the cached serial learner's leaf gathers
         # (ops/leafhist.py needs rows contiguous).
-        self.bins_rm = (jnp.asarray(np.ascontiguousarray(bins_np.T))
-                        if with_row_major else None)
+        self.bins_rm = None
+        if with_row_major and mesh is None:
+            self.bins_rm = jnp.asarray(np.ascontiguousarray(bins_np.T))
+        elif with_row_major:
+            self.bins_rm = jax.make_array_from_callback(
+                bins_np.T.shape, _row_sharding(mesh, 2),
+                lambda idx: np.ascontiguousarray(bins_np[:, idx[0]].T))
         if self.bins_rm is not None:
             h2d_xfers += 1
             h2d_bytes += int(bins_np.nbytes)
@@ -212,11 +223,14 @@ class _DeviceData:
         # across trees (uint8 bins only; uint16 routes to the cached
         # learner).
         self.bins_words = None
-        if with_row_major and self.bins_rm is not None \
-                and self.bins_rm.dtype == jnp.uint8:
+        if self.bins_rm is not None and self.bins_rm.dtype == jnp.uint8:
             from ..ops.ordered_grow import _size_classes
-            self.bins_words = _pack_words_padded(
-                self.bins_rm, _size_classes(self.padded_rows)[-1])
+            if mesh is None:
+                self.bins_words = _pack_words_padded(
+                    self.bins_rm, _size_classes(self.padded_rows)[-1])
+            else:
+                from ..parallel import shard_words
+                self.bins_words = shard_words(mesh, self.bins_rm)
         # raw f32 feature values for the linear-tree fit and its replay
         # epilogues (docs/LINEAR_TREES.md): NaN imputed to 0.0 ON UPLOAD
         # so the device fit and every predict path agree exactly; pad
@@ -228,7 +242,7 @@ class _DeviceData:
                               dataset.raw).astype(np.float32)
             if pad:
                 raw_np = np.pad(raw_np, ((0, 0), (0, pad)))
-            self.raw = jnp.asarray(raw_np)
+            self.raw = put_cols(raw_np)
             h2d_xfers += 1
             h2d_bytes += int(raw_np.nbytes)
         init = np.zeros((num_models, self.padded_rows), np.float32)
@@ -236,7 +250,7 @@ class _DeviceData:
             init[:, :self.num_data] += np.asarray(
                 dataset.metadata.init_score,
                 np.float32).reshape(num_models, self.num_data)
-        self.score = jnp.asarray(init)
+        self.score = put_cols(init)
         obs.devprof.transfer("h2d", "dataset",
                              h2d_bytes + int(init.nbytes),
                              transfers=h2d_xfers + 1)
@@ -254,7 +268,8 @@ class _DeviceData:
         if score.shape[-1] < self.padded_rows:
             score = np.pad(score, ((0, 0),
                                    (0, self.padded_rows - score.shape[-1])))
-        self.score = jnp.asarray(score)
+        self.score = jnp.asarray(score) if self.mesh is None \
+            else _put_row_blocks(self.mesh, score)
 
     def add_tree(self, tree_arrays, is_cat, cls: int, max_steps: int,
                  bundle=None):
@@ -265,6 +280,33 @@ class _DeviceData:
             tree_arrays.left_child, tree_arrays.right_child,
             tree_arrays.leaf_value, self.bins, max_steps, bundle=bundle)
         self.score = self.score.at[cls].add(delta)
+
+
+def _row_sharding(mesh, ndim: int, row_axis: int = 0):
+    """Rows (dimension ``row_axis`` of ``ndim``) in one block per device
+    of the mesh's first axis, everything else whole."""
+    from jax.sharding import NamedSharding, PartitionSpec
+    spec = [None] * ndim
+    spec[row_axis] = mesh.axis_names[0]
+    return NamedSharding(mesh, PartitionSpec(*spec))
+
+
+def _put_row_blocks(mesh, host):
+    """A host array whose LAST dimension is rows, placed one row block
+    per device: each block goes from the host to its own device."""
+    host = np.asarray(host)
+    return jax.device_put(host,
+                          _row_sharding(mesh, host.ndim, host.ndim - 1))
+
+
+def shard_padded_rows(num_data: int, shards: int) -> int:
+    """Rows of a row-sharded dataset: equal blocks, each a whole number
+    of the histogram kernel's 8,192-row blocks once it is that large
+    (the root pass then pads nothing); pad rows sit at the end of the
+    last block with zero weight."""
+    per = -(-max(int(num_data), 1) // shards)
+    step = 8192 if per > 8192 else 8
+    return shards * (-(-per // step) * step)
 
 
 @obs.instrumented_jit(program="finite_guard")
@@ -578,7 +620,8 @@ class GBDT:
         self.train_set = train_set
         self.data_fingerprint = getattr(train_set, "data_fingerprint", None)
         self.objective = objective or create_objective(cfg)
-        self.objective.init(train_set.metadata, train_set.num_data)
+        self._mesh = self._learner_mesh(cfg)
+        self._init_objective(train_set)
         self.num_class = self.objective.num_tree_per_iteration
         self.num_data = train_set.num_data
         self.num_features = train_set.num_features
@@ -593,21 +636,7 @@ class GBDT:
         self.grow_params = self._make_grow_params(cfg)
         self.shrinkage_rate = cfg.learning_rate
 
-        # shape-bucketed training rows (utils/compile_cache.py): nearby
-        # dataset sizes share one compiled train_step/grow program.
-        # Legacy custom objectives (pre-round-7 gradients() overrides)
-        # close over unpadded arrays, so they opt out.
-        self._padded_rows = (compile_cache.bucket_rows(self.num_data)
-                             if self._row_buckets_enabled(cfg)
-                             and not self.objective.uses_legacy_gradients()
-                             else self.num_data)
-        self._linear = self._setup_linear(cfg, train_set)
-        self._check_memory_budget(cfg, train_set)
-        with obs.span("Dataset::to_device"):
-            self.train_data = _DeviceData(
-                train_set, self.num_class, with_row_major=True,
-                padded_rows=self._padded_rows,
-                with_raw=self._linear is not None)
+        self._place_training_data(cfg, train_set)
         self.valid_data: List[_DeviceData] = []
         self.valid_metrics: List[List[Metric]] = []
         self.train_metrics = self._make_metrics(cfg, train_set)
@@ -628,7 +657,7 @@ class GBDT:
         self._bag_key = jax.random.PRNGKey(cfg.bagging_seed)
         self._feature_rng = np.random.RandomState(cfg.feature_fraction_seed)
         self._init_row_state()
-        self._grad_arrays = self.objective.gradient_arrays(self._padded_rows)
+        self._grad_arrays = self._make_grad_arrays()
         self._grad_fn = self._make_grad_fn()
         self._setup_screening(cfg)
         self._grow_fn = self._make_grow_fn()
@@ -748,30 +777,116 @@ class GBDT:
         un-bagged iteration reuses."""
         mask = np.zeros(self._padded_rows, bool)
         mask[:self.num_data] = True
-        self._real_rows = jnp.asarray(mask)
-        self._ones_weight = jnp.asarray(mask.astype(np.float32))
+        put = jnp.asarray if self._row_mesh is None else \
+            functools.partial(_put_row_blocks, self._row_mesh)
+        self._real_rows = put(mask)
+        self._ones_weight = put(mask.astype(np.float32))
         self._row_weight = self._ones_weight
 
-    def _shard_rows(self, mesh) -> None:
-        """Single-process row-sharded learners (data, voting): place the
-        binned matrix, the score cache and the row weights on the mesh
-        ONCE, one row block per device.  Left where ``_DeviceData`` put
-        them (the first device), every train step would move the whole
-        matrix across the chips again before its shard_map could start.
-        A row count the mesh does not divide stays put: the grow program
-        pads it per call."""
-        if self._padded_rows % mesh.devices.size:
-            return
+    @staticmethod
+    def _learner_mesh(cfg: Config):
+        """The device mesh of a distributed tree learner, or None: the
+        serial learner, or a single device to run on.  num_machines
+        bounds the mesh (it is the reference's machine count; here a
+        device count)."""
+        if not getattr(cfg, "is_parallel", False):
+            return None
+        ndev = len(jax.devices())
+        # single-controller-per-host: num_machines counts HOSTS (the
+        # reference's machine list, wired up by parallel/multihost.py);
+        # under a multi-process runtime the mesh spans every global
+        # device.  In one process it bounds the local mesh instead
+        # (the virtual-device test rigs).
+        k = ndev if jax.process_count() > 1 else min(cfg.num_machines, ndev)
+        if k <= 1:
+            log.warning("tree_learner=%s requested but only %d device(s) "
+                        "available; falling back to serial",
+                        cfg.tree_learner, ndev)
+            return None
+        from jax.sharding import Mesh
+        return Mesh(np.array(jax.devices()[:k]), ("data",))
+
+    @property
+    def _row_mesh(self):
+        """The mesh whose devices each hold one row block of the
+        training state: a row-sharded learner (data, voting) in one
+        process.  Feature-parallel replicates the rows; under a
+        multi-process runtime parallel/multihost.py promotes each
+        process's arrays per call."""
+        if self._mesh is None or jax.process_count() > 1 \
+                or self.config.tree_learner == "feature":
+            return None
+        return self._mesh
+
+    def _init_objective(self, train_set: BinnedDataset) -> None:
+        """``objective.init`` keeps whole-dataset label and weight
+        arrays.  For a row-sharded learner they stay on the host's own
+        backend (where there is one): ``_make_grad_arrays`` then hands
+        each device its block, and no chip holds all labels."""
+        with jax.default_device(self._host_device()):
+            self.objective.init(train_set.metadata, train_set.num_data)
+
+    def _host_device(self):
+        """Where a row-sharded learner keeps whole-dataset arrays until
+        they are placed in blocks: the host's own backend, or None (JAX's
+        default device) for every other learner and where no CPU backend
+        is up."""
+        if self._row_mesh is None:
+            return None
+        try:
+            return jax.devices("cpu")[0]
+        except RuntimeError:
+            return None
+
+    def _place_training_data(self, cfg: Config,
+                             train_set: BinnedDataset) -> None:
+        """Row padding, the admission gate and the device-resident
+        training data.  Serial training pads rows up a shared shape
+        bucket (utils/compile_cache.py): nearby dataset sizes share one
+        compiled train_step/grow program (legacy custom objectives close
+        over unpadded arrays, so they opt out).  A row-sharded learner
+        pads to equal row blocks and places one on each device."""
+        mesh = self._row_mesh
+        if mesh is not None:
+            self._padded_rows = shard_padded_rows(self.num_data,
+                                                  mesh.devices.size)
+        else:
+            self._padded_rows = (
+                compile_cache.bucket_rows(self.num_data)
+                if self._row_buckets_enabled(cfg)
+                and not self.objective.uses_legacy_gradients()
+                else self.num_data)
+        self._linear = self._setup_linear(cfg, train_set)
+        self._check_memory_budget(cfg, train_set)
+        # data-parallel over uint8 unbundled bins grows leaf-ordered
+        # shards, which read the row-major layout the serial learner does
+        from ..parallel.grow import grows_ordered
+        ordered_shards = grows_ordered(cfg.tree_learner, train_set.bins.dtype,
+                                       self._bundle is not None)
+        with obs.span("Dataset::to_device"):
+            self.train_data = _DeviceData(
+                train_set, self.num_class,
+                with_row_major=mesh is None or ordered_shards,
+                padded_rows=self._padded_rows,
+                with_raw=self._linear is not None, mesh=mesh)
+
+    def _make_grad_arrays(self):
+        """The objective's per-dataset arrays at the padded row count;
+        for a row-sharded learner the row-aligned ones in row blocks,
+        the rest replicated."""
+        mesh = self._row_mesh
+        if mesh is None:
+            return self.objective.gradient_arrays(self._padded_rows)
         from jax.sharding import NamedSharding, PartitionSpec
-        axis = mesh.axis_names[0]
-        cols = NamedSharding(mesh, PartitionSpec(None, axis))
-        rows = NamedSharding(mesh, PartitionSpec(axis))
-        td = self.train_data
-        td.bins = jax.device_put(td.bins, cols)
-        td.score = jax.device_put(td.score, cols)
-        self._real_rows = jax.device_put(self._real_rows, rows)
-        self._ones_weight = jax.device_put(self._ones_weight, rows)
-        self._row_weight = self._ones_weight
+        with jax.default_device(self._host_device()):
+            arrays = self.objective.gradient_arrays(self._padded_rows)
+        whole = NamedSharding(mesh, PartitionSpec())
+
+        def place(a):
+            if getattr(a, "ndim", 0) and a.shape[-1] == self._padded_rows:
+                return _put_row_blocks(mesh, a)
+            return jax.device_put(a, whole)
+        return jax.tree.map(place, arrays)
 
     def _make_grad_fn(self):
         """Per-booster binding of the SHARED gradients program: the
@@ -830,7 +945,7 @@ class GBDT:
         soon as the footprint fits."""
         fused = cfg.serial_grow == "fused"
         return estimate_train_memory(
-            self._padded_rows, train_set.num_columns, cfg.num_leaves,
+            self._rows_per_device(), train_set.num_columns, cfg.num_leaves,
             cfg.max_bin, self.num_class,
             bin_itemsize=train_set.bins.dtype.itemsize,
             donate_score=not guard and self._donation_on(),
@@ -838,6 +953,13 @@ class GBDT:
             leaf_cache=not fused and not self._degrade_leaf_cache_off,
             linear_k=(self._linear.max_features
                       if self._linear is not None else 0))
+
+    def _rows_per_device(self) -> int:
+        """Rows the fullest device holds: all of them, or one block of a
+        row-sharded learner's."""
+        mesh = self._row_mesh
+        return self._padded_rows // (mesh.devices.size if mesh is not None
+                                     else 1)
 
     def _donation_on(self) -> bool:
         """This booster's round-to-round donation decision (before the
@@ -961,8 +1083,9 @@ class GBDT:
                           "cache — children recompute instead of "
                           "sibling-subtraction (slower, never wrong)")
             elif step == "row_pad":
-                if self._padded_rows <= self.num_data:
-                    continue
+                if self._padded_rows <= self.num_data \
+                        or self._row_mesh is not None:
+                    continue        # equal row blocks are not a bucket pad
                 pad = self._padded_rows - self.num_data
                 saved = est["total"] - self._estimate_probe_rows(
                     cfg, train_set, guard)["total"]
@@ -1023,64 +1146,47 @@ class GBDT:
 
     def _make_grow_fn(self):
         """Pick the tree learner (TreeLearner::CreateTreeLearner,
-        tree_learner.cpp:1-26): serial, or a distributed learner over a
-        device mesh when tree_learner != serial and >1 device is present.
-        num_machines bounds the mesh size (it is the reference's machine
-        count; here it is a device count)."""
+        tree_learner.cpp:1-26): serial, or a distributed learner over
+        the device mesh ``_learner_mesh`` found."""
         cfg = self.config
         self._comm_traffic = None           # serial: no collectives
         self._comm_traffic_totals = (0, 0)
         self._parallel_grow_active = False
-        if getattr(cfg, "is_parallel", False):
-            ndev = len(jax.devices())
-            # single-controller-per-host: num_machines counts HOSTS (the
-            # reference's machine list, wired up by parallel/multihost.py);
-            # under a multi-process runtime the mesh spans every global
-            # device.  In one process it bounds the local mesh instead
-            # (the virtual-device test rigs).
-            k = ndev if jax.process_count() > 1 \
-                else min(cfg.num_machines, ndev)
-            if k > 1:
-                from jax.sharding import Mesh
-                from ..parallel import make_parallel_grow
-                mesh = Mesh(np.array(jax.devices()[:k]), ("data",))
-                log.info("Using %s-parallel tree learner over %d devices",
-                         cfg.tree_learner, k)
-                fn = make_parallel_grow(mesh, cfg.tree_learner,
-                                        self.grow_params, top_k=cfg.top_k)
-                # static per-tree collective account (obs layer): computed
-                # once from shapes, accumulated per iteration.  Under EFB
-                # data-parallel reduces COLUMN-shaped histograms (and is
-                # forced to the full psum — mirrored by bundled=True);
-                # voting/feature ship per-ORIGINAL-feature payloads.
-                from ..parallel.comm import traffic_totals
-                traffic_f = (self.num_columns if cfg.tree_learner == "data"
-                             else self.num_features)
-                self._comm_traffic = fn.traffic_per_tree(
-                    traffic_f, bundled=self._bundle is not None)
-                self._comm_traffic_totals = traffic_totals(self._comm_traffic)
-                self._parallel_grow_active = True
-                if jax.process_count() == 1 \
-                        and cfg.tree_learner != "feature":
-                    self._shard_rows(mesh)
-                if jax.process_count() > 1:
-                    # multi-controller runtime: promote per-process inputs
-                    # to global arrays / gather sharded outputs back
-                    # (bundling is disabled under multihost loading, so
-                    # the wrapped signature never carries a bundle)
-                    from ..parallel.multihost import globalize_grow_fn
-                    fn = globalize_grow_fn(fn, mesh)
-                    return (lambda view, nb, ic, fm, g, h, w, lr:
-                            fn(view.bins, nb, ic, fm, g, h, w, lr))
-                if self._bundle is None:
-                    return (lambda view, nb, ic, fm, g, h, w, lr:
-                            fn(view.bins, nb, ic, fm, g, h, w, lr))
+        mesh = self._mesh
+        if mesh is not None:
+            from ..parallel import make_parallel_grow
+            log.info("Using %s-parallel tree learner over %d devices",
+                     cfg.tree_learner, mesh.devices.size)
+            fn = make_parallel_grow(mesh, cfg.tree_learner,
+                                    self.grow_params, top_k=cfg.top_k)
+            # static per-tree collective account (obs layer): computed
+            # once from shapes, accumulated per iteration.  Data-parallel
+            # exchanges COLUMN-shaped sums (digit sums where it grows
+            # leaf-ordered shards, float histograms under EFB or uint16
+            # bins); voting/feature ship per-ORIGINAL-feature payloads.
+            from ..parallel.comm import traffic_totals
+            traffic_f = (self.num_columns if cfg.tree_learner == "data"
+                         else self.num_features)
+            self._comm_traffic = fn.traffic_per_tree(
+                traffic_f, bundled=self._bundle is not None,
+                bins_dtype=self.train_data.bins.dtype)
+            self._comm_traffic_totals = traffic_totals(self._comm_traffic)
+            self._parallel_grow_active = True
+            if jax.process_count() > 1:
+                # multi-controller runtime: promote per-process inputs
+                # to global arrays / gather sharded outputs back
+                # (bundling is disabled under multihost loading, so
+                # the wrapped signature never carries a bundle)
+                from ..parallel.multihost import globalize_grow_fn
+                fn = globalize_grow_fn(fn, mesh)
                 return (lambda view, nb, ic, fm, g, h, w, lr:
-                        fn(view.bins, nb, ic, fm, g, h, w, lr,
-                           bundle=view.bundle))
-            log.warning("tree_learner=%s requested but only %d device(s) "
-                        "available; falling back to serial",
-                        cfg.tree_learner, ndev)
+                        fn(view.bins, nb, ic, fm, g, h, w, lr))
+            # bins_rm / bins_words: the leaf-ordered shards' resident
+            # layout (None for every other learner, which ignores them)
+            return (lambda view, nb, ic, fm, g, h, w, lr:
+                    fn(view.bins, nb, ic, fm, g, h, w, lr,
+                       bundle=view.bundle, bins_rm=view.bins_rm,
+                       bins_words=view.bins_words))
         params = self.grow_params
         kind = self._serial_grow_kind()
         if kind == "ordered":
@@ -1142,9 +1248,15 @@ class GBDT:
             self._full_view = self._make_full_view()
             self._train_step = None
         new_params = self._make_grow_params(config)
-        if new_params != self.grow_params or (
-                old_cfg is not None
-                and old_cfg.tree_learner != config.tree_learner):
+        if old_cfg is not None and (
+                old_cfg.tree_learner, old_cfg.num_machines) != (
+                config.tree_learner, config.num_machines):
+            # another learner or mesh: the training state is placed for
+            # the one it was built for (whole on a device, or one row
+            # block a device), so it is placed again
+            self.grow_params = new_params
+            self.reset_training_data(self.train_set)
+        elif new_params != self.grow_params:
             # Rebuild only when the jitted growth program actually changes:
             # a fresh closure would force an XLA recompile every iteration
             # under reset_parameter schedules (learning_rate is a runtime
@@ -1178,15 +1290,12 @@ class GBDT:
         if new_fp is not None:
             self.data_fingerprint = new_fp
         self.num_data = train_set.num_data
-        self.objective.init(train_set.metadata, train_set.num_data)
+        self._mesh = self._learner_mesh(cfg)
+        self._init_objective(train_set)
         self.num_bin = jnp.asarray(train_set.num_bin_per_feature())
         self.is_cat = jnp.asarray(train_set.is_categorical_per_feature())
         self.num_columns = train_set.num_columns
         self._setup_bundle(train_set, cfg)
-        self._padded_rows = (compile_cache.bucket_rows(self.num_data)
-                             if self._row_buckets_enabled(cfg)
-                             and not self.objective.uses_legacy_gradients()
-                             else self.num_data)
         # re-run the HBM admission gate against the NEW dataset: the
         # recomputed pad would otherwise silently undo a row_pad degrade
         # step, and a larger reset dataset must be refused/degraded here
@@ -1194,14 +1303,8 @@ class GBDT:
         # valid-set accounting survives the gate's reset (valid sets
         # are not touched by a training-data swap).
         valid_bytes = getattr(self, "_valid_mem_bytes", 0)
-        self._linear = self._setup_linear(cfg, train_set)
-        self._check_memory_budget(cfg, train_set)
+        self._place_training_data(cfg, train_set)
         self._valid_mem_bytes = valid_bytes
-        with obs.span("Dataset::to_device"):
-            self.train_data = _DeviceData(
-                train_set, self.num_class, with_row_major=True,
-                padded_rows=self._padded_rows,
-                with_raw=self._linear is not None)
         self.train_metrics = self._make_metrics(cfg, train_set)
         self._init_row_state()
         self._full_feat_mask = jnp.ones(self.num_features, bool)
@@ -1210,7 +1313,7 @@ class GBDT:
         # rebind the SHARED gradients program to this dataset's arrays
         # (no retrace unless the shapes changed — the labels are runtime
         # arguments now, not compile-time constants)
-        self._grad_arrays = self.objective.gradient_arrays(self._padded_rows)
+        self._grad_arrays = self._make_grad_arrays()
         self._grad_fn = self._make_grad_fn()
         self._setup_screening(cfg)
         self._grow_fn = self._make_grow_fn()
@@ -1474,16 +1577,20 @@ class GBDT:
     def _make_train_step_local(self, guard: bool):
         """Per-booster fused step for the distributed learners: their
         grow fn closes over a device mesh (shard_map), which the shared
-        registry cannot key portably."""
+        registry cannot key portably.  Every per-dataset array is an
+        ARGUMENT, as in the shared step: closed over, the labels were
+        compiled in as a constant (168 MB at 42M rows, on every device),
+        and since they differ from data set to data set the persistent
+        compile cache never served the program twice."""
         grow = self._grow_fn
-        obj_grad = self._grad_fn
-        num_bin, is_cat = self.num_bin, self.is_cat
+        holder = self.objective.program_holder()
         num_class = self.num_class
 
         @obs.instrumented_jit(program="train_step")
-        def step_fn(score, feat_masks, row_weight, lr, view):
+        def step_fn(score, feat_masks, row_weight, lr, view, num_bin,
+                    is_cat, grad_arrays):
             with jax.named_scope("gradients"):
-                grad, hess = obj_grad(score)
+                grad, hess = holder.gradients_with(grad_arrays, score)
                 ok = (_all_finite(grad, hess) if guard
                       else jnp.asarray(True))
             outs = []
@@ -1496,7 +1603,13 @@ class GBDT:
                     packed = pack_tree_arrays(ta)
                 outs.append((packed, ta, delta))
             return score, outs, ok
-        return step_fn
+
+        data = (self.num_bin, self.is_cat, self._grad_arrays)
+
+        def step(*a):
+            return step_fn(*a, *data)
+        step.lower = lambda *a: step_fn.lower(*a, *data)
+        return step
 
     # -- pipelined host materialization --------------------------------
     @property

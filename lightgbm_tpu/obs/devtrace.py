@@ -104,8 +104,9 @@ def _operands(body: str) -> List[str]:
 def parse_hlo(text: str) -> Dict[str, Any]:
     """``{"module": name, "instructions": {name: record}}`` from
     ``compile().as_text()``.  A record: ``comp`` (its computation),
-    ``root``, ``opcode``, ``operands`` (names), ``op_name``, ``called``
-    ([(attribute, computation)])."""
+    ``root``, ``opcode``, ``operands`` (names), ``op_name``, ``located``
+    (its metadata holds a source location: the program's own operation),
+    ``called`` ([(attribute, computation)])."""
     module, comp = "", ""
     instrs: Dict[str, Dict[str, Any]] = {}
     for line in text.splitlines():
@@ -129,7 +130,9 @@ def parse_hlo(text: str) -> Dict[str, Any]:
         instrs[m.group(2)] = {
             "comp": comp, "root": bool(m.group(1)), "opcode": opcode,
             "operands": _operands(body[len(opcode):]) if paren else [],
-            "op_name": op.group(1) if op else None, "called": called}
+            "op_name": op.group(1) if op else None,
+            "located": "stack_frame_id=" in line or "source_file=" in line,
+            "called": called}
     return {"module": module, "instructions": instrs}
 
 
@@ -167,12 +170,23 @@ def phase_map(text: str) -> Dict[str, Any]:
     own: Dict[str, Optional[str]] = {}
     for name, rec in instrs.items():
         op = rec["op_name"]
-        if op and op.rpartition("/")[2].startswith("jit(") \
+        if op and op.rpartition("/")[2].startswith(("jit(", "shard_map")) \
                 and phases.leaf_phase(op + "/") is None:
             # a literal that jax hoisted to the top of a jitted function
-            # carries the CALL's path and no primitive: nameless, unless
-            # the call itself sits under a scope
+            # (or of a shard_map's body) carries the CALL's path and no
+            # primitive: nameless, unless the call itself sits under a
+            # scope
             op = None
+        elif op and op.rpartition("/")[0].endswith("/shard_map") \
+                and phases.leaf_phase(op) is None:
+            # the compiler inlines a shard_map's body and puts the call's
+            # path before what was bare or nameless in it
+            # (``.../shard_map/shift-right-logical.22``: an instruction
+            # it made itself, nameless in the serial program): bare or
+            # nameless again, so it resolves through its operands as
+            # there.  The learners' bodies hold one call of a jitted
+            # grower and no operation of their own that this could hide
+            op = op.rpartition("/")[2] if rec["located"] else None
         named[name] = op
         ph = phases.leaf_phase(op) if op else None
         if rec["opcode"] == "fusion":
@@ -464,7 +478,8 @@ def reduce_dir(trace_dir: str, rounds: int = 1,
     """Reduce the newest window under ``trace_dir`` by the phase maps
     ``map_paths`` (every ``phase_map.*.json`` there when None) and write
     ``device_phases.json`` there.  The first device's phases are the
-    report's; every device's busy and window seconds are listed.  A
+    report's; every device's busy and window seconds and its phases' ms a
+    round are listed.  A
     backend whose trace has no ``/device:TPU:n`` plane (the CPU) gets a
     report with no phases.  None when there is no trace to read."""
     path = find_xplane(trace_dir)
@@ -480,8 +495,12 @@ def reduce_dir(trace_dir: str, rounds: int = 1,
     for ordinal in sorted(devices):
         r = reduce_events(devices[ordinal]["ops"], devices[ordinal]["modules"],
                           maps, planes["host"], rounds)
-        per_device[str(ordinal)] = {"busy_s": r["busy_s"],
-                                    "window_s": r["window_s"]}
+        # a sharded round: the exchange phases hold each shard's wait
+        # for the slowest, so they differ by device where the rest agrees
+        per_device[str(ordinal)] = {
+            "busy_s": r["busy_s"], "window_s": r["window_s"],
+            "phases_ms_per_round": {p: v["ms_per_round"]
+                                    for p, v in r["phases"].items()}}
         report = report or r
     if report is None:
         report = reduce_events([], [], maps, planes["host"], rounds)

@@ -15,7 +15,9 @@ boundaries:
   programs.  The fused round (models/gbdt.py ``train_step`` over
   ops/ordered_grow.py) is covered whole: every operation traced into it
   sits under exactly one LEAF phase, the innermost declared scope of its
-  ``op_name`` (``leaf_phase`` below).  The chip's trace names a device
+  ``op_name`` (``leaf_phase`` below); so is the data-parallel learner's
+  round over leaf-ordered shards, whose collectives are the two
+  ``exchange/*`` phases (parallel/comm.py).  The chip's trace names a device
   event by its HLO text and carries no scope path, so the program joins
   the two itself: ``obs/compile_ledger.py`` exports
   ``{instruction -> leaf phase}`` from the compiled text and
@@ -87,6 +89,9 @@ ROUND_PHASES = (
     "gradients",          # objective gradients, weighting, root sums
     "layout",             # digit quantisation, word packing, the
                           # compaction sort
+    "exchange/root",      # data-parallel shards only (parallel/comm.py
+                          # HistExchange): the scales' max and the
+                          # root's sums and histogram over the shards
     "hist/root",          # root pass: combine, cache seed (the kernel
                           # itself is hist/kernel, its feed hist/window)
     "grow_loop",          # the fori_loop itself, best-leaf pick, the
@@ -97,6 +102,10 @@ ROUND_PHASES = (
     "split/window_write",  # sorted window written back in place
     "hist/window",        # slices and unpacking that feed the kernel
     "hist/kernel",        # digit_histogram (Pallas) or its scatter twin
+    "exchange/hist",      # data-parallel shards only: the one all-reduce
+                          # of a split step, a shard's left child's digit
+                          # sums; its device time holds the wait for the
+                          # slowest shard
     "hist/subtract",      # sibling subtraction and the cache update
     "find_split",         # root and children
     "leaf_table",         # node and leaf rows, TreeArrays
@@ -149,17 +158,19 @@ TRANSFER_PHASES = frozenset({
 def leaf_phase(op_name):
     """The leaf phase of an HLO ``op_name`` path
     (``jit(step_fn)/grow_loop/while/body/.../split/sort/sort``): the
-    INNERMOST declared device phase on the path, the longer name where
-    two start at the same component (``hist/kernel`` over ``hist``).
-    The last component is the primitive and never a scope.  None when no
-    declared phase is on the path."""
+    INNERMOST declared device phase on the path, which is the one that
+    ends last; the longer name where two end at the same component
+    (``hist/kernel`` over ``hist`` after it, ``exchange/hist`` over the
+    ``hist`` inside it).  The last component is the primitive and never
+    a scope.  None when no declared phase is on the path."""
     path = "/" + str(op_name).rpartition("/")[0] + "/"
-    best, best_at = None, -1
+    best, best_end = None, -1
     for phase in DEVICE_PHASES:
         at = path.rfind("/" + phase + "/")
-        if at > best_at or (at == best_at and at >= 0
-                            and len(phase) > len(best)):
-            best, best_at = phase, at
+        end = at + len(phase)
+        if at >= 0 and (end > best_end or (end == best_end
+                                           and len(phase) > len(best))):
+            best, best_end = phase, end
     return best
 
 

@@ -82,12 +82,36 @@ def quantize_digits(g, h, w, scales):
     return digits.reshape(9, -1).T.astype(jnp.int8)   # [N, 9]
 
 
+def split_halves(sums_i32):
+    """int32 digit sums [..., 9, B] -> [..., 18, B]: the high 16 bits of
+    every sum (signed) in streams 0..8, the low 16 bits in 9..17.  A sum
+    of halves over any number of shards stays far inside int32 where the
+    sum of whole int32 sums would wrap (2^31 / 128 rows of one bin), and
+    ``value = high * 65536 + low`` stays linear, so the cached parent's
+    halves minus a child's are the sibling's."""
+    return jnp.concatenate([sums_i32 >> 16, sums_i32 & 0xFFFF], axis=-2)
+
+
+def digit_row_counts(halves_i32):
+    """Whole rows per bin, int32 [..., B], from the weight stream of
+    ``split_halves`` sums whose row weights are 0 or 1: a row of weight 1
+    at scale 1 is 2^QBITS, all of it in the high digit (64).  float32
+    holds a count only to 2^24 rows; this one is exact to 2^31."""
+    unit = 1 << (QBITS - 16)
+    return (halves_i32[..., 6, :] * (65536 // unit)
+            + halves_i32[..., NUM_STREAMS + 6, :] // unit)
+
+
 def combine_digit_sums(sums_i32, scales):
-    """int32 digit sums [..., 9, B] -> f32 histogram [..., B, 3].
+    """int32 digit sums [..., 9, B], or their ``split_halves``
+    [..., 18, B], -> f32 histogram [..., B, 3].
 
     Exact up to one f32 rounding per entry: the digit sums themselves are
-    exact integers."""
+    exact integers (of halves, both terms are exact floats and their sum
+    rounds once, to what the whole integer would)."""
     s = sums_i32.astype(jnp.float32)
+    if sums_i32.shape[-2] == 2 * NUM_STREAMS:
+        s = s[..., :NUM_STREAMS, :] * 65536.0 + s[..., NUM_STREAMS:, :]
     out = []
     for v in range(3):
         acc = (s[..., 3 * v, :] * _DIGIT_W[0]

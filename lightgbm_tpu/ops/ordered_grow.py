@@ -73,7 +73,8 @@ from .split import BestSplit, find_best_split, leaf_output, K_MIN_SCORE
 # Column layout of the packed per-leaf / per-node state buffers.
 _LF = dict(best_gain=0, best_left_g=1, best_left_h=2, best_left_c=3,
            total_g=4, total_h=5, total_c=6, cur_value=7)
-_LI = dict(best_feat=0, best_bin=1, parent=2, depth=3, start=4, cnt=5)
+_LI = dict(best_feat=0, best_bin=1, parent=2, depth=3, start=4, cnt=5,
+           rows=6, best_left_rows=7)    # the last two: sharded growth only
 _ND = dict(feature=0, bin=1, gain=2, left=3, right=4, value=5, count=6)
 
 
@@ -134,11 +135,12 @@ def _put_row(buf, i, vec):
     return jax.lax.dynamic_update_slice(buf, vec[None, :], (i, 0))
 
 
-@instrumented_jit(program="grow_tree_ordered", static_argnames=("params",))
+@instrumented_jit(program="grow_tree_ordered",
+                  static_argnames=("params", "exchange"))
 def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
                       row_weight, learning_rate, params: GrowParams,
-                      bins_rm=None, bins_words=None):
-    """Drop-in replacement for ops.grow.grow_tree (serial learner only).
+                      bins_rm=None, bins_words=None, exchange=None):
+    """Drop-in replacement for ops.grow.grow_tree.
 
     Args/returns: see grow_tree.  ``bins_rm`` ([N, F] row-major) feeds the
     root histogram; ``bins_words`` (tuple of ceil(F/4) [N] i32 arrays from
@@ -150,7 +152,25 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
     zero ``row_weight``, so they ride the partition sorts inside
     segments without touching any histogram sum or weighted count —
     exactly like bagged-out rows — and ``compact_inactive`` moves them
-    behind the active segment together with the bagging zeros."""
+    behind the active segment together with the bagging zeros.
+
+    ``exchange`` (static; parallel/comm.py ``HistExchange``) makes this
+    the data-parallel learner's per-shard program under
+    ``jax.shard_map``: every array above is the shard's own row block,
+    and only histogram sums and scalars cross chips.  The quantisation
+    scales and the root's sums are global; each shard histograms the
+    smaller child of ITS OWN segment (never more than half a window,
+    whatever the skew between shards), keeps a second, local cache to
+    derive its sibling, and hands its left child's sums to one
+    all-reduce a split, outside the chain of conds (each shard is in
+    its own size class).  The global sums come back in 16-bit halves
+    (ops/leafhist.py ``split_halves``: whole int32 sums over all shards
+    could wrap); the global cache, the sibling subtraction and the
+    split search then run replicated on them, so gains,
+    thresholds, counts and ``min_data_in_leaf`` are the serial
+    learner's; segment starts and counts, lanes, sorts and the leaf
+    reconstruction stay local.  With ``exchange=None`` nothing below
+    differs from the serial program."""
     L = params.num_leaves
     B = params.max_bin
     F, N = bins.shape
@@ -180,6 +200,10 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
 
     with jax.named_scope("layout"):
         scales = leafhist.compute_scales(g, h, row_weight)
+    if exchange is not None:
+        scales, (root_g, root_h, root_c), root_rows = exchange.root(
+            scales, (root_g, root_h, root_c))
+    with jax.named_scope("layout"):
         digits = leafhist.quantize_digits(g, h, row_weight,
                                           scales)       # [N, 9] i8
 
@@ -192,7 +216,15 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
         dig_w = tuple(jnp.pad(dw, (0, PAD)) for dw in pack_u8_words(
             jax.lax.bitcast_convert_type(digits, jnp.uint8)))
         DW = len(dig_w)
-        row_ord = jnp.pad(jnp.arange(N, dtype=jnp.int32), (0, PAD))
+        if exchange is None:
+            row_ord = jnp.pad(jnp.arange(N, dtype=jnp.int32), (0, PAD))
+        else:
+            # the same lane with the pad numbered on (nothing reads a row
+            # id past N): the chip's compiler folds the padded iota into
+            # a constant of the lane's size, 109 MB of the executable at
+            # 10.5M rows (sandbox compile, PR 30; ROADMAP S5 has the
+            # serial program's)
+            row_ord = jnp.arange(N + PAD, dtype=jnp.int32)
 
     if params.compact_inactive:
         # one stable sort per tree (over the REAL N rows only — the
@@ -262,13 +294,36 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
         else:
             # root histogram over the initial (original-order) layout
             sums_root = leafhist.digit_histogram(bins_rm, digits, B)
+    if exchange is not None:
+        sums_root_local = sums_root
+        sums_root = exchange.hist(sums_root, root=True)
+    with jax.named_scope("hist/root"):
         hist_root = leafhist.combine_digit_sums(sums_root, scales)
+    def left_rows(sums, feature, threshold):
+        """The split's left count as an integer, from the exchanged sums'
+        weight stream (a float32 running count is exact only to 2^24
+        rows).  Sharded growth only, where a node holds all shards' rows."""
+        f = jnp.maximum(feature, 0)
+        col = leafhist.digit_row_counts(jax.lax.dynamic_index_in_dim(
+            sums, f, 0, keepdims=False))
+        b = jnp.arange(B, dtype=jnp.int32)
+        left = jnp.where(is_cat[f], b == threshold, b <= threshold)
+        return jnp.sum(jnp.where(left, col, 0))
+
     with jax.named_scope("find_split"):
         root_split = find_best_split(hist_root, root_g, root_h, root_c,
                                      num_bin, is_cat, feat_mask,
                                      jnp.asarray(True), sp)
+        if exchange is not None:
+            root_left_rows = left_rows(sums_root, root_split.feature,
+                                       root_split.threshold)
     with jax.named_scope("hist/root"):
-        cache = jnp.zeros((L, F, 9, B), jnp.int32).at[0].set(sums_root)
+        cache = jnp.zeros((L,) + sums_root.shape, jnp.int32) \
+            .at[0].set(sums_root)
+        # a shard's own sums by leaf, beside the global ones (in halves)
+        caches = (cache,) if exchange is None else (
+            cache, jnp.zeros((L, F, 9, B), jnp.int32)
+            .at[0].set(sums_root_local))
 
     with jax.named_scope("leaf_table"):
         root_f32 = jnp.stack([
@@ -281,6 +336,9 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
             .at[_LI["best_feat"]].set(root_split.feature) \
             .at[_LI["best_bin"]].set(root_split.threshold) \
             .at[_LI["cnt"]].set(root_cnt)
+        if exchange is not None:
+            root_i32 = root_i32.at[_LI["rows"]].set(root_rows) \
+                .at[_LI["best_left_rows"]].set(root_left_rows)
         leaf_i32 = jnp.zeros((L, 8), jnp.int32) \
             .at[:, _LI["parent"]].set(-1).at[0].set(root_i32)
         empty_node = jnp.zeros((8,), jnp.int32).at[_ND["feature"]].set(-1)
@@ -367,7 +425,7 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
         sizes_arr = jnp.asarray(classes, jnp.int32)
 
     def step(k, carry):
-        (num_leaves, stopped, leaf_f32, leaf_i32, node_i32, cache,
+        (num_leaves, stopped, leaf_f32, leaf_i32, node_i32, caches,
          bins_w, dig_w, row_ord) = carry
         gains = leaf_f32[:, _LF["best_gain"]]
         best_leaf = jnp.argmax(gains).astype(jnp.int32)
@@ -432,24 +490,48 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
             rp = rp.at[_ND["right"]].set(
                 jnp.where(upd_parent & ~was_left, node, rp[_ND["right"]]))
             node_i32 = _put_row(node_i32, p_safe, rp)
+            if exchange is None:
+                parent_rows = parent_c.astype(jnp.int32)
+                rows_lr = (jnp.int32(0), jnp.int32(0))
+            else:
+                parent_rows = rb_i[_LI["rows"]]
+                rows_lr = (rb_i[_LI["best_left_rows"]],
+                           parent_rows - rb_i[_LI["best_left_rows"]])
             new_node = jnp.stack([
                 rb_i[_LI["best_feat"]], tbin, _f2i(gain), ~best_leaf,
                 ~right_leaf, _f2i(rb_f[_LF["cur_value"]]),
-                parent_c.astype(jnp.int32), jnp.int32(0)])
+                parent_rows, jnp.int32(0)])
             node_i32 = _put_row(node_i32, node,
                                 jnp.where(do_split, new_node, empty_node))
 
         # --- child histograms via exact sibling subtraction -------------
-        with jax.named_scope("hist/subtract"):
-            sums_parent = cache[best_leaf]
-            sums_large = sums_parent - sums_small
-            sums_left = jnp.where(small_left, sums_small, sums_large)
-            sums_right = jnp.where(small_left, sums_large, sums_small)
-            cache = cache.at[best_leaf].set(
-                jnp.where(do_split, sums_left, sums_parent))
-            cache = cache.at[right_leaf].set(
-                jnp.where(do_split, sums_right, cache[right_leaf]),
-                mode="drop")
+        def subtract(cache, sums_one, one_is_left):
+            """Both children's sums from one child's and the cached
+            parent's; the cache rows of the two leaves rewritten."""
+            with jax.named_scope("hist/subtract"):
+                sums_parent = cache[best_leaf]
+                sums_other = sums_parent - sums_one
+                sums_left = jnp.where(one_is_left, sums_one, sums_other)
+                sums_right = jnp.where(one_is_left, sums_other, sums_one)
+                cache = cache.at[best_leaf].set(
+                    jnp.where(do_split, sums_left, sums_parent))
+                cache = cache.at[right_leaf].set(
+                    jnp.where(do_split, sums_right, cache[right_leaf]),
+                    mode="drop")
+            return cache, sums_left, sums_right
+
+        if exchange is None:
+            cache, sums_left, sums_right = subtract(caches[0], sums_small,
+                                                    small_left)
+            caches = (cache,)
+        else:
+            # the shard's own children first (small_left is the SHARD's
+            # smaller child), then its left child into the one all-reduce
+            # of the step; the right one is the global subtraction
+            local, own_left, _ = subtract(caches[1], sums_small, small_left)
+            cache, sums_left, sums_right = subtract(
+                caches[0], exchange.hist(own_left), jnp.asarray(True))
+            caches = (cache, local)
 
         with jax.named_scope("find_split"):
             hists = leafhist.combine_digit_sums(
@@ -461,6 +543,10 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
                 hists, jnp.stack([left_g, right_g]),
                 jnp.stack([left_h, right_h]), jnp.stack([left_c, right_c]),
                 num_bin, is_cat, feat_mask, can, sp)
+            best_rows = (jnp.int32(0),) * 2 if exchange is None else tuple(
+                left_rows(sums, child_split.feature[ci],
+                          child_split.threshold[ci])
+                for ci, sums in enumerate((sums_left, sums_right)))
 
         with jax.named_scope("leaf_table"):
             def leaf_rows(ci, tot_g, tot_h, tot_c, val, seg_s, seg_c):
@@ -470,7 +556,8 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
                     tot_g, tot_h, tot_c, val])
                 i32 = jnp.stack([
                     child_split.feature[ci], child_split.threshold[ci],
-                    node, depth + 1, seg_s, seg_c, jnp.int32(0), jnp.int32(0)])
+                    node, depth + 1, seg_s, seg_c, rows_lr[ci],
+                    best_rows[ci]])
                 return f32, i32
 
             lf, li = leaf_rows(0, left_g, left_h, left_c, left_val, s, cnt_l)
@@ -485,12 +572,12 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
             leaf_i32 = _put_row(leaf_i32, right_leaf,
                                 jnp.where(do_split, ri, rr_i))
             num_leaves = num_leaves + jnp.where(do_split, 1, 0)
-        return (num_leaves, stopped, leaf_f32, leaf_i32, node_i32, cache,
+        return (num_leaves, stopped, leaf_f32, leaf_i32, node_i32, caches,
                 bins_w, dig_w, row_ord)
 
     with jax.named_scope("grow_loop"):
         carry = (jnp.asarray(1, jnp.int32), jnp.asarray(False),
-                 leaf_f32, leaf_i32, node_i32, cache, bins_w, dig_w,
+                 leaf_f32, leaf_i32, node_i32, caches, bins_w, dig_w,
                  row_ord)
     with jax.named_scope("grow_loop"):
         (num_leaves, _, leaf_f32, leaf_i32, node_i32, _, _, _, row_ord) = \
@@ -508,7 +595,8 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
             internal_value=_i2f(node_i32[:, _ND["value"]]),
             internal_count=node_i32[:, _ND["count"]],
             leaf_value=shrunk,
-            leaf_count=leaf_f32[:, _LF["total_c"]].astype(jnp.int32),
+            leaf_count=(leaf_f32[:, _LF["total_c"]].astype(jnp.int32)
+                        if exchange is None else leaf_i32[:, _LI["rows"]]),
             leaf_parent=leaf_i32[:, _LI["parent"]],
             leaf_depth=leaf_i32[:, _LI["depth"]],
         )

@@ -18,9 +18,12 @@ socket/MPI collective stack (src/network/):
 
 Here each strategy is a static NamedTuple plugged into
 ops.grow._grow_tree_impl under ``jax.shard_map``; the byte-level reducers
-become XLA collectives on structured values: psum / psum_scatter for
+become XLA collectives on structured values: psum for
 HistogramBinEntry sums, and an all_gather + tournament
 (ops.split.combine_gathered_splits) for the SplitInfo max-reduce.
+Data-parallel over uint8 unbundled bins does not go through
+``_grow_tree_impl`` at all: ``HistExchange`` is what crosses chips when
+every shard grows its own leaf-ordered row block (ops/ordered_grow.py).
 """
 
 from __future__ import annotations
@@ -31,6 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops import leafhist
 from ..ops.bundle import expand_histogram
 from ..ops.histogram import children_histograms, root_histogram
 from ..ops.split import (BestSplit, SplitParams, combine_gathered_splits,
@@ -157,37 +161,77 @@ def _offset_features(split: BestSplit, offset) -> BestSplit:
                           split.feature))
 
 
-def _pad_feature_dim(hist, num_bin, is_cat, feat_mask, num_shards: int):
-    """Pad the feature dimension to a multiple of num_shards so the
-    histogram block layout of the reduce-scatter is uniform (the reference
-    computes ragged per-rank block sizes instead,
-    data_parallel_tree_learner.cpp:48-110 — fixed shapes want padding)."""
-    F = hist.shape[-3]
-    pad = (-F) % num_shards
-    if pad:
-        widths = [(0, 0)] * hist.ndim
-        widths[hist.ndim - 3] = (0, pad)
-        hist = jnp.pad(hist, widths)
-        num_bin = jnp.pad(num_bin, (0, pad))
-        is_cat = jnp.pad(is_cat, (0, pad))
-        feat_mask = jnp.pad(feat_mask, (0, pad))
-    return hist, num_bin, is_cat, feat_mask, F + pad
+class HistExchange(NamedTuple):
+    """What crosses chips when every shard grows its own leaf-ordered
+    row block (ops/ordered_grow.py ``grow_tree_ordered(exchange=...)``
+    under ``jax.shard_map``): the root's scales and sums once a tree,
+    and one digit-sum histogram a split.  Integer sums are exact and
+    order-free, so the summed histograms are the serial learner's on the
+    same rows to the bit; only the root's float32 sums depend on the
+    order of the shards.  Segments, row lanes, sorts and the score update
+    never leave a shard.
+
+    Each call sits under its own leaf phase (obs/phases.py
+    ``exchange/root``, ``exchange/hist``): a collective's device time
+    includes the wait for the slowest shard.  Call ``hist`` OUTSIDE any
+    ``lax.cond``: each shard is in its own size class, and a collective
+    inside a branch would deadlock."""
+    axis_name: str = "data"
+    num_shards: int = 1
+
+    def root(self, scales, sums):
+        """Global quantisation scales (a max over shards, so all shards
+        cut the same digits), the root's <sum_g, sum_h, count> and its
+        rows as an integer: float32 holds a count exactly only up to
+        2^24 rows, which one shard keeps under (ops/leafhist.py) and
+        four together do not."""
+        with jax.named_scope("exchange/root"):
+            total = lax.psum(jnp.stack(sums), self.axis_name)
+            rows = jnp.round(sums[2]).astype(jnp.int32)
+            return (lax.pmax(scales, self.axis_name),
+                    tuple(total[i] for i in range(len(sums))),
+                    lax.psum(rows, self.axis_name))
+
+    def hist(self, sums_i32, root: bool = False):
+        """A shard's ``[F, 9, B]`` int32 digit sums summed over the
+        shards, the root's (``exchange/root``) or a split step's, as
+        ``[F, 18, B]`` halves (ops/leafhist.py ``split_halves``): a digit
+        is up to 128, so whole int32 sums over all shards would wrap
+        once one (feature, bin) held 2^31 / 128 = 16.7M rows, which a
+        three-valued column of a 42M-row table does."""
+        # two literal scopes: tools/lint_phase_scopes.py reads them
+        if root:
+            with jax.named_scope("exchange/root"):
+                return lax.psum(leafhist.split_halves(sums_i32),
+                                self.axis_name)
+        with jax.named_scope("exchange/hist"):
+            return lax.psum(leafhist.split_halves(sums_i32), self.axis_name)
+
+    def traffic_per_tree(self, num_features: int, max_bin: int,
+                         num_leaves: int):
+        """Static per-tree account: one all-reduce of digit-sum halves
+        for the root and one a split step (``num_leaves`` in all; the
+        loop's trip count is fixed, so saturated steps still exchange),
+        plus the root's 3 scales, 3 sums and its integer row count."""
+        hist_b = num_features * 2 * leafhist.NUM_STREAMS * max_bin * 4
+        return _traffic(pmax=(1, 3 * 4),
+                        psum=(2 + num_leaves, 4 * 4 + hist_b * num_leaves))
 
 
 class DataParallelComm(NamedTuple):
     """Rows sharded over ``axis_name``; histograms globally reduced.
 
-    hist_reduce:
-      * "reduce_scatter" (default, faithful to the reference): psum_scatter
-        the [*, F, B, 3] histogram along features, find the best split on
-        the owned block, then all_gather+tournament the tiny SplitInfo.
-        Comm volume per split: one histogram pass over ICI + k SplitInfos.
-      * "psum": allreduce the full histogram and find splits redundantly on
-        every shard.  Simpler lowering; sometimes faster on small meshes.
+    The strategy ops/grow.py runs for data-parallel learning where the
+    leaf-ordered grower cannot (uint16 bins, EFB columns): the
+    [*, F, B, 3] float histogram is all-reduced and every shard finds
+    the split on it, replicated.  The reference's reduce-scatter by
+    feature block with a SplitInfo tournament was a second form of the
+    same sum; the chip's compiler served it as this all-reduce plus a
+    slice (PERF.md, PR 24), so the one form stays.  uint8 unbundled data
+    goes through ``HistExchange`` on the leaf-ordered grower instead.
     """
     axis_name: str = "data"
     num_shards: int = 1
-    hist_reduce: str = "reduce_scatter"
 
     def reduce_sums(self, sums):
         # Root Allreduce of <count, sum_g, sum_h> (data_parallel:112-139).
@@ -195,59 +239,23 @@ class DataParallelComm(NamedTuple):
 
     def traffic_per_tree(self, num_features: int, max_bin: int,
                          num_leaves: int):
-        """Static per-tree collective account (see module header).
-
-        reduce_scatter mode: one [*, F_pad, B, 3] psum_scatter per split
-        (the histogram pass over ICI) plus the tiny SplitInfo all_gather
-        tournament; psum mode allreduces the full histogram instead."""
+        """Static per-tree collective account (see module header): the
+        three root scalars, the root's histogram and both children's at
+        every split."""
         steps = max(num_leaves - 1, 0)
-        root_psum = (3, 3 * 4)                  # <g, h, count> scalars
-        if self.hist_reduce == "psum":
-            hist_b = num_features * max_bin * _HIST_ITEM
-            return _traffic(
-                psum=(root_psum[0] + 1 + steps,
-                      root_psum[1] + hist_b * (1 + 2 * steps)))
-        F_pad = num_features + (-num_features) % self.num_shards
-        hist_b = F_pad * max_bin * _HIST_ITEM
-        return _traffic(
-            psum=root_psum,
-            psum_scatter=(1 + steps, hist_b * (1 + 2 * steps)),
-            all_gather=(_SPLITINFO_FIELDS * (1 + steps),
-                        _SPLITINFO_FIELDS * 4 * (1 + 2 * steps)))
+        hist_b = num_features * max_bin * _HIST_ITEM
+        return _traffic(psum=(3 + 1 + steps,
+                              3 * 4 + hist_b * (1 + 2 * steps)))
 
     def _split_from_hist(self, hist, totals_g, totals_h, totals_c, can,
                          num_bin, is_cat, feat_mask, sp, bundle=None):
+        # under EFB the wire payload is the (much smaller) COLUMN
+        # histogram, expanded to feature space after the sum
+        hist = lax.psum(hist, self.axis_name)
         if bundle is not None:
-            # EFB: allreduce the (already much smaller) COLUMN histogram
-            # — a column-block reduce_scatter cannot be expanded per
-            # shard without re-gathering other shards' columns — then
-            # expand to feature space and find splits replicated.  The
-            # wire payload is [C, B], the bundling win itself.
-            hist = lax.psum(hist, self.axis_name)
             hist = expand_histogram(hist, bundle)
-            return find_best_split(hist, totals_g, totals_h, totals_c,
-                                   num_bin, is_cat, feat_mask, can, sp)
-        if self.hist_reduce == "psum":
-            hist = lax.psum(hist, self.axis_name)
-            return find_best_split(hist, totals_g, totals_h, totals_c,
-                                   num_bin, is_cat, feat_mask, can, sp)
-        # --- reduce-scatter by feature block ------------------------------
-        k = self.num_shards
-        hist, num_bin, is_cat, feat_mask, F_pad = _pad_feature_dim(
-            hist, num_bin, is_cat, feat_mask, k)
-        f_blk = F_pad // k
-        hist_blk = lax.psum_scatter(hist, self.axis_name,
-                                    scatter_dimension=hist.ndim - 3,
-                                    tiled=True)
-        shard = lax.axis_index(self.axis_name)
-        offset = shard * f_blk
-        nb = lax.dynamic_slice_in_dim(num_bin, offset, f_blk)
-        ic = lax.dynamic_slice_in_dim(is_cat, offset, f_blk)
-        fm = lax.dynamic_slice_in_dim(feat_mask, offset, f_blk)
-        local = find_best_split(hist_blk, totals_g, totals_h, totals_c,
-                                nb, ic, fm, can, sp)
-        local = _offset_features(local, offset)
-        return _allgather_combine(local, self.axis_name, k)
+        return find_best_split(hist, totals_g, totals_h, totals_c,
+                               num_bin, is_cat, feat_mask, can, sp)
 
     def prepare(self, bins, bins_rm, g, h, w, params):
         return None

@@ -1,12 +1,17 @@
 """Mesh-level entry points for distributed tree growth.
 
-Builds a jitted ``grow`` function that runs ops.grow._grow_tree_impl under
-``jax.shard_map`` over a ``jax.sharding.Mesh`` with the communication
-strategy of the requested tree_learner type ("data" | "feature" | "voting"
-— the reference's TreeLearner factory, src/treelearner/tree_learner.cpp).
-The returned TreeArrays are replicated (every shard deterministically grows
-the identical tree); leaf_id and the score delta stay row-sharded in
-data/voting modes.
+Builds a jitted ``grow`` function that runs one of the serial growers
+under ``jax.shard_map`` over a ``jax.sharding.Mesh`` with the
+communication strategy of the requested tree_learner type ("data" |
+"feature" | "voting" — the reference's TreeLearner factory,
+src/treelearner/tree_learner.cpp).  Data-parallel over uint8, unbundled
+bins (what the serial learner grows leaf-ordered) runs
+ops/ordered_grow.py on each shard's own row block with one histogram
+exchange a split (comm.py ``HistExchange``); everything else runs
+ops/grow.py ``_grow_tree_impl``, which passes over all local rows at
+every split.  The returned TreeArrays are replicated (every shard
+deterministically grows the identical tree); leaf_id and the score delta
+stay row-sharded in data/voting modes.
 """
 
 from __future__ import annotations
@@ -19,14 +24,16 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..obs.compile_ledger import instrumented_jit
 from ..ops.grow import GrowParams, _grow_tree_impl
-from .comm import DataParallelComm, FeatureParallelComm, VotingParallelComm
+from ..ops.ordered_grow import _size_classes, grow_tree_ordered, \
+    pack_u8_words
+from .comm import (DataParallelComm, FeatureParallelComm, HistExchange,
+                   VotingParallelComm)
 
 
 def make_comm(mode: str, axis_name: str, num_shards: int,
-              num_features: int, top_k: int = 20,
-              hist_reduce: str = "reduce_scatter"):
+              num_features: int, top_k: int = 20):
     if mode == "data":
-        return DataParallelComm(axis_name, num_shards, hist_reduce)
+        return DataParallelComm(axis_name, num_shards)
     if mode == "feature":
         f_block = -(-num_features // num_shards)
         return FeatureParallelComm(axis_name, num_shards, f_block)
@@ -35,15 +42,46 @@ def make_comm(mode: str, axis_name: str, num_shards: int,
     raise ValueError(f"unknown parallel tree learner mode: {mode!r}")
 
 
+def grows_ordered(mode: str, bins_dtype, bundled: bool) -> bool:
+    """Whether the data-parallel learner grows leaf-ordered shards: where
+    the serial learner would (the i32 lane packing is uint8-only and
+    carries no EFB column decode)."""
+    return mode == "data" and not bundled and bins_dtype == jnp.uint8
+
+
+def shard_words(mesh: Mesh, bins_rm, axis_name: Optional[str] = None):
+    """The leaf-ordered grower's padded bin-word lanes, one block per
+    shard, from a row-sharded row-major matrix: each device packs its own
+    rows and pads them by its own largest window class, so that shard
+    ``i`` of every returned ``[k * (N/k + PAD)]`` lane is what
+    ``grow_tree_ordered`` takes as ``bins_words`` there."""
+    axis_name = axis_name or mesh.axis_names[0]
+    k = mesh.shape[axis_name]
+    pad = _size_classes(bins_rm.shape[0] // k)[-1]
+    words = -(-bins_rm.shape[1] // 4)
+
+    @instrumented_jit(program="pack_words")
+    def pack(rm):
+        return jax.shard_map(
+            lambda b: tuple(jnp.pad(w, (0, pad)) for w in pack_u8_words(b)),
+            mesh=mesh, in_specs=P(axis_name, None),
+            out_specs=(P(axis_name),) * words)(rm)
+    return pack(bins_rm)
+
+
 def make_parallel_grow(mesh: Mesh, mode: str, params: GrowParams,
-                       axis_name: Optional[str] = None, top_k: int = 20,
-                       hist_reduce: str = "reduce_scatter"):
+                       axis_name: Optional[str] = None, top_k: int = 20):
     """Build a jitted distributed grow(bins, num_bin, is_cat, feat_mask,
     grad, hess, row_weight, learning_rate) for the given mesh.
 
     Accepts unpadded inputs: rows are padded to a multiple of the mesh axis
     with zero row_weight (dead rows), features to a multiple with a False
     feat_mask (dead features); outputs are cropped back.
+
+    ``bins_rm`` ([N, F], rows sharded) and ``bins_words`` (``shard_words``)
+    are the leaf-ordered shards' resident layout, shared across trees;
+    left out, or where rows had to be padded, each shard derives them
+    from its block of ``bins`` once a tree.
     """
     axis_name = axis_name or mesh.axis_names[0]
     k = mesh.shape[axis_name]
@@ -64,55 +102,76 @@ def make_parallel_grow(mesh: Mesh, mode: str, params: GrowParams,
     # ledger now makes visible instead of silent)
     @instrumented_jit(program="dist_grow_tree")
     def grow(bins, num_bin, is_cat, feat_mask, grad, hess, row_weight,
-             learning_rate, bundle=None):
+             learning_rate, bundle=None, bins_rm=None, bins_words=None):
         F, N = bins.shape
         pad_n = ((-N) % k) if row_sharded else 0
         pad_f = ((-F) % k) if mode == "feature" else 0
-        if pad_n or pad_f:
-            bins = jnp.pad(bins, ((0, pad_f), (0, pad_n)))
-            grad = jnp.pad(grad, (0, pad_n))
-            hess = jnp.pad(hess, (0, pad_n))
-            row_weight = jnp.pad(row_weight, (0, pad_n))  # 0 = dead row
-        if pad_f and bundle is None:
-            # EFB keeps feature metadata in ORIGINAL space; only the
-            # column matrix pads (a zero pad column owns no feature)
-            num_bin = jnp.pad(num_bin, (0, pad_f))
-            is_cat = jnp.pad(is_cat, (0, pad_f))
-            feat_mask = jnp.pad(feat_mask, (0, pad_f))  # False = dead feat
+        # what the boundary itself costs (the pads here, the crops below)
+        # is layout; the growers scope everything inside, and an
+        # operation they leave unscoped stays unscoped
+        with jax.named_scope("layout"):
+            if pad_n or pad_f:
+                bins = jnp.pad(bins, ((0, pad_f), (0, pad_n)))
+                grad = jnp.pad(grad, (0, pad_n))
+                hess = jnp.pad(hess, (0, pad_n))
+                row_weight = jnp.pad(row_weight, (0, pad_n))  # 0 = dead row
+            if pad_f and bundle is None:
+                # EFB keeps feature metadata in ORIGINAL space; only the
+                # column matrix pads (a zero pad column owns no feature)
+                num_bin = jnp.pad(num_bin, (0, pad_f))
+                is_cat = jnp.pad(is_cat, (0, pad_f))
+                feat_mask = jnp.pad(feat_mask, (0, pad_f))  # False = dead
 
-        comm = make_comm(mode, axis_name, k, F + pad_f, top_k,
-                         "psum" if bundle is not None else hist_reduce)
+        args = (bins, num_bin, is_cat, feat_mask, grad, hess, row_weight,
+                learning_rate)
+        specs = in_specs
+        if grows_ordered(mode, bins.dtype, bundle is not None):
+            exchange = HistExchange(axis_name, k)
+            resident = ()
+            if not pad_n and bins_rm is not None and bins_words is not None:
+                resident = (bins_rm, tuple(bins_words))
+                specs += (P(axis_name, None),
+                          (P(axis_name),) * len(bins_words))
 
-        def local_fn(b, nb, ic, fm, g, h, w, lr, *bnd):
-            return _grow_tree_impl(b, nb, ic, fm, g, h, w, lr, params, comm,
-                                   bundle=bnd[0] if bnd else None)
+            def local_fn(b, nb, ic, fm, g, h, w, lr, *res):
+                rm, words = res if res else (None, None)
+                return grow_tree_ordered(b, nb, ic, fm, g, h, w, lr, params,
+                                         bins_rm=rm, bins_words=words,
+                                         exchange=exchange)
+            args += resident
+        else:
+            comm = make_comm(mode, axis_name, k, F + pad_f, top_k)
 
-        specs = in_specs if bundle is None else in_specs + (P(),)
+            def local_fn(b, nb, ic, fm, g, h, w, lr, *bnd):
+                return _grow_tree_impl(b, nb, ic, fm, g, h, w, lr, params,
+                                       comm, bundle=bnd[0] if bnd else None)
+            if bundle is not None:
+                specs += (P(),)
+                args += (bundle,)
         # no varying-manual-axes check: the growers' replicated outputs
         # are deterministic by construction (every shard grows the
         # identical tree)
         sharded = jax.shard_map(local_fn, mesh=mesh, in_specs=specs,
                                 out_specs=out_specs, check_vma=False)
-        args = (bins, num_bin, is_cat, feat_mask, grad, hess, row_weight,
-                learning_rate)
-        if bundle is not None:
-            args = args + (bundle,)
         tree, leaf_id, delta = sharded(*args)
         if pad_n:
-            leaf_id = leaf_id[:N]
-            delta = delta[:N]
+            with jax.named_scope("layout"):
+                leaf_id = leaf_id[:N]
+                delta = delta[:N]
         return tree, leaf_id, delta
 
-    def traffic_per_tree(num_features: int, bundled: bool = False):
+    def traffic_per_tree(num_features: int, bundled: bool = False,
+                         bins_dtype=jnp.uint8):
         """Static per-tree collective traffic of this learner at the given
         (unpadded) feature count — the comm strategy's own account with
-        the same feature padding the jitted path applies (obs layer).
-        ``bundled`` mirrors the jitted path's EFB behavior: data-parallel
-        forces the full-histogram psum (the reduce-scatter block layout
-        cannot expand per shard), so the account must too."""
-        pad_f = ((-num_features) % k) if mode == "feature" else 0
-        comm = make_comm(mode, axis_name, k, num_features + pad_f, top_k,
-                         "psum" if bundled else hist_reduce)
+        the same feature padding and the same choice of grower the jitted
+        path makes (obs layer)."""
+        if grows_ordered(mode, bins_dtype, bundled):
+            comm = HistExchange(axis_name, k)
+            pad_f = 0
+        else:
+            pad_f = ((-num_features) % k) if mode == "feature" else 0
+            comm = make_comm(mode, axis_name, k, num_features + pad_f, top_k)
         return comm.traffic_per_tree(num_features + pad_f, params.max_bin,
                                      params.num_leaves)
 

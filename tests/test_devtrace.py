@@ -10,6 +10,7 @@ program, ``inserted``, ``unattributed``, idle gaps named by ``lgbt:``
 spans)."""
 
 import json
+import re
 
 import numpy as np
 import pytest
@@ -82,8 +83,14 @@ def test_leaf_phase_is_the_innermost_declared_scope():
     assert lp("jit(f)/split/sort") == "split"
     assert lp("jit(f)/jit(take_along_axis)/gather") is None
     assert lp("gather") is None
+    # the data-parallel shards' exchange holds a ``hist`` of its own in
+    # its name: the phase that ends last is the innermost
+    assert lp("jit(f)/grow_loop/while/body/exchange/hist/psum") \
+        == "exchange/hist"
+    assert lp("jit(f)/exchange/root/psum") == "exchange/root"
+    assert lp("jit(f)/exchange/hist/hist/kernel/x") == "hist/kernel"
     assert set(phases.ROUND_PHASES) <= phases.DEVICE_PHASES
-    assert len(set(phases.ROUND_PHASES)) == 16
+    assert len(set(phases.ROUND_PHASES)) == 18
 
 
 def test_phase_map_parses_the_compiled_text():
@@ -131,6 +138,39 @@ def test_inserted_follows_the_first_operand_until_one_is_named():
     assert ph["broadcast.2"] == "gradients" and "broadcast.2" in ins
     # named instructions are never flagged
     assert not ins & {"sort.8", "fusion.4", "rogue.1", "while.1"}
+
+
+SHARDED_HLO = """HloModule jit_step_fn, is_scheduled=true
+
+ENTRY %main (x: f32[8]) -> f32[8] {
+  %x = f32[8]{0} parameter(0), metadata={op_name="x"}
+  %constant.1 = f32[] constant(0), metadata={op_name="jit(step_fn)/jit(grow)/shard_map"}
+  %broadcast.1 = f32[8]{0} broadcast(%constant.1), dimensions={}, metadata={op_name="jit(step_fn)/jit(grow)/shard_map"}
+  %mul.1 = f32[8]{0} multiply(%x, %broadcast.1), metadata={op_name="jit(step_fn)/jit(grow)/shard_map/jit(grow_tree_ordered)/hist/window/mul" stack_frame_id=7}
+  %shift-right-logical.3 = f32[8]{0} negate(%mul.1), metadata={op_name="jit(step_fn)/jit(grow)/shard_map/shift-right-logical.22"}
+  %rogue.1 = f32[8]{0} negate(%shift-right-logical.3), metadata={op_name="jit(step_fn)/jit(grow)/shard_map/jit(grow_tree_ordered)/neg" stack_frame_id=8}
+  ROOT %add.1 = f32[8]{0} add(%rogue.1, %mul.1), metadata={op_name="jit(step_fn)/jit(grow)/shard_map/jit(grow_tree_ordered)/exchange/hist/psum"}
+}
+"""
+
+
+def test_what_the_compiler_names_by_the_shard_map_call_is_nameless():
+    """The chip's compiler inlines a ``shard_map``'s body and puts the
+    call's path before what was bare or nameless inside it: an
+    instruction of its own making then reads
+    ``.../shard_map/shift-right-logical.22`` where the serial program's
+    twin has no name.  It resolves through its operand, as there; an
+    operation the grower itself leaves under no scope still shows."""
+    pm = devtrace.phase_map(SHARDED_HLO)
+    ph, ins = pm["phases"], set(pm["inserted"])
+    assert ph["shift-right-logical.3"] == "hist/window"
+    assert "shift-right-logical.3" in ins
+    assert ph["broadcast.1"] == "hist/window" and "broadcast.1" in ins
+    assert ph["add.1"] == "exchange/hist"
+    assert ph["rogue.1"] == devtrace.UNSCOPED
+    assert pm["ops_unscoped"] == 1
+    assert pm["unscoped_op_names"] == [
+        "jit(step_fn)/jit(grow)/shard_map/jit(grow_tree_ordered)/neg"]
 
 
 # -- the reduction ----------------------------------------------------------
@@ -255,10 +295,13 @@ def no_persistent_cache():
     cc.reset_cache()
 
 
-def test_train_step_has_no_unscoped_operation(no_persistent_cache):
+@pytest.mark.parametrize("learner", ["serial", "data"])
+def test_train_step_has_no_unscoped_operation(no_persistent_cache, learner):
     """(a) ``train_step`` lowered and compiled at a toy shape: every
     operation with an ``op_name`` of its own sits under a declared phase
-    and every phase of the round's top level occurs."""
+    and every phase of the round's top level occurs.  ``data``: the
+    sharded round over four virtual devices, leaf-ordered shards with
+    the two ``exchange/*`` phases, which the serial round lacks."""
     import jax.numpy as jnp
     import lightgbm_tpu as lgb
     rng = np.random.RandomState(0)
@@ -268,7 +311,8 @@ def test_train_step_has_no_unscoped_operation(no_persistent_cache):
     # entry compiled under older scopes would be served with their names
     g = lgb.Booster(params={"objective": "binary", "num_leaves": 7,
                             "verbose": -1, "bagging_fraction": 0.5,
-                            "bagging_freq": 1, "compile_cache_dir": "off"},
+                            "bagging_freq": 1, "compile_cache_dir": "off",
+                            "tree_learner": learner, "num_machines": 4},
                     train_set=lgb.Dataset(X, label=y))._booster
     step = g._make_train_step()
     text = step.lower(g.train_data.score, g._feature_masks_all(),
@@ -276,9 +320,17 @@ def test_train_step_has_no_unscoped_operation(no_persistent_cache):
                       g._select_view()).compile().as_text()
     pm = devtrace.phase_map(text)
     assert pm["ops_unscoped"] == 0, pm["unscoped_op_names"]
-    missing = [p for p in phases.ROUND_PHASES if p not in pm["ops_by_phase"]]
-    assert missing == []
+    exchange = {"exchange/root", "exchange/hist"}
+    missing = {p for p in phases.ROUND_PHASES if p not in pm["ops_by_phase"]}
+    assert missing == (exchange if learner == "serial" else set())
     assert pm["ops_scoped"] > 500
+    if learner == "data":
+        # the root's scales, sums, rows and histogram, and a split's
+        # histogram (the compiler may combine some of the root's)
+        reduces = [n for n, rec in devtrace.parse_hlo(text)[
+            "instructions"].items() if rec["opcode"] == "all-reduce"]
+        assert 2 <= len(reduces) <= 5
+        assert {pm["phases"][n] for n in reduces} == exchange
 
 
 # -- the window itself, on the CPU ------------------------------------------

@@ -135,27 +135,29 @@ def test_event_recorder_commit_on_advance(tmp_path):
 def test_comm_traffic_hand_computed():
     from lightgbm_tpu.parallel.comm import (DataParallelComm,
                                             FeatureParallelComm,
+                                            HistExchange,
                                             VotingParallelComm,
                                             traffic_totals)
     F, B, L, k = 6, 16, 8, 8
     steps = L - 1
-    # data-parallel / reduce_scatter: one histogram pass over the
-    # interconnect per split.  Features pad to a multiple of 8 shards;
-    # each bin entry is <sum_g, sum_h, count> f32 = 12 bytes.
     F_pad = 8
-    hist_b = F_pad * B * 3 * 4
-    t = DataParallelComm("d", k, "reduce_scatter").traffic_per_tree(F, B, L)
-    assert t["psum_scatter"]["calls"] == 1 + steps
-    assert t["psum_scatter"]["bytes"] == hist_b * (1 + 2 * steps)
-    assert t["psum"] == {"calls": 3, "bytes": 12}  # root <g,h,c> scalars
-    # SplitInfo tournament: 6 scalar fields, root 1 leaf + 2 per step
-    assert t["all_gather"]["calls"] == 6 * (1 + steps)
-    assert t["all_gather"]["bytes"] == 6 * 4 * (1 + 2 * steps)
+    # data-parallel over leaf-ordered shards: one all-reduce of int32
+    # digit sums in 16-bit halves, [F, 18, B], for the root and one a
+    # split step (L in all),
+    # the root's three sums and its integer row count in two more calls,
+    # its three scales in a pmax
+    t = HistExchange("d", k).traffic_per_tree(F, B, L)
+    assert t["psum"] == {"calls": 2 + L, "bytes": 16 + F * 18 * B * 4 * L}
+    assert t["pmax"] == {"calls": 1, "bytes": 12}
+    assert set(t) == {"psum", "pmax"}
 
-    # psum mode allreduces the FULL (unpadded) histogram every split
-    t2 = DataParallelComm("d", k, "psum").traffic_per_tree(F, B, L)
+    # data-parallel on ops/grow.py (uint16 bins, EFB): the FULL float
+    # histogram all-reduced, the root's and both children's at every
+    # split; each bin entry is <sum_g, sum_h, count> f32 = 12 bytes
+    t2 = DataParallelComm("d", k).traffic_per_tree(F, B, L)
+    assert t2["psum"]["calls"] == 3 + 1 + steps
     assert t2["psum"]["bytes"] == 12 + F * B * 12 * (1 + 2 * steps)
-    assert "psum_scatter" not in t2 and "all_gather" not in t2
+    assert set(t2) == {"psum"}
 
     # feature-parallel ships ONLY SplitInfos — zero histogram bytes
     t3 = FeatureParallelComm("f", k, 1).traffic_per_tree(F_pad, B, L)
@@ -186,8 +188,14 @@ def test_comm_traffic_through_parallel_grow():
     params = GrowParams(num_leaves=8, max_bin=16, min_data_in_leaf=1,
                         min_sum_hessian_in_leaf=0.0)
     fn = make_parallel_grow(mesh, "data", params)
+    # uint8 unbundled bins grow leaf-ordered shards: 8 digit-sum
+    # exchanges a tree; uint16 bins or EFB columns keep the float
+    # histograms of ops/grow.py
     t = fn.traffic_per_tree(6)
-    assert t["psum_scatter"]["bytes"] == 8 * 16 * 3 * 4 * (1 + 2 * 7)
+    assert t["psum"] == {"calls": 2 + 8, "bytes": 16 + 6 * 18 * 16 * 4 * 8}
+    for kw in ({"bins_dtype": np.uint16}, {"bundled": True}):
+        t = fn.traffic_per_tree(6, **kw)
+        assert t["psum"]["bytes"] == 12 + 6 * 16 * 3 * 4 * (1 + 2 * 7)
 
 
 def test_gbdt_accumulates_comm_bytes(tmp_path):

@@ -135,6 +135,9 @@ def test_forest_walk_kernel_compiles(spec, variant, bucket):
             spec((F, bucket), jnp.float32), name="forest_walk")
 
 
+_TEXTS = {}     # compiled texts, by program and rows: a compile each
+
+
 def _ordered_grower_text(spec, monkeypatch, n):
     """Compiled text of ``grow_tree_ordered`` at ``n`` rows (four features
     keep the kernel's unroll short), 7 leaves, on the chip's kernel."""
@@ -143,13 +146,44 @@ def _ordered_grower_text(spec, monkeypatch, n):
     from lightgbm_tpu.utils import device
     monkeypatch.setattr(device, "on_tpu", lambda: True)
     f = 4
-    return grow_tree_ordered.lower(
-        spec((f, n), jnp.uint8), spec((f,), jnp.int32),
-        spec((f,), jnp.bool_), spec((f,), jnp.bool_),
-        spec((n,), jnp.float32), spec((n,), jnp.float32),
-        spec((n,), jnp.float32), spec((), jnp.float32),
-        GrowParams(num_leaves=7, max_bin=B, min_data_in_leaf=50)
-    ).compile().as_text()
+    if ("serial", n) not in _TEXTS:
+        _TEXTS["serial", n] = grow_tree_ordered.lower(
+            spec((f, n), jnp.uint8), spec((f,), jnp.int32),
+            spec((f,), jnp.bool_), spec((f,), jnp.bool_),
+            spec((n,), jnp.float32), spec((n,), jnp.float32),
+            spec((n,), jnp.float32), spec((), jnp.float32),
+            GrowParams(num_leaves=7, max_bin=B, min_data_in_leaf=50)
+        ).compile().as_text()
+    return _TEXTS["serial", n]
+
+
+def _sharded_grower_text(topo, monkeypatch, n):
+    """Compiled text of the data-parallel learner's grow program over the
+    four described chips, ``n`` rows a shard: ``make_parallel_grow``'s
+    ``shard_map`` of the leaf-ordered grower with its exchange."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from lightgbm_tpu.ops.grow import GrowParams
+    from lightgbm_tpu.parallel import make_parallel_grow
+    from lightgbm_tpu.utils import device
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    f, k = 4, len(topo.devices)
+    if ("sharded", n) not in _TEXTS:
+        mesh = Mesh(np.array(topo.devices), ("data",))
+
+        def on(shape, dtype, *parts):
+            return jax.ShapeDtypeStruct(
+                shape, dtype, sharding=NamedSharding(mesh, P(*parts)))
+        grow = make_parallel_grow(
+            mesh, "data",
+            GrowParams(num_leaves=7, max_bin=B, min_data_in_leaf=50))
+        _TEXTS["sharded", n] = grow.lower(
+            on((f, k * n), jnp.uint8, None, "data"), on((f,), jnp.int32),
+            on((f,), jnp.bool_), on((f,), jnp.bool_),
+            on((k * n,), jnp.float32, "data"),
+            on((k * n,), jnp.float32, "data"),
+            on((k * n,), jnp.float32, "data"), on((), jnp.float32)
+        ).compile().as_text()
+    return _TEXTS["sharded", n]
 
 
 def test_ordered_grower_text_carries_the_phase_paths(spec, monkeypatch):
@@ -174,6 +208,22 @@ def test_ordered_grower_text_carries_the_phase_paths(spec, monkeypatch):
     assert pm["inserted"], "the chip's compiler inserts copies here"
 
 
+def _reached(instrs, comp):
+    """The computations reached from ``comp`` through its instructions'
+    called computations, itself included."""
+    by_comp = {}
+    for name, rec in instrs.items():
+        by_comp.setdefault(rec["comp"], []).append(name)
+    seen, todo = set(), [comp]
+    while todo:
+        c = todo.pop()
+        if c not in seen:
+            seen.add(c)
+            todo += [callee for name in by_comp.get(c, ())
+                     for _, callee in instrs[name]["called"]]
+    return seen
+
+
 def _whole_lane_copies_in_grow_loop(text, lane):
     """Names of the ``copy`` instructions whose result is a whole row
     lane (``s32[lane]``) in the body of the grow loop (the ``while``
@@ -182,25 +232,11 @@ def _whole_lane_copies_in_grow_loop(text, lane):
     into ``S(1)``) are other opcodes and are not counted."""
     from lightgbm_tpu.obs import devtrace
     instrs = devtrace.parse_hlo(text)["instructions"]
-    by_comp = {}
-    for name, rec in instrs.items():
-        by_comp.setdefault(rec["comp"], []).append(name)
-
-    def reached(comp):
-        seen, todo = set(), [comp]
-        while todo:
-            c = todo.pop()
-            if c not in seen:
-                seen.add(c)
-                todo += [callee for name in by_comp.get(c, ())
-                         for _, callee in instrs[name]["called"]]
-        return seen
-
-    loops = [reached(dict(rec["called"])["body"])
+    loops = [_reached(instrs, dict(rec["called"])["body"])
              for rec in instrs.values() if rec["opcode"] == "while"]
-    in_loop = set().union(*(comps for comps in loops if any(
-        (instrs[n]["op_name"] or "").endswith("/split/sort/sort")
-        for c in comps for n in by_comp.get(c, ()))))
+    sorts = {rec["comp"] for rec in instrs.values()
+             if (rec["op_name"] or "").endswith("/split/sort/sort")}
+    in_loop = set().union(*(comps for comps in loops if comps & sorts))
     assert in_loop, "no loop reaches the segment sorts"
     whole = re.compile(rf"%([\w.\-]+) = s32\[{lane}\](\{{[^}}]*\}})? copy\(")
     names = (m.group(1) for m in map(whole.search, text.splitlines()) if m)
@@ -227,6 +263,75 @@ def test_ordered_grower_copies_no_whole_lane_in_the_grow_loop(spec,
     lane = n + classes[-1]
     assert f"s32[{lane}]" in text and "dynamic-update-slice(" in text
     assert _whole_lane_copies_in_grow_loop(text, lane) == []
+
+
+# grow_tree_ordered at 32,768 x 4, 7 leaves, for the described v5e: the
+# program of PR 29 and, the exchange hook in place, of PR 30.  Whoever
+# changes the serial grower knowingly changes this number with it.
+SERIAL_INSTRUCTIONS = 3282
+COLLECTIVES = ("all-reduce", "all-reduce-start", "reduce-scatter",
+               "all-gather", "all-gather-start", "all-to-all",
+               "collective-permute", "collective-permute-start")
+
+
+def test_exchange_hook_leaves_the_serial_program_as_it_was(spec,
+                                                           monkeypatch):
+    """``grow_tree_ordered(exchange=None)`` is the serial program:
+    the same count of instructions as before the hook, no collective."""
+    from lightgbm_tpu.obs import devtrace
+    text = _ordered_grower_text(spec, monkeypatch, 32768)
+    instrs = devtrace.parse_hlo(text)["instructions"]
+    assert len(instrs) == SERIAL_INSTRUCTIONS
+    assert not [n for n, r in instrs.items() if r["opcode"] in COLLECTIVES]
+
+
+def test_sharded_grower_exchanges_once_a_split_outside_every_branch(
+        topo, spec, monkeypatch):
+    """The data-parallel learner over the four described chips, 32,768
+    rows a shard (three size classes).  One histogram collective in the
+    grow loop's body, the all-reduce of ``exchange/hist``: each shard is
+    in its own size class, so a collective inside a conditional's branch
+    would deadlock, and none is reached from one.  Four more before the
+    loop, ``exchange/root``.  PR 29's property holds in the sharded
+    program: no whole-lane ``copy`` in the grow loop."""
+    from lightgbm_tpu.obs import devtrace, phases
+    from lightgbm_tpu.ops.ordered_grow import _size_classes
+    n = 32768
+    text = _sharded_grower_text(topo, monkeypatch, n)
+    assert "tpu_custom_call" in text and "digit_histogram" in text
+    instrs = devtrace.parse_hlo(text)["instructions"]
+    coll = {name: rec for name, rec in instrs.items()
+            if rec["opcode"] in COLLECTIVES}
+    by_phase = {}
+    for name, rec in coll.items():
+        by_phase.setdefault(phases.leaf_phase(rec["op_name"]),
+                            []).append(name)
+    assert sorted(by_phase) == ["exchange/hist", "exchange/root"], by_phase
+    assert len(by_phase["exchange/hist"]) == 1
+    # scales, sums, rows, histogram: the compiler may combine two
+    assert len(by_phase["exchange/root"]) in (3, 4)
+    hist = coll[by_phase["exchange/hist"][0]]
+    assert re.search(r"s32\[4,18,\d+\]", text.split(
+        f"%{by_phase['exchange/hist'][0]} = ")[1].split("\n")[0])
+    # its computation is the loop's body (or one the body calls outright),
+    # and no computation reached through a conditional holds a collective
+    callers = {}
+    for name, rec in instrs.items():
+        for attr, callee in rec["called"]:
+            callers.setdefault(callee, []).append((rec["opcode"], attr))
+    body = [c for c, by in callers.items() if ("while", "body") in by]
+    assert hist["comp"] in body, (hist["comp"], body)
+
+    under_cond = set().union(*(
+        _reached(instrs, callee) for rec in instrs.values()
+        if rec["opcode"] == "conditional" for _, callee in rec["called"]))
+    assert under_cond, "the size-class dispatch is a chain of conditionals"
+    assert not [n_ for n_, r in coll.items() if r["comp"] in under_cond]
+    lane = n + _size_classes(n)[-1]
+    assert f"s32[{lane}]" in text
+    assert _whole_lane_copies_in_grow_loop(text, lane) == []
+    pm = devtrace.phase_map(text)
+    assert pm["ops_unscoped"] == 0, pm["unscoped_op_names"]
 
 
 def test_fused_gain_kernel_is_refused_with_the_quoted_words(spec):

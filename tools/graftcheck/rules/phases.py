@@ -37,9 +37,12 @@ SERIES_RE = re.compile(r"^phase_seconds_[a-z_][a-z0-9_]*$")
 
 # the jitted paths carrying the device taxonomy: the fused round
 # (models/gbdt.py train_step, the growers, the histogram kernel's own
-# scope) plus the compiled-forest inference program (serve/forest.py)
+# scope, the data-parallel shards' exchange in parallel/comm.py and
+# their shard_map boundary in parallel/grow.py) plus
+# the compiled-forest inference program (serve/forest.py)
 DEVICE_FILES = ("models/gbdt.py", "ops/grow.py", "ops/ordered_grow.py",
-                "ops/leafhist.py", "serve/forest.py")
+                "ops/leafhist.py", "parallel/comm.py", "parallel/grow.py",
+                "serve/forest.py")
 
 
 def _load_phases(pkg: pathlib.Path):
