@@ -96,10 +96,16 @@ ROUND_PHASES = (
                           # itself is hist/kernel, its feed hist/window)
     "grow_loop",          # the fori_loop itself, best-leaf pick, the
                           # size-class dispatch (a chain of conds)
-    "split/window_read",  # dynamic slices of the parent's window
-    "split/key",          # split column pick, go-right key, counts
-    "split/sort",         # the stable segment sort
-    "split/window_write",  # sorted window written back in place
+    "split/window_read",  # dynamic slices of the parent's window (on a
+                          # TPU they fuse into the partition kernel's
+                          # feed and count under split/sort)
+    "split/key",          # split column pick, goes-left bit, counts
+    "split/sort",         # the window's stable two-way partition: on a
+                          # TPU the segment_partition kernel with its
+                          # feed and the slices of its output
+                          # (ops/partition.py), else the stable sort; the
+                          # phase keeps its name
+    "split/window_write",  # partitioned window written back in place
     "hist/window",        # slices and unpacking that feed the kernel
     "hist/kernel",        # digit_histogram (Pallas) or its scatter twin
     "exchange/hist",      # data-parallel shards only: the one all-reduce
@@ -157,7 +163,7 @@ TRANSFER_PHASES = frozenset({
 
 def leaf_phase(op_name):
     """The leaf phase of an HLO ``op_name`` path
-    (``jit(step_fn)/grow_loop/while/body/.../split/sort/sort``): the
+    (``jit(step_fn)/grow_loop/while/body/.../split/sort/segment_partition``): the
     INNERMOST declared device phase on the path, which is the one that
     ends last; the longer name where two end at the same component
     (``hist/kernel`` over ``hist`` after it, ``exchange/hist`` over the

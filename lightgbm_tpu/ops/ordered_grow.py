@@ -9,11 +9,13 @@ ITSELF: binned rows and gradient digits live physically grouped by leaf.
 Splitting leaf ``l`` then only touches its own segment:
 
   * the split feature column is a contiguous dynamic slice (no gather),
-  * the stable left/right partition is a segment-local 12-operand sort
-    whose cost tracks the PARENT segment (padded to a power-of-two class),
-    not N — sum over a tree ~ O(N * depth) instead of O(N * leaves),
+  * the stable left/right partition is segment-local: on a TPU the
+    counting kernel of ops/partition.py, two passes over the window and
+    O(P); elsewhere the stable sort it replaced (the tests' oracle).  Its
+    cost tracks the PARENT segment (padded to a power-of-two class), not
+    N — sum over a tree ~ O(N * depth) instead of O(N * leaves),
   * the smaller child's histogram kernel reads a contiguous slice of
-    the sorted window,
+    the partitioned window,
   * the sibling histogram comes from the exact int32 parent-cache
     subtraction (ops/leafhist.py).
 
@@ -24,19 +26,23 @@ by a nested one) every carried lane was copied whole, per branch and per
 step, by the chip's compiler — 447 ms of a 2,073 ms round at 10.5M rows
 (PERF.md, PR 29; tests/test_tpu_compile.py holds the property).
 
-Row payloads travel through the sort as WORD-MAJOR i32 lanes (7 words of
-bins + 3 words of digits + original row id, each a separate 1-D array, so
-every slice/sort operand/write-back is contiguous).  The window suffix
-beyond the segment gets sort key 2 so the stable sort provably leaves it
-in place (the suffix IS the tail of the window, all-equal keys,
-stability).  The lane packing assumes uint8 bins (max_bin <= 256);
-GBDT._make_grow_fn routes uint16 datasets to the cached learner instead.
+Row payloads travel through the partition as WORD-MAJOR i32 lanes (7
+words of bins + 3 words of digits + original row id at 28 features, each
+a separate 1-D array, so every slice, operand and write-back is
+contiguous).  The partition is two-way on one bit, "goes left": the
+window's suffix beyond the segment and every row of a rejected split are
+"other" rows, and a stable two-way partition leaves both where they were
+(the suffix IS the tail of the window).  The lane packing assumes uint8
+bins (max_bin <= 256); GBDT._make_grow_fn routes uint16 datasets to the
+cached learner instead.
 
-Alternatives measured and rejected on TPU (tools/probe_primitives.py;
-PERF.md, "Carried over", dead ends): XLA row gathers run ~12-200 ns/row (lowered
-per-index), so permutation-only layouts that gather payloads on demand
-are 2x SLOWER end-to-end; the 12-operand bitonic sort at ~6 ms per 1M
-rows remains the fastest stable partition XLA offers.
+Alternatives measured and rejected on TPU (tools/probe_primitives.py,
+tools/probe_partition.py; PERF.md, "Carried over", dead ends): XLA row
+gathers run ~12-200 ns/row (lowered per-index), so permutation-only
+layouts that gather payloads on demand are 2x SLOWER end-to-end; the
+12-operand sort is a comparison sort (11.7 ns a row slot at 16M rows,
+half of a round at 10.5M: PERF.md, PR 31) and stays where nothing
+measures it: the once-a-tree bagging compaction below.
 
 Per-step bookkeeping (SplitInfo/LeafSplits, serial_tree_learner.cpp:
 167-224) lives in three PACKED buffers so a step issues ~12 indexed
@@ -66,7 +72,7 @@ import jax.numpy as jnp
 
 from ..obs.compile_ledger import instrumented_jit
 from ..utils import device
-from . import leafhist
+from . import leafhist, partition
 from .grow import GrowParams, TreeArrays
 from .split import BestSplit, find_best_split, leaf_output, K_MIN_SCORE
 
@@ -83,7 +89,7 @@ def _size_classes(n: int, smallest: int = 8192):
 
     A x4-spaced ladder was tried for compile time and REVERTED: it saved
     no measurable warmup on the installation of the time and cost ~5%
-    throughput in sort padding (PERF.md, "Carried over").  Warm runs
+    throughput in window padding (PERF.md, "Carried over").  Warm runs
     hide the compile behind the persistent compilation cache
     (utils/compile_cache.py, applied by every entry point).
 
@@ -149,7 +155,7 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
 
     N here may be the row-BUCKET shape (models/gbdt.py pads every row
     array up the shared ladder): pad rows carry bin 0, zero digits and
-    zero ``row_weight``, so they ride the partition sorts inside
+    zero ``row_weight``, so they ride the window partitions inside
     segments without touching any histogram sum or weighted count —
     exactly like bagged-out rows — and ``compact_inactive`` moves them
     behind the active segment together with the bagging zeros.
@@ -168,7 +174,7 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
     could wrap); the global cache, the sibling subtraction and the
     split search then run replicated on them, so gains,
     thresholds, counts and ``min_data_in_leaf`` are the serial
-    learner's; segment starts and counts, lanes, sorts and the leaf
+    learner's; segment starts and counts, lanes, partitions and the leaf
     reconstruction stay local.  With ``exchange=None`` nothing below
     differs from the serial program."""
     L = params.num_leaves
@@ -229,7 +235,7 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
     if params.compact_inactive:
         # one stable sort per tree (over the REAL N rows only — the
         # window pad stays put) moves zero-weight rows behind the active
-        # segment: every later window, partition sort, and histogram then
+        # segment: every later window, partition, and histogram then
         # costs O(subsample), not O(N) — the reference's bag-subset
         # dataset switch (gbdt.cpp:271-278)
         with jax.named_scope("layout"):
@@ -253,7 +259,7 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
         packed layout, read as ONE Psz-row window with every other row's
         digit streams masked to zero.  The window starts at ``off``, or
         ends with the arrays where ``off + Psz`` would overrun them (a
-        sorted split window; the full lanes carry PAD spare rows).  The
+        partitioned split window; the full lanes carry PAD spare rows).  The
         ONE histogram formulation every call site shares (per-split
         child windows and the compacted root)."""
         with jax.named_scope("hist/window"):
@@ -368,19 +374,18 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
                 go_r = jnp.where(cat, fcol != tbin, fcol > tbin)
                 iota = jnp.arange(P, dtype=jnp.int32)
                 inseg = iota < c
-                # key 2 freezes: suffix rows (other segments / tail pad)
-                # and everything when the split is rejected (identity
-                # permutation)
-                key = jnp.where(do_split & inseg,
-                                go_r.astype(jnp.uint8), jnp.uint8(2))
+                # every other row keeps its order behind the left ones:
+                # the segment's right rows, then the suffix (other
+                # segments / tail pad), which so stays where it was; a
+                # rejected split has no left row (identity permutation)
+                is_left = do_split & inseg & ~go_r
 
             with jax.named_scope("split/sort"):
-                operands = (key,) + win_b + win_d + (win_r,)
-                sorted_ops = jax.lax.sort(operands, num_keys=1,
-                                          is_stable=True)
-                sb = sorted_ops[1:1 + W]
-                sd = sorted_ops[1 + W:1 + W + DW]
-                sr = sorted_ops[-1]
+                parts = partition.stable_partition(
+                    win_b + win_d + (win_r,), is_left)
+                sb = parts[:W]
+                sd = parts[W:W + DW]
+                sr = parts[-1]
 
             with jax.named_scope("split/window_write"):
                 bins_w = tuple(jax.lax.dynamic_update_slice(bw, nb, (s,))
@@ -404,7 +409,7 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
                 scnt = jnp.minimum(cnt_l, cnt_r)
 
             def hist_at(Psz):
-                # reads the SORTED WINDOW, not the lanes just written:
+                # reads the PARTITIONED WINDOW, not the lanes just written:
                 # a lane that this nested cond read after its write-back
                 # was copied whole around the write by the chip's compiler
                 return lambda win: hist_window(*win, off, scnt, Psz)

@@ -81,6 +81,22 @@ def test_digit_histogram_kernel_compiles(spec):
         name="digit_histogram")
 
 
+@pytest.mark.parametrize("rows", [8192, 1 << 20])
+def test_segment_partition_kernel_compiles(spec, rows):
+    """The grower's window partition at the cells' width (7 bin words, 3
+    digit words and the row order at 28 features), at the smallest size
+    class and at one of a million rows: the lanes read as they lie and
+    laid along lanes in VMEM, the int8 one-hot contraction, the triangular
+    rank contraction, the scalar sums and the output DMA at a dynamic
+    offset that is a proven multiple of the tile."""
+    from lightgbm_tpu.ops import partition
+    lanes = -(-F // 4) + 3 + 1
+    _assert_kernel_compiles(
+        lambda m, *ls: partition.segment_partition(ls, m),
+        spec((rows,), jnp.bool_), *[spec((rows,), jnp.int32)] * lanes,
+        name="segment_partition")
+
+
 def test_children_histograms_kernel_compiles(spec):
     """The parallel learners' two-children histogram kernel."""
     from lightgbm_tpu.ops.pallas_histogram import children_histograms_pallas
@@ -191,19 +207,25 @@ def test_ordered_grower_text_carries_the_phase_paths(spec, monkeypatch):
     out no scope path; the compiled text does (``op_name``), which is
     what the program's phase map (obs/devtrace.py) is parsed from.  The
     ordered grower at ONE size class (8,192 rows; four features keep the
-    kernel's unroll short): the segment sort carries ``split/sort``, the
-    kernel its own name, and no operation is left under no phase."""
+    kernel's unroll short): the partition kernel sits under
+    ``split/sort`` by its own name and no HLO ``sort`` is left there (the
+    two that remain are ``leaf_delta``'s), the histogram kernel carries
+    its own name, and no operation is left under no phase."""
     from lightgbm_tpu.obs import devtrace
     text = _ordered_grower_text(spec, monkeypatch, 8192)
-    sorts = re.findall(r'%(sort[\w.\-]*) = .* sort\(.*op_name="([^"]*)"', text)
-    assert sorts and any(op.endswith("/split/sort/sort") for _, op in sorts)
     pm = devtrace.phase_map(text)
     assert pm["module"] == "jit_grow_tree_ordered"
+    instrs = devtrace.parse_hlo(text)["instructions"]
+    under_sort = {k for k, ph in pm["phases"].items() if ph == "split/sort"}
+    assert [k for k in under_sort if k.startswith("segment_partition")], \
+        sorted(under_sort)
+    assert not [k for k in under_sort if instrs[k]["opcode"] == "sort"]
+    sorts = {pm["phases"][k] for k, r in instrs.items()
+             if r["opcode"] == "sort"}
+    assert sorts <= {"leaf_delta"}, sorts
     kernels = [k for k, ph in pm["phases"].items() if ph == "hist/kernel"
                and k.startswith("digit_histogram")]
     assert kernels, sorted(pm["phases"])[:20]
-    assert {pm["phases"][name] for name, op in sorts
-            if op.endswith("/split/sort/sort")} == {"split/sort"}
     assert pm["ops_unscoped"] == 0, pm["unscoped_op_names"]
     assert pm["inserted"], "the chip's compiler inserts copies here"
 
@@ -227,17 +249,17 @@ def _reached(instrs, comp):
 def _whole_lane_copies_in_grow_loop(text, lane):
     """Names of the ``copy`` instructions whose result is a whole row
     lane (``s32[lane]``) in the body of the grow loop (the ``while``
-    that reaches the segment sorts) or in any computation it calls.
+    that reaches the partition kernels) or in any computation it calls.
     ``copy-start``/``copy-done`` (the compiler's moves of a small lane
     into ``S(1)``) are other opcodes and are not counted."""
     from lightgbm_tpu.obs import devtrace
     instrs = devtrace.parse_hlo(text)["instructions"]
     loops = [_reached(instrs, dict(rec["called"])["body"])
              for rec in instrs.values() if rec["opcode"] == "while"]
-    sorts = {rec["comp"] for rec in instrs.values()
-             if (rec["op_name"] or "").endswith("/split/sort/sort")}
-    in_loop = set().union(*(comps for comps in loops if comps & sorts))
-    assert in_loop, "no loop reaches the segment sorts"
+    kernels = {rec["comp"] for name, rec in instrs.items()
+               if name.startswith("segment_partition")}
+    in_loop = set().union(*(comps for comps in loops if comps & kernels))
+    assert in_loop, "no loop reaches the partition kernels"
     whole = re.compile(rf"%([\w.\-]+) = s32\[{lane}\](\{{[^}}]*\}})? copy\(")
     names = (m.group(1) for m in map(whole.search, text.splitlines()) if m)
     return sorted(n for n in names if instrs[n]["comp"] in in_loop)
@@ -265,10 +287,12 @@ def test_ordered_grower_copies_no_whole_lane_in_the_grow_loop(spec,
     assert _whole_lane_copies_in_grow_loop(text, lane) == []
 
 
-# grow_tree_ordered at 32,768 x 4, 7 leaves, for the described v5e: the
-# program of PR 29 and, the exchange hook in place, of PR 30.  Whoever
+# grow_tree_ordered at 32,768 x 4, 7 leaves, for the described v5e: 3,282
+# for the program of PR 29 and, the exchange hook in place, of PR 30;
+# PR 31 put the partition kernel, its mask and the slices of its output
+# in the segment sort's place in each of three size classes.  Whoever
 # changes the serial grower knowingly changes this number with it.
-SERIAL_INSTRUCTIONS = 3282
+SERIAL_INSTRUCTIONS = 3340
 COLLECTIVES = ("all-reduce", "all-reduce-start", "reduce-scatter",
                "all-gather", "all-gather-start", "all-to-all",
                "collective-permute", "collective-permute-start")
