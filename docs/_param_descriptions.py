@@ -15,11 +15,20 @@ DESC = {
                        "delta-forest damping)",
     "num_leaves": "max leaves per tree (leaf-wise growth)",
     "tree_learner": "serial | feature | data | voting — distributed learner "
-                    "over the device mesh",
-    "serial_grow": "ordered | cached | fused — serial-learner strategy "
-                   "(leaf-ordered physical layout, original-order cached "
-                   "learner, or full-pass growth through the fused "
-                   "histogram→split-gain kernel; TPU-specific extension)",
+                    "over the device mesh (`num_machines` devices of one "
+                    "process, or every device of a multi-process runtime). "
+                    "`data`: each device holds one equal row block, placed "
+                    "there from the host, and grows it with the "
+                    "leaf-ordered grower (the serial learner's program); "
+                    "one all-reduce of int32 histogram sums a split (in "
+                    "16-bit halves, `[F, 18, B]`, exact at any row count) "
+                    "and the root's sums cross devices, so the tree is the "
+                    "serial learner's (uint8 bins without EFB; wider bins "
+                    "or bundled columns pass over all local rows a split "
+                    "on `ops/grow.py` and all-reduce float histograms). "
+                    "`voting`: rows sharded as for `data`, top-k feature "
+                    "election. `feature`: rows replicated, split search "
+                    "sharded by feature",
     "compile_cache_dir": "persistent XLA compilation cache directory so "
                          "repeated/resumed runs skip the warmup compile "
                          "tax ('' = one fixed directory inside the "
@@ -294,11 +303,6 @@ DESC = {
                          "numbers the processes, locates the "
                          "coordinator, and seeds the watchdog heartbeat "
                          "mesh (docs/FAULT_TOLERANCE.md §Distributed)",
-    "tpu_histogram_impl": "auto | scatter | onehot | pallas — histogram "
-                          "kernel selection (ops/histogram.py; auto "
-                          "picks pallas on TPU, onehot elsewhere)",
-    "tpu_double_hist": "accumulate histograms in float64 (CPU parity "
-                       "tests; TPUs run f32)",
     # fault tolerance (docs/FAULT_TOLERANCE.md)
     "snapshot_dir": "crash-safe snapshot directory; also enables "
                     "auto-resume (multihost: rank 0 writes, resume runs "

@@ -107,14 +107,6 @@ _DEFAULTS: Dict[str, Any] = {
     "objective": "regression",
     "boosting_type": "gbdt",
     "tree_learner": "serial",
-    # serial-learner strategy: "ordered" = leaf-ordered physical layout
-    # (ops/ordered_grow.py, uint8 bins; >256-bin datasets fall back to
-    # the cached learner with a log line); "cached" = original-order
-    # cached learner (ops/grow.py); "fused" = full-pass growth through
-    # the fused histogram->split-gain kernel (ops/pallas_histogram.py,
-    # no per-leaf cache).  TPU-specific extension, not a reference
-    # parameter.
-    "serial_grow": "ordered",
     "seed": 0,
     "num_threads": 0,
     "metric": [],
@@ -207,9 +199,6 @@ _DEFAULTS: Dict[str, Any] = {
     "local_listen_port": 12400,
     "time_out": 120,
     "machine_list_file": "",
-    # TPU-specific extensions (no reference equivalent)
-    "tpu_histogram_impl": "auto",  # auto | scatter | onehot | pallas
-    "tpu_double_hist": False,      # float64 histogram accumulation (CPU tests)
     # fault tolerance (lightgbm_tpu/snapshot.py, docs/FAULT_TOLERANCE.md)
     "snapshot_freq": 0,        # checkpoint every K iterations (0 = off)
     "snapshot_dir": "",        # where snapshots live; also enables resume
@@ -455,9 +444,6 @@ class Config:
         v = self._values
         if v["tree_learner"] not in ("serial", "feature", "data", "voting"):
             raise ValueError(f"Unknown tree learner type {v['tree_learner']}")
-        if v["serial_grow"] not in ("ordered", "cached", "fused"):
-            raise ValueError(
-                f"Unknown serial_grow strategy {v['serial_grow']}")
         if v["nan_policy"] not in ("none", "fail_fast", "skip_tree"):
             raise ValueError(
                 f"Unknown nan_policy {v['nan_policy']} "

@@ -27,13 +27,13 @@ from ..io.dataset import BinnedDataset
 from ..ops.bundle import BundleDecode
 from ..metric import Metric, create_metric
 from ..objective import ObjectiveFunction, create_objective
+from ..ops import ordered_grow
 from ..ops.grow import (GrowParams, SerialComm, grow_tree, pack_tree_arrays,
                         unpack_tree_arrays)
-from ..ops.ordered_grow import grow_tree_ordered, pack_u8_words
 from ..ops.predict import (predict_binned_forest,
                            predict_binned_forest_linear,
                            predict_binned_tree)
-from ..utils import compile_cache, device, log, timetag
+from ..utils import compile_cache, log, timetag
 from ..utils.log import LightGBMError
 from .linear import (LinearParams, affine_epilogue, attach_linear,
                      fit_leaf_models, pack_linear, unpack_linear)
@@ -57,7 +57,6 @@ def estimate_train_memory(num_data: int, num_features: int, num_leaves: int,
                           max_bin: int, num_models: int,
                           bin_itemsize: int = 1, *,
                           donate_score: bool = False,
-                          fused_scratch: bool = False,
                           leaf_cache: bool = True,
                           linear_k: int = 0) -> Dict[str, int]:
     """Rough per-device HBM footprint (bytes) of training, by component.
@@ -74,21 +73,17 @@ def estimate_train_memory(num_data: int, num_features: int, num_leaves: int,
 
     Components mirror what training actually allocates: column- and
     row-major bin copies (+ word-packed lanes for the ordered grower,
-    padded to the largest window class), the 9-stream int8 digit payload,
+    padded by ``ordered_grow.lane_pad``), the 9-stream int8 digit payload,
     per-class score buffers, the [L, F, 9, B] int32 histogram cache
-    (``leaf_cache=False`` — the fused kernel and the ``hist_cache``
-    degrade step — zeroes it), the score-update double buffer
-    (``donate_score=True`` — in-place XLA aliasing — zeroes it), and the
-    fused kernel's VMEM scratch (``fused_scratch``: both children's
-    histogram tiles live in VMEM during the pass instead of HBM).
+    (``leaf_cache=False`` — the ``hist_cache`` degrade step — zeroes
+    it), and the score-update double buffer (``donate_score=True`` —
+    in-place XLA aliasing — zeroes it).
     ``num_data`` is the PADDED row count when row bucketing is on — the
     pad rows allocate like real ones.  ``working`` doubles the sort
     payload: lax.sort and the window update-slices hold one extra copy
     of their operands live."""
-    from ..ops.ordered_grow import _size_classes
-
     n, f = num_data, num_features
-    pad = _size_classes(max(n, 1))[-1]
+    pad = ordered_grow.lane_pad(n)
     words = -(-f // 4) if bin_itemsize == 1 else 0
     bins_cm = n * f * bin_itemsize
     bins_rm = n * f * bin_itemsize
@@ -101,10 +96,6 @@ def estimate_train_memory(num_data: int, num_features: int, num_leaves: int,
     # NEXT TO the old one at the update peak
     double_buf = 0 if donate_score else num_models * n * 4
     cache = (num_leaves * f * 9 * max_bin * 4) if leaf_cache else 0
-    # fused histogram->split-gain kernel: both children's [F, B, 3] f32
-    # tiles are scratch resident during the pass (never landed in HBM,
-    # but the budget must still cover them — VMEM pressure spills)
-    vmem = (2 * f * max_bin * 3 * 4) if fused_scratch else 0
     # linear_tree (docs/LINEAR_TREES.md): the resident [F, N] f32 raw
     # copy, the per-row [N, K+1] covariate/phi gather (x2: phi and the
     # per-slot segment-sum operand are live together), and the batched
@@ -122,11 +113,10 @@ def estimate_train_memory(num_data: int, num_features: int, num_leaves: int,
         "scores_and_gradients": scores,
         "score_double_buffer": double_buf,
         "histogram_cache": cache,
-        "vmem_scratch": vmem,
         "linear_fit": linear,
         "working": payload,
         "total": (bins_cm + bins_rm + 2 * payload + scores + double_buf
-                  + cache + vmem + linear),
+                  + cache + linear),
     }
 
 
@@ -220,17 +210,12 @@ class _DeviceData:
             h2d_xfers += 1
             h2d_bytes += int(bins_np.nbytes)
         # Word-packed payload lanes for the leaf-ordered grower, shared
-        # across trees (uint8 bins only; uint16 routes to the cached
-        # learner).
+        # across trees, wherever the bins are of a kind it takes.
         self.bins_words = None
-        if self.bins_rm is not None and self.bins_rm.dtype == jnp.uint8:
-            from ..ops.ordered_grow import _size_classes
-            if mesh is None:
-                self.bins_words = _pack_words_padded(
-                    self.bins_rm, _size_classes(self.padded_rows)[-1])
-            else:
-                from ..parallel import shard_words
-                self.bins_words = shard_words(mesh, self.bins_rm)
+        if self.bins_rm is not None \
+                and ordered_grow.accepts(self.bins_rm.dtype):
+            self.bins_words = ordered_grow.pack_word_lanes(self.bins_rm,
+                                                           mesh)
         # raw f32 feature values for the linear-tree fit and its replay
         # epilogues (docs/LINEAR_TREES.md): NaN imputed to 0.0 ON UPLOAD
         # so the device fit and every predict path agree exactly; pad
@@ -356,15 +341,6 @@ def _device_bag_mask(key, n: int, bag_cnt: int, n_real: int = -1):
     return keep.astype(jnp.float32)
 
 
-@obs.instrumented_jit(program="pack_words", static_argnames=("pad",))
-def _pack_words_padded(rm, pad: int):
-    """Word-pack a row-major bin matrix and pad each word lane by the
-    ordered grower's largest window class.  Module-level (pad is a
-    static argument, not a closure) so every booster over the same
-    shapes shares ONE compiled program."""
-    return tuple(jnp.pad(w, (0, pad)) for w in pack_u8_words(rm))
-
-
 _PACK_TREE = obs.instrumented_jit(pack_tree_arrays, program="pack_tree")
 
 
@@ -446,13 +422,34 @@ def _shared_gradients_fn(objective):
                        program="train_gradients")
 
 
-def _build_shared_train_step(objective, num_class: int, guard: bool,
-                             kind: str, params: GrowParams,
-                             linear: Optional[LinearParams] = None):
+def _grower(kind: str, params: GrowParams):
+    """The serial grower ``GBDT._choose_grower`` named, as a callable of
+    the one signature every grower has here (``GBDT._make_grow_fn`` gives
+    the distributed learners the same): ``grow(view, num_bin, is_cat,
+    feat_mask, grad, hess, row_weight, lr)`` -> (TreeArrays, leaf_id,
+    delta).  The inner grow jits inline under an enclosing trace
+    (obs/compile_ledger.py passthrough)."""
+    if kind == "ordered":
+        # no column decode: the choice guarantees view.bundle is None
+        return lambda view, *a: ordered_grow.grow_tree_ordered(
+            view.bins, *a, params, bins_rm=view.bins_rm,
+            bins_words=view.bins_words)
+    # ops/grow.py with the resident [L, F, 9, B] histogram cache
+    # ("cached"), or in full passes a split without it ("nocache": the
+    # hist_cache degrade step; exact parity, both scan the same sums)
+    comm = SerialComm(leaf_cache=kind == "cached")
+    return lambda view, *a: grow_tree(view.bins, *a, params, comm,
+                                      view.bins_rm, bundle=view.bundle)
+
+
+def _round_step(objective, num_class: int, guard: bool, grow,
+                linear: Optional[LinearParams] = None):
     """One fused boosting iteration as a PURE function of device arrays:
     gradients -> per-class grow -> score update -> packed host vectors.
-    ``kind`` picks the serial growth strategy; the inner grow jits
-    inline under this trace (obs/compile_ledger.py passthrough).
+    ``grow`` is a grower of ``_grower``'s signature; every per-dataset
+    array is an ARGUMENT (closed over, the labels were compiled in as a
+    constant: 168 MB at 42M rows, on every device, and the persistent
+    compile cache never served the program twice).
 
     ``linear`` (docs/LINEAR_TREES.md) appends the batched per-leaf
     affine fit after each class's growth: the fitted intercepts replace
@@ -462,54 +459,32 @@ def _build_shared_train_step(objective, num_class: int, guard: bool,
     byte-identical to the pre-linear program.
 
     Every operation traced here sits under one leaf phase of
-    obs/phases.py ROUND_PHASES (the ordered grower scopes its own), so
-    a profiler window reduces to named phases (obs/devtrace.py)."""
-    fused_comm = SerialComm(leaf_cache=False, fused_gain=True)
-    nocache_comm = SerialComm(leaf_cache=False)
-
-    def step_fn(score, feat_masks, row_weight, lr, bins, num_bin, is_cat,
-                grad_arrays, bins_rm, bins_words, bundle, raw=None):
+    obs/phases.py ROUND_PHASES (the growers scope their own), so a
+    profiler window reduces to named phases (obs/devtrace.py)."""
+    def step_fn(score, feat_masks, row_weight, lr, view, num_bin, is_cat,
+                grad_arrays, raw=None):
         with jax.named_scope("gradients"):
             grad, hess = objective.gradients_with(grad_arrays, score)
             ok = (_all_finite(grad, hess) if guard else jnp.asarray(True))
         outs = []
         for cls in range(num_class):
             with jax.named_scope("gradients"):
-                args = (bins, num_bin, is_cat, feat_masks[cls], grad[cls],
+                args = (num_bin, is_cat, feat_masks[cls], grad[cls],
                         hess[cls], row_weight, lr)
-            if kind == "ordered":
-                # the leaf-ordered grower has no column decode; kind
-                # selection guarantees bundle is None here
-                ta, _, delta = grow_tree_ordered(*args, params,
-                                                 bins_rm=bins_rm,
-                                                 bins_words=bins_words)
-            elif kind == "fused":
-                ta, _, delta = grow_tree(*args, params, fused_comm, bins_rm,
-                                         bundle=bundle)
-            elif kind == "nocache":
-                # hist_cache degrade step: full-pass growth, no resident
-                # [L, F, 9, B] cache (memory_policy=degrade)
-                ta, _, delta = grow_tree(*args, params, nocache_comm,
-                                         bins_rm, bundle=bundle)
-            else:
-                ta, _, delta = grow_tree(*args, params, bins_rm=bins_rm,
-                                         bundle=bundle)
+            ta, _, delta = grow(view, *args)
+            lin = ()
             if linear is not None:
                 ta, coeff, feat, delta, fb = fit_leaf_models(
-                    ta, bins, is_cat, raw, grad[cls], hess[cls],
-                    row_weight, lr, linear, bundle=bundle)
-                with jax.named_scope("score_update"):
-                    score = score.at[cls].add(delta)
-                with jax.named_scope("pack_tree"):
-                    packed = pack_tree_arrays(ta) \
-                        + pack_linear(coeff, feat, fb)
-                outs.append((packed, ta, delta, (coeff, feat)))
-            else:
-                with jax.named_scope("score_update"):
-                    score = score.at[cls].add(delta)
-                with jax.named_scope("pack_tree"):
-                    packed = pack_tree_arrays(ta)
-                outs.append((packed, ta, delta))
+                    ta, view.bins, is_cat, raw, grad[cls], hess[cls],
+                    row_weight, lr, linear, bundle=view.bundle)
+                lin = ((coeff, feat),)
+            with jax.named_scope("score_update"):
+                score = score.at[cls].add(delta)
+            with jax.named_scope("pack_tree"):
+                packed = pack_tree_arrays(ta)
+                if linear is not None:
+                    packed += pack_linear(coeff, feat, fb)
+            outs.append((packed, ta, delta) + lin)
         return score, outs, ok
     return step_fn
 
@@ -520,11 +495,23 @@ def _shared_train_step(objective, num_class: int, guard: bool, kind: str,
     key = ("train_step", objective.program_key(), num_class, guard, kind,
            params, donate, linear)
     holder = objective.program_holder()
+
+    def make():
+        body = _round_step(holder, num_class, guard, _grower(kind, params),
+                           linear)
+
+        # the registry's program keeps its flat argument list: the order
+        # of a program's parameters is part of its compiled text, and
+        # with it of the persistent cache's key
+        def step_fn(score, feat_masks, row_weight, lr, bins, num_bin,
+                    is_cat, grad_arrays, bins_rm, bins_words, bundle,
+                    raw=None):
+            return body(score, feat_masks, row_weight, lr,
+                        _HistView(bins, bins_rm, bins_words, bundle),
+                        num_bin, is_cat, grad_arrays, raw)
+        return step_fn
     return _shared_jit(
-        key,
-        lambda: _build_shared_train_step(holder, num_class, guard,
-                                         kind, params, linear),
-        program="train_step",
+        key, make, program="train_step",
         # round-to-round state donation: the score cache is the only
         # argument that is dead after the call (the caller immediately
         # rebinds it to the output), so XLA may update it in place
@@ -575,6 +562,7 @@ class GBDT:
     _screener = None              # models/screening.py GainScreener
     _screen_mask_dev = None
     _parallel_grow_active = False
+    _grower_kind = "ordered"      # _choose_grower's answer, at _make_grow_fn
     # -- piece-wise linear trees (models/linear.py, docs/LINEAR_TREES.md;
     # None = constant leaves, the default) ------------------------------
     _linear: Optional[LinearParams] = None
@@ -858,15 +846,13 @@ class GBDT:
                 else self.num_data)
         self._linear = self._setup_linear(cfg, train_set)
         self._check_memory_budget(cfg, train_set)
-        # data-parallel over uint8 unbundled bins grows leaf-ordered
-        # shards, which read the row-major layout the serial learner does
-        from ..parallel.grow import grows_ordered
-        ordered_shards = grows_ordered(cfg.tree_learner, train_set.bins.dtype,
-                                       self._bundle is not None)
+        # leaf-ordered shards read the row-major layout the serial
+        # learner does; every other distributed learner reads columns
         with obs.span("Dataset::to_device"):
             self.train_data = _DeviceData(
                 train_set, self.num_class,
-                with_row_major=mesh is None or ordered_shards,
+                with_row_major=(mesh is None
+                                or self._choose_grower()[0] == "ordered"),
                 padded_rows=self._padded_rows,
                 with_raw=self._linear is not None, mesh=mesh)
 
@@ -897,41 +883,39 @@ class GBDT:
         arrays = self._grad_arrays
         return lambda score: jit(arrays, score)
 
-    def _serial_grow_kind(self) -> str:
-        cfg = self.config
-        if cfg.serial_grow == "fused":
-            if device.on_tpu():
-                from ..ops.pallas_histogram import FUSED_GAIN_TPU_REFUSAL
-                raise device.KernelRefusedOnTPU(
-                    "serial_grow=fused cannot run on a TPU: the chip's "
-                    "compiler refuses the fused histogram->split-gain "
-                    f"kernel ({FUSED_GAIN_TPU_REFUSAL!r}); use "
-                    "serial_grow=ordered (the default) or cached")
-            return "fused"
-        # the hist_cache degrade step (memory_policy=degrade) dropped
-        # the per-leaf histogram cache: route through the cacheless
-        # full-pass learner (exact parity with the cached one — both
-        # scan the same histograms; only the reuse strategy differs)
+    def _choose_grower(self) -> Tuple[str, str]:
+        """Which grower this booster's trees grow on, and why: THE choice,
+        made from what can be observed (the learner and its mesh, the bin
+        dtype, the bundle, the screener, the degrade ladder), never from
+        an option.  ``ordered``: ops/ordered_grow.py, whole or one shard
+        a device; ``cached`` and ``nocache``: ops/grow.py with and
+        without the per-leaf histogram cache (``_grower``; a distributed
+        learner's ``nocache`` exchanges through its own comm).  All grow
+        the same trees (tests/test_ordered_grow.py)."""
+        dtype = self.train_set.bins.dtype
+        bundled = self._bundle is not None
+        if self._mesh is not None:
+            # parallel/grow.py asks the same rule of each view it is
+            # handed, so a screener's compacted views take full passes
+            from ..parallel.grow import grows_ordered
+            if grows_ordered(self.config.tree_learner, dtype, bundled):
+                return "ordered", ("leaf-ordered shards, one histogram "
+                                   "exchange a split")
+            return "nocache", ("full passes a split: leaf-ordered shards "
+                               "are for tree_learner=data over uint8 bins "
+                               "without EFB columns")
         if self._degrade_leaf_cache_off:
-            return "nocache"
-        # EFB columns and screening's compacted views both need the
-        # per-split column decode, which the leaf-ordered grower's packed
-        # word lanes do not carry — route to the cached learner (exact
-        # parity with ordered is pinned by tests/test_ordered_grow.py)
-        needs_decode = (self._bundle is not None
-                        or self._screener is not None)
-        if needs_decode:
-            if cfg.serial_grow == "ordered":
-                log.warn_once(
-                    "serial_grow_decode",
-                    "serial_grow=ordered: using the cached serial "
-                    "learner instead (EFB bundling / feature screening "
-                    "need the column-decode path)")
-            return "cached"
-        if cfg.serial_grow == "ordered" \
-                and self.train_data.bins_words is not None:
-            return "ordered"
-        return "cached"
+            return "nocache", ("memory_policy=degrade dropped the "
+                               "per-leaf histogram cache")
+        # EFB columns and screening's compacted views need the per-split
+        # column decode, which the packed word lanes do not carry
+        decode = bundled or self._screener is not None
+        if ordered_grow.accepts(dtype, decode):
+            return "ordered", "uint8 bins, no column decode"
+        return "cached", ("EFB bundling / feature screening need the "
+                          "column decode" if decode
+                          else "max_bin > 256: the leaf-ordered layout "
+                               "packs uint8 bins")
 
     # -- HBM admission control (docs/FAULT_TOLERANCE.md §Resource
     # exhaustion).  The estimate/gate/degrade machinery is host-side
@@ -939,18 +923,20 @@ class GBDT:
     # by tests/test_resource_chaos.py).
 
     def _estimate_now(self, cfg: Config, train_set: BinnedDataset,
-                      guard: bool) -> Dict[str, int]:
+                      guard: bool,
+                      rows: Optional[int] = None) -> Dict[str, int]:
         """The training estimate under the CURRENT construction state —
         re-evaluated after each degrade step so the ladder can stop as
-        soon as the footprint fits."""
-        fused = cfg.serial_grow == "fused"
+        soon as the footprint fits.  ``rows``: as it WOULD look at
+        another row count (the ``row_pad`` step's savings, without
+        mutating state yet)."""
         return estimate_train_memory(
-            self._rows_per_device(), train_set.num_columns, cfg.num_leaves,
+            self._rows_per_device() if rows is None else rows,
+            train_set.num_columns, cfg.num_leaves,
             cfg.max_bin, self.num_class,
             bin_itemsize=train_set.bins.dtype.itemsize,
             donate_score=not guard and self._donation_on(),
-            fused_scratch=fused,
-            leaf_cache=not fused and not self._degrade_leaf_cache_off,
+            leaf_cache=not self._degrade_leaf_cache_off,
             linear_k=(self._linear.max_features
                       if self._linear is not None else 0))
 
@@ -1075,7 +1061,6 @@ class GBDT:
                           "instead of double-allocating)")
             elif step == "hist_cache":
                 if self._degrade_leaf_cache_off \
-                        or cfg.serial_grow == "fused" \
                         or est["histogram_cache"] <= 0:
                     continue
                 saved = est["histogram_cache"]
@@ -1087,8 +1072,8 @@ class GBDT:
                         or self._row_mesh is not None:
                     continue        # equal row blocks are not a bucket pad
                 pad = self._padded_rows - self.num_data
-                saved = est["total"] - self._estimate_probe_rows(
-                    cfg, train_set, guard)["total"]
+                saved = est["total"] - self._estimate_now(
+                    cfg, train_set, guard, rows=self.num_data)["total"]
                 detail = (f"capping the row-bucket pad ({pad} pad rows "
                           f"released; this run compiles per-N programs "
                           f"instead of sharing the bucket ladder)")
@@ -1097,21 +1082,6 @@ class GBDT:
             self._apply_degrade(step, max(int(saved), 0), detail)
             est = self._estimate_now(cfg, train_set, guard)
         return est
-
-    def _estimate_probe_rows(self, cfg: Config, train_set: BinnedDataset,
-                             guard: bool) -> Dict[str, int]:
-        """The estimate as it WOULD look with the pad capped (savings
-        math for the ``row_pad`` step, without mutating state yet)."""
-        fused = cfg.serial_grow == "fused"
-        return estimate_train_memory(
-            self.num_data, train_set.num_columns, cfg.num_leaves,
-            cfg.max_bin, self.num_class,
-            bin_itemsize=train_set.bins.dtype.itemsize,
-            donate_score=not guard and self._donation_on(),
-            fused_scratch=fused,
-            leaf_cache=not fused and not self._degrade_leaf_cache_off,
-            linear_k=(self._linear.max_features
-                      if self._linear is not None else 0))
 
     @staticmethod
     def _make_grow_params(cfg: Config) -> GrowParams:
@@ -1153,6 +1123,10 @@ class GBDT:
         self._comm_traffic_totals = (0, 0)
         self._parallel_grow_active = False
         mesh = self._mesh
+        # once a booster, and again where a reset rebuilds the grower
+        self._grower_kind, why = self._choose_grower()
+        log.info("Growing trees on the %s grower: %s", self._grower_kind,
+                 why)
         if mesh is not None:
             from ..parallel import make_parallel_grow
             log.info("Using %s-parallel tree learner over %d devices",
@@ -1187,42 +1161,7 @@ class GBDT:
                     fn(view.bins, nb, ic, fm, g, h, w, lr,
                        bundle=view.bundle, bins_rm=view.bins_rm,
                        bins_words=view.bins_words))
-        params = self.grow_params
-        kind = self._serial_grow_kind()
-        if kind == "ordered":
-            # leaf-ordered physical layout: partition cost ~ parent
-            # segment, no gathers (ops/ordered_grow.py; exact-parity
-            # tested against the unordered cached learner).  Its i32 lane
-            # packing is uint8-only; >256-bin datasets use the cached
-            # learner (logged so the throughput change is visible).
-            return (lambda view, nb, ic, fm, g, h, w, lr:
-                    grow_tree_ordered(view.bins, nb, ic, fm, g, h, w, lr,
-                                      params, bins_rm=view.bins_rm,
-                                      bins_words=view.bins_words))
-        if kind == "fused":
-            # full-pass growth through the fused histogram->split-gain
-            # kernel (ops/pallas_histogram.py): both children's
-            # per-feature BestSplit candidates come straight out of the
-            # histogram pass — the [2, F, B, 3] tensor never lands in HBM
-            comm = SerialComm(leaf_cache=False, fused_gain=True)
-            return (lambda view, nb, ic, fm, g, h, w, lr:
-                    grow_tree(view.bins, nb, ic, fm, g, h, w, lr, params,
-                              comm, view.bins_rm, bundle=view.bundle))
-        if kind == "nocache":
-            # hist_cache degrade step (memory_policy=degrade): full-pass
-            # growth without the resident per-leaf histogram cache
-            comm = SerialComm(leaf_cache=False)
-            return (lambda view, nb, ic, fm, g, h, w, lr:
-                    grow_tree(view.bins, nb, ic, fm, g, h, w, lr, params,
-                              comm, view.bins_rm, bundle=view.bundle))
-        if cfg.serial_grow == "ordered" and self._bundle is None \
-                and self._screener is None:
-            log.info("max_bin > 256: using the cached (original-order) "
-                     "serial learner; the leaf-ordered fast path is "
-                     "uint8-only")
-        return (lambda view, nb, ic, fm, g, h, w, lr:
-                grow_tree(view.bins, nb, ic, fm, g, h, w, lr, params,
-                          bins_rm=view.bins_rm, bundle=view.bundle))
+        return _grower(self._grower_kind, self.grow_params)
 
     def reset_config(self, config: Config) -> None:
         """Booster::ResetConfig (c_api.cpp:96-134): re-derive learner
@@ -1554,7 +1493,7 @@ class GBDT:
         if self._parallel_grow_active:
             return self._make_train_step_local(guard)
         jit = _shared_train_step(self.objective, self.num_class, guard,
-                                 self._serial_grow_kind(), self.grow_params,
+                                 self._grower_kind, self.grow_params,
                                  donate=not guard and self._donation_on(),
                                  linear=self._linear)
         num_bin, is_cat = self.num_bin, self.is_cat
@@ -1577,33 +1516,13 @@ class GBDT:
     def _make_train_step_local(self, guard: bool):
         """Per-booster fused step for the distributed learners: their
         grow fn closes over a device mesh (shard_map), which the shared
-        registry cannot key portably.  Every per-dataset array is an
-        ARGUMENT, as in the shared step: closed over, the labels were
-        compiled in as a constant (168 MB at 42M rows, on every device),
-        and since they differ from data set to data set the persistent
-        compile cache never served the program twice."""
-        grow = self._grow_fn
-        holder = self.objective.program_holder()
-        num_class = self.num_class
-
-        @obs.instrumented_jit(program="train_step")
-        def step_fn(score, feat_masks, row_weight, lr, view, num_bin,
-                    is_cat, grad_arrays):
-            with jax.named_scope("gradients"):
-                grad, hess = holder.gradients_with(grad_arrays, score)
-                ok = (_all_finite(grad, hess) if guard
-                      else jnp.asarray(True))
-            outs = []
-            for cls in range(num_class):
-                ta, _, delta = grow(view, num_bin, is_cat, feat_masks[cls],
-                                    grad[cls], hess[cls], row_weight, lr)
-                with jax.named_scope("score_update"):
-                    score = score.at[cls].add(delta)
-                with jax.named_scope("pack_tree"):
-                    packed = pack_tree_arrays(ta)
-                outs.append((packed, ta, delta))
-            return score, outs, ok
-
+        registry cannot key portably.  The body is the shared step's
+        (``_round_step``), constant leaves only: ``_setup_linear``
+        refuses ``linear_tree`` under distributed training."""
+        step_fn = obs.instrumented_jit(
+            _round_step(self.objective.program_holder(), self.num_class,
+                        guard, self._grow_fn),
+            program="train_step")
         data = (self.num_bin, self.is_cat, self._grad_arrays)
 
         def step(*a):

@@ -84,7 +84,7 @@ HOST_PHASES = frozenset({
 })
 
 # The fused round, top level, in program order (models/gbdt.py
-# _build_shared_train_step, ops/ordered_grow.py, ops/leafhist.py).
+# _round_step, ops/ordered_grow.py, ops/leafhist.py).
 ROUND_PHASES = (
     "gradients",          # objective gradients, weighting, root sums
     "layout",             # digit quantisation, word packing, the

@@ -31,10 +31,9 @@ import jax.numpy as jnp
 from ..obs.compile_ledger import instrumented_jit
 
 from .bundle import decode_feature_bins, expand_digit_sums, expand_histogram
-from .histogram import (children_histograms, children_split_candidates,
-                        root_histogram)
-from .split import (BestSplit, SplitParams, combine_feature_candidates,
-                    find_best_split, leaf_output, K_MIN_SCORE)
+from .histogram import children_histograms, root_histogram
+from .split import (BestSplit, SplitParams, find_best_split, leaf_output,
+                    K_MIN_SCORE)
 
 
 class _SerialPrep(NamedTuple):
@@ -78,19 +77,8 @@ class SerialComm(NamedTuple):
     reference's f64 accumulators (bin.h:25-27).  ``leaf_cache=False`` keeps
     the one-full-pass-per-split strategy (used by tests needing bit-parity
     with the distributed learners, which share that code path).
-
-    ``fused_gain`` (with ``leaf_cache=False``) routes the full-pass
-    strategy through the fused histogram->split-gain kernel
-    (ops/pallas_histogram.py via ops/histogram.py's dispatcher): each
-    split's pass emits only the per-feature BestSplit candidates —
-    [2, F, 8]-ish floats — instead of landing the [2, F, B, 3] histogram
-    in HBM between two programs.  Bit-identical to find_best_split (the
-    kernel traces the same per_feature_scan; parity-pinned in
-    tests/test_fused_gain.py); ignored when the leaf cache is on, which
-    needs the histograms themselves for the sibling subtraction.
     """
     leaf_cache: bool = True
-    fused_gain: bool = False
 
     def reduce_sums(self, sums):
         return sums
@@ -117,20 +105,6 @@ class SerialComm(NamedTuple):
                    num_bin, is_cat, feat_mask, max_bin: int,
                    sp: SplitParams, num_leaves: int, bundle=None):
         if not self.leaf_cache:
-            if self.fused_gain:
-                # all rows in the "left" child; the right child's totals
-                # are zero and its candidates are discarded
-                totals = jnp.stack([
-                    jnp.stack([root_g, root_h, root_c]),
-                    jnp.zeros(3, jnp.float32)])
-                cand = children_split_candidates(
-                    bins, g, h, w, jnp.zeros(bins.shape[1], jnp.int32),
-                    0, -2, totals, num_bin, is_cat, feat_mask, max_bin, sp,
-                    bundle=bundle)
-                split = combine_feature_candidates(
-                    jax.tree.map(lambda a: a[0], cand), root_g, root_h,
-                    jnp.asarray(True), sp)
-                return split, ()
             hist = root_histogram(bins, g, h, w, max_bin)
             if bundle is not None:
                 hist = expand_histogram(hist, bundle)
@@ -159,15 +133,6 @@ class SerialComm(NamedTuple):
                         num_bin, is_cat, feat_mask, max_bin: int,
                         sp: SplitParams, bundle=None):
         if not self.leaf_cache:
-            if self.fused_gain:
-                totals = jnp.stack([totals_g, totals_h, totals_c], axis=-1)
-                cand = children_split_candidates(
-                    bins, g, h, w, step.leaf_id, step.parent_leaf,
-                    step.right_leaf, totals, num_bin, is_cat, feat_mask,
-                    max_bin, sp, bundle=bundle)
-                split = combine_feature_candidates(cand, totals_g, totals_h,
-                                                   can, sp)
-                return split, cache
             hists = children_histograms(bins, g, h, w, step.leaf_id,
                                         step.parent_leaf, step.right_leaf,
                                         max_bin)

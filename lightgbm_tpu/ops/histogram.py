@@ -45,46 +45,6 @@ def root_histogram(bins, grad, hess, weight, max_bin: int):
     return build_root_histogram(bins, grad, hess, weight, max_bin)
 
 
-def children_split_candidates(bins, grad, hess, weight, leaf_id,
-                              parent_leaf, right_leaf, totals, num_bin,
-                              is_cat, feat_mask, max_bin: int, params,
-                              bundle=None):
-    """Platform dispatcher for the FUSED histogram -> per-feature
-    split-gain pass: per-child ``split.FeatureCandidates`` ([2, F]
-    fields) without ever materializing the [2, F, B, 3] histogram in HBM
-    (TPU; pallas_histogram.py).  Elsewhere the same candidates come from
-    the scatter histogram + ``per_feature_candidates`` — identical math,
-    so CPU tests and the kernel agree bit-for-bit.
-
-    With ``bundle`` (EFB, ops/bundle.py) the pass is only half fused:
-    the histogram kernel runs over the BUNDLED columns (that is where
-    the FLOPs shrink), the column histograms are expanded back to
-    original feature space, and the scan runs on the expansion — the
-    in-VMEM fused epilogue cannot expand, so it is skipped."""
-    from .split import FeatureCandidates, per_feature_candidates
-    if bundle is not None:
-        from .bundle import expand_histogram
-        hists = children_histograms(bins, grad, hess, weight, leaf_id,
-                                    parent_leaf, right_leaf, max_bin)
-        hists = expand_histogram(hists, bundle)
-        return per_feature_candidates(hists, totals[:, 0], totals[:, 1],
-                                      totals[:, 2], num_bin, is_cat,
-                                      feat_mask, params)
-    if device.on_tpu():
-        from .pallas_histogram import fused_children_split_candidates_pallas
-        raw = fused_children_split_candidates_pallas(
-            bins, grad, hess, weight, leaf_id, parent_leaf, right_leaf,
-            totals, num_bin, is_cat, feat_mask, max_bin, params)
-        return FeatureCandidates(
-            gain=raw[:, :, 0], threshold=raw[:, :, 1].astype(jnp.int32),
-            left_g=raw[:, :, 2], left_h=raw[:, :, 3], left_c=raw[:, :, 4])
-    hists = build_children_histograms(bins, grad, hess, weight, leaf_id,
-                                      parent_leaf, right_leaf, max_bin)
-    return per_feature_candidates(hists, totals[:, 0], totals[:, 1],
-                                  totals[:, 2], num_bin, is_cat, feat_mask,
-                                  params)
-
-
 def histogram_scatter(bins, seg, num_seg: int, grad, hess, weight):
     """Scatter-add histogram.
 

@@ -33,13 +33,17 @@ contiguous).  The partition is two-way on one bit, "goes left": the
 window's suffix beyond the segment and every row of a rejected split are
 "other" rows, and a stable two-way partition leaves both where they were
 (the suffix IS the tail of the window).  The lane packing assumes uint8
-bins (max_bin <= 256); GBDT._make_grow_fn routes uint16 datasets to the
-cached learner instead.
+bins (max_bin <= 256) and carries no column decode: ``accepts`` is that
+rule, and whoever chooses a grower (models/gbdt.py, parallel/grow.py)
+asks it here.  The layout itself is this module's business too: others
+take the lane pad of a row count from ``lane_pad`` and the padded bin
+word lanes of a dataset, whole or one block a shard, from
+``pack_word_lanes``.
 
-Alternatives measured and rejected on TPU (tools/probe_primitives.py,
-tools/probe_partition.py; PERF.md, "Carried over", dead ends): XLA row
-gathers run ~12-200 ns/row (lowered per-index), so permutation-only
-layouts that gather payloads on demand are 2x SLOWER end-to-end; the
+Alternatives measured and rejected on TPU (tools/probe_partition.py;
+PERF.md, "Carried over", dead ends): XLA row gathers run ~12-200 ns/row
+(lowered per-index), so permutation-only layouts that gather payloads on
+demand are 2x SLOWER end-to-end; the
 12-operand sort is a comparison sort (11.7 ns a row slot at 16M rows,
 half of a round at 10.5M: PERF.md, PR 31) and stays where nothing
 measures it: the once-a-tree bagging compaction below.
@@ -69,6 +73,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ..obs.compile_ledger import instrumented_jit
 from ..utils import device
@@ -105,6 +110,21 @@ def _size_classes(n: int, smallest: int = 8192):
     return tuple(out)
 
 
+def accepts(bins_dtype, column_decode: bool = False) -> bool:
+    """Whether the leaf-ordered grower can grow this data: uint8 bins
+    (the i32 lanes hold four bin codes a word) read without a column
+    decode (EFB bundles and screening's compacted views decode the split
+    column, which the packed lanes do not carry).  Everything else grows
+    on ops/grow.py."""
+    return bins_dtype == jnp.uint8 and not column_decode
+
+
+def lane_pad(n: int) -> int:
+    """Slots behind the ``n`` rows of every lane: the largest window
+    class, so a window that starts at the last row still fits."""
+    return _size_classes(max(n, 1))[-1]
+
+
 def pack_u8_words(x_u8):
     """[N, C] u8 -> tuple of ceil(C/4) [N] i32 word arrays (bit-packed)."""
     n, c = x_u8.shape
@@ -115,6 +135,39 @@ def pack_u8_words(x_u8):
     words = jax.lax.bitcast_convert_type(
         x_u8.reshape(n, w, 4), jnp.int32)               # [N, w]
     return tuple(words[:, i] for i in range(w))
+
+
+def _word_lanes(rm):
+    pad = lane_pad(rm.shape[0])
+    return tuple(jnp.pad(w, (0, pad)) for w in pack_u8_words(rm))
+
+
+# module-level, so that boosters over the same shapes share ONE compiled
+# program.  (Both programs keep the names earlier versions compiled them
+# under: a name is part of the persistent compile cache's key.)
+@instrumented_jit(program="pack_words")
+def _pack_words_padded(rm):
+    return _word_lanes(rm)
+
+
+def pack_word_lanes(bins_rm, mesh=None):
+    """The padded bin word lanes ``grow_tree_ordered`` takes as
+    ``bins_words``, from the [N, F] row-major uint8 matrix, once a
+    dataset.  With ``mesh`` (rows of ``bins_rm`` in one block a device of
+    its first axis) each device packs its own rows and pads them by its
+    own ``lane_pad``, so block ``i`` of every returned
+    ``[k * (N/k + PAD)]`` lane is what shard ``i`` grows from.  That
+    program is this call's own and is released with it: kept, its 26 MiB
+    of code (10.5M rows a shard) stay on every chip through training
+    (PERF.md, PR 32)."""
+    if mesh is None:
+        return _pack_words_padded(bins_rm)
+    axis = mesh.axis_names[0]
+
+    def pack(rm):
+        return jax.shard_map(_word_lanes, mesh=mesh, in_specs=P(axis, None),
+                             out_specs=P(axis))(rm)
+    return instrumented_jit(pack, program="pack_words")(bins_rm)
 
 
 def _unpack_words(cols, c: int):
@@ -213,7 +266,7 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
         digits = leafhist.quantize_digits(g, h, row_weight,
                                           scales)       # [N, 9] i8
 
-        # callers (GBDT._DeviceData) pre-pad the shared bin words once
+        # callers hand over pack_word_lanes' padded lanes, packed once
         # per dataset; pad here only when handed bare [N] words
         bins_w = tuple(bw if bw.shape[0] >= N + PAD
                        else jnp.pad(bw, (0, N + PAD - bw.shape[0]))
@@ -397,7 +450,7 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
             # smaller child's histogram from its CONTIGUOUS slice; pad to
             # P/8 when the child is small enough (splits are often very
             # unbalanced — a fixed P/2 pad wastes up to 4x kernel work).
-            # Measured dead ends (tools/probe_dynhist.py): a dynamic-grid
+            # Measured dead ends (PERF.md, "Carried over"): a dynamic-grid
             # packed-word kernel runs 3x slower per row (Mosaic keeps all
             # one-hot temporaries live under a dynamic grid, forcing tiny
             # blocks), so the static size-class structure stays.

@@ -47,8 +47,8 @@ def predict_binned_tree(split_feature, split_bin, is_cat_node, left_child,
             fbin = decode_feature_bins(bins, feat, bundle)
         elif F <= 64:
             # per-row feature pick as a select chain: XLA TPU lowers the
-            # take_along_axis gather per index (~14 ns/row/level, measured
-            # tools/probe_primitives.py) — F sequential [N] selects are
+            # take_along_axis gather per index (~14 ns/row/level: PERF.md,
+            # "Carried over") — F sequential [N] selects are
             # 5-10x cheaper for the narrow feature counts GBDTs run at
             fbin = bins[0].astype(jnp.int32)
             for f in range(1, F):

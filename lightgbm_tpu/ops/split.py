@@ -50,19 +50,6 @@ class BestSplit(NamedTuple):
     left_count: jax.Array  # f32 (bagging-weighted row count)
 
 
-class FeatureCandidates(NamedTuple):
-    """Per-FEATURE best-split candidates, fields shaped [..., F]: the
-    histogram-side half of split finding.  The fused Pallas kernel
-    (ops/pallas_histogram.py) emits exactly this — ~[F, 5] floats per
-    child instead of the [2, F, B, 3] histogram — and
-    ``combine_feature_candidates`` turns it into a ``BestSplit``."""
-    gain: jax.Array        # f32, parent gain_shift NOT yet subtracted
-    threshold: jax.Array   # i32 (or f32 bit-exact ints from the kernel)
-    left_g: jax.Array      # f32, left sums AT this feature's threshold
-    left_h: jax.Array
-    left_c: jax.Array
-
-
 def leaf_split_gain(sum_g, sum_h, l1: float, l2: float):
     """GetLeafSplitGain (feature_histogram.hpp:270-276)."""
     reg = jnp.maximum(jnp.abs(sum_g) - l1, 0.0)
@@ -90,8 +77,7 @@ def per_feature_scan(hist, total_g, total_h, total_c, num_bin, is_cat,
     th = total_h[..., None, None]
     tc = total_c[..., None, None]
 
-    # 2-D iota so this scan also traces inside the fused Pallas kernel
-    # (Mosaic rejects 1-D iota); [F, B] broadcasts over any leading dims
+    # [F, B] broadcasts over any leading dims
     bins = jax.lax.broadcasted_iota(jnp.int32, (F, B), 1)
 
     # ---- numerical: left = cumsum over bins <= t --------------------------
@@ -138,51 +124,6 @@ def per_feature_scan(hist, total_g, total_h, total_c, num_bin, is_cat,
     return feat_best_gain, feat_best_t, left_g, left_h, left_c
 
 
-def per_feature_candidates(hist, total_g, total_h, total_c, num_bin, is_cat,
-                           feat_mask, p: SplitParams) -> FeatureCandidates:
-    """Per-feature best candidates with left sums gathered at each
-    feature's own best threshold — the full histogram-side reduction.
-    This is the contract the fused Pallas kernel reproduces in VMEM."""
-    feat_best_gain, feat_best_t, left_g, left_h, left_c = per_feature_scan(
-        hist, total_g, total_h, total_c, num_bin, is_cat, feat_mask, p)
-    t = feat_best_t[..., None]
-
-    def _at_t(arr):
-        return jnp.take_along_axis(arr, t, axis=-1)[..., 0]
-
-    return FeatureCandidates(gain=feat_best_gain, threshold=feat_best_t,
-                             left_g=_at_t(left_g), left_h=_at_t(left_h),
-                             left_c=_at_t(left_c))
-
-
-def combine_feature_candidates(cand: FeatureCandidates, total_g, total_h,
-                               can_split, p: SplitParams) -> BestSplit:
-    """Across-features half of split finding, over [..., F] candidates:
-    max gain, ties to the smallest feature index (argmax returns the
-    first occurrence), then the parent gain_shift subtraction and the
-    can_split mask.  Shared by the histogram path (``find_best_split``)
-    and the fused histogram->gain kernel, so the two agree bit-for-bit
-    by construction."""
-    gain_shift = leaf_split_gain(total_g, total_h, p.lambda_l1, p.lambda_l2)
-    best_f = jnp.argmax(cand.gain, axis=-1).astype(jnp.int32)
-
-    def _at_f(arr):
-        return jnp.take_along_axis(arr, best_f[..., None], axis=-1)[..., 0]
-
-    best_gain = _at_f(cand.gain)
-    best_t = _at_f(cand.threshold).astype(jnp.int32)
-    splittable = jnp.isfinite(best_gain) & can_split
-    best_gain_out = jnp.where(splittable, best_gain - gain_shift, K_MIN_SCORE)
-    return BestSplit(
-        gain=best_gain_out.astype(jnp.float32),
-        feature=jnp.where(splittable, best_f, -1).astype(jnp.int32),
-        threshold=jnp.where(splittable, best_t, 0).astype(jnp.int32),
-        left_sum_g=_at_f(cand.left_g).astype(jnp.float32),
-        left_sum_h=_at_f(cand.left_h).astype(jnp.float32),
-        left_count=_at_f(cand.left_c).astype(jnp.float32),
-    )
-
-
 def find_best_split(hist, total_g, total_h, total_c, num_bin, is_cat,
                     feat_mask, can_split, p: SplitParams) -> BestSplit:
     """Best split for one leaf (or a batch of leaves via leading dims).
@@ -197,9 +138,36 @@ def find_best_split(hist, total_g, total_h, total_c, num_bin, is_cat,
       p: static constraints.
     Returns BestSplit with fields shaped [...].
     """
-    cand = per_feature_candidates(hist, total_g, total_h, total_c, num_bin,
-                                  is_cat, feat_mask, p)
-    return combine_feature_candidates(cand, total_g, total_h, can_split, p)
+    feat_best_gain, feat_best_t, left_g, left_h, left_c = per_feature_scan(
+        hist, total_g, total_h, total_c, num_bin, is_cat, feat_mask, p)
+    t = feat_best_t[..., None]
+
+    def _at_t(arr):
+        # left sums at each feature's own best threshold: [..., F]
+        return jnp.take_along_axis(arr, t, axis=-1)[..., 0]
+
+    left_g, left_h, left_c = _at_t(left_g), _at_t(left_h), _at_t(left_c)
+    # across features: max gain, ties to the smallest feature index
+    # (argmax returns the first occurrence), then the parent gain_shift
+    # subtraction and the can_split mask
+    gain_shift = leaf_split_gain(total_g, total_h, p.lambda_l1, p.lambda_l2)
+    best_f = jnp.argmax(feat_best_gain, axis=-1).astype(jnp.int32)
+
+    def _at_f(arr):
+        return jnp.take_along_axis(arr, best_f[..., None], axis=-1)[..., 0]
+
+    best_gain = _at_f(feat_best_gain)
+    best_t = _at_f(feat_best_t).astype(jnp.int32)
+    splittable = jnp.isfinite(best_gain) & can_split
+    best_gain_out = jnp.where(splittable, best_gain - gain_shift, K_MIN_SCORE)
+    return BestSplit(
+        gain=best_gain_out.astype(jnp.float32),
+        feature=jnp.where(splittable, best_f, -1).astype(jnp.int32),
+        threshold=jnp.where(splittable, best_t, 0).astype(jnp.int32),
+        left_sum_g=_at_f(left_g).astype(jnp.float32),
+        left_sum_h=_at_f(left_h).astype(jnp.float32),
+        left_count=_at_f(left_c).astype(jnp.float32),
+    )
 
 
 def better_split(a: BestSplit, b: BestSplit) -> BestSplit:

@@ -7,4 +7,4 @@ inside shard_map; see comm.py for the per-learner communication patterns.
 
 from .comm import (DataParallelComm, FeatureParallelComm,  # noqa: F401
                    HistExchange, VotingParallelComm)
-from .grow import make_comm, make_parallel_grow, shard_words  # noqa: F401
+from .grow import make_comm, make_parallel_grow  # noqa: F401

@@ -23,9 +23,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..obs.compile_ledger import instrumented_jit
+from ..ops import ordered_grow
 from ..ops.grow import GrowParams, _grow_tree_impl
-from ..ops.ordered_grow import _size_classes, grow_tree_ordered, \
-    pack_u8_words
 from .comm import (DataParallelComm, FeatureParallelComm, HistExchange,
                    VotingParallelComm)
 
@@ -43,30 +42,9 @@ def make_comm(mode: str, axis_name: str, num_shards: int,
 
 
 def grows_ordered(mode: str, bins_dtype, bundled: bool) -> bool:
-    """Whether the data-parallel learner grows leaf-ordered shards: where
-    the serial learner would (the i32 lane packing is uint8-only and
-    carries no EFB column decode)."""
-    return mode == "data" and not bundled and bins_dtype == jnp.uint8
-
-
-def shard_words(mesh: Mesh, bins_rm, axis_name: Optional[str] = None):
-    """The leaf-ordered grower's padded bin-word lanes, one block per
-    shard, from a row-sharded row-major matrix: each device packs its own
-    rows and pads them by its own largest window class, so that shard
-    ``i`` of every returned ``[k * (N/k + PAD)]`` lane is what
-    ``grow_tree_ordered`` takes as ``bins_words`` there."""
-    axis_name = axis_name or mesh.axis_names[0]
-    k = mesh.shape[axis_name]
-    pad = _size_classes(bins_rm.shape[0] // k)[-1]
-    words = -(-bins_rm.shape[1] // 4)
-
-    @instrumented_jit(program="pack_words")
-    def pack(rm):
-        return jax.shard_map(
-            lambda b: tuple(jnp.pad(w, (0, pad)) for w in pack_u8_words(b)),
-            mesh=mesh, in_specs=P(axis_name, None),
-            out_specs=(P(axis_name),) * words)(rm)
-    return pack(bins_rm)
+    """Whether a learner grows leaf-ordered shards: the data-parallel
+    one, over data the leaf-ordered grower accepts."""
+    return mode == "data" and ordered_grow.accepts(bins_dtype, bundled)
 
 
 def make_parallel_grow(mesh: Mesh, mode: str, params: GrowParams,
@@ -78,8 +56,9 @@ def make_parallel_grow(mesh: Mesh, mode: str, params: GrowParams,
     with zero row_weight (dead rows), features to a multiple with a False
     feat_mask (dead features); outputs are cropped back.
 
-    ``bins_rm`` ([N, F], rows sharded) and ``bins_words`` (``shard_words``)
-    are the leaf-ordered shards' resident layout, shared across trees;
+    ``bins_rm`` ([N, F], rows sharded) and ``bins_words``
+    (``ordered_grow.pack_word_lanes`` over the mesh) are the
+    leaf-ordered shards' resident layout, shared across trees;
     left out, or where rows had to be padded, each shard derives them
     from its block of ``bins`` once a tree.
     """
@@ -135,9 +114,9 @@ def make_parallel_grow(mesh: Mesh, mode: str, params: GrowParams,
 
             def local_fn(b, nb, ic, fm, g, h, w, lr, *res):
                 rm, words = res if res else (None, None)
-                return grow_tree_ordered(b, nb, ic, fm, g, h, w, lr, params,
-                                         bins_rm=rm, bins_words=words,
-                                         exchange=exchange)
+                return ordered_grow.grow_tree_ordered(
+                    b, nb, ic, fm, g, h, w, lr, params, bins_rm=rm,
+                    bins_words=words, exchange=exchange)
             args += resident
         else:
             comm = make_comm(mode, axis_name, k, F + pad_f, top_k)

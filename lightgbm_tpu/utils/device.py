@@ -9,14 +9,6 @@ chip steers all of them by replacing this one function.
 
 from __future__ import annotations
 
-from .log import LightGBMError
-
-
-class KernelRefusedOnTPU(LightGBMError):
-    """A user option selected a Pallas kernel the chip's compiler refuses;
-    the message quotes the compiler.  Raised at construction, instead of
-    swapping in another path."""
-
 
 def on_tpu() -> bool:
     """True when jax dispatches to a TPU backend.  A backend that cannot

@@ -45,10 +45,12 @@ def one_hot_data(n=2500, blocks=8, block_size=6, seed=0, act=0.7,
     return X, y
 
 
-def train_gbdt(X, y, *, enable_bundle, iters=6, grow="cached", extra=None,
-               max_bin=63):
+def train_gbdt(X, y, *, enable_bundle, iters=6, extra=None, max_bin=63):
+    """Bundled data grows on the cached learner, unbundled data
+    leaf-ordered (the booster chooses from the data), so every
+    bit-identity asserted below is also the growers' parity."""
     p = {"objective": "binary", "num_leaves": 15, "min_data_in_leaf": 20,
-         "min_sum_hessian_in_leaf": 1e-3, "serial_grow": grow,
+         "min_sum_hessian_in_leaf": 1e-3,
          "max_bin": max_bin, "num_iterations": iters}
     p.update(extra or {})
     ds = BinnedDataset.from_matrix(X, y, max_bin=max_bin,
@@ -156,22 +158,13 @@ def test_zero_conflict_bundled_training_bit_identical():
 
 
 def test_default_grow_bundled_matches_unbundled_ordered():
-    # default serial_grow=ordered falls back to the cached learner for
-    # bundled datasets; exact cross-grower parity keeps the models
-    # bit-identical anyway
+    # bundled data grows on the cached learner, unbundled leaf-ordered;
+    # exact cross-grower parity keeps the models bit-identical
     X, y = one_hot_data(seed=1)
-    b0, _ = train_gbdt(X, y, enable_bundle=False, grow="ordered")
-    b1, _ = train_gbdt(X, y, enable_bundle=True, grow="ordered")
+    b0, _ = train_gbdt(X, y, enable_bundle=False)
+    b1, _ = train_gbdt(X, y, enable_bundle=True)
+    assert (b0._grower_kind, b1._grower_kind) == ("ordered", "cached")
     assert b1.save_model_to_string() == b0.save_model_to_string()
-
-
-def test_fused_grow_composes_with_bundling():
-    X, y = one_hot_data(seed=2)
-    b1, ds1 = train_gbdt(X, y, enable_bundle=True, grow="fused")
-    assert ds1.bundle_plan is not None
-    assert len(b1.models) == 6
-    raw = b1.predict_raw(X[:200])
-    assert np.isfinite(raw).all()
 
 
 def test_goss_and_dart_compose_with_bundling():

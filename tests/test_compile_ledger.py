@@ -37,19 +37,19 @@ def fresh_train_programs(monkeypatch):
     # re-jitting the SAME function object would hit jax's
     # function-identity executable cache and still record nothing; a
     # fresh closure breaks the identity so the compile really happens
-    raw_pack_words = gbdt._pack_words_padded._fn.__wrapped__
+    from lightgbm_tpu.ops import ordered_grow
+    raw_pack_words = ordered_grow._pack_words_padded._fn.__wrapped__
     raw_pack_tree = gbdt._PACK_TREE._fn.__wrapped__
 
-    def fresh_pack_words(rm, pad):
-        return raw_pack_words(rm, pad)
+    def fresh_pack_words(rm):
+        return raw_pack_words(rm)
 
     def fresh_pack_tree(*args, **kwargs):
         return raw_pack_tree(*args, **kwargs)
 
     monkeypatch.setattr(
-        gbdt, "_pack_words_padded",
-        obs.instrumented_jit(fresh_pack_words, program="pack_words",
-                             static_argnames=("pad",)))
+        ordered_grow, "_pack_words_padded",
+        obs.instrumented_jit(fresh_pack_words, program="pack_words"))
     monkeypatch.setattr(
         gbdt, "_PACK_TREE",
         obs.instrumented_jit(fresh_pack_tree, program="pack_tree"))

@@ -23,14 +23,15 @@ def _np_hist(bins, g, h, w, B):
     return out
 
 
-def _np_best_split(hist, tg, th, tc, num_bin, is_cat, p: SplitParams):
+def _np_best_split(hist, tg, th, tc, num_bin, is_cat, p: SplitParams,
+                   feat_mask=None):
     """Reference scan transcription (feature_histogram.hpp:75-187)."""
     F, B, _ = hist.shape
     best = dict(gain=-np.inf, feat=-1, t=-1, lg=0.0, lh=0.0, lc=0.0)
     gain_shift = _gain(tg, th, p)
     for f in range(F):
         nb = num_bin[f]
-        if nb <= 1:
+        if nb <= 1 or (feat_mask is not None and not feat_mask[f]):
             continue
         if is_cat[f]:
             cands = [(t, hist[f, t, 0], hist[f, t, 1], hist[f, t, 2])
@@ -79,47 +80,133 @@ def test_histogram_matches_numpy():
     np.testing.assert_allclose(hist, expected, rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("seed", range(4))
-@pytest.mark.parametrize("l1,l2,min_data,min_hess", [
-    (0.0, 0.0, 5, 1e-3), (0.5, 1.0, 10, 0.5)])
-def test_find_best_split_matches_oracle(seed, l1, l2, min_data, min_hess):
+def _one_leaf_case(seed, l1, l2, min_data, min_hess):
+    """One leaf over all rows: [F, B, 3], scalar totals."""
     bins, g, h = _make_data(seed=seed, B=16)
     F = bins.shape[0]
-    w = np.ones_like(g)
-    hist = _np_hist(bins, g, h, w, 16)
-    p = SplitParams(min_data_in_leaf=min_data, min_sum_hessian_in_leaf=min_hess,
+    hist = _np_hist(bins, g, h, np.ones_like(g), 16)
+    p = SplitParams(min_data_in_leaf=min_data,
+                    min_sum_hessian_in_leaf=min_hess,
                     lambda_l1=l1, lambda_l2=l2, min_gain_to_split=0.0)
-    num_bin = np.full(F, 16, np.int32)
-    is_cat = np.zeros(F, bool)
-    tg, th, tc = g.sum(), h.sum(), float(len(g))
-    oracle = _np_best_split(hist, tg, th, tc, num_bin, is_cat, p)
+    return dict(hist=hist, totals=np.asarray([g.sum(), h.sum(), len(g)]),
+                num_bin=np.full(F, 16, np.int32), is_cat=np.zeros(F, bool),
+                feat_mask=np.ones(F, bool), can=np.asarray(True), p=p)
 
+
+def _two_child_case(seed, p, n=700, f=6, B=21, n_cat=2, masked_from=None,
+                    can=(True, True), splittable=True):
+    """Both children of a split leaf as the growers hand them over: a
+    [2, F, B, 3] histogram with [2] totals and guards, bagged-out rows,
+    rows of a third leaf, features with fewer bins than B, two
+    categorical features."""
+    rng = np.random.RandomState(seed)
+    bins = rng.randint(0, B, size=(f, n))
+    g = rng.normal(size=n).astype(np.float32)
+    h = rng.uniform(0.2, 1.5, size=n).astype(np.float32)
+    w = (rng.uniform(size=n) > 0.25).astype(np.float32)
+    leaf = rng.randint(0, 3, size=n)
+    num_bin = rng.randint(2, B + 1, size=f).astype(np.int32)
+    is_cat = np.arange(f) < n_cat
+    feat_mask = np.ones(f, bool)
+    if masked_from is not None:
+        feat_mask[masked_from:] = False
+    hist, totals = [], []
+    for child in (0, 1):
+        m = (leaf == child) * w
+        hist.append(_np_hist(bins, g * m, h * m, m, B))
+        totals.append([(g * m).sum(), (h * m).sum(), m.sum()])
+    return dict(hist=np.stack(hist), totals=np.asarray(totals).T,
+                num_bin=num_bin, is_cat=is_cat, feat_mask=feat_mask,
+                can=np.asarray(can), p=p, splittable=splittable)
+
+
+_LOOSE = SplitParams(min_data_in_leaf=5, min_sum_hessian_in_leaf=1e-3)
+_SPLIT_CASES = {
+    **{f"leaf-{l1}-{l2}-{md}-{mh}-{seed}":
+       (_one_leaf_case, (seed, l1, l2, md, mh))
+       for (l1, l2, md, mh) in [(0.0, 0.0, 5, 1e-3), (0.5, 1.0, 10, 0.5)]
+       for seed in range(4)},
+    **{f"two_child-{seed}": (_two_child_case, (seed, _LOOSE))
+       for seed in range(3)},
+    "l1_and_min_gain": (_two_child_case, (3, SplitParams(
+        min_data_in_leaf=10, min_sum_hessian_in_leaf=0.5, lambda_l1=0.3,
+        lambda_l2=0.7, min_gain_to_split=0.05), 900, 6, 17)),
+    # min_data_in_leaf near the leaf size (about 100 bagged rows a
+    # child): most candidates invalid, the valid frontier decides
+    "min_data_edge": (_two_child_case, (4, SplitParams(
+        min_data_in_leaf=40, min_sum_hessian_in_leaf=10.0), 400)),
+    # impossible constraints: gain -inf, feature -1, threshold 0
+    "all_unsplittable": (_two_child_case, (5, SplitParams(
+        min_data_in_leaf=10_000), 300, 6, 21, 2, None, (True, True),
+        False)),
+    # only features 0 and 1 usable, and the right child may not split
+    "mask_and_can_split": (_two_child_case, (6, _LOOSE, 700, 6, 21, 2, 2,
+                                              (True, False))),
+}
+
+
+@pytest.mark.parametrize("case", list(_SPLIT_CASES))
+def test_find_best_split_matches_oracle(case):
+    make, args = _SPLIT_CASES[case]
+    c = make(*args)
+    p, hist, (tg, th, tc) = c["p"], c["hist"], c["totals"]
     got = find_best_split(jnp.asarray(hist, jnp.float32), jnp.float32(tg),
                           jnp.float32(th), jnp.float32(tc),
-                          jnp.asarray(num_bin), jnp.asarray(is_cat),
-                          jnp.ones(F, bool), jnp.asarray(True), p)
-    assert int(got.feature) == oracle["feat"]
-    assert int(got.threshold) == oracle["t"]
-    np.testing.assert_allclose(float(got.gain), oracle["gain"], rtol=1e-4)
-    np.testing.assert_allclose(float(got.left_count), oracle["lc"], rtol=1e-5)
+                          jnp.asarray(c["num_bin"]), jnp.asarray(c["is_cat"]),
+                          jnp.asarray(c["feat_mask"]), jnp.asarray(c["can"]),
+                          p)
+    # one oracle call a leaf: leading dims of the batched input, if any
+    leaves = [()] if hist.ndim == 3 else [(0,), (1,)]
+    finite = []
+    for ix in leaves:
+        oracle = _np_best_split(hist[ix], tg[ix], th[ix], tc[ix],
+                                c["num_bin"], c["is_cat"], p,
+                                c["feat_mask"])
+        if not c["can"][ix]:
+            oracle = dict(gain=-np.inf, feat=-1)
+        rec = [np.asarray(f)[ix] for f in got]
+        assert int(rec[1]) == oracle["feat"]
+        if oracle["feat"] < 0:
+            assert rec[0] == -np.inf and int(rec[2]) == 0
+            continue
+        finite.append(ix)
+        assert int(rec[2]) == oracle["t"]
+        np.testing.assert_allclose(rec[0], oracle["gain"], rtol=1e-4)
+        np.testing.assert_allclose(rec[3], oracle["lg"], rtol=1e-4,
+                                   atol=1e-5)
+        np.testing.assert_allclose(rec[4], oracle["lh"], rtol=1e-5)
+        np.testing.assert_allclose(rec[5], oracle["lc"], rtol=1e-5)
+        assert c["feat_mask"][oracle["feat"]]
+    assert bool(finite) == c.get("splittable", True), "degenerate scenario"
 
 
-def test_find_best_split_categorical():
+@pytest.mark.parametrize("case", ["noisy_category_5",
+                                  "category_0_has_the_negative_mass"])
+def test_find_best_split_categorical(case):
     rng = np.random.RandomState(3)
-    n, B = 600, 8
-    bins = rng.randint(0, B, size=(1, n)).astype(np.int32)
-    # category 5 has clearly different gradient
-    g = np.where(bins[0] == 5, -2.0, 0.5).astype(np.float32) \
-        + rng.normal(scale=0.1, size=n).astype(np.float32)
-    h = np.ones(n, np.float32)
     p = SplitParams(min_data_in_leaf=5, min_sum_hessian_in_leaf=1e-3)
+    if case == "noisy_category_5":
+        n, B, want = 600, 8, (0, 5)
+        bins = rng.randint(0, B, size=(1, n)).astype(np.int32)
+        g = np.where(bins[0] == 5, -2.0, 0.5).astype(np.float32) \
+            + rng.normal(scale=0.1, size=n).astype(np.float32)
+        num_bin, is_cat = [B], [True]
+    else:
+        # known answer beside a numerical feature that says nothing:
+        # one-vs-rest, "category 0 goes left"
+        n, B, want = 512, 8, (0, 0)
+        cats = rng.randint(0, 4, size=n)
+        bins = np.stack([cats, rng.randint(0, B, size=n)]).astype(np.int32)
+        g = np.where(cats == 0, -2.0, 1.0).astype(np.float32)
+        num_bin, is_cat = [4, B], [True, False]
+    h = np.ones(n, np.float32)
     hist = _np_hist(bins, g, h, np.ones(n), B)
     got = find_best_split(jnp.asarray(hist, jnp.float32),
                           jnp.float32(g.sum()), jnp.float32(h.sum()),
-                          jnp.float32(n), jnp.asarray([B], np.int32),
-                          jnp.asarray([True]), jnp.asarray([True]),
+                          jnp.float32(n), jnp.asarray(num_bin, np.int32),
+                          jnp.asarray(is_cat), jnp.ones(len(is_cat), bool),
                           jnp.asarray(True), p)
-    assert int(got.threshold) == 5
+    assert (int(got.feature), int(got.threshold)) == want
 
 
 def test_grow_tree_structure_and_fit():

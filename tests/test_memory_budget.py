@@ -25,8 +25,8 @@ def test_estimate_components_scale_with_problem():
     big_cache = estimate_train_memory(1000, 8, 1023, 256, 1)
     assert set(small) == {"bins_device", "packed_payload",
                          "scores_and_gradients", "score_double_buffer",
-                         "histogram_cache", "vmem_scratch", "linear_fit",
-                         "working", "total"}
+                         "histogram_cache", "linear_fit", "working",
+                         "total"}
     assert all(v >= 0 for v in small.values())
     assert big_rows["bins_device"] > small["bins_device"]
     assert big_rows["total"] > small["total"]
@@ -53,15 +53,12 @@ def test_estimate_flags_zero_their_components():
     base = estimate_train_memory(1000, 8, 31, 64, 1)
     donated = estimate_train_memory(1000, 8, 31, 64, 1, donate_score=True)
     nocache = estimate_train_memory(1000, 8, 31, 64, 1, leaf_cache=False)
-    fused = estimate_train_memory(1000, 8, 31, 64, 1, fused_scratch=True)
     assert base["score_double_buffer"] == 1000 * 4
     assert donated["score_double_buffer"] == 0
     assert donated["total"] == base["total"] - base["score_double_buffer"]
     assert nocache["histogram_cache"] == 0
     assert nocache["total"] == base["total"] - base["histogram_cache"]
-    assert base["vmem_scratch"] == 0
-    assert fused["vmem_scratch"] == 2 * 8 * 64 * 3 * 4
-    for est in (base, donated, nocache, fused):
+    for est in (base, donated, nocache):
         assert est["total"] == sum(v for k, v in est.items()
                                    if k != "total")
 

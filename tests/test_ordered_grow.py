@@ -189,21 +189,95 @@ def test_uint16_bins_fall_back_to_cached_learner():
     assert np.isfinite(p).all()
 
 
-def test_serial_grow_config_knob():
-    """serial_grow=cached selects the original-order learner; results
-    match the ordered default exactly."""
+def _train(params, X, y, rounds=3):
     import lightgbm_tpu as lgb
-    rng = np.random.RandomState(1)
-    X = rng.normal(size=(3000, 4))
-    y = (X[:, 0] + 0.2 * X[:, 1] > 0).astype(np.float64)
-    preds = []
-    for strategy in ("ordered", "cached"):
-        bst = lgb.train({"objective": "binary", "num_leaves": 15,
-                         "verbose": -1, "min_data_in_leaf": 20,
-                         "serial_grow": strategy},
-                        lgb.Dataset(X, label=y), num_boost_round=5)
-        preds.append(bst.predict(X[:200], raw_score=True))
-    np.testing.assert_allclose(preds[0], preds[1], rtol=1e-6, atol=1e-7)
+    p = {"objective": "binary", "num_leaves": 15, "verbose": -1,
+         "min_data_in_leaf": 20, **params}
+    return lgb.train(p, lgb.Dataset(X, label=y, params=p),
+                     num_boost_round=rounds)
+
+
+def _dense(seed=1, n=3000, f=4):
+    rng = np.random.RandomState(seed)
+    X = rng.normal(size=(n, f))
+    return X, (X[:, 0] + 0.2 * X[:, 1] > 0).astype(np.float64)
+
+
+def _one_hot(seed=1, n=3000, blocks=3, size=5):
+    """Blocks with at most one non-zero a row: what EFB bundles."""
+    rng = np.random.RandomState(seed)
+    X = np.zeros((n, blocks * size))
+    for b in range(blocks):
+        X[np.arange(n), b * size + rng.randint(0, size, n)] = \
+            rng.randint(1, 4, n)
+    return X, (X[:, 0] - X[:, size + 1] + rng.normal(0, .5, n) > 0) * 1.0
+
+
+def test_stale_serial_grow_key_is_ignored():
+    """``serial_grow`` was an option until PR 32; a conf file that still
+    names it is read like any unknown key and trains the default's
+    model, on the grower the data chooses."""
+    X, y = _dense()
+    stale = _train({"serial_grow": "cached"}, X, y, rounds=5)
+    assert stale._booster._grower_kind == "ordered"
+    assert stale.model_to_string().split("parameters")[0] == \
+        _train({}, X, y, rounds=5).model_to_string().split("parameters")[0]
+
+
+# what the data looks like -> the grower _choose_grower names, what the
+# device holds for it, and the parameters of a run that must grow the
+# same trees on another grower (None: nothing comparable)
+_CHOICES = {
+    "uint8_unbundled": (_dense, {}, "ordered", None),
+    "max_bin_500": (_dense, {"max_bin": 500}, "cached", None),
+    "efb_bundle": (_one_hot, {}, "cached", {"enable_bundle": False}),
+    "screening": (_dense, {"feature_screen_ratio": 0.5,
+                           "feature_screen_warmup": 1}, "cached", None),
+    "hist_cache_degrade": (_dense, {"memory_policy": "degrade",
+                                    "histogram_pool_size": 0.001},
+                           "nocache", {}),
+    "data_parallel_uint8": (_dense, {"tree_learner": "data",
+                                     "num_machines": 4}, "ordered", {}),
+    "data_parallel_uint16": (_dense, {"tree_learner": "data",
+                                      "num_machines": 4, "max_bin": 500},
+                             "nocache", None),   # float sums: near ties
+}
+
+
+@pytest.mark.parametrize("case", list(_CHOICES))
+def test_grower_is_chosen_from_the_data_and_trains(case):
+    """One choice, from what the booster can observe, and every choice
+    trains: finite scores, and where another grower takes the same data
+    the same trees."""
+    from lightgbm_tpu.utils import log
+    log.reset_warn_once()
+    make, params, kind, same_as = _CHOICES[case]
+    X, y = make()
+    bst = _train(params, X, y)
+    g = bst._booster
+    assert g._grower_kind == kind and g._choose_grower()[0] == kind
+    td = g.train_data
+    if params.get("tree_learner") == "data":
+        # leaf-ordered shards keep their layout resident, one block a
+        # device; full passes read columns only
+        assert g._parallel_grow_active
+        assert (td.bins_words is not None) == (kind == "ordered")
+        assert (td.bins_rm is not None) == (kind == "ordered")
+    else:
+        assert (td.bins_words is not None) == (td.bins.dtype == np.uint8)
+    assert bst.num_trees() == 3
+    assert np.isfinite(bst.predict(X[:100], raw_score=True)).all()
+    if same_as is not None:
+        other = _train(same_as, X, y)
+        assert other._booster._grower_kind != kind \
+            or other._booster._parallel_grow_active \
+            != g._parallel_grow_active
+        for a, c in zip(g.models, other._booster.models):
+            np.testing.assert_array_equal(a.split_feature, c.split_feature)
+            np.testing.assert_array_equal(a.threshold_in_bin,
+                                          c.threshold_in_bin)
+            np.testing.assert_allclose(a.leaf_value, c.leaf_value,
+                                       rtol=2e-4, atol=2e-6)
 
 
 def test_misaligned_valid_set_rejected():

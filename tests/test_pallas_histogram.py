@@ -12,9 +12,9 @@ from lightgbm_tpu.ops.pallas_histogram import (children_histograms_pallas,
                                                root_histogram_pallas)
 
 
-def _data(seed, n, f, B):
+def _data(seed, n, f, B, dtype=jnp.int32):
     rng = np.random.RandomState(seed)
-    bins = jnp.asarray(rng.randint(0, B, size=(f, n)), jnp.int32)
+    bins = jnp.asarray(rng.randint(0, B, size=(f, n)), dtype)
     g = jnp.asarray(rng.normal(size=n), jnp.float32)
     h = jnp.abs(g) + 0.1
     w = jnp.asarray((rng.rand(n) > 0.3), jnp.float32)  # bagging-style mask
@@ -22,17 +22,25 @@ def _data(seed, n, f, B):
     return bins, g, h, w, leaf
 
 
-@pytest.mark.parametrize("n,B,n_blk", [
-    (1024, 16, 256),      # exact block multiple
-    (1000, 16, 256),      # row padding path
-    (700, 255, 256),      # max_bin not a lane multiple -> crop path
+@pytest.mark.parametrize("n,B,n_blk,dtype,leaves", [
+    (1024, 16, 256, jnp.int32, (1, 3)),   # exact block multiple
+    (1000, 16, 256, jnp.int32, (1, 3)),   # row padding path
+    (700, 255, 256, jnp.int32, (1, 3)),   # max_bin not a lane multiple: crop
+    (700, 21, 256, jnp.uint8, (1, 3)),    # the bins as the device holds them
+    # the root as the left child of a split nothing went right in
+    # (right_leaf -2 matches no row): what root_histogram_pallas calls
+    (512, 8, 256, jnp.uint8, (0, -2)),
 ])
-def test_children_parity_interpret(n, B, n_blk):
-    bins, g, h, w, leaf = _data(0, n, 5, B)
-    want = np.asarray(build_children_histograms(bins, g, h, w, leaf, 1, 3, B))
-    got = np.asarray(children_histograms_pallas(bins, g, h, w, leaf, 1, 3, B,
-                                                n_blk=n_blk, interpret=True))
+def test_children_parity_interpret(n, B, n_blk, dtype, leaves):
+    bins, g, h, w, leaf = _data(0, n, 5, B, dtype)
+    want = np.asarray(build_children_histograms(bins, g, h, w, leaf,
+                                                *leaves, B))
+    got = np.asarray(children_histograms_pallas(bins, g, h, w, leaf, *leaves,
+                                                B, n_blk=n_blk,
+                                                interpret=True))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
+    if leaves[1] < 0:
+        assert not got[1].any() and got[0].any()
 
 
 def test_root_parity_interpret():

@@ -15,9 +15,9 @@ import pytest
 from jax.sharding import Mesh
 
 from lightgbm_tpu.ops.grow import GrowParams, grow_tree
-from lightgbm_tpu.ops.ordered_grow import grow_tree_ordered
+from lightgbm_tpu.ops.ordered_grow import grow_tree_ordered, pack_word_lanes
 from lightgbm_tpu.ops.split import SplitParams
-from lightgbm_tpu.parallel import make_parallel_grow, shard_words
+from lightgbm_tpu.parallel import make_parallel_grow
 
 
 def _make_data(seed=0, n=512, f=6, B=16):
@@ -324,9 +324,9 @@ def test_ordered_shards_match_serial_and_oracle(case, n_dev):
 
 
 def test_ordered_shards_take_the_resident_layout():
-    """``bins_rm`` and ``shard_words`` (what models/gbdt.py keeps on the
-    devices, one block a shard) give the tree the per-tree derivation
-    gives."""
+    """``bins_rm`` and ``pack_word_lanes`` over the mesh (what
+    models/gbdt.py keeps on the devices, one block a shard) give the tree
+    the per-tree derivation gives."""
     bins, g, h, w, params = _sharded_case("zero_weight")
     F, N = bins.shape
     mesh = _mesh(4)
@@ -336,7 +336,7 @@ def test_ordered_shards_take_the_resident_layout():
     fn = make_parallel_grow(mesh, "data", params)
     t0, leaf0, _ = fn(jnp.asarray(bins), *meta, *rows)
     rm = jnp.asarray(np.ascontiguousarray(bins.T))
-    words = shard_words(mesh, rm)
+    words = pack_word_lanes(rm, mesh)
     assert len(words) == 1 and words[0].shape[0] == 4 * (N // 4 + 8192)
     t1, leaf1, _ = fn(jnp.asarray(bins), *meta, *rows, bins_rm=rm,
                       bins_words=words)

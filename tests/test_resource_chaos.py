@@ -546,7 +546,7 @@ def test_admission_and_diskguard_record_zero_xla_programs(tmp_path):
     n0 = len(compile_ledger.events())
     estimate_train_memory(100_000, 64, 255, 255, 2)
     estimate_train_memory(100_000, 64, 255, 255, 2, donate_score=True,
-                          fused_scratch=True, leaf_cache=False)
+                          leaf_cache=False)
     from lightgbm_tpu.utils import resource
     resource.set_budget_table({"total": 1, "bins_device": 1}, "pin")
     resource.format_table({"total": 1, "bins_device": 1})

@@ -356,37 +356,3 @@ def test_sharded_grower_exchanges_once_a_split_outside_every_branch(
     assert _whole_lane_copies_in_grow_loop(text, lane) == []
     pm = devtrace.phase_map(text)
     assert pm["ops_unscoped"] == 0, pm["unscoped_op_names"]
-
-
-def test_fused_gain_kernel_is_refused_with_the_quoted_words(spec):
-    """``serial_grow=fused`` is fenced off on a TPU (models/gbdt.py) with
-    the compiler's own words; they must stay the compiler's."""
-    from lightgbm_tpu.ops import pallas_histogram as ph
-    from lightgbm_tpu.ops.split import SplitParams
-    n = 8192
-    with pytest.raises(NotImplementedError) as exc:
-        ph.fused_children_split_candidates_pallas.lower(
-            spec((F, n), jnp.uint8), spec((n,), jnp.float32),
-            spec((n,), jnp.float32), spec((n,), jnp.float32),
-            spec((n,), jnp.int32), 1, 3, spec((2, 3), jnp.float32),
-            spec((F,), jnp.int32), spec((F,), jnp.bool_),
-            spec((F,), jnp.bool_), max_bin=B,
-            params=SplitParams(min_data_in_leaf=50)).compile()
-    assert ph.FUSED_GAIN_TPU_REFUSAL in str(exc.value)
-
-
-def test_serial_grow_fused_fails_at_construction_on_tpu(monkeypatch):
-    """... and a booster asking for it on a TPU is refused when it is
-    built, by name, never switched to another grower."""
-    import lightgbm_tpu as lgb
-    from lightgbm_tpu.ops.pallas_histogram import FUSED_GAIN_TPU_REFUSAL
-    from lightgbm_tpu.utils import device
-    rng = np.random.RandomState(0)
-    X = rng.normal(size=(400, 4))
-    y = (X[:, 0] > 0).astype(np.float64)
-    params = {"objective": "binary", "num_leaves": 4, "verbose": -1,
-              "serial_grow": "fused"}
-    monkeypatch.setattr(device, "on_tpu", lambda: True)
-    with pytest.raises(device.KernelRefusedOnTPU) as exc:
-        lgb.Booster(params=params, train_set=lgb.Dataset(X, label=y))
-    assert FUSED_GAIN_TPU_REFUSAL in str(exc.value)
