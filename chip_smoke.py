@@ -267,6 +267,23 @@ def digit_parity(n: int = 100_000, f: int = 28, b: int = 255) -> None:
           "parity: digit_histogram_pallas != digit_histogram_scatter")
     say(f"parity: digit_histogram_pallas == scatter reference, exactly "
         f"({n} x {f}, {b} bins)")
+    # the same rows as the leaf-ordered layout's word lanes, a segment
+    # inside a window of whole kernel steps
+    import jax
+    from lightgbm_tpu.ops import ordered_grow as og
+    rows = n // lh.STEP_ROWS * lh.STEP_ROWS
+    first, scnt = 1234, rows // 2
+    lanes = lh.digit_histogram_lanes(
+        tuple(x[:rows] for x in og.pack_u8_words(bins)),
+        tuple(x[:rows] for x in og.pack_u8_words(
+            jax.lax.bitcast_convert_type(digits, jnp.uint8))),
+        jnp.int32(first), jnp.int32(scnt), f, b)
+    want = lh.digit_histogram_scatter(bins[first:first + scnt],
+                                      digits[first:first + scnt], b)
+    check(np.array_equal(np.asarray(lanes), np.asarray(want)),
+          "parity: digit_histogram_lanes != digit_histogram_scatter")
+    say(f"parity: digit_histogram_lanes == scatter reference, exactly "
+        f"({scnt} of {rows} x {f}, {b} bins)")
 
 
 def ordered_tree_parity(chip, n: int = 60_000, f: int = 10, b: int = 64):

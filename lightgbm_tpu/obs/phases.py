@@ -106,7 +106,9 @@ ROUND_PHASES = (
                           # (ops/partition.py), else the stable sort; the
                           # phase keeps its name
     "split/window_write",  # partitioned window written back in place
-    "hist/window",        # slices and unpacking that feed the kernel
+    "hist/window",        # the slices that cut a histogram's window out
+                          # of the word lanes (the root's too); off the
+                          # TPU also the unpacking for the scatter
     "hist/kernel",        # digit_histogram (Pallas) or its scatter twin
     "exchange/hist",      # data-parallel shards only: the one all-reduce
                           # of a split step, a shard's left child's digit

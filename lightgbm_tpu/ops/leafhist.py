@@ -21,21 +21,43 @@ TPU-shaped design, three pieces:
    is of the same order as f32 input rounding.  Digit sums stay exact while
    128 * rows_per_shard < 2^31, i.e. ~16M rows per device shard.
 
-2. **MXU one-hot kernel** (`_digit_hist_kernel`): for each row block, the
-   bin one-hot matrix is generated in VMEM (never HBM) per feature and
-   contracted against the digit block on the MXU.  Bins stream from HBM in
-   ROW-major uint8 (the cheap broadcast direction for the one-hot compare —
-   feature-major layout forces a lane->sublane relayout that dominates
-   runtime).  Measured ~10.5 ms for a full 1M x 28 x 256 pass on v5e.
+2. **MXU one-hot kernels**, one for each layout the growers hold rows
+   in; which one runs follows from the grower (models/gbdt.py
+   ``_choose_grower``), never from an option.
 
-3. **Compaction + size-class dispatch** (`compact_rows`, `leaf_histogram`):
-   the smaller child's row indices are compacted with one stable
-   key/payload sort (selected rows first — see compact_rows for why sort
-   beats scatter on TPU), its rows gathered, and the kernel run at a
-   power-of-two padded size chosen by `lax.switch` over static size
-   classes — fixed shapes for XLA, work proportional to the leaf.
+   `_lanes_hist_kernel` (`digit_histogram_lanes`; the leaf-ordered
+   grower, ops/ordered_grow.py): the window's WORD lanes as they lie,
+   four bin codes or four digits an int32, each lane ``[P]`` read as
+   ``[P / 128, 128]`` (a bitcast).  Rows run along lanes and bins along
+   sublanes: the one-hot of the four features of a word comes from one
+   XOR of the word, broadcast along sublanes, with the bin index in every
+   byte, an exact zero-byte test, and the int32 block read as int8
+   ``[4 B, T]``; it is contracted with the nine digit planes over the
+   rows (``A x B^T`` in int8, int32 sums), so nothing is transposed and
+   nothing row-major is built in HBM.  The segment's rows are masked in
+   the kernel from two prefetched scalars.  2.6 ns a row slot at 28
+   features and 255 bins on a v5e (PERF.md, PR 33), in a loop whose code
+   does not grow with the window.
 
-The scatter-add fallback (`hist_of_gathered_scatter`) keeps every piece
+   `_digit_hist_kernel` (`digit_histogram_pallas`; the gathered rows of
+   the cached grower below, row-major by construction): bins stream from
+   HBM in ROW-major uint8, the one-hot is a compare against a lane iota
+   per feature, 7.5 ns a row.  Until PR 33 the leaf-ordered grower fed it
+   too, through an XLA relayout of its lanes at every split (4.3 ns a
+   row slot more).  An earlier note here said that a feature-major
+   layout "forces a lane->sublane relayout that dominates runtime": that
+   was measured on a kernel whose BINS lie along lanes; with the bins
+   along sublanes the broadcast is the cheap one and no relayout is left.
+
+3. **Compaction + size-class dispatch** (`compact_rows`, `leaf_histogram`;
+   the `cached` grower of ops/grow.py only): the smaller child's row
+   indices are compacted with one stable key/payload sort (selected rows
+   first — see compact_rows for why sort beats scatter on TPU), its rows
+   gathered, and the row-major kernel run at a power-of-two padded size
+   chosen by `lax.switch` over static size classes — fixed shapes for
+   XLA, work proportional to the leaf.
+
+The scatter-add fallback (`digit_histogram_scatter`) keeps every piece
 runnable (and testable) on CPU with identical integer semantics.
 """
 
@@ -185,6 +207,123 @@ def digit_histogram_pallas(bins_rm, digits, max_bin: int, n_blk: int = 8192,
             name="digit_histogram",
         )(bins_rm, digits)
         return out[:, :, :max_bin]
+
+
+# ---------------------------------------------------------------------------
+# Pallas kernel: the same sums over the leaf-ordered layout's word lanes
+# ---------------------------------------------------------------------------
+
+LANE = 128              # rows of a sub-block: one row of a [P / 128, 128] lane
+SUB_BLOCKS = 8          # sub-blocks side by side in one contraction
+STEP_ROWS = 8192        # rows of a grid step
+PLANES = 16             # the 9 digit planes in whole int32 sublane tiles
+
+
+def _lanes_hist_kernel(seg_ref, *refs, w, bb, nsub):
+    """Grid (row blocks,): out[word] += planes x onehot(word)^T, a group
+    of ``SUB_BLOCKS`` sub-blocks of 128 rows at a time.
+
+    seg_ref   SMEM [2]            first row of the segment, its row count
+    w bin lane refs [nsub, 128]   four bin codes a word, as the lane lies
+    3 digit lane refs [nsub, 128] the nine int8 digits in three words
+    out_ref   [w, 16, 4 * bb]     int32, resident across the grid: column
+                                  ``4 * bin + k`` of word lane ``i`` is bin
+                                  ``bin`` of feature ``4 * i + k``
+    """
+    bin_refs, dig_refs, out_ref = refs[:w], refs[w:w + 3], refs[w + 3]
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    first = seg_ref[0]
+    stop = first + seg_ref[1]
+    k = SUB_BLOCKS * LANE
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+    plane = jax.lax.broadcasted_iota(jnp.int32, (PLANES, k), 0)
+    # the bin index in every byte of a word, bins along sublanes
+    bins4 = jax.lax.broadcasted_iota(jnp.int32, (bb, k), 0) * 0x01010101
+
+    def rows_of(ref, j0):
+        # rows along lanes: row r of the step is element (r // 128,
+        # r % 128) of a lane's block, so a sub-block is one row of it
+        return jnp.concatenate(
+            [ref[pl.ds(j0 + u, 1), :] for u in range(SUB_BLOCKS)], axis=1)
+
+    def group(g, carry):
+        j0 = g * SUB_BLOCKS
+        row = (i * nsub + j0) * LANE + lane
+        inside = (row >= first) & (row < stop)
+        d = [jnp.where(inside, rows_of(ref, j0), 0) for ref in dig_refs]
+        # digit k is byte k % 4 of word k // 4; the truncation to int8
+        # gives the signed digit back.  Planes 12 to 15 repeat word 2 and
+        # planes 9 to 11 are its empty bytes: their sums are never read
+        word = jnp.where(plane < 4, d[0], jnp.where(plane < 8, d[1], d[2]))
+        planes = (word >> ((plane & 3) * 8)).astype(jnp.int8)   # [16, k]
+        for wi, ref in enumerate(bin_refs):
+            # four features' one-hots at once: a byte of ``t`` is zero
+            # where that feature's bin is the sublane's, and the exact
+            # zero-byte test leaves 0x01 there and 0x00 elsewhere (no
+            # carry crosses a byte: 0x7F + 0x7F < 0x100)
+            t = bins4 ^ rows_of(ref, j0)                        # [bb, k]
+            y = (t & 0x7F7F7F7F) + 0x7F7F7F7F
+            y = ~(y | t | 0x7F7F7F7F)
+            onehot = pltpu.bitcast(jax.lax.shift_right_logical(y, 7),
+                                   jnp.int8)                    # [4 bb, k]
+            # row 4 * bin + byte of the one-hot is byte ``byte`` of the
+            # word in row ``bin``; contracted over the lanes of both
+            # operands (A x B^T), so nothing is transposed
+            out_ref[wi] += jax.lax.dot_general(
+                planes, onehot, dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.int32)               # [16, 4 bb]
+        return carry
+    # a loop, not an unrolled step: the code of an instance does not grow
+    # with its rows (the grow program holds two dozen instances)
+    jax.lax.fori_loop(0, nsub // SUB_BLOCKS, group, 0)
+
+
+def digit_histogram_lanes(bin_lanes, dig_lanes, first, scnt, num_features: int,
+                          max_bin: int, step_rows: int = STEP_ROWS,
+                          interpret: bool = False):
+    """[F, 9, B] int32 digit sums over rows ``[first, first + scnt)`` of
+    a window of the leaf-ordered layout, read as its word lanes lie.
+
+    bin_lanes: ceil(F / 4) [P] int32 lanes, four bin codes a word
+    (ops/ordered_grow.py ``pack_u8_words``); dig_lanes: the 3 [P] int32
+    lanes of the nine int8 digits.  P a multiple of ``step_rows``, or a
+    smaller multiple of 1,024.  The kernel visits all P rows and zeroes the
+    digits of every row outside the segment itself, so its time goes
+    with P and its sums with the segment."""
+    w = len(bin_lanes)
+    rows = bin_lanes[0].shape[0]
+    nsub = min(step_rows, rows) // LANE
+    assert w == -(-num_features // 4) and len(dig_lanes) == 3 \
+        and rows % (nsub * LANE) == 0 and nsub % SUB_BLOCKS == 0, \
+        (w, num_features, rows, nsub)
+    B = -(-max_bin // 128) * 128
+    seg = jnp.stack([first, scnt]).astype(jnp.int32)
+    with jax.named_scope("hist/kernel"):
+        out = pl.pallas_call(
+            functools.partial(_lanes_hist_kernel, w=w, bb=B, nsub=nsub),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(rows // (nsub * LANE),),
+                in_specs=[pl.BlockSpec((nsub, LANE),
+                                       lambda i, seg: (i, 0))] * (w + 3),
+                out_specs=pl.BlockSpec((w, PLANES, 4 * B),
+                                       lambda i, seg: (0, 0, 0))),
+            out_shape=jax.ShapeDtypeStruct((w, PLANES, 4 * B), jnp.int32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=interpret,
+            # the benchmark's hist_ms_per_round and hist_roofline find
+            # both histogram kernels by this name and by nothing else
+            name="digit_histogram",
+        )(seg, *(lane.reshape(rows // LANE, LANE)
+                 for lane in tuple(bin_lanes) + tuple(dig_lanes)))
+        out = out.reshape(w, PLANES, B, 4).transpose(0, 3, 1, 2) \
+            .reshape(4 * w, PLANES, B)
+        return out[:num_features, :NUM_STREAMS, :max_bin]
 
 
 def digit_histogram_scatter(bins_rm, digits, max_bin: int):

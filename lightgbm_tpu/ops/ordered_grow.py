@@ -201,10 +201,10 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
                       bins_rm=None, bins_words=None, exchange=None):
     """Drop-in replacement for ops.grow.grow_tree.
 
-    Args/returns: see grow_tree.  ``bins_rm`` ([N, F] row-major) feeds the
-    root histogram; ``bins_words`` (tuple of ceil(F/4) [N] i32 arrays from
-    pack_u8_words, shared across trees) seeds the physical layout —
-    derived from bins_rm when omitted.
+    Args/returns: see grow_tree.  ``bins_words`` (tuple of ceil(F/4) [N]
+    i32 arrays from pack_u8_words, shared across trees) seeds the physical
+    layout, which every histogram reads, the root's too; it is derived
+    from ``bins_rm`` ([N, F] row-major) or ``bins`` when omitted.
 
     N here may be the row-BUCKET shape (models/gbdt.py pads every row
     array up the shared ladder): pad rows carry bin 0, zero digits and
@@ -240,10 +240,9 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
     # are metadata only, and obs/compile_ledger.py joins them to the
     # chip's device events through the compiled text.
     with jax.named_scope("layout"):
-        if bins_rm is None:
-            bins_rm = bins.T
         if bins_words is None:
-            bins_words = pack_u8_words(bins_rm)
+            bins_words = pack_u8_words(bins.T if bins_rm is None
+                                       else bins_rm)
 
     with jax.named_scope("gradients"):
         g = grad * row_weight
@@ -313,26 +312,29 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
         digit streams masked to zero.  The window starts at ``off``, or
         ends with the arrays where ``off + Psz`` would overrun them (a
         partitioned split window; the full lanes carry PAD spare rows).  The
-        ONE histogram formulation every call site shares (per-split
-        child windows and the compacted root)."""
+        ONE histogram formulation every call site shares (the root, the
+        compacted root and the per-split child windows).  On a TPU the
+        kernel takes the window's word lanes as they lie and masks the
+        rows itself; nothing row-major is built for it (PERF.md, PR 33)."""
         with jax.named_scope("hist/window"):
             start = jnp.minimum(off, bw_tuple[0].shape[0] - Psz)
             first = off - start
-            ch_bins = _unpack_words(
-                tuple(jax.lax.dynamic_slice(bw, (start,), (Psz,))
-                      for bw in bw_tuple), F)
+            win_b = tuple(jax.lax.dynamic_slice(bw, (start,), (Psz,))
+                          for bw in bw_tuple)
+            win_d = tuple(jax.lax.dynamic_slice(dw, (start,), (Psz,))
+                          for dw in dw_tuple)
+        # the kernels scope themselves (hist/kernel, ops/leafhist.py)
+        if device.on_tpu():
+            return leafhist.digit_histogram_lanes(win_b, win_d, first, scnt,
+                                                  F, B)
+        # off the TPU the rows are unpacked for the scatter: the oracle
+        with jax.named_scope("hist/window"):
+            ch_bins = _unpack_words(win_b, F)
             ch_dig = jax.lax.bitcast_convert_type(
-                jax.lax.bitcast_convert_type(
-                    jnp.stack(
-                        tuple(jax.lax.dynamic_slice(dw, (start,), (Psz,))
-                              for dw in dw_tuple), axis=1),
-                    jnp.uint8).reshape(Psz, -1)[:, :9], jnp.int8)
+                _unpack_words(win_d, leafhist.NUM_STREAMS), jnp.int8)
             row = jnp.arange(Psz, dtype=jnp.int32)[:, None]
             ch_dig = jnp.where((row >= first) & (row < first + scnt),
                                ch_dig, 0)
-        # the kernel scopes itself (hist/kernel, ops/leafhist.py)
-        if device.on_tpu():
-            return leafhist.digit_histogram_pallas(ch_bins, ch_dig, B)
         return leafhist.digit_histogram_scatter(ch_bins, ch_dig, B)
 
     def windowed_hist(off, scnt):
@@ -351,8 +353,11 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
             # either way)
             sums_root = windowed_hist(jnp.int32(0), root_cnt)
         else:
-            # root histogram over the initial (original-order) layout
-            sums_root = leafhist.digit_histogram(bins_rm, digits, B)
+            # root histogram over the initial (original-order) layout:
+            # all N rows as one window of whole kernel steps
+            step = leafhist.STEP_ROWS
+            sums_root = hist_window(bins_w, dig_w, jnp.int32(0), root_cnt,
+                                    -(-N // step) * step)
     if exchange is not None:
         sums_root_local = sums_root
         sums_root = exchange.hist(sums_root, root=True)

@@ -112,3 +112,65 @@ def test_size_classes():
                                           131072, 262144, 524288)
     assert lh.size_classes(10000, min_size=1024) == (1024, 2048, 4096, 8192)
     assert lh.size_classes(100, min_size=8192) == (64,)
+
+
+# --- the kernel over the leaf-ordered layout's word lanes ------------------
+# rows of the lanes, window, features, max_bin, off, scnt, rows a grid step,
+# what fills the rows
+LANES_CASES = {
+    "segment_at_the_windows_start":
+        (8192, 4096, 6, 255, 0, 3001, 4096, "random"),
+    "window_ends_with_the_arrays":
+        (8192, 4096, 6, 255, 6000, 2192, 4096, "random"),
+    "segment_inside_the_window":
+        (8192, 4096, 6, 255, 5000, 700, 4096, "random"),
+    "scnt_zero": (8192, 4096, 6, 255, 1234, 0, 4096, "random"),
+    "scnt_is_the_window": (8192, 4096, 6, 255, 4096, 4096, 4096, "random"),
+    "bins_of_0_and_255": (4096, 4096, 6, 256, 0, 4096, 4096, "bin_ends"),
+    "digits_of_minus_128_and_127":
+        (4096, 4096, 6, 255, 3, 4000, 4096, "digit_ends"),
+    "max_bin_63": (4096, 4096, 6, 63, 100, 3000, 4096, "random"),
+    "twenty_eight_features": (4096, 4096, 28, 255, 100, 3000, 4096, "random"),
+    "hundred_thirty_six_features":
+        (1024, 1024, 136, 255, 10, 1000, 1024, "random"),
+    "several_grid_steps": (16384, 8192, 6, 255, 9000, 5000, 2048, "random"),
+    "one_small_grid_step": (2048, 1024, 6, 255, 1000, 600, 1024, "random"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LANES_CASES))
+def test_lanes_kernel_is_the_scatter_of_the_unpacked_rows(case):
+    """``digit_histogram_lanes`` (interpreted) over a window of the word
+    lanes, cut as ``ordered_grow.hist_window`` cuts it, equals
+    ``digit_histogram_scatter`` of the unpacked rows with the rows outside
+    the segment masked, exactly."""
+    from lightgbm_tpu.ops import ordered_grow as og
+    total, psz, f, max_bin, off, scnt, step, fill = LANES_CASES[case]
+    rng = np.random.RandomState(len(case))
+    bins = rng.randint(0, max_bin, size=(total, f)).astype(np.uint8)
+    digits = rng.randint(-128, 128, size=(total, 9)).astype(np.int8)
+    if fill == "bin_ends":
+        bins = np.where(rng.rand(total, f) < 0.5, 0, 255).astype(np.uint8)
+    if fill == "digit_ends":
+        # bytes of 128 and over come back sign-extended
+        digits = np.where(rng.rand(total, 9) < 0.5, -128, 127) \
+            .astype(np.int8)
+    bw = og.pack_u8_words(jnp.asarray(bins))
+    dw = og.pack_u8_words(jax.lax.bitcast_convert_type(
+        jnp.asarray(digits), jnp.uint8))
+    assert len(bw) == -(-f // 4) and len(dw) == 3
+    start = min(off, total - psz)
+    first = off - start
+    got = lh.digit_histogram_lanes(
+        tuple(x[start:start + psz] for x in bw),
+        tuple(x[start:start + psz] for x in dw),
+        jnp.int32(first), jnp.int32(scnt), f, max_bin, step_rows=step,
+        interpret=True)
+    inside = (np.arange(total) >= off) & (np.arange(total) < off + scnt)
+    want = lh.digit_histogram_scatter(
+        jnp.asarray(bins), jnp.asarray(np.where(inside[:, None], digits, 0)),
+        max_bin)
+    assert got.shape == (f, 9, max_bin) and got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    if scnt:
+        assert np.asarray(got).any()

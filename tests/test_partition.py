@@ -115,3 +115,51 @@ def test_grower_with_the_kernel_returns_the_sort_paths_tree(monkeypatch):
     for g, w in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("compact_inactive", [False, True])
+def test_grower_with_the_lanes_histogram_returns_the_scatter_paths_tree(
+        monkeypatch, compact_inactive):
+    """``grow_tree_ordered`` with the histogram kernel that reads the word
+    lanes (forced on, interpreted: the root, the compacted root under
+    bagging and every child window) returns the ``TreeArrays``,
+    ``leaf_id`` and ``output_delta`` of the scatter path, equal exactly."""
+    from lightgbm_tpu.ops import leafhist, ordered_grow
+    from lightgbm_tpu.ops.grow import GrowParams
+    from lightgbm_tpu.utils import device
+    rng = np.random.RandomState(5)
+    n, f = 5000, 6
+    bins = jnp.asarray(rng.randint(0, 64, size=(f, n)).astype(np.uint8))
+    y = (np.asarray(bins[1]) > 20) ^ (np.asarray(bins[4]) > 40)
+    grad = jnp.asarray((0.5 - y + 0.1 * rng.normal(size=n))
+                       .astype(np.float32))
+    hess = jnp.full(n, 0.25, jnp.float32)
+    weight = jnp.asarray((rng.rand(n) < 0.7).astype(np.float32)) \
+        if compact_inactive else jnp.ones(n, jnp.float32)
+    args = (bins, jnp.full(f, 64, jnp.int32), jnp.zeros(f, bool),
+            jnp.ones(f, bool), grad, hess, weight, jnp.float32(0.1))
+    params = GrowParams(num_leaves=9, max_bin=64, min_data_in_leaf=20,
+                        compact_inactive=compact_inactive)
+    grow = ordered_grow.grow_tree_ordered
+    want = grow(*args, params=params)
+    calls = []
+
+    def kernel(bin_lanes, dig_lanes, *a, **kw):
+        calls.append(bin_lanes[0].shape[0])
+        return lanes_kernel(bin_lanes, dig_lanes, *a, interpret=True, **kw)
+    lanes_kernel = leafhist.digit_histogram_lanes
+    monkeypatch.setattr(leafhist, "digit_histogram_lanes", kernel)
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    # the partition stays the sort: this test is the histogram's
+    monkeypatch.setattr(partition, "stable_partition",
+                        partition.sort_partition)
+    jax.clear_caches()                # or the scatter path's program answers
+    got = grow(*args, params=params)
+    jax.clear_caches()
+    # the root (one window of whole kernel steps, or its size class under
+    # bagging) and the one size class's child window (P/2 is P/8 here)
+    assert calls == [8192, 4096], calls
+    assert int(want[0].num_leaves) == 9
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

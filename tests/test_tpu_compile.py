@@ -81,6 +81,23 @@ def test_digit_histogram_kernel_compiles(spec):
         name="digit_histogram")
 
 
+@pytest.mark.parametrize("rows", [4096, 1 << 20])
+def test_lanes_histogram_kernel_compiles(spec, rows):
+    """The ordered grower's histogram kernel at the cells' width (7 bin
+    word lanes and 3 digit word lanes at 28 features), at the smallest
+    child window and at one of a million rows: the lanes read as they
+    lie, the segment's two scalars prefetched, four features' one-hots
+    from one word (the int32 block read as int8), the int8 contraction
+    over the lanes of both operands, under the row-major kernel's name."""
+    from lightgbm_tpu.ops import leafhist
+    w = -(-F // 4)
+    _assert_kernel_compiles(
+        lambda first, scnt, *ls: leafhist.digit_histogram_lanes(
+            ls[:w], ls[w:], first, scnt, F, B),
+        spec((), jnp.int32), spec((), jnp.int32),
+        *[spec((rows,), jnp.int32)] * (w + 3), name="digit_histogram")
+
+
 @pytest.mark.parametrize("rows", [8192, 1 << 20])
 def test_segment_partition_kernel_compiles(spec, rows):
     """The grower's window partition at the cells' width (7 bin words, 3
@@ -230,6 +247,28 @@ def test_ordered_grower_text_carries_the_phase_paths(spec, monkeypatch):
     assert pm["inserted"], "the chip's compiler inserts copies here"
 
 
+def test_ordered_grower_builds_nothing_row_major_for_the_histogram(
+        spec, monkeypatch):
+    """The histogram kernel takes the window's word lanes as they lie:
+    what is left under ``hist/window`` is the slices that cut the window,
+    all ``s32[rows]``.  Until PR 33 the lanes were stacked on a new minor
+    axis, bitcast to bytes and masked there, ``u8[rows, F]`` and
+    ``s8[rows, 9]`` written out at every split for the row-major kernel
+    (202 ms of a 970 ms round at 10.5M rows: PERF.md, PR 33)."""
+    from lightgbm_tpu.obs import devtrace
+    text = _ordered_grower_text(spec, monkeypatch, 8192)
+    pm = devtrace.phase_map(text)
+    bytes_2d = re.compile(r"%([\w.\-]+) = \(?[us]8\[\d+,\d+\]")
+    names = [m.group(1) for m in map(bytes_2d.search, text.splitlines())
+             if m]
+    assert [n for n in names if pm["phases"].get(n) == "layout"], \
+        "the digits are quantised row-major, once a tree"
+    assert [n for n in names
+            if pm["phases"].get(n, "").startswith("hist/")] == []
+    feed = [k for k, ph in pm["phases"].items() if ph == "hist/window"]
+    assert feed, "the window's slices"
+
+
 def _reached(instrs, comp):
     """The computations reached from ``comp`` through its instructions'
     called computations, itself included."""
@@ -290,9 +329,11 @@ def test_ordered_grower_copies_no_whole_lane_in_the_grow_loop(spec,
 # grow_tree_ordered at 32,768 x 4, 7 leaves, for the described v5e: 3,282
 # for the program of PR 29 and, the exchange hook in place, of PR 30;
 # PR 31 put the partition kernel, its mask and the slices of its output
-# in the segment sort's place in each of three size classes.  Whoever
+# in the segment sort's place in each of three size classes (3,340);
+# PR 33 took the row-major feed of the histogram kernel out of every
+# child window and moved the root pass onto the word lanes.  Whoever
 # changes the serial grower knowingly changes this number with it.
-SERIAL_INSTRUCTIONS = 3340
+SERIAL_INSTRUCTIONS = 3096
 COLLECTIVES = ("all-reduce", "all-reduce-start", "reduce-scatter",
                "all-gather", "all-gather-start", "all-to-all",
                "collective-permute", "collective-permute-start")
