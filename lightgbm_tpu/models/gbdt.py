@@ -811,6 +811,8 @@ class GBDT:
         arrays.  For a row-sharded learner they stay on the host's own
         backend (where there is one): ``_make_grad_arrays`` then hands
         each device its block, and no chip holds all labels."""
+        if self._mesh is not None:
+            self.objective.over_devices()
         with jax.default_device(self._host_device()):
             self.objective.init(train_set.metadata, train_set.num_data)
 

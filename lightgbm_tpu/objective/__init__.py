@@ -18,8 +18,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..utils import log
+from .. import obs
+from ..utils import device, log
 from ..io.dataset import Metadata
+from ..ops import rank_lambda
 
 
 def _pad_rows(arr, num_rows: Optional[int]):
@@ -68,6 +70,12 @@ class ObjectiveFunction:
         self.weights = (None if metadata.weights is None
                         else jnp.asarray(metadata.weights, jnp.float32))
 
+    def over_devices(self) -> None:
+        """Told before ``init`` that the round is ONE program over several
+        devices (a parallel learner's mesh): an objective with a form the
+        partitioner cannot split (a Pallas kernel) falls back to the one
+        it can."""
+
     # -- functional gradient interface ---------------------------------
     def gradient_arrays(self, num_rows: Optional[int] = None) -> dict:
         """Pytree of the per-dataset arrays ``gradients_with`` consumes,
@@ -106,7 +114,8 @@ class ObjectiveFunction:
     # by program_holder so the process-wide jit registry retains only
     # scalars, not a dead dataset's device memory
     _ARRAY_ATTRS = ("label", "weights", "label_int", "label_pos_weights",
-                    "query_classes", "discounts", "label_gain_j")
+                    "query_classes", "query_slabs", "discounts",
+                    "label_gain_j")
 
     def program_holder(self) -> "ObjectiveFunction":
         """The object the shared-program registry may retain for process
@@ -367,15 +376,22 @@ class LambdarankNDCG(ObjectiveFunction):
     """Per-query pairwise LambdaRank with NDCG weighting
     (rank_objective.hpp:19-228).
 
-    TPU formulation: queries are bucketed by power-of-two size class (a
-    query of 100 docs pads to 128, not to the global max — on MSLR-scale
-    data where the longest query is ~10x the mean, per-class padding keeps
-    pairwise work within ~4x of optimal instead of ~100x).  Within a class
-    the pairwise lambda matrix [P, P] is computed per query with masking,
-    queries processed in blocks via lax.map; one scatter-add per class
-    accumulates into the row-order gradient.  The reference's 1M-entry
-    sigmoid lookup table (rank_objective.hpp:177-190) is replaced by the
-    exact sigmoid 2/(1+exp(2*sigma*d)) it approximates.
+    Two forms of the same arithmetic (all label-differing pairs,
+    ``max_position`` only in the inverse max DCG, float32; the
+    reference's 1M-entry sigmoid lookup table, rank_objective.hpp:177-190,
+    is replaced by the exact sigmoid 2/(1+exp(2*sigma*d)) it
+    approximates), chosen by the backend and never by an option:
+
+    - on a TPU the slab frame of ops/rank_lambda.py: a query's scores
+      are read as whole rows of 128 documents, a query's pair work is
+      the ``rank_lambda`` kernel's, and nothing is gathered, sorted or
+      scattered a document at a time;
+    - elsewhere, and under a parallel learner's mesh, the ``jax.numpy``
+      form (the tests' oracle): queries bucketed by power-of-two size
+      class (a query of 100 docs pads to 128, not to the global max),
+      the pairwise lambda matrix [P, P] computed per query with masking,
+      queries processed in blocks via lax.map, one scatter-add per class
+      into the row-order gradient.
     """
     name = "lambdarank"
 
@@ -386,62 +402,89 @@ class LambdarankNDCG(ObjectiveFunction):
         gains = list(config.label_gain) or default_label_gain()
         self.label_gain = np.asarray(gains, np.float64)
         self.optimize_pos_at = int(config.max_position)
+        # the pair work's form follows the backend, as the grower's two
+        # kernels do (ops/partition.py, ops/leafhist.py): never an option
+        self.use_kernel = device.on_tpu()
+
+    def over_devices(self):
+        # a Mosaic kernel cannot be partitioned automatically; the
+        # ``jax.numpy`` form's gathers of a row-sharded score can
+        self.use_kernel = False
 
     def init(self, metadata, num_data):
         super().init(metadata, num_data)
         if metadata.query_boundaries is None:
             log.fatal("Lambdarank tasks require query information")
+        with obs.span("Rank::bucket"):
+            self._bucket(metadata)
+
+    def _bucket(self, metadata):
+        """The host's bucketing, by array operations a size class: the
+        inverse maximum DCG of every query, then the tables of the form
+        this backend runs (the slab frame of ops/rank_lambda.py on a TPU,
+        the padded classes of the ``jax.numpy`` form elsewhere).  The
+        counters say what was bucketed."""
         qb = np.asarray(metadata.query_boundaries, np.int64)
         self.num_queries = len(qb) - 1
         sizes = np.diff(qb)
-        M = int(sizes.max())
-        label = np.asarray(metadata.label)
-        # discounts must cover the LARGEST padded class, not just M
-        disc_len = 16
-        while disc_len < M:
-            disc_len *= 2
-        discounts = 1.0 / np.log2(np.arange(disc_len) + 2.0)
+        label = np.asarray(metadata.label).astype(np.int64)
+        # the jax.numpy form's classes: the power of two at or over a
+        # query's size, 16 at least; discounts cover the LARGEST class
+        pad_of = np.maximum(
+            16, 2 ** np.ceil(np.log2(np.maximum(sizes, 1))).astype(np.int64))
+        discounts = 1.0 / np.log2(np.arange(int(pad_of.max())) + 2.0)
         self.discounts = jnp.asarray(discounts, jnp.float32)
         self.label_gain_j = jnp.asarray(self.label_gain, jnp.float32)
-
-        # bucket queries by pow-2 padded size
-        def pad_class(n):
-            p = 16
-            while p < n:
-                p *= 2
-            return p
-
-        buckets = {}
-        for q in range(self.num_queries):
-            buckets.setdefault(pad_class(int(sizes[q])), []).append(q)
-
-        self.query_classes = []
-        for P, qlist in sorted(buckets.items()):
-            Qc = len(qlist)
-            doc_idx = np.zeros((Qc, P), np.int32)
-            doc_valid = np.zeros((Qc, P), bool)
-            inv_max_dcg = np.zeros(Qc, np.float64)
-            for i, q in enumerate(qlist):
-                cnt = int(sizes[q])
-                doc_idx[i, :cnt] = np.arange(qb[q], qb[q + 1])
-                doc_valid[i, :cnt] = True
-                # inverse max DCG per query (rank_objective.hpp:54-64)
-                lbl = np.sort(label[qb[q]:qb[q + 1]])[::-1]
-                k = min(self.optimize_pos_at, cnt)
-                dcg = (self.label_gain[lbl[:k].astype(int)]
-                       * discounts[:k]).sum()
-                inv_max_dcg[i] = 1.0 / dcg if dcg > 0 else 0.0
-            padded_label = np.where(doc_valid, label[doc_idx], 0)
-            self.query_classes.append({
-                "P": P,
-                "doc_idx": jnp.asarray(doc_idx),
-                "doc_valid": jnp.asarray(doc_valid),
-                "label": jnp.asarray(padded_label.astype(np.int32)),
-                "inv_max_dcg": jnp.asarray(inv_max_dcg, jnp.float32),
-            })
+        inv_max_dcg = np.zeros(self.num_queries, np.float64)
+        padded = {}
+        for P in np.unique(pad_of):
+            q = np.flatnonzero(pad_of == P)
+            doc_idx = qb[q, None] + np.arange(P)
+            doc_valid = np.arange(P) < sizes[q, None]
+            doc_idx = np.where(doc_valid, doc_idx, 0)
+            lab = np.where(doc_valid, label[doc_idx], -1)
+            # inverse max DCG per query (rank_objective.hpp:54-64): the
+            # labels in falling order, the first max_position of them
+            k = min(self.optimize_pos_at, int(P))
+            top = -np.sort(-lab, axis=1)[:, :k]
+            dcg = (np.where(top >= 0, self.label_gain[np.maximum(top, 0)],
+                            0.0) * discounts[:k]).sum(axis=1)
+            inv_max_dcg[q] = np.where(dcg > 0, 1.0 / np.where(dcg > 0, dcg,
+                                                               1.0), 0.0)
+            padded[int(P)] = (q, doc_idx, doc_valid, lab)
+        n_labels = len(self.label_gain)
+        per_label = np.bincount(
+            np.repeat(np.arange(self.num_queries), sizes) * n_labels + label,
+            minlength=self.num_queries * n_labels).reshape(-1, n_labels)
+        pairs_real = int(((sizes ** 2 - (per_label ** 2).sum(axis=1))
+                          // 2).sum())
+        self.query_classes, self.query_slabs = [], None
+        if self.use_kernel:
+            self.query_slabs, slots = rank_lambda.slab_tables(
+                qb, label, self.label_gain, inv_max_dcg)
+            n_classes = len(self.query_slabs)
+        else:
+            for P, (q, doc_idx, doc_valid, lab) in sorted(padded.items()):
+                self.query_classes.append({
+                    "P": P,
+                    "doc_idx": jnp.asarray(doc_idx.astype(np.int32)),
+                    "doc_valid": jnp.asarray(doc_valid),
+                    "label": jnp.asarray(np.maximum(lab, 0)
+                                         .astype(np.int32)),
+                    "inv_max_dcg": jnp.asarray(inv_max_dcg[q], jnp.float32),
+                })
+            slots = int(sum(len(v[0]) * P * P for P, v in padded.items()))
+            n_classes = len(self.query_classes)
+        obs.set_gauge("rank_queries", self.num_queries)
+        obs.set_gauge("rank_size_classes", n_classes)
+        obs.set_gauge("rank_pairs_real", pairs_real)
+        obs.set_gauge("rank_pair_slots", slots)
 
     def gradient_arrays(self, num_rows=None):
         arrays = super().gradient_arrays(num_rows)
+        if self.query_slabs is not None:
+            arrays["slabs"] = self.query_slabs
+            return arrays
         arrays["discounts"] = self.discounts
         arrays["label_gain_j"] = self.label_gain_j
         # per-size-class query tables WITHOUT the static pad size P —
@@ -453,14 +496,22 @@ class LambdarankNDCG(ObjectiveFunction):
         return arrays
 
     def program_key(self):
-        return (type(self).__name__, self.sigmoid, self.optimize_pos_at)
+        return (type(self).__name__, self.sigmoid, self.optimize_pos_at,
+                self.use_kernel)
 
     def gradients_with(self, arrays, score):
         s = jnp.asarray(score)[0]
-        g = jnp.zeros_like(s)
-        h = jnp.zeros_like(s)
-        for cls in arrays["classes"]:
-            g, h = self._class_gradients(arrays, s, cls, g, h)
+        if "slabs" in arrays:
+            # the TPU's form: one slice a query, the pair work in the
+            # rank_lambda kernel (ops/rank_lambda.py)
+            g, h = rank_lambda.slab_gradients(
+                arrays["slabs"], s, sigma=self.sigmoid,
+                interpret=not device.on_tpu())
+        else:
+            g = jnp.zeros_like(s)
+            h = jnp.zeros_like(s)
+            for cls in arrays["classes"]:
+                g, h = self._class_gradients(arrays, s, cls, g, h)
         weights = arrays["weights"]
         if weights is not None:
             g = g * weights

@@ -50,6 +50,8 @@ HOST_PHASES = frozenset({
     "Bin::fingerprint",   # drift fingerprint (obs/drift.py): missing
                           # rates and a second, exact binning of all rows
     "Dataset::to_device",  # binned matrix, labels, word packing up
+    "Rank::bucket",       # LambdarankNDCG.init: queries to size classes,
+                          # inverse max DCG, the slab tables (host)
     "GBDT::iteration",    # whole boosting round (obs.span, always on)
     "GBDT::boosting",
     "GBDT::bagging",
@@ -123,7 +125,16 @@ ROUND_PHASES = (
     "pack_tree",
 )
 
-DEVICE_PHASES = frozenset(ROUND_PHASES) | frozenset({
+# What objective=lambdarank adds to ``gradients`` on a TPU
+# (ops/rank_lambda.py); a round of another objective has neither.
+RANK_PHASES = (
+    "gradients/rank_slab",    # scores to query slabs (whole rows of 128
+                              # documents by row number) and the slabs of
+                              # gradients and hessians added back
+    "gradients/rank_lambda",  # the pair kernel
+)
+
+DEVICE_PHASES = frozenset(ROUND_PHASES) | frozenset(RANK_PHASES) | frozenset({
     # ops/grow.py (cached and parallel learners): the reference's three
     "hist",
     "split",
@@ -137,7 +148,7 @@ DEVICE_PHASES = frozenset(ROUND_PHASES) | frozenset({
 })
 
 DEVICE_PARENT = {
-    **{p: "GBDT::tree" for p in ROUND_PHASES},
+    **{p: "GBDT::tree" for p in ROUND_PHASES + RANK_PHASES},
     "hist": "GBDT::tree",
     "split": "GBDT::tree",
     "bin_lookup": "Predict::forest",

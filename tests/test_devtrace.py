@@ -373,6 +373,41 @@ def test_span_is_on_the_profilers_clock(tmp_path):
     assert handle.trace is None            # the causal tracer is not armed
 
 
+def test_lambdarank_round_sits_under_the_rank_phases(no_persistent_cache,
+                                                     monkeypatch):
+    """The round of ``objective=lambdarank`` in the TPU's form (the slab
+    frame and the ``rank_lambda`` kernel, interpreted here): every
+    operation under a declared phase, ``gradients/rank_slab`` and
+    ``gradients/rank_lambda`` among them, as PERF.md section 5 reads the
+    new cell's window."""
+    import jax.numpy as jnp
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.objective import LambdarankNDCG
+    init = LambdarankNDCG.__init__
+
+    def with_kernel(self, config):
+        init(self, config)
+        self.use_kernel = True
+    monkeypatch.setattr(LambdarankNDCG, "__init__", with_kernel)
+    rng = np.random.RandomState(0)
+    sizes = [1, 40, 130, 200, 29, 300]
+    X = rng.normal(size=(sum(sizes), 6))
+    y = rng.randint(0, 5, size=len(X)).astype(np.float64)
+    g = lgb.Booster(params={"objective": "lambdarank", "num_leaves": 7,
+                            "verbose": -1, "compile_cache_dir": "off"},
+                    train_set=lgb.Dataset(X, label=y, group=sizes))._booster
+    step = g._make_train_step()
+    text = step.lower(g.train_data.score, g._feature_masks_all(),
+                      g._bagging_mask(0), jnp.float32(0.1),
+                      g._select_view()).compile().as_text()
+    pm = devtrace.phase_map(text)
+    assert pm["ops_unscoped"] == 0, pm["unscoped_op_names"]
+    assert set(phases.RANK_PHASES) <= set(pm["ops_by_phase"])
+    assert phases.leaf_phase(
+        "jit(step_fn)/gradients/gradients/rank_lambda/pallas_call") \
+        == "gradients/rank_lambda"
+
+
 def test_trace_window_writes_phase_maps_and_device_phases(
         tmp_path, no_persistent_cache):
     """(e) a ``trace_dir`` window over a 6-round CPU train: the armed

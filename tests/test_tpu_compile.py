@@ -114,6 +114,45 @@ def test_segment_partition_kernel_compiles(spec, rows):
         name="segment_partition")
 
 
+@pytest.mark.parametrize("rows", [1, 2, 17])
+def test_rank_lambda_kernel_compiles(spec, rows):
+    """LambdaRank's pair kernel (ops/rank_lambda.py) at three of the
+    ranking cell's size classes: a slab of one row, of two (the class most
+    queries of MSLR-WEB30K's sizes fall in) and of 17 (its 1,251-document
+    queries); the event name is what ``rank_lambda_ms_per_round`` finds."""
+    from lightgbm_tpu.ops import rank_lambda
+    q = 8 * rank_lambda.QUERIES_PER_STEP
+    slab = spec((q, rows, 128), jnp.float32)
+    _assert_kernel_compiles(
+        lambda nr, inv, s, lab, gain: rank_lambda.rank_lambda(
+            nr, inv, s, lab, gain, sigma=1.0),
+        spec((q,), jnp.int32), spec((q,), jnp.float32), slab, slab, slab,
+        name="rank_lambda")
+
+
+def test_rank_slab_feed_moves_whole_rows_not_documents(spec):
+    """The feed around the kernel: scores reach the query slabs and the
+    sums come back by whole rows of 128 documents (``slice_sizes={1,128}``
+    gathers and row scatters), never an element a document, and nothing
+    is sorted."""
+    from lightgbm_tpu.ops import rank_lambda
+    rng = np.random.RandomState(0)
+    sizes = rng.randint(1, 700, size=400)
+    qb = np.concatenate([[0], np.cumsum(sizes)])
+    label = rng.randint(0, 5, size=qb[-1])
+    classes, slots = rank_lambda.slab_tables(
+        qb, label, 2.0 ** np.arange(31) - 1.0, np.ones(len(sizes)))
+    shapes = jax.tree.map(lambda a: spec(a.shape, a.dtype), classes)
+    text = jax.jit(lambda c, s: rank_lambda.slab_gradients(
+        c, s, sigma=1.0)).lower(
+            shapes, spec((int(qb[-1]),), jnp.float32)).compile().as_text()
+    gathers = re.findall(r"= \S+ gather\(.*?slice_sizes=\{([\d,]+)\}", text)
+    assert gathers and all(g == "1,128" for g in gathers), gathers
+    assert " sort(" not in text
+    assert text.count('custom_call_target="tpu_custom_call"') == len(classes)
+    assert slots >= int((sizes.astype(np.int64) ** 2).sum())
+
+
 def test_children_histograms_kernel_compiles(spec):
     """The parallel learners' two-children histogram kernel."""
     from lightgbm_tpu.ops.pallas_histogram import children_histograms_pallas

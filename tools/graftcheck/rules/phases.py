@@ -41,8 +41,8 @@ SERIES_RE = re.compile(r"^phase_seconds_[a-z_][a-z0-9_]*$")
 # their shard_map boundary in parallel/grow.py) plus
 # the compiled-forest inference program (serve/forest.py)
 DEVICE_FILES = ("models/gbdt.py", "ops/grow.py", "ops/ordered_grow.py",
-                "ops/leafhist.py", "parallel/comm.py", "parallel/grow.py",
-                "serve/forest.py")
+                "ops/leafhist.py", "ops/rank_lambda.py", "parallel/comm.py",
+                "parallel/grow.py", "serve/forest.py")
 
 
 def _load_phases(pkg: pathlib.Path):

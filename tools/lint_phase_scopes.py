@@ -14,7 +14,7 @@ declarations in ``lightgbm_tpu/obs/phases.py``.  Checks:
    HOST_PHASES, and every declared host phase is used in code;
 2. every ``jax.named_scope("X")`` in the jitted device files
    (models/gbdt.py, ops/grow.py, ops/ordered_grow.py, ops/leafhist.py,
-   parallel/comm.py, parallel/grow.py, serve/forest.py) is declared in DEVICE_PHASES, and vice versa; names
+   ops/rank_lambda.py, parallel/comm.py, parallel/grow.py, serve/forest.py) is declared in DEVICE_PHASES, and vice versa; names
    nest with ``/`` (``split/sort``);
 3. DEVICE_PARENT maps every device phase onto a declared host phase, and
    every JITTED_HOST_PHASE is covered by at least one device phase —
