@@ -24,7 +24,16 @@ thread the row lanes, NOT through one ``lax.switch``, and nothing reads a
 lane after its write-back: handed to an N-way conditional (or read again
 by a nested one) every carried lane was copied whole, per branch and per
 step, by the chip's compiler — 447 ms of a 2,073 ms round at 10.5M rows
-(PERF.md, PR 29; tests/test_tpu_compile.py holds the property).
+(PERF.md, PR 29; tests/test_tpu_compile.py holds the property).  The
+carried histogram cache has the same trap by another way in: a step reads
+one row of it (the parent's sums) and writes two (the children's), and a
+plain slice is free to be fused into every consumer.  The compiler fused
+it a second time into the second row write, so the OLD cache was read
+after the first write: it was copied whole before that write and the
+second write's result copied back into the carry, 495 ms of a 1,444 ms
+round at 255 leaves x 136 features (a 318 MB cache; PERF.md, PR 35).
+``subtract`` therefore materialises the parent's row once, behind an
+``optimization_barrier``, before either write.
 
 Row payloads travel through the partition as WORD-MAJOR i32 lanes (7
 words of bins + 3 words of digits + original row id at 28 features, each
@@ -570,9 +579,12 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
         # --- child histograms via exact sibling subtraction -------------
         def subtract(cache, sums_one, one_is_left):
             """Both children's sums from one child's and the cached
-            parent's; the cache rows of the two leaves rewritten."""
+            parent's; the cache rows of the two leaves rewritten.  The
+            parent's row is read ONCE, into a buffer of its own, before
+            either write: fused again into the second write it kept the
+            old cache alive past the first (module docstring)."""
             with jax.named_scope("hist/subtract"):
-                sums_parent = cache[best_leaf]
+                sums_parent = jax.lax.optimization_barrier(cache[best_leaf])
                 sums_other = sums_parent - sums_one
                 sums_left = jnp.where(one_is_left, sums_one, sums_other)
                 sums_right = jnp.where(one_is_left, sums_other, sums_one)

@@ -28,12 +28,17 @@ def _data(n=20000, f=6, seed=0, cat_feature=False):
             jnp.asarray(w))
 
 
-@pytest.mark.parametrize("num_leaves,cat", [(15, False), (31, True),
-                                            (7, False)])
-def test_ordered_matches_unordered(num_leaves, cat):
+@pytest.mark.parametrize("num_leaves,cat,min_hess", [
+    (15, False, 1.0), (31, True, 1.0), (7, False, 1.0),
+    # the ranking cell's regime in its first rounds: the hessian floor
+    # stops growth well before num_leaves, and every step after that is
+    # a rejected one that still rewrites the histogram cache's rows
+    (255, False, 400.0)])
+def test_ordered_matches_unordered(num_leaves, cat, min_hess):
     bins, num_bin, is_cat, feat_mask, g, h, w = _data(cat_feature=cat)
     params = GrowParams(num_leaves=num_leaves, max_bin=32,
-                        min_data_in_leaf=20, min_sum_hessian_in_leaf=1.0)
+                        min_data_in_leaf=20,
+                        min_sum_hessian_in_leaf=min_hess)
     bins_rm = jnp.asarray(np.ascontiguousarray(np.asarray(bins).T))
     lr = jnp.float32(0.1)
 
@@ -45,6 +50,8 @@ def test_ordered_matches_unordered(num_leaves, cat):
         bins_rm=bins_rm)
 
     assert int(t_ord.num_leaves) == int(t_ref.num_leaves)
+    if min_hess > 1.0:
+        assert 8 < int(t_ref.num_leaves) < num_leaves // 2
     for field in ("split_feature", "split_bin", "left_child", "right_child",
                   "leaf_count", "leaf_parent", "leaf_depth"):
         np.testing.assert_array_equal(

@@ -207,39 +207,41 @@ def test_forest_walk_kernel_compiles(spec, variant, bucket):
             spec((F, bucket), jnp.float32), name="forest_walk")
 
 
-_TEXTS = {}     # compiled texts, by program and rows: a compile each
+_TEXTS = {}     # compiled texts, by program and shape: a compile each
 
 
-def _ordered_grower_text(spec, monkeypatch, n):
-    """Compiled text of ``grow_tree_ordered`` at ``n`` rows (four features
-    keep the kernel's unroll short), 7 leaves, on the chip's kernel."""
+def _ordered_grower_text(spec, monkeypatch, n, f=4, leaves=7):
+    """Compiled text of ``grow_tree_ordered`` at ``n`` rows, ``f``
+    features (four keep the kernel's unroll short) and ``leaves`` leaves,
+    on the chip's kernel."""
     from lightgbm_tpu.ops.grow import GrowParams
     from lightgbm_tpu.ops.ordered_grow import grow_tree_ordered
     from lightgbm_tpu.utils import device
     monkeypatch.setattr(device, "on_tpu", lambda: True)
-    f = 4
-    if ("serial", n) not in _TEXTS:
-        _TEXTS["serial", n] = grow_tree_ordered.lower(
+    key = ("serial", n, f, leaves)
+    if key not in _TEXTS:
+        _TEXTS[key] = grow_tree_ordered.lower(
             spec((f, n), jnp.uint8), spec((f,), jnp.int32),
             spec((f,), jnp.bool_), spec((f,), jnp.bool_),
             spec((n,), jnp.float32), spec((n,), jnp.float32),
             spec((n,), jnp.float32), spec((), jnp.float32),
-            GrowParams(num_leaves=7, max_bin=B, min_data_in_leaf=50)
+            GrowParams(num_leaves=leaves, max_bin=B, min_data_in_leaf=50)
         ).compile().as_text()
-    return _TEXTS["serial", n]
+    return _TEXTS[key]
 
 
-def _sharded_grower_text(topo, monkeypatch, n):
+def _sharded_grower_text(topo, monkeypatch, n, f=4, leaves=7):
     """Compiled text of the data-parallel learner's grow program over the
-    four described chips, ``n`` rows a shard: ``make_parallel_grow``'s
-    ``shard_map`` of the leaf-ordered grower with its exchange."""
+    four described chips, ``n`` rows a shard, ``f`` features, ``leaves``
+    leaves: ``make_parallel_grow``'s ``shard_map`` of the leaf-ordered
+    grower with its exchange."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from lightgbm_tpu.ops.grow import GrowParams
     from lightgbm_tpu.parallel import make_parallel_grow
     from lightgbm_tpu.utils import device
     monkeypatch.setattr(device, "on_tpu", lambda: True)
-    f, k = 4, len(topo.devices)
-    if ("sharded", n) not in _TEXTS:
+    k, key = len(topo.devices), ("sharded", n, f, leaves)
+    if key not in _TEXTS:
         mesh = Mesh(np.array(topo.devices), ("data",))
 
         def on(shape, dtype, *parts):
@@ -247,15 +249,15 @@ def _sharded_grower_text(topo, monkeypatch, n):
                 shape, dtype, sharding=NamedSharding(mesh, P(*parts)))
         grow = make_parallel_grow(
             mesh, "data",
-            GrowParams(num_leaves=7, max_bin=B, min_data_in_leaf=50))
-        _TEXTS["sharded", n] = grow.lower(
+            GrowParams(num_leaves=leaves, max_bin=B, min_data_in_leaf=50))
+        _TEXTS[key] = grow.lower(
             on((f, k * n), jnp.uint8, None, "data"), on((f,), jnp.int32),
             on((f,), jnp.bool_), on((f,), jnp.bool_),
             on((k * n,), jnp.float32, "data"),
             on((k * n,), jnp.float32, "data"),
             on((k * n,), jnp.float32, "data"), on((), jnp.float32)
         ).compile().as_text()
-    return _TEXTS["sharded", n]
+    return _TEXTS[key]
 
 
 def test_ordered_grower_text_carries_the_phase_paths(spec, monkeypatch):
@@ -324,12 +326,14 @@ def _reached(instrs, comp):
     return seen
 
 
-def _whole_lane_copies_in_grow_loop(text, lane):
-    """Names of the ``copy`` instructions whose result is a whole row
-    lane (``s32[lane]``) in the body of the grow loop (the ``while``
-    that reaches the partition kernels) or in any computation it calls.
-    ``copy-start``/``copy-done`` (the compiler's moves of a small lane
-    into ``S(1)``) are other opcodes and are not counted."""
+def _whole_in_grow_loop(text, dims, opcode="copy"):
+    """Names of the ``opcode`` instructions whose result is a whole
+    carried buffer, ``s32[dims]`` (``dims`` a pattern: a row lane's
+    length, the histogram cache's four extents), in the body of the grow
+    loop (the ``while`` that reaches the partition kernels) or in any
+    computation it calls, fusions included.  ``copy-start``/``copy-done``
+    (the compiler's moves of a small buffer into ``S(1)``) are other
+    opcodes and are not counted."""
     from lightgbm_tpu.obs import devtrace
     instrs = devtrace.parse_hlo(text)["instructions"]
     loops = [_reached(instrs, dict(rec["called"])["body"])
@@ -338,7 +342,8 @@ def _whole_lane_copies_in_grow_loop(text, lane):
                if name.startswith("segment_partition")}
     in_loop = set().union(*(comps for comps in loops if comps & kernels))
     assert in_loop, "no loop reaches the partition kernels"
-    whole = re.compile(rf"%([\w.\-]+) = s32\[{lane}\](\{{[^}}]*\}})? copy\(")
+    whole = re.compile(rf"%([\w.\-]+) = s32\[{dims}\](\{{[^}}]*\}})? "
+                       rf"{re.escape(opcode)}\(")
     names = (m.group(1) for m in map(whole.search, text.splitlines()) if m)
     return sorted(n for n in names if instrs[n]["comp"] in in_loop)
 
@@ -362,7 +367,35 @@ def test_ordered_grower_copies_no_whole_lane_in_the_grow_loop(spec,
     text = _ordered_grower_text(spec, monkeypatch, n)
     lane = n + classes[-1]
     assert f"s32[{lane}]" in text and "dynamic-update-slice(" in text
-    assert _whole_lane_copies_in_grow_loop(text, lane) == []
+    assert _whole_in_grow_loop(text, lane) == []
+
+
+@pytest.mark.parametrize("program", ["serial", "sharded"])
+def test_ordered_grower_copies_no_whole_cache_in_the_grow_loop(
+        topo, spec, monkeypatch, program):
+    """The grow loop carries the histogram cache (``s32[L, F, 9, B]``;
+    under the exchange the shard's own and the global one in halves,
+    ``s32[L, F, 18, B]``) and a split step reads ONE row of it, the
+    parent's, and writes two, the children's.  Left free to fuse that read
+    into each consumer, the chip's compiler read the OLD cache again in
+    the second row write: the old cache outlived the first write, so it
+    was copied whole before it and the second write's result copied back
+    into the carry, twice 318 MB at each of 254 split steps, 495 ms of a
+    1,444 ms round at 255 leaves x 136 features (PERF.md, PR 35).  The
+    copies show only from a few size classes on: the serial program at the
+    ranking cell's widths and the sharded one at Higgs's, 262,144 rows (a
+    shard) each, held two and four of them."""
+    n = 262144
+    if program == "serial":
+        f, leaves = 136, 255
+        text = _ordered_grower_text(spec, monkeypatch, n, f, leaves)
+    else:
+        f, leaves = F, 63
+        text = _sharded_grower_text(topo, monkeypatch, n, f, leaves)
+    cache = rf"{leaves},{f},(9|18),{B}"
+    writes = _whole_in_grow_loop(text, cache, "dynamic-update-slice")
+    assert len(writes) >= (2 if program == "serial" else 4), writes
+    assert _whole_in_grow_loop(text, cache) == []
 
 
 # grow_tree_ordered at 32,768 x 4, 7 leaves, for the described v5e: 3,282
@@ -370,9 +403,11 @@ def test_ordered_grower_copies_no_whole_lane_in_the_grow_loop(spec,
 # PR 31 put the partition kernel, its mask and the slices of its output
 # in the segment sort's place in each of three size classes (3,340);
 # PR 33 took the row-major feed of the histogram kernel out of every
-# child window and moved the root pass onto the word lanes.  Whoever
-# changes the serial grower knowingly changes this number with it.
-SERIAL_INSTRUCTIONS = 3096
+# child window and moved the root pass onto the word lanes (3,096); PR 35
+# reads the cache's parent row once, behind a barrier, where three
+# consumers each had their own slice of it.  Whoever changes the serial
+# grower knowingly changes this number with it.
+SERIAL_INSTRUCTIONS = 3090
 COLLECTIVES = ("all-reduce", "all-reduce-start", "reduce-scatter",
                "all-gather", "all-gather-start", "all-to-all",
                "collective-permute", "collective-permute-start")
@@ -433,6 +468,6 @@ def test_sharded_grower_exchanges_once_a_split_outside_every_branch(
     assert not [n_ for n_, r in coll.items() if r["comp"] in under_cond]
     lane = n + _size_classes(n)[-1]
     assert f"s32[{lane}]" in text
-    assert _whole_lane_copies_in_grow_loop(text, lane) == []
+    assert _whole_in_grow_loop(text, lane) == []
     pm = devtrace.phase_map(text)
     assert pm["ops_unscoped"] == 0, pm["unscoped_op_names"]
