@@ -23,8 +23,16 @@ from .utils import log
 from .utils.log import LightGBMError
 
 
+def _is_sparse(data) -> bool:
+    """A scipy.sparse matrix, told without importing scipy."""
+    return hasattr(data, "tocsc")
+
+
 def _to_dense(data):
-    """Accept numpy / pandas / scipy-sparse / list-of-lists."""
+    """Accept numpy / pandas / scipy-sparse / list-of-lists.  (A sparse
+    matrix handed to ``Dataset`` never comes here: it is binned from its
+    stored entries, io/sparse.py; ``Booster.predict`` still widens the
+    rows it is handed.)"""
     if hasattr(data, "toarray"):          # scipy CSR/CSC without importing it
         data = data.toarray()
     if hasattr(data, "values") and hasattr(data, "dtypes"):  # pandas
@@ -198,10 +206,11 @@ class Dataset:
                 _data_from_pandas(data, self.feature_name,
                                   self.categorical_feature)
             from . import obs
-            with obs.span("Bin::apply"):
-                # the float64 widening of the whole matrix is part of
-                # applying the bins (io/dataset.py reads the wide copy)
-                data = _to_dense(data)
+            if not _is_sparse(data):
+                with obs.span("Bin::apply"):
+                    # the float64 widening of the whole matrix is part of
+                    # applying the bins (io/dataset.py reads the wide copy)
+                    data = _to_dense(data)
 
         feature_name = (None if self.feature_name == "auto"
                         else list(self.feature_name))
@@ -236,7 +245,9 @@ class Dataset:
                           "task": "train"})
             if file_roles is not None:
                 cat_idx = sorted(set(cat_idx) | file_roles.categorical)
-            self._binned = BinnedDataset.from_matrix(
+            build = (BinnedDataset.from_sparse if _is_sparse(data)
+                     else BinnedDataset.from_matrix)
+            self._binned = build(
                 data, self.label,
                 max_bin=int(self.params.get("max_bin", self.max_bin)),
                 min_data_in_leaf=cfg.min_data_in_leaf,

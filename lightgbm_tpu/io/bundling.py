@@ -30,11 +30,13 @@ features, which is what makes the zero-conflict bit-parity pin
 
 from __future__ import annotations
 
-from typing import Callable, List, Optional, Sequence
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from ..utils import log
+from ..utils.threads import map_features
+from .sparse import SparseColumns
 
 # Candidates must be at least this sparse (BinMapper.sparse_rate = share
 # of rows in the default bin).  Denser features gain little from
@@ -73,6 +75,14 @@ class BundlePlan:
     def features_bundled(self) -> int:
         return sum(len(m) for m in self.bundles)
 
+    def publish(self) -> None:
+        """The plan's shape as gauges (again after ``encode_exact`` moved
+        members)."""
+        from .. import obs
+        obs.set_gauge("efb_bundles", len(self.bundles))
+        obs.set_gauge("efb_features_bundled", self.features_bundled)
+        obs.set_gauge("efb_columns", self.num_columns)
+
     def signature(self) -> tuple:
         """Cheap equality key for Dataset::CheckAlign-style alignment."""
         return (tuple(tuple(m) for m in self.column_members),
@@ -87,35 +97,163 @@ class BundlePlan:
         codes for the n rows.  Bundle members write their non-default
         bins at ``offset + bin - 1``; on a conflicting row the LAST
         member in column order wins (deterministic)."""
+        return self._encode(self.stored_of(feature_bins, n),
+                            [0] * self.num_features, n, dtype)[0]
+
+    def encode_columns_sparse(self, stored_bins: Callable, zero_bins, n: int,
+                              dtype) -> np.ndarray:
+        """``encode_columns`` from stored entries alone: O(stored) writes
+        into the ``[C, n]`` codes, never a dense column a feature.
+
+        ``stored_bins(inner)`` returns ``(rows, bins)`` of that used
+        feature's stored entries; ``zero_bins[inner]`` is the bin of 0.0,
+        which every other row of a singleton column holds (a bundle
+        member's is 0: ``_is_candidate``).  A member writes only its
+        stored rows; the LAST member in column order wins a conflicting
+        row, as above."""
+        return self._encode(stored_bins, zero_bins, n, dtype)[0]
+
+    def encode_exact(self, stored_bins: Callable, zero_bins, n: int, dtype,
+                     num_bins: Sequence[int], max_total_bin: int,
+                     max_conflicts: int):
+        """``(bins, plan)``: the training set's encoding, with the plan
+        held to its conflict budget on ALL rows.
+
+        The planner sees the FindBin sample; two members that never met
+        there can meet on rows outside it (two rare levels of different
+        categoricals of a 12M-row one-hot table do, on a few hundred
+        rows), and the later member's write would silently erase the
+        earlier one's value: the column-space histograms and the rows'
+        routing would then differ from the features' own, budget or
+        not.  So a member whose rows would take its column's conflicts
+        past ``max_conflicts`` (``max_conflict_rate`` of the rows: 0
+        admits none) is taken out of the column as it is met, and the
+        members taken out are packed greedily, by the same rule on all
+        rows, into columns appended behind the plan's (a lone one stays
+        an identity column).  With ``max_conflict_rate=0`` the encoding
+        is therefore exact on every row, whatever the sample missed."""
+        bins, gone = self._encode(stored_bins, zero_bins, n, dtype,
+                                  max_conflicts)
+        members = [[f for f in m if f not in gone]
+                   for m in self.column_members]
+        offsets = [[o for f, o in zip(m, offs) if f not in gone]
+                   for m, offs in zip(self.column_members,
+                                      self.column_offsets)]
+        pending = sorted(gone)
+        extra = []
+        while pending:
+            col = np.zeros(n, dtype)
+            took, offs, conflicts, width = [], [], 0, 1
+            for f in pending:
+                nb = int(num_bins[f])
+                if width + nb - 1 > max_total_bin:
+                    continue
+                rows, vb = stored_bins(f)
+                nz = vb > 0
+                hit = int(np.count_nonzero(col[rows[nz]]))
+                if conflicts + hit > max_conflicts:
+                    continue
+                col[rows[nz]] = width + vb[nz] - 1
+                took.append(f)
+                offs.append(width)
+                conflicts += hit
+                width += nb - 1
+            if len(took) == 1:              # alone: its own bin codes
+                rows, vb = stored_bins(took[0])
+                col.fill(zero_bins[took[0]])
+                col[rows] = vb
+                offs = [0]
+            pending = [f for f in pending if f not in took]
+            members.append(took)
+            offsets.append(offs)
+            extra.append(col)
+        if extra:
+            log.info("EFB: %d member(s) met on rows outside the sample and "
+                     "moved into %d further column(s)", len(gone), len(extra))
+            bins = np.concatenate([bins, np.stack(extra)])
+        return bins, BundlePlan(members, offsets, self.num_features,
+                                self.sample_conflicts)
+
+    def stored_of(self, feature_bins: Callable[[int], np.ndarray], n: int):
+        """``stored_bins`` for the encoders from whole columns of bin
+        codes: a bundle member's non-default rows, a singleton's every
+        row."""
+        alone = {m[0] for m, o in zip(self.column_members,
+                                      self.column_offsets)
+                 if len(m) == 1 and o[0] == 0}
+
+        def stored(inner):
+            vb = np.asarray(feature_bins(inner), np.int64)
+            rows = np.arange(n) if inner in alone else np.flatnonzero(vb)
+            return rows, vb[rows]
+        return stored
+
+    def _encode(self, stored_bins, zero_bins, n, dtype, max_conflicts=None):
+        """The one encoder.  Returns the codes and, where a conflict
+        budget is given, the set of members it took out of their columns
+        (their slots stay unused)."""
         out = np.zeros((self.num_columns, n), dtype)
-        for c, (members, offsets) in enumerate(
-                zip(self.column_members, self.column_offsets)):
+
+        def fill(c):
+            members, offsets = self.column_members[c], self.column_offsets[c]
             if len(members) == 1 and offsets[0] == 0:
-                out[c] = feature_bins(members[0]).astype(dtype)
-                continue
-            col = np.zeros(n, np.int64)
+                out[c].fill(zero_bins[members[0]])
+                rows, vb = stored_bins(members[0])
+                out[c][rows] = vb
+                return []
+            gone, conflicts = [], 0
             for f, off in zip(members, offsets):
-                vb = np.asarray(feature_bins(f), np.int64)
-                nz = vb > 0          # candidates have default_bin == 0
-                col[nz] = off + vb[nz] - 1
-            out[c] = col.astype(dtype)
-        return out
+                rows, vb = stored_bins(f)
+                nz = vb > 0
+                rows = rows[nz]
+                if max_conflicts is not None:
+                    hit = int(np.count_nonzero(out[c][rows]))
+                    if conflicts + hit > max_conflicts:
+                        gone.append(f)
+                        continue
+                    conflicts += hit
+                out[c][rows] = off + vb[nz] - 1
+            return gone
+        gone = map_features(fill, range(self.num_columns), n)
+        return out, {f for g in gone for f in g}
 
     # -- device decode tables (ops/bundle.py BundleDecode) ---------------
     def decode_arrays(self, num_bins: Sequence[int],
-                      default_bins: Sequence[int], max_bin: int) -> dict:
+                      default_bins: Sequence[int], max_bin: int,
+                      shape: Optional[Tuple[int, int]] = None) -> dict:
         """Numpy decode tables for :class:`ops.bundle.BundleDecode`.
+
+        ``shape``: the ``(columns, features)`` the tables are made for,
+        at least the plan's own (ops/ordered_grow.py ``bundled_shape``:
+        the rung of the device layout, so that tables of nearby plans
+        share one compiled program).  What lies beyond the plan stands
+        for nothing: a column that holds no feature, a feature of no
+        slot that no search is ever told of (``multi`` is filled up to a
+        power of two with -1).
 
         ``num_bins``/``default_bins`` are per used original feature; the
         slot map routes each feature's default bin (and any bin past its
         range) to the zero slot ``max_bin`` so the expansion's integer
-        default-bin reconstruction never double-counts."""
-        F, B = self.num_features, int(max_bin)
+        default-bin reconstruction never double-counts.
+
+        The last three tables are the column-space split search's
+        (ops/bundle.py ``find_best_split_columns``), which reads every
+        feature where it lies instead of expanding ``[F, B]``:
+        ``col_feat [C]`` the feature an identity column holds (-1: a
+        bundle's column), ``slot_feat [C, B]`` the two-bin member whose
+        one non-default bin is that slot of a bundle's column (-1: none),
+        ``multi [M]`` the bundled members of more than two bins, which
+        alone are still expanded."""
+        C, F = shape or (self.num_columns, self.num_features)
+        B = int(max_bin)
         col = np.zeros(F, np.int32)
         off = np.zeros(F, np.int32)
         width = np.zeros(F, np.int32)
         slot_map = np.full((F, B), B, np.int32)
         default = np.zeros(F, np.int32)
+        col_feat = np.full(C, -1, np.int32)
+        slot_feat = np.full((C, B), -1, np.int32)
+        multi = []
         for c, (members, offsets) in enumerate(
                 zip(self.column_members, self.column_offsets)):
             for f, o in zip(members, offsets):
@@ -125,6 +263,12 @@ class BundlePlan:
                 width[f] = max(nb - 1, 0)
                 default[f] = int(default_bins[f])
                 if o == 0:
+                    col_feat[c] = f
+                elif nb == 2 and o < B:
+                    slot_feat[c, o] = f
+                else:
+                    multi.append(f)
+                if o == 0:
                     b = np.arange(min(nb, B))
                     slot_map[f, b] = b
                 else:
@@ -132,8 +276,13 @@ class BundlePlan:
                     slot_map[f, b] = o + b - 1
                 if 0 <= default[f] < B:
                     slot_map[f, default[f]] = B
+        if shape and multi:
+            multi += [-1] * ((1 << (len(multi) - 1).bit_length())
+                             - len(multi))
         return {"col": col, "off": off, "width": width,
-                "slot_map": slot_map, "default_bin": default}
+                "slot_map": slot_map, "default_bin": default,
+                "col_feat": col_feat, "slot_feat": slot_feat,
+                "multi": np.asarray(multi, np.int32)}
 
     # -- serialization (binary dataset cache) ----------------------------
     def to_state(self) -> dict:
@@ -161,14 +310,16 @@ def _is_candidate(mapper) -> bool:
             and mapper.sparse_rate >= MIN_BUNDLE_SPARSE_RATE)
 
 
-def plan_bundles(sample: np.ndarray, mappers, used_feature_map,
+def plan_bundles(sample, mappers, used_feature_map,
                  *, max_conflict_rate: float, max_total_bin: int,
                  enable_bundle: bool = True,
                  is_enable_sparse: bool = True) -> Optional[BundlePlan]:
     """Greedy conflict-bounded bundling over the mapper sample.
 
     Args:
-      sample: [S, F_real] raw sampled rows (the same sample FindBin saw).
+      sample: [S, F_real] raw sampled rows (the same sample FindBin
+        saw), or their ``SparseColumns``: a feature's non-default rows
+        are then read from its stored entries, O(stored) in all.
       mappers: per-USED-feature BinMapper list.
       used_feature_map: used index -> real column in ``sample``.
       max_conflict_rate: allowed conflicting-row share per bundle.
@@ -198,9 +349,7 @@ def plan_bundles(sample: np.ndarray, mappers, used_feature_map,
         plan = _plan_bundles_impl(sample, mappers, used_feature_map,
                                   max_conflict_rate, max_total_bin)
     if plan is not None:
-        obs.set_gauge("efb_bundles", len(plan.bundles))
-        obs.set_gauge("efb_features_bundled", plan.features_bundled)
-        obs.set_gauge("efb_columns", plan.num_columns)
+        plan.publish()
         # the one-line dataset sparsity summary (reference-style)
         n_sparse = sum(1 for m in mappers if _is_candidate(m))
         log.info("EFB: %d sparse feature(s), %d bundled into %d bundle(s) "
@@ -209,6 +358,25 @@ def plan_bundles(sample: np.ndarray, mappers, used_feature_map,
                  plan.num_features, plan.num_columns,
                  plan.sample_conflicts)
     return plan
+
+
+def _popcount(words: np.ndarray) -> int:
+    return int(np.bitwise_count(words).sum())
+
+
+def _nondefault_bits(sample, col: int, mapper) -> np.ndarray:
+    """The sample rows on which the feature is not in its default bin
+    (bin 0: ``_is_candidate``), as a bit set in uint64 words."""
+    S = sample.shape[0]
+    if isinstance(sample, SparseColumns):
+        rows = sample.rows(col)
+        nd = np.zeros(S, bool)
+        nd[rows[np.asarray(mapper.value_to_bin(sample.values(col))) != 0]] \
+            = True
+    else:
+        nd = np.asarray(mapper.value_to_bin(sample[:, col])) != 0
+    packed = np.packbits(nd)
+    return np.pad(packed, (0, (-len(packed)) % 8)).view(np.uint64)
 
 
 def _plan_bundles_impl(sample, mappers, used_feature_map,
@@ -221,25 +389,22 @@ def _plan_bundles_impl(sample, mappers, used_feature_map,
     # sparsest first: the emptiest features pack tightest and burn the
     # least conflict budget (the ISSUE's sparse_rate ranking)
     cand.sort(key=lambda f: (-mappers[f].sparse_rate, f))
-    nondefault = {}
-    for f in cand:
-        col = sample[:, used_feature_map[f]]
-        nondefault[f] = np.asarray(
-            mappers[f].value_to_bin(col)) != 0
     budget = int(float(max_conflict_rate) * S)
 
+    # bit sets over the sample rows (25 KB a feature at 200,000 rows): a
+    # conflict count is one AND and one population count
     bundles: List[List[int]] = []       # member lists
     occupied: List[np.ndarray] = []     # per-bundle any-member-nonzero
     conflicts: List[int] = []           # per-bundle cumulative conflicts
     bins_used: List[int] = []           # per-bundle 1 + sum(nb - 1)
     for f in cand:
-        nd = nondefault[f]
+        nd = _nondefault_bits(sample, used_feature_map[f], mappers[f])
         nb = int(mappers[f].num_bin)
         placed = False
         for bi in range(len(bundles)):
             if bins_used[bi] + (nb - 1) > max_total_bin:
                 continue
-            c = int(np.count_nonzero(occupied[bi] & nd))
+            c = _popcount(occupied[bi] & nd)
             if conflicts[bi] + c > budget:
                 continue
             bundles[bi].append(f)
@@ -250,7 +415,7 @@ def _plan_bundles_impl(sample, mappers, used_feature_map,
             break
         if not placed:
             bundles.append([f])
-            occupied.append(nd.copy())
+            occupied.append(nd)
             conflicts.append(0)
             bins_used.append(1 + (nb - 1))
     keep = {}

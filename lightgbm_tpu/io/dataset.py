@@ -23,6 +23,7 @@ from ..utils import log
 from ..utils.threads import map_features
 from .binning import BinMapper, CATEGORICAL, NUMERICAL
 from .bundling import BundlePlan, plan_bundles
+from .sparse import SparseColumns
 
 _BINARY_TOKEN = b"__lightgbm_tpu_dataset_v1__"
 
@@ -104,7 +105,7 @@ class Metadata:
         return 0 if self.query_boundaries is None else len(self.query_boundaries) - 1
 
 
-def build_mappers_from_sample(sample: np.ndarray, num_data: int, *,
+def build_mappers_from_sample(sample, num_data: int, *,
                               max_bin: int, min_data_in_bin: int,
                               min_data_in_leaf: int,
                               categorical_features=frozenset(),
@@ -114,7 +115,10 @@ def build_mappers_from_sample(sample: np.ndarray, num_data: int, *,
     """Per-REAL-feature BinMapper list (None for ignored features) from a
     row sample — the FindBin stage of dataset_loader.cpp:656-722, shared
     by in-memory, two-round/streaming, and distributed loading so all
-    three produce identical mappers from identical samples.
+    three produce identical mappers from identical samples.  ``sample``
+    is the ``[S, F]`` rows or their ``SparseColumns`` (io/sparse.py):
+    ``find_bin`` takes a column's non-zero values and the sample's row
+    count either way, so a column's stored entries are all it reads.
 
     The trivial-feature filter count is scaled to the sample
     (dataset_loader.cpp:490,704): 0.95 * min_data_in_leaf / num_data *
@@ -122,6 +126,7 @@ def build_mappers_from_sample(sample: np.ndarray, num_data: int, *,
     features (the feature-sharded distributed FindBin); unlisted features
     get None."""
     total_sample_cnt = sample.shape[0]
+    sparse = isinstance(sample, SparseColumns)
     filter_cnt = int(0.95 * min_data_in_leaf / max(1, num_data)
                      * total_sample_cnt)
     todo = range(sample.shape[1]) if feature_indices is None \
@@ -134,7 +139,7 @@ def build_mappers_from_sample(sample: np.ndarray, num_data: int, *,
                 predefined_mappers[f] is not None:
             out[f] = predefined_mappers[f]
             continue
-        col = sample[:, f]
+        col = sample.values(f) if sparse else sample[:, f]
         nonzero = col[col != 0.0]
         out[f] = BinMapper().find_bin(
             nonzero, total_sample_cnt, max_bin, min_data_in_bin,
@@ -153,6 +158,19 @@ def _bins_dtype(mappers, plan) -> type:
                    if len(members) > 1 else mappers[members[0]].num_bin
                    for members in plan.column_members]
     return np.uint8 if max(per_col or [1]) <= 256 else np.uint16
+
+
+def _raw_rows(data, used) -> np.ndarray:
+    """[len(used), N] f32 raw values of the used features: feature-major
+    like ``bins`` so the linear-fit gather reads contiguous lanes; f32
+    (the fit solves in f32 anyway).  Dense by design of the linear fit,
+    whatever the input was."""
+    if not isinstance(data, SparseColumns):
+        return np.ascontiguousarray(data[:, used].T, dtype=np.float32)
+    raw = np.zeros((len(used), data.num_rows), np.float32)
+    for inner, f in enumerate(used):
+        raw[inner, data.rows(f)] = data.values(f)
+    return raw
 
 
 class BinnedDataset:
@@ -197,23 +215,11 @@ class BinnedDataset:
 
     # -- construction ---------------------------------------------------
     @classmethod
-    def from_matrix(cls, data: np.ndarray, label=None, *,
-                    max_bin: int = 255, min_data_in_bin: int = 5,
-                    min_data_in_leaf: int = 100,
-                    bin_construct_sample_cnt: int = 200000,
-                    categorical_features: Sequence[int] = (),
-                    ignore_features: Sequence[int] = (),
-                    feature_names: Optional[Sequence[str]] = None,
-                    data_random_seed: int = 1,
-                    label_idx: int = 0,
-                    predefined_mappers: Optional[List[Optional[BinMapper]]] = None,
-                    enable_bundle: bool = False,
-                    max_conflict_rate: float = 0.0,
-                    is_enable_sparse: bool = True,
-                    keep_raw: bool = False,
+    def from_matrix(cls, data: np.ndarray, label=None, **kwargs
                     ) -> "BinnedDataset":
         """Bin a raw [N, F] float matrix (dataset_loader.cpp:656-820 flow:
-        sample rows -> per-feature FindBin -> extract features)."""
+        sample rows -> per-feature FindBin -> extract features).  Keyword
+        arguments: ``_build``'s."""
         from .. import obs
         with obs.span("Bin::apply"):
             # the float64 widening is part of applying the bins: every
@@ -222,6 +228,47 @@ class BinnedDataset:
             data = np.ascontiguousarray(np.asarray(data, dtype=np.float64))
         if data.ndim != 2:
             raise ValueError("data must be 2-D [num_data, num_features]")
+        return cls._build(data, label, **kwargs)
+
+    @classmethod
+    def from_sparse(cls, data, label=None, **kwargs) -> "BinnedDataset":
+        """Bin a ``scipy.sparse`` matrix (anything with ``tocsc``; a CSR is
+        converted once) from its stored entries: the same mappers, bundle
+        plan and ``bins`` as ``from_matrix`` gives the dense matrix of
+        the same values, in O(stored entries) of work and memory beside
+        the ``[C, N]`` codes themselves; no dense ``[N, F]`` or ``[S, F]``
+        array is ever made (io/sparse.py, docs/SPARSE.md)."""
+        from .. import obs
+        with obs.span("Bin::apply"):
+            data = SparseColumns.from_scipy(data)
+        obs.set_gauge("sparse_stored_entries", data.nnz)
+        obs.set_gauge("sparse_stored_per_row",
+                      data.nnz / max(1, data.num_rows))
+        return cls._build(data, label, **kwargs)
+
+    @classmethod
+    def _build(cls, data, label=None, *,
+               max_bin: int = 255, min_data_in_bin: int = 5,
+               min_data_in_leaf: int = 100,
+               bin_construct_sample_cnt: int = 200000,
+               categorical_features: Sequence[int] = (),
+               ignore_features: Sequence[int] = (),
+               feature_names: Optional[Sequence[str]] = None,
+               data_random_seed: int = 1,
+               label_idx: int = 0,
+               predefined_mappers: Optional[List[Optional[BinMapper]]] = None,
+               enable_bundle: bool = False,
+               max_conflict_rate: float = 0.0,
+               is_enable_sparse: bool = True,
+               keep_raw: bool = False,
+               ) -> "BinnedDataset":
+        """The one construction both inputs share.  ``data`` is the
+        float64 ``[N, F]`` matrix or its ``SparseColumns``; the row draw,
+        FindBin, the bundle planner and the encoding are the same
+        functions on either, each reading a column's stored entries
+        where the matrix is sparse."""
+        from .. import obs
+        sparse = isinstance(data, SparseColumns)
         num_data, num_features = data.shape
         self = cls()
         self.num_total_features = num_features
@@ -241,7 +288,8 @@ class BinnedDataset:
             if num_data > bin_construct_sample_cnt:
                 sample_idx = np.sort(rng.choice(
                     num_data, bin_construct_sample_cnt, replace=False))
-                sample = data[sample_idx]
+                sample = data.take_rows(sample_idx) if sparse \
+                    else data[sample_idx]
             else:
                 sample = data
 
@@ -276,24 +324,20 @@ class BinnedDataset:
             enable_bundle=enable_bundle, is_enable_sparse=is_enable_sparse)
 
         dtype = _bins_dtype(mappers, self.bundle_plan)
-        feature_bins = (lambda inner:
-                        mappers[inner].value_to_bin(data[:, used[inner]]))
         with obs.span("Bin::apply"):
-            if self.bundle_plan is not None:
-                self.bins = self.bundle_plan.encode_columns(
-                    feature_bins, num_data, dtype)
+            if sparse or self.bundle_plan is not None:
+                self.bins = self._encode(data, dtype, exact_to=int(
+                    float(max_conflict_rate) * num_data))
             else:
                 self.bins = np.zeros((len(used), num_data), dtype=dtype)
 
                 def fill(inner):
-                    self.bins[inner] = feature_bins(inner).astype(dtype)
+                    self.bins[inner] = mappers[inner].value_to_bin(
+                        data[:, used[inner]]).astype(dtype)
                 map_features(fill, range(len(used)), num_data)
 
         if keep_raw and used:
-            # feature-major like ``bins`` so the linear-fit gather reads
-            # contiguous lanes; f32 (the fit solves in f32 anyway)
-            self.raw = np.ascontiguousarray(data[:, used].T,
-                                            dtype=np.float32)
+            self.raw = _raw_rows(data, used)
 
         self.metadata = Metadata(num_data)
         if label is not None:
@@ -305,7 +349,9 @@ class BinnedDataset:
         # bin occupancy straight from the FindBin sample the mappers just
         # retained, missing rates exact over the full matrix.  Cheap host
         # bookkeeping at bin time; serialized with the model artifact.
-        if used:
+        # (Not from a sparse matrix: it rebins every dense column; the
+        # observatory abstains, as it does for a streamed load.)
+        if used and not sparse:
             from ..obs.drift import DataFingerprint
             with obs.span("Bin::fingerprint"):
                 self.data_fingerprint = DataFingerprint.from_training(
@@ -314,10 +360,48 @@ class BinnedDataset:
                     else None)
         return self
 
+    def _encode(self, data, dtype, exact_to: Optional[int] = None
+                ) -> np.ndarray:
+        """``bins`` of ``data`` (the float64 matrix or its
+        ``SparseColumns``) under this dataset's mappers and bundle plan.
+        From a sparse matrix each used feature's stored values are binned
+        and every other row is left at the bin of 0.0
+        (``BinMapper.default_bin``).  ``exact_to``: the training set's
+        own encoding, which holds the plan to that many conflicts a
+        column on ALL rows and may move members (``BundlePlan.
+        encode_exact``: ``bundle_plan`` is then the plan as encoded)."""
+        mappers, used = self.mappers, self.used_feature_map
+        plan = self.bundle_plan
+        if plan is None:
+            # every used feature a column of its own
+            plan = BundlePlan([[i] for i in range(len(used))],
+                              [[0]] * len(used), len(used))
+        if isinstance(data, SparseColumns):
+            def stored_bins(inner):
+                f = used[inner]
+                return data.rows(f), np.asarray(
+                    mappers[inner].value_to_bin(data.values(f)))
+        else:
+            stored_bins = plan.stored_of(
+                lambda inner: mappers[inner].value_to_bin(
+                    data[:, used[inner]]), data.shape[0])
+        zero_bins = [m.default_bin for m in mappers]
+        if exact_to is None or self.bundle_plan is None:
+            return plan.encode_columns_sparse(stored_bins, zero_bins,
+                                              data.shape[0], dtype)
+        bins, self.bundle_plan = plan.encode_exact(
+            stored_bins, zero_bins, data.shape[0], dtype,
+            [m.num_bin for m in mappers], self.max_bin, exact_to)
+        self.bundle_plan.publish()
+        return bins
+
     def create_valid(self, data: np.ndarray, label=None) -> "BinnedDataset":
         """Bin a validation matrix with *this* dataset's mappers
-        (CreateValid/CopyFeatureMapperFrom, dataset.cpp:124-208)."""
-        data = np.asarray(data, dtype=np.float64)
+        (CreateValid/CopyFeatureMapperFrom, dataset.cpp:124-208); a
+        ``scipy.sparse`` one from its stored entries."""
+        sparse = hasattr(data, "tocsc")
+        data = SparseColumns.from_scipy(data) if sparse \
+            else np.asarray(data, dtype=np.float64)
         valid = BinnedDataset()
         valid.num_total_features = self.num_total_features
         valid.max_bin = self.max_bin
@@ -327,25 +411,22 @@ class BinnedDataset:
         valid.mappers = self.mappers
         valid.bundle_plan = self.bundle_plan
         num_data = data.shape[0]
-        feature_bins = (lambda inner: self.mappers[inner].value_to_bin(
-            data[:, self.used_feature_map[inner]]))
-        if self.bundle_plan is not None:
+        if sparse or self.bundle_plan is not None:
             # validation rows ride the TRAINING bundles: replay/scoring
             # happens on the bundled device matrix, so both sides must
             # share one column layout (Dataset::CheckAlign)
-            valid.bins = self.bundle_plan.encode_columns(
-                feature_bins, num_data, self.bins.dtype)
+            valid.bins = valid._encode(data, self.bins.dtype)
         else:
             valid.bins = np.zeros((len(self.used_feature_map), num_data),
                                   dtype=self.bins.dtype)
             for inner in range(len(self.used_feature_map)):
-                valid.bins[inner] = feature_bins(inner).astype(
-                    self.bins.dtype)
+                valid.bins[inner] = self.mappers[inner].value_to_bin(
+                    data[:, self.used_feature_map[inner]]).astype(
+                        self.bins.dtype)
         if self.raw is not None and self.used_feature_map:
             # valid raw rides along whenever the training set kept raw:
             # linear-tree valid scoring replays affine leaves on it
-            valid.raw = np.ascontiguousarray(
-                data[:, self.used_feature_map].T, dtype=np.float32)
+            valid.raw = _raw_rows(data, self.used_feature_map)
         valid.metadata = Metadata(num_data)
         if label is not None:
             valid.metadata.set_label(label)

@@ -180,13 +180,17 @@ class _DeviceData:
     def __init__(self, dataset: BinnedDataset, num_models: int,
                  with_row_major: bool = False,
                  padded_rows: Optional[int] = None,
-                 with_raw: bool = False, mesh=None):
+                 with_raw: bool = False, mesh=None,
+                 padded_cols: Optional[int] = None):
         self.dataset = dataset
         self.num_data = dataset.num_data
         self.padded_rows = max(int(padded_rows or 0), dataset.num_data)
         pad = self.padded_rows - dataset.num_data
-        bins_np = dataset.bins if pad == 0 else \
-            np.pad(dataset.bins, ((0, 0), (0, pad)))
+        # ``padded_cols``: an EFB layout's rung (ops/ordered_grow.py
+        # bundled_shape); a pad column holds bin 0 on every row
+        cpad = max(int(padded_cols or 0) - dataset.bins.shape[0], 0)
+        bins_np = dataset.bins if pad == 0 and cpad == 0 else \
+            np.pad(dataset.bins, ((0, cpad), (0, pad)))
         h2d_xfers, h2d_bytes = 1, int(bins_np.nbytes)
         # ``mesh`` (a row-sharded learner in one process): every device
         # receives its own row block straight from the host, so a chip
@@ -214,8 +218,7 @@ class _DeviceData:
         self.bins_words = None
         if self.bins_rm is not None \
                 and ordered_grow.accepts(self.bins_rm.dtype):
-            self.bins_words = ordered_grow.pack_word_lanes(self.bins_rm,
-                                                           mesh)
+            self.bins_words = ordered_grow.pack_word_lanes(self.bins, mesh)
         # raw f32 feature values for the linear-tree fit and its replay
         # epilogues (docs/LINEAR_TREES.md): NaN imputed to 0.0 ON UPLOAD
         # so the device fit and every predict path agree exactly; pad
@@ -430,10 +433,11 @@ def _grower(kind: str, params: GrowParams):
     delta).  The inner grow jits inline under an enclosing trace
     (obs/compile_ledger.py passthrough)."""
     if kind == "ordered":
-        # no column decode: the choice guarantees view.bundle is None
+        # view.bundle is None or the full EFB layout, never a screener's
+        # compacted view (the choice guarantees it)
         return lambda view, *a: ordered_grow.grow_tree_ordered(
             view.bins, *a, params, bins_rm=view.bins_rm,
-            bins_words=view.bins_words)
+            bins_words=view.bins_words, bundle=view.bundle)
     # ops/grow.py with the resident [L, F, 9, B] histogram cache
     # ("cached"), or in full passes a split without it ("nocache": the
     # hist_cache degrade step; exact parity, both scan the same sums)
@@ -651,9 +655,10 @@ class GBDT:
         self._grow_fn = self._make_grow_fn()
         self._full_view = self._make_full_view()
         # device-constant caches (avoid a host->device transfer per iter)
-        self._full_feat_mask = jnp.ones(self.num_features, bool)
-        self._full_feat_masks = jnp.ones((self.num_class, self.num_features),
-                                         bool)
+        self._full_feat_mask = self._feature_row(
+            np.ones(self.num_features, bool))
+        self._full_feat_masks = jnp.stack([self._full_feat_mask]
+                                          * self.num_class)
         self._lr_cache: Tuple[float, jax.Array] = (-1.0, jnp.float32(0))
         self._train_step = None
 
@@ -663,21 +668,34 @@ class GBDT:
         plan = getattr(train_set, "bundle_plan", None)
         self._bundle_plan = plan
         self._bundle = None
+        # what the device holds of columns and features: the data's own,
+        # or the rung of a bundled layout
+        self._device_shape = (self.num_columns, self.num_features)
         if plan is None:
             self._bundle_col_np = np.arange(self.num_features, dtype=np.int64)
             return
+        screened = float(getattr(cfg, "feature_screen_ratio", 0.0)
+                         or 0.0) > 0.0
+        if self._mesh is None and ordered_grow.accepts(train_set.bins.dtype,
+                                                       screened):
+            # the layout the leaf-ordered grower carries, padded so that
+            # nearby plans share its compiled round
+            self._device_shape = ordered_grow.bundled_shape(
+                self.num_columns, self.num_features)
         dn = plan.decode_arrays(
             [m.num_bin for m in train_set.mappers],
-            [m.default_bin for m in train_set.mappers], cfg.max_bin)
-        self._bundle = BundleDecode(
-            col=jnp.asarray(dn["col"]), off=jnp.asarray(dn["off"]),
-            width=jnp.asarray(dn["width"]),
-            slot_map=jnp.asarray(dn["slot_map"]),
-            default_bin=jnp.asarray(dn["default_bin"]))
-        self._bundle_col_np = dn["col"].astype(np.int64)
+            [m.default_bin for m in train_set.mappers], cfg.max_bin,
+            self._device_shape)
+        self._bundle = BundleDecode.from_tables(dn)
+        self._bundle_col_np = dn["col"][:self.num_features].astype(np.int64)
+        fpad = self._device_shape[1] - self.num_features
+        self.num_bin = jnp.pad(self.num_bin, (0, fpad), constant_values=1)
+        self.is_cat = jnp.pad(self.is_cat, (0, fpad))
+        obs.set_gauge("efb_device_columns", self._device_shape[0])
         log.info("EFB active: %d feature(s) in %d column(s) "
-                 "(%d bundle(s))", self.num_features, self.num_columns,
-                 len(plan.bundles))
+                 "(%d bundle(s)); on the device %d column(s) of %d "
+                 "feature(s)", self.num_features, self.num_columns,
+                 len(plan.bundles), *self._device_shape)
 
     def _setup_screening(self, cfg: Config) -> None:
         """EMA-FS gain screening state (models/screening.py)."""
@@ -856,7 +874,8 @@ class GBDT:
                 with_row_major=(mesh is None
                                 or self._choose_grower()[0] == "ordered"),
                 padded_rows=self._padded_rows,
-                with_raw=self._linear is not None, mesh=mesh)
+                with_raw=self._linear is not None, mesh=mesh,
+                padded_cols=self._device_shape[0])
 
     def _make_grad_arrays(self):
         """The objective's per-dataset arrays at the padded row count;
@@ -909,13 +928,16 @@ class GBDT:
         if self._degrade_leaf_cache_off:
             return "nocache", ("memory_policy=degrade dropped the "
                                "per-leaf histogram cache")
-        # EFB columns and screening's compacted views need the per-split
-        # column decode, which the packed word lanes do not carry
-        decode = bundled or self._screener is not None
-        if ordered_grow.accepts(dtype, decode):
-            return "ordered", "uint8 bins, no column decode"
-        return "cached", ("EFB bundling / feature screening need the "
-                          "column decode" if decode
+        # an EFB layout is the dataset's own and the lanes carry it (the
+        # split member's offset decode, the search in column space); a
+        # screener's compacted views change from period to period and
+        # stay on the grower that gathers rows
+        screened = self._screener is not None
+        if ordered_grow.accepts(dtype, screened):
+            return "ordered", ("uint8 bins, EFB columns decoded at the split"
+                               if bundled else "uint8 bins, no column decode")
+        return "cached", ("feature screening's compacted views need the "
+                          "column decode of gathered rows" if screened
                           else "max_bin > 256: the leaf-ordered layout "
                                "packs uint8 bins")
 
@@ -934,7 +956,7 @@ class GBDT:
         mutating state yet)."""
         return estimate_train_memory(
             self._rows_per_device() if rows is None else rows,
-            train_set.num_columns, cfg.num_leaves,
+            self._device_shape[0], cfg.num_leaves,
             cfg.max_bin, self.num_class,
             bin_itemsize=train_set.bins.dtype.itemsize,
             donate_score=not guard and self._donation_on(),
@@ -1248,9 +1270,10 @@ class GBDT:
         self._valid_mem_bytes = valid_bytes
         self.train_metrics = self._make_metrics(cfg, train_set)
         self._init_row_state()
-        self._full_feat_mask = jnp.ones(self.num_features, bool)
-        self._full_feat_masks = jnp.ones((self.num_class, self.num_features),
-                                         bool)
+        self._full_feat_mask = self._feature_row(
+            np.ones(self.num_features, bool))
+        self._full_feat_masks = jnp.stack([self._full_feat_mask]
+                                          * self.num_class)
         # rebind the SHARED gradients program to this dataset's arrays
         # (no retrace unless the shapes changed — the labels are runtime
         # arguments now, not compile-time constants)
@@ -1356,8 +1379,14 @@ class GBDT:
         idx = self._feature_rng.choice(self.num_features, used, replace=False)
         mask = np.zeros(self.num_features, bool)
         mask[idx] = True
-        out = jnp.asarray(mask)
+        out = self._feature_row(mask)
         return out if screen is None else out & screen
+
+    def _feature_row(self, mask: np.ndarray) -> jax.Array:
+        """A per-feature mask as the device holds it: a bundled layout's
+        pad features (``_setup_bundle``) are never searched."""
+        return jnp.asarray(np.pad(
+            mask, (0, self._device_shape[1] - self.num_features)))
 
     def _feature_masks_all(self) -> jax.Array:
         """[num_class, F] per-class feature masks for the fused step (same
@@ -1418,11 +1447,7 @@ class GBDT:
                 [m.num_bin for m in self.train_set.mappers],
                 [m.default_bin for m in self.train_set.mappers],
                 self.config.max_bin)
-            self._identity_decode = BundleDecode(
-                col=jnp.asarray(dn["col"]), off=jnp.asarray(dn["off"]),
-                width=jnp.asarray(dn["width"]),
-                slot_map=jnp.asarray(dn["slot_map"]),
-                default_bin=jnp.asarray(dn["default_bin"]))
+            self._identity_decode = BundleDecode.from_tables(dn)
         return self._identity_decode
 
     def _build_active_view(self, cols: np.ndarray) -> Optional["_HistView"]:
@@ -1458,7 +1483,9 @@ class GBDT:
             # dropped features point at column 0; they are masked out of
             # the scan, so the junk expansion is never consulted
             pos[f] = pos_of.get(int(self._bundle_col_np[f]), 0)
-        bundle_act = base._replace(col=jnp.asarray(pos))
+        # (the column-space search's tables describe the full layout)
+        bundle_act = base._replace(col=jnp.asarray(pos), col_feat=None,
+                                   slot_feat=None, multi=None)
         obs.inc("screen_compactions_total")
         return _HistView(bins=bins_act, bins_rm=bins_rm_act,
                          bins_words=None, bundle=bundle_act)

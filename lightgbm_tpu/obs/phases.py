@@ -134,7 +134,21 @@ RANK_PHASES = (
     "gradients/rank_lambda",  # the pair kernel
 )
 
-DEVICE_PHASES = frozenset(ROUND_PHASES) | frozenset(RANK_PHASES) | frozenset({
+# What an EFB-bundled dataset adds to the leaf-ordered grower's round
+# (ops/ordered_grow.py with ``bundle``, ops/bundle.py); a round over
+# plain columns has neither.
+BUNDLE_PHASES = (
+    "split/decode",           # the split member's column, offset and
+                              # width, and its own bin from the column's
+                              # byte before the threshold compare
+    "find_split/columns",     # the search over original features where
+                              # they lie in the columns (root and
+                              # children) and its per-tree tables; takes
+                              # ``find_split``'s place
+)
+
+DEVICE_PHASES = frozenset(ROUND_PHASES) | frozenset(RANK_PHASES) \
+    | frozenset(BUNDLE_PHASES) | frozenset({
     # ops/grow.py (cached and parallel learners): the reference's three
     "hist",
     "split",
@@ -148,7 +162,8 @@ DEVICE_PHASES = frozenset(ROUND_PHASES) | frozenset(RANK_PHASES) | frozenset({
 })
 
 DEVICE_PARENT = {
-    **{p: "GBDT::tree" for p in ROUND_PHASES + RANK_PHASES},
+    **{p: "GBDT::tree"
+       for p in ROUND_PHASES + RANK_PHASES + BUNDLE_PHASES},
     "hist": "GBDT::tree",
     "split": "GBDT::tree",
     "bin_lookup": "Predict::forest",

@@ -27,14 +27,39 @@ This module owns that expansion plus the per-split bin decode:
   order change causes).
 - :func:`decode_feature_bins` — raw column bin -> original feature bin,
   used by the growers' partition step and the binned tree walk.
+- :func:`find_best_split_columns` — the leaf-ordered grower's split
+  search (ops/ordered_grow.py): ``ops/split.py find_best_split_sums``
+  over ORIGINAL features, computed where the features lie in the
+  columns.  The expansion gathers ``[F, 9, B]`` a child; on a one-hot
+  table of thousands of two-bin features that is a hundred times the
+  ``[C, B]`` the columns hold.  Three kinds of feature, each read in
+  place:
+
+  * an identity column IS its feature's histogram: its integer prefix
+    sums are the left sides;
+  * a two-bin member of a bundle has one candidate, "bin 0 goes left":
+    the right side is the member's one slot, the left the leaf's total
+    less it (every slot of every bundle column is scanned at once,
+    ``[C * B]`` candidates);
+  * a bundled member of more than two bins (``multi``) is expanded as
+    before, it alone (none on a one-hot table).
+
+  Sides, gains, constraints and the order of ties are ``ops/split.py``'s
+  own (``both_sides``, ``best_thresholds``, ``pick_split``), so the
+  result is ``find_best_split_sums`` over ``expand_digit_sums`` to the
+  bit, ties included (tests/test_bundling.py), and a zero-conflict
+  bundled run grows the unbundled run's trees.
 """
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+
+from .split import (BestSplit, SplitParams, best_thresholds, both_sides,
+                    candidate_gains, pick_split, prefix_sums, sums_totals)
 
 
 class BundleDecode(NamedTuple):
@@ -52,12 +77,30 @@ class BundleDecode(NamedTuple):
                           ZERO slot (index B) of the slot-padded column.
     default_bin: [F] i32  original bin reconstructed as
                           total - sum(non-default).
+
+    The column-space search's tables (``find_best_split_columns``; of
+    the FULL column layout, so a screener's compacted view drops them):
+
+    col_feat:    [C] i32  feature of an identity column, -1 for a
+                          bundle's column.
+    slot_feat:   [C, B] i32  the two-bin member whose non-default bin
+                          is this slot, -1 where there is none.
+    multi:       [M] i32  bundled members of more than two bins.
     """
     col: jax.Array
     off: jax.Array
     width: jax.Array
     slot_map: jax.Array
     default_bin: jax.Array
+    col_feat: Any = None
+    slot_feat: Any = None
+    multi: Any = None
+
+    @classmethod
+    def from_tables(cls, tables: dict) -> "BundleDecode":
+        """From ``io/bundling.py BundlePlan.decode_arrays``' numpy
+        tables."""
+        return cls(**{k: jnp.asarray(v) for k, v in tables.items()})
 
 
 def _slot_indices(dec: BundleDecode, lead_shape, tail: int):
@@ -146,3 +189,106 @@ def decode_feature_bins(bins, feat, dec: BundleDecode):
     # off == 0 marks identity-encoded features (their column stores the
     # original bin codes directly)
     return jnp.where(o > 0, decoded, raw)
+
+
+class ColumnSearch(NamedTuple):
+    """What ``find_best_split_columns`` needs of a tree that does not
+    change from split to split: per candidate feature of the three kinds
+    (identity columns, slots of bundle columns, multi members), the
+    feature it stands for (``F`` where it stands for none, which never
+    wins a tie) and that feature's ``num_bin`` / ``is_cat`` / mask.
+    Built once a tree by ``column_search``, outside the grow loop."""
+    feat: jax.Array          # [C + C * B + M] i32
+    num_bin: tuple           # per kind: [C], [C] (twos), [M]
+    is_cat: tuple
+    mask: tuple              # [C], [C, B] (a slot's own), [M]
+    multi: Any               # BundleDecode rows of the multi members
+
+
+def column_search(dec: BundleDecode, num_bin, is_cat,
+                  feat_mask) -> ColumnSearch:
+    F = dec.col.shape[0]
+    C = dec.col_feat.shape[0]
+    kinds = (dec.col_feat, dec.slot_feat, dec.multi)
+    feat = jnp.concatenate([jnp.where(k >= 0, k, F).reshape(-1)
+                            for k in kinds])
+    at = [jnp.maximum(k, 0) for k in kinds]
+    # the expansion's five tables, the multi members' rows of each
+    multi = BundleDecode(*(t[dec.multi] for t in dec[:5]))
+    return ColumnSearch(
+        feat=feat.astype(jnp.int32),
+        num_bin=(num_bin[at[0]], jnp.full((C,), 2, num_bin.dtype),
+                 num_bin[at[2]]),
+        is_cat=(is_cat[at[0]], jnp.zeros((C,), bool), is_cat[at[2]]),
+        mask=tuple(feat_mask[a] & (k >= 0) for a, k in zip(at, kinds)),
+        multi=multi)
+
+
+def find_best_split_columns(sums, scales, can_split, p: SplitParams,
+                            cs: ColumnSearch) -> BestSplit:
+    """The best split over ORIGINAL features of the leaves whose
+    column-space digit sums are ``sums`` (module docstring):
+    ``ops/split.py find_best_split_sums`` over ``expand_digit_sums``,
+    computed where the features lie.
+
+    Args:
+      sums: [..., C, 9, B] int32 digit sums in COLUMN space.
+      scales: the digits' scales (ops/leafhist.py).
+      can_split: [...] as ``find_best_split``.
+      cs: ``column_search``'s tables for this tree.
+    """
+    lead = sums.shape[:-3]
+    C, B = sums.shape[-3], sums.shape[-1]
+    total_g, total_h, _ = sums_totals(sums, scales)
+    gains, ts, sides = [], [], [[] for _ in range(6)]
+
+    def scanned(kind, left_int):
+        """Features with a histogram of their own: each one's best
+        threshold."""
+        left, right = both_sides(left_int, sums, scales)
+        bins = jax.lax.broadcasted_iota(jnp.int32, left_int.shape[-3::2], 1)
+        gain, t = best_thresholds(left, right, total_g, total_h, bins,
+                                  cs.num_bin[kind], cs.is_cat[kind],
+                                  cs.mask[kind], p)
+        gains.append(gain)
+        ts.append(t)
+        for out, x in zip(sides, left + right):
+            out.append(jnp.take_along_axis(x, t[..., None], axis=-1)[..., 0])
+
+    # identity columns: the column is the feature's histogram
+    scanned(0, jnp.where(cs.is_cat[0][:, None, None], sums,
+                         prefix_sums(sums)))
+    # a two-bin member at slot s of a bundle's column: its one other bin
+    # is the slot (the right side), bin 0 the rest of the leaf
+    # (FixHistogram, dataset.cpp:451-471, in exact integers).  Every slot
+    # of every column at once, where it lies: a candidate a slot,
+    # threshold 0
+    total = jnp.sum(sums[..., :1, :, :], axis=-1, keepdims=True)
+    left, right = both_sides(total - sums, sums, scales)
+    gains.append(candidate_gains(
+        left, right, total_g, total_h, jnp.zeros((C, B), jnp.int32),
+        cs.num_bin[1], cs.is_cat[1], cs.mask[1], p).reshape(lead + (C * B,)))
+    ts.append(jnp.zeros(lead + (C * B,), jnp.int32))
+    for out, x in zip(sides, left + right):
+        out.append(x.reshape(lead + (C * B,)))
+    if cs.multi.col.shape[0]:
+        scanned(2, prefix_sums(expand_digit_sums(sums, cs.multi)))
+    gain = jnp.concatenate(gains, axis=-1)                  # [..., K]
+    feat = cs.feat
+
+    # across features: the largest gain, ties to the smallest ORIGINAL
+    # feature (find_best_split's argmax over features in index order); a
+    # feature is one candidate, its threshold ties already settled
+    best_gain = jnp.max(gain, axis=-1)
+    best_f = jnp.min(jnp.where(gain == best_gain[..., None], feat,
+                               jnp.iinfo(jnp.int32).max), axis=-1)
+    k = jnp.argmax((gain == best_gain[..., None]) & (feat == best_f[..., None]),
+                   axis=-1)
+
+    def _at_k(arrs):
+        return jnp.take_along_axis(jnp.concatenate(arrs, axis=-1),
+                                   k[..., None], axis=-1)[..., 0]
+
+    return pick_split(best_gain, best_f, _at_k(ts),
+                      tuple(_at_k(x) for x in sides), total_g, total_h,
+                      can_split, p)

@@ -32,7 +32,8 @@ from ..obs.compile_ledger import instrumented_jit
 
 from .bundle import decode_feature_bins, expand_digit_sums, expand_histogram
 from .histogram import children_histograms, root_histogram
-from .split import (BestSplit, SplitParams, find_best_split, leaf_output,
+from .split import (BestSplit, SplitParams, find_best_split,
+                    find_best_split_sums, leaf_output, sums_totals,
                     K_MIN_SCORE)
 
 
@@ -65,7 +66,9 @@ class SerialComm(NamedTuple):
 
       reduce_sums((g, h, c))          -> globally-reduced leaf totals
       prepare(...)                    -> opaque per-tree state (closure data)
-      root_split(...)                 -> (BestSplit, histogram cache pytree)
+      root_split(...)                 -> (BestSplit, histogram cache pytree,
+                                          the root's (g, h, count) as the
+                                          strategy's histograms hold them)
       children_splits(...)            -> (BestSplit [2], updated cache)
 
     With ``leaf_cache=True`` (the default) the serial learner reproduces the
@@ -74,7 +77,10 @@ class SerialComm(NamedTuple):
     split over only that child's rows, and derive the sibling by
     subtraction.  The cache holds int32 fixed-point digit sums
     (ops/leafhist.py), so the subtraction is exact — stronger than the
-    reference's f64 accumulators (bin.h:25-27).  ``leaf_cache=False`` keeps
+    reference's f64 accumulators (bin.h:25-27) — and the split search
+    reads them as integers (ops/split.py ``find_best_split_sums``, the
+    leaf-ordered grower's search: the two grow the same trees to the
+    bit).  ``leaf_cache=False`` keeps
     the one-full-pass-per-split strategy (used by tests needing bit-parity
     with the distributed learners, which share that code path).
     """
@@ -110,7 +116,7 @@ class SerialComm(NamedTuple):
                 hist = expand_histogram(hist, bundle)
             split = find_best_split(hist, root_g, root_h, root_c, num_bin,
                                     is_cat, feat_mask, jnp.asarray(True), sp)
-            return split, ()
+            return split, (), (root_g, root_h, root_c)
         from . import leafhist
         F = bins.shape[0]
         sums = leafhist.digit_histogram(prep.bins_rm, prep.digits, max_bin)
@@ -121,12 +127,11 @@ class SerialComm(NamedTuple):
         # unbundled one (tests/test_bundling.py).
         scan_sums = (expand_digit_sums(sums, bundle)
                      if bundle is not None else sums)
-        hist = leafhist.combine_digit_sums(scan_sums, prep.scales)
-        split = find_best_split(hist, root_g, root_h, root_c, num_bin,
-                                is_cat, feat_mask, jnp.asarray(True), sp)
+        split = find_best_split_sums(scan_sums, prep.scales, num_bin, is_cat,
+                                     feat_mask, jnp.asarray(True), sp)
         cache = jnp.zeros((num_leaves, F, 9, max_bin), jnp.int32)
         cache = cache.at[0].set(sums)
-        return split, cache
+        return split, cache, sums_totals(sums, prep.scales)
 
     def children_splits(self, prep, cache, bins, g, h, w, step: _StepInfo,
                         totals_g, totals_h, totals_c, can,
@@ -179,9 +184,8 @@ class SerialComm(NamedTuple):
             scan_sums = jnp.stack([sums_left, sums_right])
             if bundle is not None:
                 scan_sums = expand_digit_sums(scan_sums, bundle)
-            hists = leafhist.combine_digit_sums(scan_sums, prep.scales)
-            split = find_best_split(hists, totals_g, totals_h, totals_c,
-                                    num_bin, is_cat, feat_mask, can, sp)
+            split = find_best_split_sums(scan_sums, prep.scales, num_bin,
+                                         is_cat, feat_mask, can, sp)
         return split, cache
 
 
@@ -271,6 +275,9 @@ class _GrowState(NamedTuple):
     best_left_g: jax.Array
     best_left_h: jax.Array
     best_left_c: jax.Array
+    best_right_g: jax.Array
+    best_right_h: jax.Array
+    best_right_c: jax.Array
     # per-leaf totals [L]
     total_g: jax.Array
     total_h: jax.Array
@@ -296,6 +303,9 @@ def _store_leaf_split(state: _GrowState, leaf, split: BestSplit) -> _GrowState:
         best_left_g=state.best_left_g.at[leaf].set(split.left_sum_g),
         best_left_h=state.best_left_h.at[leaf].set(split.left_sum_h),
         best_left_c=state.best_left_c.at[leaf].set(split.left_count),
+        best_right_g=state.best_right_g.at[leaf].set(split.right_sum_g),
+        best_right_h=state.best_right_h.at[leaf].set(split.right_sum_h),
+        best_right_c=state.best_right_c.at[leaf].set(split.right_count),
     )
 
 
@@ -348,10 +358,9 @@ def _grow_tree_impl(bins, num_bin, is_cat, feat_mask, grad, hess, row_weight,
         (jnp.sum(g), jnp.sum(h), jnp.sum(row_weight)))
 
     prep = comm.prepare(bins, bins_rm, g, h, row_weight, params)
-    root_split, cache0 = comm.root_split(prep, bins, g, h, row_weight,
-                                         root_g, root_h, root_c,
-                                         num_bin, is_cat, feat_mask, B, sp,
-                                         L, bundle=bundle)
+    root_split, cache0, (root_g, root_h, root_c) = comm.root_split(
+        prep, bins, g, h, row_weight, root_g, root_h, root_c, num_bin,
+        is_cat, feat_mask, B, sp, L, bundle=bundle)
 
     neg_inf = jnp.full((L,), K_MIN_SCORE, dtype=jnp.float32)
     state = _GrowState(
@@ -364,6 +373,9 @@ def _grow_tree_impl(bins, num_bin, is_cat, feat_mask, grad, hess, row_weight,
         best_left_g=jnp.zeros((L,), jnp.float32).at[0].set(root_split.left_sum_g),
         best_left_h=jnp.zeros((L,), jnp.float32).at[0].set(root_split.left_sum_h),
         best_left_c=jnp.zeros((L,), jnp.float32).at[0].set(root_split.left_count),
+        best_right_g=jnp.zeros((L,), jnp.float32).at[0].set(root_split.right_sum_g),
+        best_right_h=jnp.zeros((L,), jnp.float32).at[0].set(root_split.right_sum_h),
+        best_right_c=jnp.zeros((L,), jnp.float32).at[0].set(root_split.right_count),
         total_g=jnp.zeros((L,), jnp.float32).at[0].set(root_g),
         total_h=jnp.zeros((L,), jnp.float32).at[0].set(root_h),
         total_c=jnp.zeros((L,), jnp.float32).at[0].set(root_c),
@@ -409,16 +421,18 @@ def _grow_tree_impl(bins, num_bin, is_cat, feat_mask, grad, hess, row_weight,
             new_leaf_id = jnp.where(do_split & in_leaf & go_right,
                                     right_leaf, state.leaf_id)
 
-        # --- split sums ---------------------------------------------------
+        # --- split sums: both sides as the search held them (ops/split.py:
+        # the parent's total less the left side where the histogram is
+        # floats, the right side's own integers where it is digit sums) ----
         parent_g = state.total_g[best_leaf]
         parent_h = state.total_h[best_leaf]
         parent_c = state.total_c[best_leaf]
         left_g = state.best_left_g[best_leaf]
         left_h = state.best_left_h[best_leaf]
         left_c = state.best_left_c[best_leaf]
-        right_g = parent_g - left_g
-        right_h = parent_h - left_h
-        right_c = parent_c - left_c
+        right_g = state.best_right_g[best_leaf]
+        right_h = state.best_right_h[best_leaf]
+        right_c = state.best_right_c[best_leaf]
         left_val = leaf_output(left_g, left_h, sp.lambda_l1, sp.lambda_l2)
         right_val = leaf_output(right_g, right_h, sp.lambda_l1, sp.lambda_l2)
 
@@ -498,7 +512,10 @@ def _grow_tree_impl(bins, num_bin, is_cat, feat_mask, grad, hess, row_weight,
                       new_state.best_bin[best_leaf],
                       new_state.best_left_g[best_leaf],
                       new_state.best_left_h[best_leaf],
-                      new_state.best_left_c[best_leaf]),
+                      new_state.best_left_c[best_leaf],
+                      new_state.best_right_g[best_leaf],
+                      new_state.best_right_h[best_leaf],
+                      new_state.best_right_c[best_leaf]),
             left_rec)
         new_state = _store_leaf_split(new_state, best_leaf, store_left)
         store_right = jax.tree.map(
@@ -508,7 +525,10 @@ def _grow_tree_impl(bins, num_bin, is_cat, feat_mask, grad, hess, row_weight,
                       new_state.best_bin[right_leaf],
                       new_state.best_left_g[right_leaf],
                       new_state.best_left_h[right_leaf],
-                      new_state.best_left_c[right_leaf]),
+                      new_state.best_left_c[right_leaf],
+                      new_state.best_right_g[right_leaf],
+                      new_state.best_right_h[right_leaf],
+                      new_state.best_right_c[right_leaf]),
             right_rec)
         new_state = _store_leaf_split(new_state, right_leaf, store_right)
         return new_state, cache

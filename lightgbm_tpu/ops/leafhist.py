@@ -124,9 +124,23 @@ def digit_row_counts(halves_i32):
             + halves_i32[..., NUM_STREAMS + 6, :] // unit)
 
 
-def combine_digit_sums(sums_i32, scales):
+def canonical_halves(halves_i32):
+    """``split_halves`` sums [..., 18, B] with every low half carried
+    back into [0, 65536) (whole sums [..., 9, B] pass through).  Sums
+    and differences of halves leave the low ones anywhere in int32, and
+    ``combine_digit_sums``' one rounding holds only while they stay
+    under 2^24: the difference of two sets of halves does, a prefix sum
+    over 255 bins of four shards' does not."""
+    if halves_i32.shape[-2] != 2 * NUM_STREAMS:
+        return halves_i32
+    high = halves_i32[..., :NUM_STREAMS, :]
+    low = halves_i32[..., NUM_STREAMS:, :]
+    return jnp.concatenate([high + (low >> 16), low & 0xFFFF], axis=-2)
+
+
+def combine_digit_streams(sums_i32, scales):
     """int32 digit sums [..., 9, B], or their ``split_halves``
-    [..., 18, B], -> f32 histogram [..., B, 3].
+    [..., 18, B], -> the three f32 streams (g, h, count), each [..., B].
 
     Exact up to one f32 rounding per entry: the digit sums themselves are
     exact integers (of halves, both terms are exact floats and their sum
@@ -140,7 +154,12 @@ def combine_digit_sums(sums_i32, scales):
                + s[..., 3 * v + 1, :] * _DIGIT_W[1]
                + s[..., 3 * v + 2, :] * _DIGIT_W[2])
         out.append(acc * (scales[v] / float(1 << QBITS)))
-    return jnp.stack(out, axis=-1)
+    return tuple(out)
+
+
+def combine_digit_sums(sums_i32, scales):
+    """``combine_digit_streams`` as one f32 histogram [..., B, 3]."""
+    return jnp.stack(combine_digit_streams(sums_i32, scales), axis=-1)
 
 
 # ---------------------------------------------------------------------------

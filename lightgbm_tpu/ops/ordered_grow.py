@@ -42,11 +42,20 @@ contiguous).  The partition is two-way on one bit, "goes left": the
 window's suffix beyond the segment and every row of a rejected split are
 "other" rows, and a stable two-way partition leaves both where they were
 (the suffix IS the tail of the window).  The lane packing assumes uint8
-bins (max_bin <= 256) and carries no column decode: ``accepts`` is that
-rule, and whoever chooses a grower (models/gbdt.py, parallel/grow.py)
-asks it here.  The layout itself is this module's business too: others
-take the lane pad of a row count from ``lane_pad`` and the padded bin
-word lanes of a dataset, whole or one block a shard, from
+bins (max_bin <= 256): ``accepts`` is that rule, and whoever chooses a
+grower (models/gbdt.py, parallel/grow.py) asks it here.  EFB-bundled
+columns (``bundle``) ride the same lanes: histograms, the cache and the
+sibling subtraction stay in COLUMN space, ``[L, C, 9, B]``, which is
+where bundling's saving lives; the split member's byte is decoded by its
+offset before the threshold compare (``split/decode``), and the search
+runs over original features where they lie in the columns
+(``find_split/columns``, ops/bundle.py ``find_best_split_columns``).
+Bundled or not, the search reads the integer sums (ops/split.py
+``find_best_split_sums``) and the children's totals are its record's
+two sides, so this grower, its shards under a mesh and the cached
+grower of ops/grow.py grow the same trees to the bit.  The layout
+itself is this module's business too: others take the lane pad of a
+row count from ``lane_pad`` and the padded bin word lanes of a dataset, whole or one block a shard, from
 ``pack_word_lanes``.
 
 Alternatives measured and rejected on TPU (tools/probe_partition.py;
@@ -85,14 +94,18 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..obs.compile_ledger import instrumented_jit
-from ..utils import device
+from ..utils import compile_cache, device
 from . import leafhist, partition
+from .bundle import column_search, find_best_split_columns
 from .grow import GrowParams, TreeArrays
-from .split import BestSplit, find_best_split, leaf_output, K_MIN_SCORE
+from .split import (K_MIN_SCORE, find_best_split_sums, leaf_output,
+                    sums_totals)
 
 # Column layout of the packed per-leaf / per-node state buffers.
 _LF = dict(best_gain=0, best_left_g=1, best_left_h=2, best_left_c=3,
-           total_g=4, total_h=5, total_c=6, cur_value=7)
+           total_g=4, total_h=5, total_c=6, cur_value=7,
+           best_right_g=8, best_right_h=9, best_right_c=10)
+_LFW = 16                               # the float table's row, padded
 _LI = dict(best_feat=0, best_bin=1, parent=2, depth=3, start=4, cnt=5,
            rows=6, best_left_rows=7)    # the last two: sharded growth only
 _ND = dict(feature=0, bin=1, gain=2, left=3, right=4, value=5, count=6)
@@ -121,11 +134,39 @@ def _size_classes(n: int, smallest: int = 8192):
 
 def accepts(bins_dtype, column_decode: bool = False) -> bool:
     """Whether the leaf-ordered grower can grow this data: uint8 bins
-    (the i32 lanes hold four bin codes a word) read without a column
-    decode (EFB bundles and screening's compacted views decode the split
-    column, which the packed lanes do not carry).  Everything else grows
-    on ops/grow.py."""
+    (the i32 lanes hold four bin codes a word) without a column decode
+    that the lanes do not carry.  The dataset's own EFB layout they do
+    carry (``bundle``); a screener's compacted view, which changes from
+    period to period, and EFB columns under a learner's mesh (the
+    exchange is in feature space) they do not.  Everything else grows on
+    ops/grow.py."""
     return bins_dtype == jnp.uint8 and not column_decode
+
+
+# Mantissa bits an EFB layout's count of bin WORDS keeps on its way up
+# the shared ladder (utils/compile_cache.py bucket_rows): whole words up
+# to 8, even counts to 16, multiples of 4 to 32 (64 to 128 columns in
+# steps of 16), of 8 to 64.
+BUNDLE_WORD_BITS = 3
+
+
+def bundled_shape(columns: int, features: int):
+    """``(columns, features)`` of an EFB layout on the device: the
+    plan's own, rounded up a ladder.  How many columns a plan makes, and
+    how many features keep more than one bin, follow from the rows that
+    were drawn: the one-hot cell's table gives 71 to 74 columns from
+    seed to seed (PERF.md, PR 36), a fold or a day's refresh of a user's
+    table likewise, and every count was a ``train_step`` of its own, 260
+    to 310 s of compile where the cache serves a known shape in 35.  So
+    the layout is padded as the rows are (``bucket_rows``): columns by
+    the bin word, ``BUNDLE_WORD_BITS`` bits kept, features by the rows'
+    own rule.  A pad column holds bin 0 on every row and no feature, a
+    pad feature lies in no slot and is masked: neither can be split on,
+    and the sums of the real ones are untouched.  What it costs is the
+    pad words' share of the histogram and partition lanes (PERF.md
+    section 6, PR 36: the round at 18 and at 20 words)."""
+    words = compile_cache.bucket_rows(-(-columns // 4), BUNDLE_WORD_BITS)
+    return 4 * words, compile_cache.bucket_rows(features)
 
 
 def lane_pad(n: int) -> int:
@@ -146,37 +187,50 @@ def pack_u8_words(x_u8):
     return tuple(words[:, i] for i in range(w))
 
 
-def _word_lanes(rm):
-    pad = lane_pad(rm.shape[0])
-    return tuple(jnp.pad(w, (0, pad)) for w in pack_u8_words(rm))
+def _word_lanes(bins):
+    """[C, N] u8 columns -> ceil(C/4) padded [N + lane_pad] i32 lanes,
+    column 4w + b the word's byte b (low byte first: ``pack_u8_words``'
+    bitcast of four bytes).  From the COLUMNS, a word four shifted rows
+    of them ORed: from the row-major matrix the lanes were strided
+    slices of its relayout, 37 MB of code and 70 s of compile at 80
+    columns x 12.6M rows for what is 3.5 MB and 3 s here (sandbox
+    compile, PR 36; PERF.md section 6)."""
+    c, n = bins.shape
+    pad = lane_pad(n)
+    lanes = []
+    for w in range(-(-c // 4)):
+        word = bins[4 * w].astype(jnp.uint32)
+        for b in range(1, min(4, c - 4 * w)):
+            word = word | (bins[4 * w + b].astype(jnp.uint32) << (8 * b))
+        lanes.append(jnp.pad(
+            jax.lax.bitcast_convert_type(word, jnp.int32), (0, pad)))
+    return tuple(lanes)
 
 
 # module-level, so that boosters over the same shapes share ONE compiled
 # program.  (Both programs keep the names earlier versions compiled them
 # under: a name is part of the persistent compile cache's key.)
 @instrumented_jit(program="pack_words")
-def _pack_words_padded(rm):
-    return _word_lanes(rm)
+def _pack_words_padded(bins):
+    return _word_lanes(bins)
 
 
-def pack_word_lanes(bins_rm, mesh=None):
+def pack_word_lanes(bins, mesh=None):
     """The padded bin word lanes ``grow_tree_ordered`` takes as
-    ``bins_words``, from the [N, F] row-major uint8 matrix, once a
-    dataset.  With ``mesh`` (rows of ``bins_rm`` in one block a device of
+    ``bins_words``, from the [C, N] column-major uint8 matrix, once a
+    dataset.  With ``mesh`` (rows of ``bins`` in one block a device of
     its first axis) each device packs its own rows and pads them by its
     own ``lane_pad``, so block ``i`` of every returned
     ``[k * (N/k + PAD)]`` lane is what shard ``i`` grows from.  That
-    program is this call's own and is released with it: kept, its 26 MiB
-    of code (10.5M rows a shard) stay on every chip through training
-    (PERF.md, PR 32)."""
+    program is this call's own and is released with it (PERF.md, PR 32)."""
     if mesh is None:
-        return _pack_words_padded(bins_rm)
+        return _pack_words_padded(bins)
     axis = mesh.axis_names[0]
 
-    def pack(rm):
-        return jax.shard_map(_word_lanes, mesh=mesh, in_specs=P(axis, None),
-                             out_specs=P(axis))(rm)
-    return instrumented_jit(pack, program="pack_words")(bins_rm)
+    def pack(cm):
+        return jax.shard_map(_word_lanes, mesh=mesh, in_specs=P(None, axis),
+                             out_specs=P(axis))(cm)
+    return instrumented_jit(pack, program="pack_words")(bins)
 
 
 def _unpack_words(cols, c: int):
@@ -207,7 +261,8 @@ def _put_row(buf, i, vec):
                   static_argnames=("params", "exchange"))
 def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
                       row_weight, learning_rate, params: GrowParams,
-                      bins_rm=None, bins_words=None, exchange=None):
+                      bins_rm=None, bins_words=None, exchange=None,
+                      bundle=None):
     """Drop-in replacement for ops.grow.grow_tree.
 
     Args/returns: see grow_tree.  ``bins_words`` (tuple of ceil(F/4) [N]
@@ -238,11 +293,22 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
     thresholds, counts and ``min_data_in_leaf`` are the serial
     learner's; segment starts and counts, lanes, partitions and the leaf
     reconstruction stay local.  With ``exchange=None`` nothing below
-    differs from the serial program."""
+    differs from the serial program.
+
+    ``bundle`` (ops/bundle.py ``BundleDecode`` with its column-space
+    tables): ``bins`` is the EFB column matrix ``[C, N]`` while
+    ``num_bin``, ``is_cat``, ``feat_mask`` and the tree stay in original
+    feature space.  Serial growth only."""
     L = params.num_leaves
     B = params.max_bin
-    F, N = bins.shape
+    F, N = bins.shape           # F: COLUMNS (the features, unbundled)
     sp = params.split_params()
+    assert bundle is None or exchange is None, \
+        "EFB columns under a mesh grow on ops/grow.py"
+    if bundle is not None:
+        with jax.named_scope("find_split/columns"):
+            # what the search needs of this tree, once, outside the loop
+            searched = column_search(bundle, num_bin, is_cat, feat_mask)
 
     # Every operation below sits under exactly one leaf phase of
     # obs/phases.py ROUND_PHASES (the innermost scope wins): the scopes
@@ -257,10 +323,6 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
         g = grad * row_weight
         h = hess * row_weight
 
-        root_g = jnp.sum(g)
-        root_h = jnp.sum(h)
-        root_c = jnp.sum(row_weight)
-
     classes = _size_classes(N)
     PAD = classes[-1]          # windows may overrun the last segment
     W = len(bins_words)
@@ -268,8 +330,9 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
     with jax.named_scope("layout"):
         scales = leafhist.compute_scales(g, h, row_weight)
     if exchange is not None:
-        scales, (root_g, root_h, root_c), root_rows = exchange.root(
-            scales, (root_g, root_h, root_c))
+        with jax.named_scope("gradients"):
+            shard_rows = jnp.sum(row_weight)
+        scales, root_rows = exchange.root(scales, shard_rows)
     with jax.named_scope("layout"):
         digits = leafhist.quantize_digits(g, h, row_weight,
                                           scales)       # [N, 9] i8
@@ -283,14 +346,16 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
         dig_w = tuple(jnp.pad(dw, (0, PAD)) for dw in pack_u8_words(
             jax.lax.bitcast_convert_type(digits, jnp.uint8)))
         DW = len(dig_w)
-        if exchange is None:
+        if exchange is None and bundle is None:
             row_ord = jnp.pad(jnp.arange(N, dtype=jnp.int32), (0, PAD))
         else:
             # the same lane with the pad numbered on (nothing reads a row
             # id past N): the chip's compiler folds the padded iota into
             # a constant of the lane's size, 109 MB of the executable at
-            # 10.5M rows (sandbox compile, PR 30; ROADMAP S5 has the
-            # serial program's)
+            # 10.5M rows (sandbox compile, PR 30) and 117 of 286 at the
+            # one-hot cell's 12.6M (PR 36), more than the machine's
+            # compile cache keeps; ROADMAP S5 has the plain serial
+            # program's, whose text is left as it is
             row_ord = jnp.arange(N + PAD, dtype=jnp.int32)
 
     if params.compact_inactive:
@@ -370,8 +435,6 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
     if exchange is not None:
         sums_root_local = sums_root
         sums_root = exchange.hist(sums_root, root=True)
-    with jax.named_scope("hist/root"):
-        hist_root = leafhist.combine_digit_sums(sums_root, scales)
     def left_rows(sums, feature, threshold):
         """The split's left count as an integer, from the exchanged sums'
         weight stream (a float32 running count is exact only to 2^24
@@ -383,10 +446,22 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
         left = jnp.where(is_cat[f], b == threshold, b <= threshold)
         return jnp.sum(jnp.where(left, col, 0))
 
+    def find_split(sums, can):
+        """ops/split.py's search from integer sums, over the features as
+        they lie: plain columns, or an EFB layout's (ops/bundle.py)."""
+        if bundle is None:
+            return find_best_split_sums(sums, scales, num_bin, is_cat,
+                                        feat_mask, can, sp)
+        with jax.named_scope("find_split/columns"):
+            return find_best_split_columns(sums, scales, can, sp, searched)
+
     with jax.named_scope("find_split"):
-        root_split = find_best_split(hist_root, root_g, root_h, root_c,
-                                     num_bin, is_cat, feat_mask,
-                                     jnp.asarray(True), sp)
+        # the root's totals from its own integer sums, as every child's
+        # are its parent's split record's: a float32 sum of 12M
+        # gradients, and a parent's total less a child's, lose what a
+        # one-hot split's small side is made of (ops/split.py)
+        root_g, root_h, root_c = sums_totals(sums_root, scales)
+        root_split = find_split(sums_root, jnp.asarray(True))
         if exchange is not None:
             root_left_rows = left_rows(sums_root, root_split.feature,
                                        root_split.threshold)
@@ -399,12 +474,20 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
             .at[0].set(sums_root_local))
 
     with jax.named_scope("leaf_table"):
-        root_f32 = jnp.stack([
-            root_split.gain, root_split.left_sum_g, root_split.left_sum_h,
-            root_split.left_count, root_g, root_h, root_c,
-            jnp.float32(0.0)])
-        leaf_f32 = jnp.full((L, 8), K_MIN_SCORE, jnp.float32) \
-            .at[:, 1:].set(0.0).at[0].set(root_f32)
+        def leaf_floats(split, ci, tot_g, tot_h, tot_c, val):
+            """A leaf's row of the float table: child ``ci`` of
+            ``split``'s batch (``None``: the root's, unbatched)."""
+            at = (lambda x: x) if ci is None else (lambda x: x[ci])
+            return jnp.stack(
+                [at(split.gain), at(split.left_sum_g), at(split.left_sum_h),
+                 at(split.left_count), tot_g, tot_h, tot_c, val,
+                 at(split.right_sum_g), at(split.right_sum_h),
+                 at(split.right_count)]
+                + [jnp.float32(0.0)] * (_LFW - len(_LF)))
+
+        leaf_f32 = jnp.full((L, _LFW), K_MIN_SCORE, jnp.float32) \
+            .at[:, 1:].set(0.0).at[0].set(leaf_floats(
+                root_split, None, root_g, root_h, root_c, jnp.float32(0.0)))
         root_i32 = jnp.array([0, 0, -1, 0, 0, 0, 0, 0], jnp.int32) \
             .at[_LI["best_feat"]].set(root_split.feature) \
             .at[_LI["best_bin"]].set(root_split.threshold) \
@@ -419,7 +502,10 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
 
     def make_branch(P: int):
         def branch(ops):
-            (bins_w, dig_w, row_ord, s, c, feat, tbin, cat, do_split) = ops
+            # ``feat``: the split feature's COLUMN; ``decode``: its offset
+            # and width there, of a bundle's member (else empty)
+            (bins_w, dig_w, row_ord, s, c, feat, tbin, cat, do_split,
+             *decode) = ops
             # the reference's split phase (serial_tree_learner.cpp:
             # 10-37), divided where its device time divides
             with jax.named_scope("split/window_read"):
@@ -438,6 +524,17 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
                 for i in range(1, W):
                     col32 = jnp.where(word == i, win_b[i], col32)
                 fcol = (col32 >> (8 * byte)) & 0xFF
+            if decode:
+                # the member's own bin from its column's byte
+                # (ops/bundle.py decode_feature_bins): slots off to
+                # off + width - 1 are its bins 1 to width, every other
+                # slot its bin 0; off 0 marks an identity column
+                with jax.named_scope("split/decode"):
+                    off, width = decode
+                    mine = (fcol >= off) & (fcol < off + width)
+                    fcol = jnp.where(
+                        off > 0, jnp.where(mine, fcol - off + 1, 0), fcol)
+            with jax.named_scope("split/key"):
                 go_r = jnp.where(cat, fcol != tbin, fcol > tbin)
                 iota = jnp.arange(P, dtype=jnp.int32)
                 inseg = iota < c
@@ -506,9 +603,9 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
         stopped = ~do_split
         right_leaf = num_leaves
 
-        rb_f = _row(leaf_f32, best_leaf, 8)
+        rb_f = _row(leaf_f32, best_leaf, _LFW)
         rb_i = _row(leaf_i32, best_leaf, 8)
-        rr_f = _row(leaf_f32, right_leaf, 8)
+        rr_f = _row(leaf_f32, right_leaf, _LFW)
         rr_i = _row(leaf_i32, right_leaf, 8)
 
         feat = jnp.maximum(rb_i[_LI["best_feat"]], 0)
@@ -521,6 +618,10 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
         cls = jnp.minimum(jnp.sum(c > sizes_arr).astype(jnp.int32),
                           len(branches) - 1)
         scalars = (s, c, feat, tbin, is_cat[feat], do_split)
+        if bundle is not None:
+            with jax.named_scope("split/decode"):
+                scalars = (s, c, bundle.col[feat], tbin, is_cat[feat],
+                           do_split, bundle.off[feat], bundle.width[feat])
         # NOT a lax.switch: under an N-way conditional the chip's compiler
         # copies every lane whole, per branch and per step (module
         # docstring); a chain of two-way conds writes the lanes in place
@@ -536,16 +637,14 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
         # entered around the fori_loop below)
 
         with jax.named_scope("leaf_table"):
-            # --- split sums (exact reference decomposition) -------------
-            parent_g = rb_f[_LF["total_g"]]
-            parent_h = rb_f[_LF["total_h"]]
+            # --- split sums: both sides as the search held them --------
             parent_c = rb_f[_LF["total_c"]]
             left_g = rb_f[_LF["best_left_g"]]
             left_h = rb_f[_LF["best_left_h"]]
             left_c = rb_f[_LF["best_left_c"]]
-            right_g = parent_g - left_g
-            right_h = parent_h - left_h
-            right_c = parent_c - left_c
+            right_g = rb_f[_LF["best_right_g"]]
+            right_h = rb_f[_LF["best_right_h"]]
+            right_c = rb_f[_LF["best_right_c"]]
             left_val = leaf_output(left_g, left_h, sp.lambda_l1,
                                    sp.lambda_l2)
             right_val = leaf_output(right_g, right_h, sp.lambda_l1,
@@ -609,15 +708,11 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
             caches = (cache, local)
 
         with jax.named_scope("find_split"):
-            hists = leafhist.combine_digit_sums(
-                jnp.stack([sums_left, sums_right]), scales)
             child_depth_ok = jnp.logical_or(params.max_depth <= 0,
                                             depth + 1 < params.max_depth)
-            can = jnp.stack([do_split & child_depth_ok] * 2)
-            child_split = find_best_split(
-                hists, jnp.stack([left_g, right_g]),
-                jnp.stack([left_h, right_h]), jnp.stack([left_c, right_c]),
-                num_bin, is_cat, feat_mask, can, sp)
+            child_split = find_split(
+                jnp.stack([sums_left, sums_right]),
+                jnp.stack([do_split & child_depth_ok] * 2))
             best_rows = (jnp.int32(0),) * 2 if exchange is None else tuple(
                 left_rows(sums, child_split.feature[ci],
                           child_split.threshold[ci])
@@ -625,10 +720,7 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
 
         with jax.named_scope("leaf_table"):
             def leaf_rows(ci, tot_g, tot_h, tot_c, val, seg_s, seg_c):
-                f32 = jnp.stack([
-                    child_split.gain[ci], child_split.left_sum_g[ci],
-                    child_split.left_sum_h[ci], child_split.left_count[ci],
-                    tot_g, tot_h, tot_c, val])
+                f32 = leaf_floats(child_split, ci, tot_g, tot_h, tot_c, val)
                 i32 = jnp.stack([
                     child_split.feature[ci], child_split.threshold[ci],
                     node, depth + 1, seg_s, seg_c, rows_lr[ci],
@@ -701,7 +793,8 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
             pval, pleaf = predict_binned_tree(
                 tree.split_feature, tree.split_bin,
                 is_cat[jnp.maximum(tree.split_feature, 0)],
-                tree.left_child, tree.right_child, shrunk, bins, L)
+                tree.left_child, tree.right_child, shrunk, bins, L,
+                bundle=bundle)
             active = row_weight > 0.0
             leaf_id = jnp.where(active, leaf_id, pleaf)
             output_delta = jnp.where(active, output_delta, pval)

@@ -167,9 +167,9 @@ class HistExchange(NamedTuple):
     under ``jax.shard_map``): the root's scales and sums once a tree,
     and one digit-sum histogram a split.  Integer sums are exact and
     order-free, so the summed histograms are the serial learner's on the
-    same rows to the bit; only the root's float32 sums depend on the
-    order of the shards.  Segments, row lanes, sorts and the score update
-    never leave a shard.
+    same rows to the bit, and every total the grower holds is taken from
+    them (ops/split.py ``sums_totals``).  Segments, row lanes, sorts and
+    the score update never leave a shard.
 
     Each call sits under its own leaf phase (obs/phases.py
     ``exchange/root``, ``exchange/hist``): a collective's device time
@@ -179,17 +179,15 @@ class HistExchange(NamedTuple):
     axis_name: str = "data"
     num_shards: int = 1
 
-    def root(self, scales, sums):
+    def root(self, scales, shard_rows):
         """Global quantisation scales (a max over shards, so all shards
-        cut the same digits), the root's <sum_g, sum_h, count> and its
-        rows as an integer: float32 holds a count exactly only up to
+        cut the same digits) and the root's rows as an integer, from each
+        shard's float count: float32 holds a count exactly only up to
         2^24 rows, which one shard keeps under (ops/leafhist.py) and
         four together do not."""
         with jax.named_scope("exchange/root"):
-            total = lax.psum(jnp.stack(sums), self.axis_name)
-            rows = jnp.round(sums[2]).astype(jnp.int32)
+            rows = jnp.round(shard_rows).astype(jnp.int32)
             return (lax.pmax(scales, self.axis_name),
-                    tuple(total[i] for i in range(len(sums))),
                     lax.psum(rows, self.axis_name))
 
     def hist(self, sums_i32, root: bool = False):
@@ -212,10 +210,10 @@ class HistExchange(NamedTuple):
         """Static per-tree account: one all-reduce of digit-sum halves
         for the root and one a split step (``num_leaves`` in all; the
         loop's trip count is fixed, so saturated steps still exchange),
-        plus the root's 3 scales, 3 sums and its integer row count."""
+        plus the root's 3 scales and its integer row count."""
         hist_b = num_features * 2 * leafhist.NUM_STREAMS * max_bin * 4
         return _traffic(pmax=(1, 3 * 4),
-                        psum=(2 + num_leaves, 4 * 4 + hist_b * num_leaves))
+                        psum=(1 + num_leaves, 4 + hist_b * num_leaves))
 
 
 class DataParallelComm(NamedTuple):
@@ -266,7 +264,8 @@ class DataParallelComm(NamedTuple):
         hist = root_histogram(bins, g, h, w, max_bin)
         return self._split_from_hist(hist, root_g, root_h, root_c,
                                      jnp.asarray(True), num_bin, is_cat,
-                                     feat_mask, sp, bundle=bundle), ()
+                                     feat_mask, sp, bundle=bundle), (), \
+            (root_g, root_h, root_c)
 
     def children_splits(self, prep, cache, bins, g, h, w, step,
                         totals_g, totals_h, totals_c, can,
@@ -350,14 +349,16 @@ class FeatureParallelComm(NamedTuple):
                                     is_cat, feat_mask & owned,
                                     jnp.asarray(True), sp)
             return _allgather_combine(local, self.axis_name,
-                                      self.num_shards), ()
+                                      self.num_shards), (), \
+                (root_g, root_h, root_c)
         offset, nb, ic, fm = self._local_meta(num_bin, is_cat, feat_mask)
         bins_blk = lax.dynamic_slice_in_dim(bins, offset, self.f_block, axis=0)
         hist = root_histogram(bins_blk, g, h, w, max_bin)
         local = find_best_split(hist, root_g, root_h, root_c, nb, ic, fm,
                                 jnp.asarray(True), sp)
         local = _offset_features(local, offset)
-        return _allgather_combine(local, self.axis_name, self.num_shards), ()
+        return _allgather_combine(local, self.axis_name, self.num_shards), \
+            (), (root_g, root_h, root_c)
 
     def children_splits(self, prep, cache, bins, g, h, w, step,
                         totals_g, totals_h, totals_c, can,
@@ -525,7 +526,8 @@ class VotingParallelComm(NamedTuple):
             hist[None], jnp.asarray([root_g]), jnp.asarray([root_h]),
             jnp.asarray([root_c]), jnp.asarray([True]),
             num_bin, is_cat, feat_mask, sp)
-        return jax.tree.map(lambda f: f[0], best), ()
+        return jax.tree.map(lambda f: f[0], best), (), \
+            (root_g, root_h, root_c)
 
     def children_splits(self, prep, cache, bins, g, h, w, step,
                         totals_g, totals_h, totals_c, can,

@@ -2,8 +2,11 @@
 
 The acceptance contract of the wide-sparse subsystem:
   * zero-conflict bundling trains BIT-IDENTICAL models to unbundled
-    training on the same data (the integer digit-sum expansion makes
-    this exact, ops/bundle.py),
+    training on the same data: bundling changes no integer digit sum, and
+    every grower with a histogram cache searches those integers with one
+    arithmetic (ops/split.py ``find_best_split_sums``; the leaf-ordered
+    grower's column-space form of it and the cached learner's expansion,
+    ops/bundle.py),
   * ``max_conflict_rate=0`` on dense data is a no-op (no bundles, plain
     layout, baseline bit-match by construction),
   * a bundled-trained model lives entirely in ORIGINAL feature space:
@@ -46,9 +49,10 @@ def one_hot_data(n=2500, blocks=8, block_size=6, seed=0, act=0.7,
 
 
 def train_gbdt(X, y, *, enable_bundle, iters=6, extra=None, max_bin=63):
-    """Bundled data grows on the cached learner, unbundled data
-    leaf-ordered (the booster chooses from the data), so every
-    bit-identity asserted below is also the growers' parity."""
+    """Bundled and unbundled data both grow leaf-ordered (the booster
+    chooses from the data; PR 36), the bundled columns decoded at the
+    split and searched in column space, so every bit-identity asserted
+    below is also that search's parity with the plain one."""
     p = {"objective": "binary", "num_leaves": 15, "min_data_in_leaf": 20,
          "min_sum_hessian_in_leaf": 1e-3,
          "max_bin": max_bin, "num_iterations": iters}
@@ -158,13 +162,25 @@ def test_zero_conflict_bundled_training_bit_identical():
 
 
 def test_default_grow_bundled_matches_unbundled_ordered():
-    # bundled data grows on the cached learner, unbundled leaf-ordered;
-    # exact cross-grower parity keeps the models bit-identical
+    # both grow leaf-ordered; with a screener the bundled data goes back
+    # to the cached learner: exact cross-grower parity keeps all three
+    # models bit-identical until the screener's first mask
     X, y = one_hot_data(seed=1)
     b0, _ = train_gbdt(X, y, enable_bundle=False)
     b1, _ = train_gbdt(X, y, enable_bundle=True)
-    assert (b0._grower_kind, b1._grower_kind) == ("ordered", "cached")
+    assert (b0._grower_kind, b1._grower_kind) == ("ordered", "ordered")
+    assert "EFB columns decoded at the split" in b1._choose_grower()[1]
     assert b1.save_model_to_string() == b0.save_model_to_string()
+    b2, _ = train_gbdt(X, y, enable_bundle=True,
+                       extra={"feature_screen_ratio": 0.5,
+                              "feature_screen_warmup": 100})
+    assert b2._grower_kind == "cached"
+    assert "screening" in b2._choose_grower()[1]
+    # the cached learner expands the integer sums and searches them as
+    # the plain columns are searched: bit for bit the unbundled model
+    trees = lambda b: b.save_model_to_string().split("parameters")[0] \
+        .split("feature importances")[0]
+    assert trees(b2) == trees(b0)
 
 
 def test_goss_and_dart_compose_with_bundling():
@@ -357,3 +373,180 @@ def test_bench_regress_passes_sparse_keys_through():
            "unit": "iters/sec"}
     v2 = mod.compare(old, cand, threshold_pct=5.0)
     assert v2["ok"] and "efb_baseline" not in v2
+
+
+# ---------------------------------------------------------------------------
+# the column-space split search (ops/bundle.py, PR 36)
+# ---------------------------------------------------------------------------
+
+def _random_layout(rng, B=63, last=(2, 2)):
+    """A bundle plan over features of mixed bin counts: identity
+    columns (one categorical), bundles of two-bin members, a bundle with
+    many-bin members; ``last``: the bin counts of the last bundle's."""
+    num_bins, cols, offs, is_cat = [], [], [], []
+    def column(member_bins):
+        members, o, off = [], 1, []
+        for nb in member_bins:
+            members.append(len(num_bins))
+            num_bins.append(nb)
+            is_cat.append(False)
+            off.append(o)
+            o += nb - 1
+        assert o <= B
+        cols.append(members)
+        offs.append(off)
+    for nb in (int(rng.randint(2, B)), B, 5):           # identity columns
+        cols.append([len(num_bins)])
+        offs.append([0])
+        num_bins.append(nb)
+        is_cat.append(nb == 5)
+    column([2] * 40)
+    column([2] * 7 + [6, 2, 9] + [2] * 5)
+    column(list(last))
+    plan = BundlePlan(cols, offs, len(num_bins))
+    return plan, np.asarray(num_bins, np.int32), np.asarray(is_cat)
+
+
+@pytest.mark.parametrize("case", ["random", "ties", "masked", "hessian_floor",
+                                  "unsplittable", "padded"])
+def test_column_space_search_is_the_expanded_search(case):
+    """``find_best_split_columns`` on the bundled columns against
+    ``find_best_split_sums`` over ``expand_digit_sums``' ``[F, 9, B]`` on
+    random digit sums of a batch of two leaves: the same gain, feature,
+    threshold and sums of both sides, bit for bit (the sums are exact
+    integers either way); and against ``find_best_split`` over the
+    expanded float32 histograms: the same feature and threshold wherever
+    no other candidate comes within float32's rounding of the best.  ``ties`` repeats members' slots, so
+    that equal gains meet across the three kinds of feature and the
+    lowest original feature must win.  ``padded`` searches the layout as
+    the device holds it on a rung of ``bundled_shape``: columns that hold
+    no feature (every row in their bin 0), features of no slot, ``multi``
+    filled up with -1; the record is the plain layout's."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops import leafhist
+    from lightgbm_tpu.ops.bundle import (BundleDecode, column_search,
+                                         expand_digit_sums,
+                                         find_best_split_columns)
+    from lightgbm_tpu.ops.split import (SplitParams, find_best_split,
+                                        find_best_split_sums, sums_totals)
+    B = 63
+    for seed in range(6):
+        rng = np.random.RandomState(seed)
+        # three many-bin members where the layout is padded, so that
+        # ``multi`` is filled up to four
+        plan, num_bin, is_cat = _random_layout(
+            rng, B, last=(2, 4) if case == "padded" else (2, 2))
+        dn = plan.decode_arrays(num_bin, np.zeros_like(num_bin), B)
+        dec = BundleDecode.from_tables(dn)
+        C, F = plan.num_columns, plan.num_features
+        Cp, Fp = (C + 3, F + 5) if case == "padded" else (C, F)
+        # digit sums as a leaf's could be: every column sums to one total
+        rows = rng.randint(200, 4000, size=2)
+        sums = np.zeros((2, Cp, 9, B), np.int32)
+        for leaf in range(2):
+            # a row's nine digits go to one slot of EVERY column
+            dig = rng.randint(-100, 100, (rows[leaf], 9))
+            dig[:, 3:6] = np.abs(dig[:, 3:6])       # hessians
+            dig[:, 6:] = [0, 0, 1]                  # a row counts once
+            if case == "ties":
+                dig[:, :3] = (dig[:, :1] // 50) * 50
+                dig[:, 3:6] = 40
+            for c, members in enumerate(plan.column_members):
+                slots = 1 + sum(num_bin[f] - 1 for f in members) \
+                    if len(members) > 1 else num_bin[members[0]]
+                where = rng.randint(0, slots, rows[leaf])
+                if case == "ties":
+                    # two columns in step, slot for slot: their features'
+                    # gains are EQUAL wherever both have a candidate
+                    where = (np.arange(rows[leaf]) * 7 + c % 2) % min(
+                        slots, 3)
+                np.add.at(sums[leaf, c], (slice(None), where), dig.T)
+            sums[leaf, C:, :, 0] = dig.sum(axis=0)[None, :]
+        scales = jnp.asarray([1e-2, 1e-3, 1.0], jnp.float32)
+        sums = jnp.asarray(sums)
+        tg, th, tc = sums_totals(sums, scales)
+        mask = np.ones(F, bool)
+        if case == "masked":
+            mask[rng.rand(F) < 0.5] = False
+        p = SplitParams(min_data_in_leaf=0, min_sum_hessian_in_leaf=(
+            float(th.min()) * 0.3 if case == "hessian_floor" else 1e-3))
+        can = jnp.asarray([True, case != "unsplittable"])
+        args = (jnp.asarray(num_bin), jnp.asarray(is_cat), jnp.asarray(mask))
+        padded = BundleDecode.from_tables(plan.decode_arrays(
+            num_bin, np.zeros_like(num_bin), B, (Cp, Fp)))
+        assert padded.multi.shape[0] == (4 if case == "padded" else 2)
+        got = find_best_split_columns(
+            sums, scales, can, p, column_search(
+                padded, jnp.pad(args[0], (0, Fp - F), constant_values=1),
+                jnp.pad(args[1], (0, Fp - F)), jnp.pad(args[2], (0, Fp - F))))
+        # the plain search over the expanded sums of the plan's own columns
+        sums = sums[:, :C]
+        expanded = expand_digit_sums(sums, dec)
+        want = find_best_split_sums(expanded, scales, *args, can, p)
+        ok = np.asarray(want.feature) >= 0
+        assert ok[0] and ok[1] == (case != "unsplittable")
+        for field in want._fields:
+            w, g = np.asarray(getattr(want, field)), np.asarray(
+                getattr(got, field))
+            if field.startswith(("left_", "right_")):
+                w, g = w[ok], g[ok]         # an unsplittable leaf's: unread
+            np.testing.assert_array_equal(g, w, err_msg=f"{field} {seed}")
+        # the float32 search over the expanded histograms
+        floats = find_best_split(
+            leafhist.combine_digit_sums(expanded, scales), tg, th, tc,
+            *args, can, p)
+        # (a gain is a difference of the leaf's own terms in float32)
+        np.testing.assert_allclose(np.asarray(floats.gain)[ok],
+                                   np.asarray(got.gain)[ok], rtol=1e-3,
+                                   atol=1e-4)
+        if case != "ties":
+            np.testing.assert_array_equal(np.asarray(floats.feature),
+                                          np.asarray(got.feature))
+            np.testing.assert_array_equal(np.asarray(floats.threshold),
+                                          np.asarray(got.threshold))
+
+
+# ---------------------------------------------------------------------------
+# the bundled layout's rung on the device (ops/ordered_grow.py, PR 36)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("plan_shape,device_shape", [
+    ((5, 20), (8, 20)),             # whole words up to 8 of them
+    ((31, 33), (32, 34)),
+    ((35, 700), (40, 704)),         # even counts of words to 16
+    ((66, 4228), (80, 4352)),       # multiples of 4 words to 32:
+    ((71, 4226), (80, 4352)),       #   the one-hot cell's seeds, one rung
+    ((74, 4228), (80, 4352)),
+    ((80, 4352), (80, 4352)),       # a rung is its own rung
+    ((81, 4353), (96, 4608)),
+])
+def test_bundled_shape_ladder(plan_shape, device_shape):
+    from lightgbm_tpu.ops.ordered_grow import bundled_shape
+    assert bundled_shape(*plan_shape) == device_shape
+
+
+def test_nearby_bundle_plans_share_one_compiled_round():
+    """Two tables of the same width whose plans differ in columns (8 and
+    6) lie on one rung (8 columns: two bin words), so the second booster
+    compiles no training program; the pad columns and features change no
+    tree (the parity pins above run on the padded layout)."""
+    from lightgbm_tpu.obs import compile_ledger
+
+    def train_events():
+        return [e for e in compile_ledger.events()
+                if e["program"] in ("train_step", "pack_words")]
+    Xa, ya = one_hot_data(blocks=8, block_size=6, seed=5)
+    Xb, yb = one_hot_data(blocks=6, block_size=8, seed=6)
+    a, dsa = train_gbdt(Xa, ya, enable_bundle=True, iters=2)
+    before = len(train_events())
+    b, dsb = train_gbdt(Xb, yb, enable_bundle=True, iters=2)
+    assert dsa.num_columns != dsb.num_columns
+    assert a._device_shape == b._device_shape == (8, 48)
+    assert a.train_data.bins.shape[0] == b.train_data.bins.shape[0] == 8
+    assert a._bundle.col.shape == b._bundle.col.shape
+    assert train_events()[before:] == []
+    # a screener's compacted views keep the plan's own shape (they grow
+    # on ops/grow.py)
+    c, dsc = train_gbdt(Xb, yb, enable_bundle=True, iters=1,
+                        extra={"feature_screen_ratio": 0.5})
+    assert c._device_shape == (dsc.num_columns, dsc.num_features)

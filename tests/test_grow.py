@@ -315,3 +315,57 @@ def test_grow_tree_matches_oracle_sequence():
                       np.asarray(tree.split_bin)[:num_leaves - 1])]
     assert got_splits == [(f, t) for _, f, t in splits]
     np.testing.assert_array_equal(np.asarray(leaf_id), leaf)
+
+
+# ---- the integer search (ops/split.py find_best_split_sums, PR 36) --------
+
+def _leaf_sums(rng, rows, feats, bins, shards=1):
+    """int32 digit sums [shards, feats, 9, bins] of ``rows`` rows a shard
+    as a histogram's are: every feature holds every row once."""
+    dig = rng.randint(-128, 128, (shards, rows, 9))
+    dig[..., 3:] = np.abs(dig[..., 3:])         # hessians, weights
+    sums = np.zeros((shards, feats, 9, bins), np.int64)
+    for s in range(shards):
+        for f in range(feats):
+            np.add.at(sums[s, f], (slice(None), rng.randint(0, bins, rows)),
+                      dig[s].T)
+    return sums
+
+
+@pytest.mark.parametrize("bins,scale", [(255, 1), (255, 60000), (256, 60000),
+                                        (500, 40000)])
+def test_prefix_sums_are_the_exact_integers(bins, scale):
+    """At sums of a 16M-row leaf (``scale``: every row counted that many
+    times) as at small ones, and past 256 bins, where the form changes."""
+    from lightgbm_tpu.ops.split import prefix_sums
+    rng = np.random.RandomState(bins + scale)
+    sums = _leaf_sums(rng, 250, 3, bins)[0] * scale
+    want = np.cumsum(sums, axis=-1)
+    assert np.abs(want).max() < 2 ** 31 and (scale == 1
+                                             or np.abs(want).max() > 2 ** 24)
+    got = np.asarray(prefix_sums(jnp.asarray(sums.astype(np.int32))))
+    np.testing.assert_array_equal(got, want)
+
+
+def test_search_on_halves_is_the_search_on_whole_sums():
+    """Four shards' sums added as 16-bit halves (parallel/comm.py
+    ``HistExchange``) give the record of the whole int32 sums, bit for
+    bit: the mesh's trees are the serial learner's."""
+    from lightgbm_tpu.ops import leafhist
+    from lightgbm_tpu.ops.split import find_best_split_sums
+    rng = np.random.RandomState(3)
+    shards = _leaf_sums(rng, 4000, 5, 64, shards=4) * 700
+    assert np.abs(shards.sum(0)).max() < 2 ** 31
+    halves = sum(leafhist.split_halves(jnp.asarray(s.astype(np.int32)))
+                 for s in shards)
+    whole = jnp.asarray(shards.sum(0).astype(np.int32))
+    scales = jnp.asarray([3e-2, 2e-3, 1.0], jnp.float32)
+    args = (scales, jnp.full((5,), 64, jnp.int32), jnp.zeros((5,), bool),
+            jnp.ones((5,), bool), jnp.asarray(True),
+            SplitParams(min_data_in_leaf=0, min_sum_hessian_in_leaf=1e-3))
+    a = find_best_split_sums(halves, *args)
+    b = find_best_split_sums(whole, *args)
+    assert int(b.feature) >= 0
+    for field in b._fields:
+        np.testing.assert_array_equal(np.asarray(getattr(a, field)),
+                                      np.asarray(getattr(b, field)), field)

@@ -144,10 +144,10 @@ def test_comm_traffic_hand_computed():
     # data-parallel over leaf-ordered shards: one all-reduce of int32
     # digit sums in 16-bit halves, [F, 18, B], for the root and one a
     # split step (L in all),
-    # the root's three sums and its integer row count in two more calls,
-    # its three scales in a pmax
+    # the root's integer row count in one more call (its sums are the
+    # histogram's own), its three scales in a pmax
     t = HistExchange("d", k).traffic_per_tree(F, B, L)
-    assert t["psum"] == {"calls": 2 + L, "bytes": 16 + F * 18 * B * 4 * L}
+    assert t["psum"] == {"calls": 1 + L, "bytes": 4 + F * 18 * B * 4 * L}
     assert t["pmax"] == {"calls": 1, "bytes": 12}
     assert set(t) == {"psum", "pmax"}
 
@@ -192,7 +192,7 @@ def test_comm_traffic_through_parallel_grow():
     # exchanges a tree; uint16 bins or EFB columns keep the float
     # histograms of ops/grow.py
     t = fn.traffic_per_tree(6)
-    assert t["psum"] == {"calls": 2 + 8, "bytes": 16 + 6 * 18 * 16 * 4 * 8}
+    assert t["psum"] == {"calls": 1 + 8, "bytes": 4 + 6 * 18 * 16 * 4 * 8}
     for kw in ({"bins_dtype": np.uint16}, {"bundled": True}):
         t = fn.traffic_per_tree(6, **kw)
         assert t["psum"]["bytes"] == 12 + 6 * 16 * 3 * 4 * (1 + 2 * 7)

@@ -237,12 +237,18 @@ def test_stale_serial_grow_key_is_ignored():
 _CHOICES = {
     "uint8_unbundled": (_dense, {}, "ordered", None),
     "max_bin_500": (_dense, {"max_bin": 500}, "cached", None),
-    "efb_bundle": (_one_hot, {}, "cached", {"enable_bundle": False}),
+    "efb_bundle": (_one_hot, {}, "ordered", {"enable_bundle": False}),
+    "efb_bundle_screening": (_one_hot, {"feature_screen_ratio": 0.5,
+                                        "feature_screen_warmup": 1},
+                             "cached", None),
     "screening": (_dense, {"feature_screen_ratio": 0.5,
                            "feature_screen_warmup": 1}, "cached", None),
+    # (float sums here, integer sums there: a pure leaf's last splits are
+    # rounding noise of a gain of 2e-4 in either, and are kept out)
     "hist_cache_degrade": (_dense, {"memory_policy": "degrade",
-                                    "histogram_pool_size": 0.001},
-                           "nocache", {}),
+                                    "histogram_pool_size": 0.001,
+                                    "min_gain_to_split": 1e-3},
+                           "nocache", {"min_gain_to_split": 1e-3}),
     "data_parallel_uint8": (_dense, {"tree_learner": "data",
                                      "num_machines": 4}, "ordered", {}),
     "data_parallel_uint16": (_dense, {"tree_learner": "data",
@@ -278,7 +284,8 @@ def test_grower_is_chosen_from_the_data_and_trains(case):
         other = _train(same_as, X, y)
         assert other._booster._grower_kind != kind \
             or other._booster._parallel_grow_active \
-            != g._parallel_grow_active
+            != g._parallel_grow_active \
+            or (other._booster._bundle is None) != (g._bundle is None)
         for a, c in zip(g.models, other._booster.models):
             np.testing.assert_array_equal(a.split_feature, c.split_feature)
             np.testing.assert_array_equal(a.threshold_in_bin,
@@ -306,3 +313,125 @@ def test_misaligned_valid_set_rejected():
     b.add_valid_dataset(good)                 # aligned: fine
     with pytest.raises(Exception):
         b.add_valid_dataset(bad)
+
+
+# ---- EFB bundles on the leaf-ordered grower (PR 36) -----------------------
+
+def _bundled(n, seed, kind):
+    """A table EFB bundles, binned: ``two_bin`` one-hot blocks alone (a
+    bundle of two-bin members); ``numeric_member`` two mutually exclusive
+    sparse columns of eight values beside them (a bundle with many-bin
+    members); ``singletons`` dense numerics and a frequent level beside
+    the bundles."""
+    from lightgbm_tpu.io.dataset import BinnedDataset
+    rng = np.random.RandomState(seed)
+    cols = []
+    for size in (6, 12, 3, 40):
+        lvl = np.minimum(rng.zipf(1.5, n) - 1, size - 1)
+        hot = np.zeros((n, size))
+        hot[np.arange(n), lvl] = 1.0
+        cols.append(hot)
+    if kind == "numeric_member":
+        a = np.zeros((n, 2))
+        which = rng.randint(0, 6, n)
+        for j in range(2):
+            a[which == j, j] = rng.randint(1, 9, (which == j).sum())
+        cols.append(a)
+    if kind == "singletons":
+        cols.append(rng.randn(n, 3))
+    X = np.concatenate(cols, 1)
+    y = (X[:, 0] - X[:, 7] + 0.3 * X[:, 30] + 0.5 * X[:, -1]
+         + rng.randn(n) * 0.5 > 0.4).astype(np.float64)
+    ds = BinnedDataset.from_matrix(X, y, max_bin=255, min_data_in_leaf=0,
+                                   min_data_in_bin=3, enable_bundle=True)
+    assert ds.bundle_plan is not None
+    return X, y, ds
+
+
+def _grow_args(ds, y, seed=5):
+    from lightgbm_tpu.ops.bundle import BundleDecode
+    n = ds.num_data
+    rng = np.random.RandomState(seed)
+    g = (rng.randn(n) + (y * 2 - 1) * 0.3).astype(np.float32)
+    h = (np.abs(rng.randn(n)) + 0.1).astype(np.float32)
+    f = ds.num_features
+    dec = None
+    if ds.bundle_plan is not None:
+        dec = BundleDecode.from_tables(ds.bundle_plan.decode_arrays(
+            [m.num_bin for m in ds.mappers],
+            [m.default_bin for m in ds.mappers], 255))
+    return (jnp.asarray(ds.bins), jnp.asarray(ds.num_bin_per_feature()),
+            jnp.zeros(f, bool), jnp.ones(f, bool), jnp.asarray(g),
+            jnp.asarray(h), jnp.ones(n, jnp.float32), jnp.float32(0.1)), dec
+
+
+def _assert_same_growth(got, ref):
+    """Every ``TreeArrays`` field, float gains and values included, the
+    leaf of every row and the score delta EQUAL."""
+    for field in ref[0]._fields:
+        np.testing.assert_array_equal(np.asarray(getattr(got[0], field)),
+                                      np.asarray(getattr(ref[0], field)),
+                                      err_msg=field)
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(ref[1]))
+    np.testing.assert_array_equal(np.asarray(got[2]), np.asarray(ref[2]))
+
+
+@pytest.mark.parametrize("kind,leaves", [
+    ("two_bin", 63), ("two_bin", 255), ("numeric_member", 63),
+    ("singletons", 63), ("singletons", 255)])
+def test_ordered_grows_the_unbundled_trees_on_bundles(kind, leaves):
+    """``max_conflict_rate=0``: bundling changes no integer sum, and the
+    search reads integers (ops/split.py ``find_best_split_sums``).  The
+    grower on the bundled columns (the split member decoded from the
+    lanes' byte, the features searched where they lie) against the grower
+    on the unbundled columns: bit for bit."""
+    from lightgbm_tpu.io.dataset import BinnedDataset
+    X, y, ds = _bundled(20000, 1, kind)
+    wide = BinnedDataset.from_matrix(X, y, max_bin=255, min_data_in_leaf=0,
+                                     min_data_in_bin=3, enable_bundle=False)
+    assert wide.num_columns > ds.num_columns and wide.bundle_plan is None
+    (args, dec), (wargs, _) = _grow_args(ds, y), _grow_args(wide, y)
+    if kind == "numeric_member":
+        assert len(np.asarray(dec.multi)) == 2
+    params = GrowParams(num_leaves=leaves, max_bin=255, min_data_in_leaf=0,
+                        min_sum_hessian_in_leaf=5.0)
+    got = grow_tree_ordered(*args, params, bundle=dec)
+    ref = grow_tree_ordered(*wargs, params)
+    assert int(got[0].num_leaves) == int(ref[0].num_leaves) > leaves // 2
+    _assert_same_growth(got, ref)
+    used = set(np.asarray(ref[0].split_feature)[:leaves - 1].tolist())
+    members = {f for m in ds.bundle_plan.bundles for f in m}
+    assert used & members and (kind == "two_bin" or used - members)
+
+
+@pytest.mark.parametrize("other", ["cached", "cached_unbundled"])
+@pytest.mark.parametrize("kind", ["two_bin", "numeric_member", "singletons"])
+def test_ordered_on_bundles_grows_the_cached_growers_trees(kind, other):
+    """Against the cached grower (gathered rows, ``find_best_split_sums``
+    over the expanded ``[F, 9, B]``) on the same bundles and on the
+    unbundled columns: node for node and bit for bit, one search
+    arithmetic for every grower that holds integer sums.  And the leaf
+    values are the rows' own float64 sums' to float32's rounding."""
+    from lightgbm_tpu.io.dataset import BinnedDataset
+    X, y, ds = _bundled(20000, 2, kind)
+    params = GrowParams(num_leaves=63, max_bin=255, min_data_in_leaf=0,
+                        min_sum_hessian_in_leaf=5.0)
+    args, dec = _grow_args(ds, y)
+    got = grow_tree_ordered(*args, params, bundle=dec)
+    if other == "cached_unbundled":
+        ds = BinnedDataset.from_matrix(
+            X, y, max_bin=255, min_data_in_leaf=0, min_data_in_bin=3,
+            enable_bundle=False)
+        args, dec = _grow_args(ds, y)
+    bins_rm = jnp.asarray(np.ascontiguousarray(ds.bins.T))
+    ref = grow_tree(*args, params, bins_rm=bins_rm, bundle=dec)
+    n = int(ref[0].num_leaves)
+    assert n > 31
+    _assert_same_growth(got, ref)
+    g, h = np.asarray(args[4], np.float64), np.asarray(args[5], np.float64)
+    leaf = np.asarray(got[1])
+    truth = -0.1 * np.bincount(leaf, weights=g, minlength=n) \
+        / np.bincount(leaf, weights=h, minlength=n)
+    off = np.abs(np.asarray(got[0].leaf_value)[:n] - truth) \
+        / np.maximum(np.abs(truth), 1e-3)
+    assert off.max() < 1e-5

@@ -336,7 +336,7 @@ def test_ordered_shards_take_the_resident_layout():
     fn = make_parallel_grow(mesh, "data", params)
     t0, leaf0, _ = fn(jnp.asarray(bins), *meta, *rows)
     rm = jnp.asarray(np.ascontiguousarray(bins.T))
-    words = pack_word_lanes(rm, mesh)
+    words = pack_word_lanes(jnp.asarray(bins), mesh)
     assert len(words) == 1 and words[0].shape[0] == 4 * (N // 4 + 8192)
     t1, leaf1, _ = fn(jnp.asarray(bins), *meta, *rows, bins_rm=rm,
                       bins_words=words)
@@ -409,7 +409,7 @@ def test_data_parallel_booster_grows_ordered_shards():
     assert ordered.train_data.bins_words is not None
     assert ordered._comm_traffic == {
         "pmax": {"calls": 1, "bytes": 12},
-        "psum": {"calls": 8, "bytes": 16 + 6 * 5 * 18 * 32 * 4}}
+        "psum": {"calls": 7, "bytes": 4 + 6 * 5 * 18 * 32 * 4}}
     Xw = rng.normal(size=(4000, 5))
     wide = BinnedDataset.from_matrix(Xw, (Xw[:, 0] > 0).astype(np.float32),
                                      max_bin=400, min_data_in_leaf=10)

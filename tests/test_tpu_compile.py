@@ -210,23 +210,33 @@ def test_forest_walk_kernel_compiles(spec, variant, bucket):
 _TEXTS = {}     # compiled texts, by program and shape: a compile each
 
 
-def _ordered_grower_text(spec, monkeypatch, n, f=4, leaves=7):
+def _ordered_grower_text(spec, monkeypatch, n, f=4, leaves=7, bundled=0):
     """Compiled text of ``grow_tree_ordered`` at ``n`` rows, ``f``
     features (four keep the kernel's unroll short) and ``leaves`` leaves,
-    on the chip's kernel."""
+    on the chip's kernel.  ``bundled``: the ``f`` are EFB columns that
+    hold this many original features (two-bin members and identity
+    columns, as a one-hot table's are)."""
+    from lightgbm_tpu.ops.bundle import BundleDecode
     from lightgbm_tpu.ops.grow import GrowParams
     from lightgbm_tpu.ops.ordered_grow import grow_tree_ordered
     from lightgbm_tpu.utils import device
     monkeypatch.setattr(device, "on_tpu", lambda: True)
-    key = ("serial", n, f, leaves)
+    key = ("serial", n, f, leaves, bundled)
     if key not in _TEXTS:
+        feats = bundled or f
+        i32 = lambda *shape: spec(shape, jnp.int32)
+        bundle = BundleDecode(
+            col=i32(feats), off=i32(feats), width=i32(feats),
+            slot_map=i32(feats, B), default_bin=i32(feats),
+            col_feat=i32(f), slot_feat=i32(f, B), multi=i32(0)) \
+            if bundled else None
         _TEXTS[key] = grow_tree_ordered.lower(
-            spec((f, n), jnp.uint8), spec((f,), jnp.int32),
-            spec((f,), jnp.bool_), spec((f,), jnp.bool_),
+            spec((f, n), jnp.uint8), spec((feats,), jnp.int32),
+            spec((feats,), jnp.bool_), spec((feats,), jnp.bool_),
             spec((n,), jnp.float32), spec((n,), jnp.float32),
             spec((n,), jnp.float32), spec((), jnp.float32),
-            GrowParams(num_leaves=leaves, max_bin=B, min_data_in_leaf=50)
-        ).compile().as_text()
+            GrowParams(num_leaves=leaves, max_bin=B, min_data_in_leaf=50),
+            bundle=bundle).compile().as_text()
     return _TEXTS[key]
 
 
@@ -370,7 +380,7 @@ def test_ordered_grower_copies_no_whole_lane_in_the_grow_loop(spec,
     assert _whole_in_grow_loop(text, lane) == []
 
 
-@pytest.mark.parametrize("program", ["serial", "sharded"])
+@pytest.mark.parametrize("program", ["serial", "sharded", "bundled"])
 def test_ordered_grower_copies_no_whole_cache_in_the_grow_loop(
         topo, spec, monkeypatch, program):
     """The grow loop carries the histogram cache (``s32[L, F, 9, B]``;
@@ -384,18 +394,42 @@ def test_ordered_grower_copies_no_whole_cache_in_the_grow_loop(
     1,444 ms round at 255 leaves x 136 features (PERF.md, PR 35).  The
     copies show only from a few size classes on: the serial program at the
     ranking cell's widths and the sharded one at Higgs's, 262,144 rows (a
-    shard) each, held two and four of them."""
+    shard) each, held two and four of them.  ``bundled``: the one-hot
+    cell's widths (PR 36): the cache in COLUMN space, 60 columns of 4,228
+    features, which the column-space search reads where the features
+    lie."""
     n = 262144
     if program == "serial":
         f, leaves = 136, 255
         text = _ordered_grower_text(spec, monkeypatch, n, f, leaves)
+    elif program == "bundled":
+        f, leaves = 60, 255
+        text = _ordered_grower_text(spec, monkeypatch, n, f, leaves,
+                                    bundled=4228)
+        assert "4228,9," not in text, "something is expanded to [F, 9, B]"
     else:
         f, leaves = F, 63
         text = _sharded_grower_text(topo, monkeypatch, n, f, leaves)
     cache = rf"{leaves},{f},(9|18),{B}"
     writes = _whole_in_grow_loop(text, cache, "dynamic-update-slice")
-    assert len(writes) >= (2 if program == "serial" else 4), writes
+    assert len(writes) >= (4 if program == "sharded" else 2), writes
     assert _whole_in_grow_loop(text, cache) == []
+
+
+@pytest.mark.parametrize("columns", [28, 80, 136])
+def test_pack_words_is_a_small_program(spec, columns):
+    """``pack_words`` at a cell's columns x 4M rows: a word is four
+    shifted columns ORed.  From the row-major matrix the lanes were
+    strided slices of a relayout: 37.4 MiB of code and 70 s of compile at
+    80 columns x 12.6M rows (sandbox compile, PR 36) for what is 3.5 MiB
+    and 3 s."""
+    from lightgbm_tpu.ops import ordered_grow
+    lowered = ordered_grow._pack_words_padded.lower(
+        spec((columns, 1 << 22), jnp.uint8))
+    compiled = lowered.compile()
+    code = compiled.memory_analysis().generated_code_size_in_bytes
+    assert code < (columns // 4) * (1 << 19), code      # half a MiB a word
+    assert "transpose" not in compiled.as_text()
 
 
 # grow_tree_ordered at 32,768 x 4, 7 leaves, for the described v5e: 3,282
@@ -405,9 +439,12 @@ def test_ordered_grower_copies_no_whole_cache_in_the_grow_loop(
 # PR 33 took the row-major feed of the histogram kernel out of every
 # child window and moved the root pass onto the word lanes (3,096); PR 35
 # reads the cache's parent row once, behind a barrier, where three
-# consumers each had their own slice of it.  Whoever changes the serial
-# grower knowingly changes this number with it.
-SERIAL_INSTRUCTIONS = 3090
+# consumers each had their own slice of it (3,090); PR 36 searches the
+# integer sums (ops/split.py find_best_split_sums: prefix sums as two
+# products, both sides combined, a split record of both sides' sums).
+# Whoever changes the serial grower knowingly changes this number with
+# it.
+SERIAL_INSTRUCTIONS = 3580
 COLLECTIVES = ("all-reduce", "all-reduce-start", "reduce-scatter",
                "all-gather", "all-gather-start", "all-to-all",
                "collective-permute", "collective-permute-start")
@@ -430,7 +467,7 @@ def test_sharded_grower_exchanges_once_a_split_outside_every_branch(
     rows a shard (three size classes).  One histogram collective in the
     grow loop's body, the all-reduce of ``exchange/hist``: each shard is
     in its own size class, so a collective inside a conditional's branch
-    would deadlock, and none is reached from one.  Four more before the
+    would deadlock, and none is reached from one.  Three more before the
     loop, ``exchange/root``.  PR 29's property holds in the sharded
     program: no whole-lane ``copy`` in the grow loop."""
     from lightgbm_tpu.obs import devtrace, phases
@@ -447,8 +484,9 @@ def test_sharded_grower_exchanges_once_a_split_outside_every_branch(
                             []).append(name)
     assert sorted(by_phase) == ["exchange/hist", "exchange/root"], by_phase
     assert len(by_phase["exchange/hist"]) == 1
-    # scales, sums, rows, histogram: the compiler may combine two
-    assert len(by_phase["exchange/root"]) in (3, 4)
+    # scales, rows, histogram (the root's sums are the histogram's own,
+    # PR 36): the compiler may combine two
+    assert len(by_phase["exchange/root"]) in (2, 3)
     hist = coll[by_phase["exchange/hist"][0]]
     assert re.search(r"s32\[4,18,\d+\]", text.split(
         f"%{by_phase['exchange/hist'][0]} = ")[1].split("\n")[0])
