@@ -119,8 +119,11 @@ ROUND_PHASES = (
     "hist/subtract",      # sibling subtraction and the cache update
     "find_split",         # root and children
     "leaf_table",         # node and leaf rows, TreeArrays
-    "leaf_delta",         # segment-to-leaf sort, searchsorted, scatter
-                          # back to row order, the out-of-bag walk
+    "leaf_delta",         # ops/ordered_grow.py leaf_delta: the L
+                          # segment starts sorted, every position's leaf
+                          # selected by compares against them, scattered
+                          # back to row order, the value selected by the
+                          # leaf; the out-of-bag walk
     "score_update",
     "pack_tree",
 )

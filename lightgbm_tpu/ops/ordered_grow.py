@@ -79,7 +79,10 @@ device ops instead of ~40 scalar SoA updates (the round-2 ablation's
 The per-row leaf assignment is NOT maintained per step (the round-2
 implementation paid a full-[N] select per split): leaf segments are
 contiguous, so it is reconstructed once per tree from (seg_start,
-seg_cnt) with one searchsorted + one scatter back to original row order.
+seg_cnt): ``leaf_delta`` selects every position's leaf by comparing the
+position with the sorted segment starts, scatters it back to original row
+order once and selects the row's value by its leaf (no search, no N-row
+table gather).
 
 Outputs are identical to ops/grow.py's serial learner: the same splits,
 the same TreeArrays (int histogram sums are order-invariant).
@@ -255,6 +258,58 @@ def _row(buf, i, w: int):
 
 def _put_row(buf, i, vec):
     return jax.lax.dynamic_update_slice(buf, vec[None, :], (i, 0))
+
+
+def sorted_segments(start, cnt, num_leaves, n: int):
+    """The live leaves' segments in position order: ``lo[k] <= p <
+    hi[k]`` holds for exactly one k at every position p, and
+    ``leaf_sorted[k]`` is that segment's leaf.  A leaf that is not grown
+    or holds no row sorts to the end with ``lo == hi == n``; the last
+    live segment runs to ``n`` (positions past its rows are the
+    zero-weight rows of a compacted bag, rerouted by the caller)."""
+    leaf_iota = jnp.arange(start.shape[0], dtype=jnp.int32)
+    live = (leaf_iota < num_leaves) & (cnt > 0)
+    sv = jnp.where(live, start, jnp.int32(n))
+    lo, leaf_sorted = jax.lax.sort((sv, leaf_iota), num_keys=1,
+                                   is_stable=True)
+    hi = jnp.concatenate([lo[1:], jnp.full((1,), n, jnp.int32)])
+    return lo, hi, leaf_sorted
+
+
+def leaf_delta(start, cnt, num_leaves, shrunk, row_ord, n: int):
+    """Per-row leaf id and leaf value from the contiguous segments:
+    ``(leaf_id, output_delta)``, both ``[n]`` in ORIGINAL row order.
+
+    ``start``, ``cnt`` ``[L]`` int32 are the leaves' segments in position
+    space, ``shrunk`` ``[L]`` float32 their values and ``row_ord`` the
+    row held at each position.  The segment starts are sorted and the
+    positions an iota, so a position's leaf is SELECTED by two compares
+    against L numbers that sit in registers (a one-hot over the segments,
+    summed), scattered to row order once, and a row's value selected the
+    same way by its leaf: the one-hot picks the value's BITS, summed as
+    integers with one non-zero term, so every float comes out as it went
+    in, ``-0.0`` and infinities too.  Nothing searches and nothing
+    gathers n elements from a table: a binary search is a gather of all
+    n positions a step, 8.2 ns a row each from a table of 256 entries on
+    a v5e (1,186 of a 2,313 ms round at 12.6M rows), where both selects
+    together are under 1 ns a row and the scatter 6 to 7 (PERF.md, PR 37;
+    tools/probe_leaf_delta.py times the forms).  The ``[L, n]`` compares
+    are fused into their reductions and never materialised."""
+    with jax.named_scope("leaf_delta"):
+        lo, hi, leaf_sorted = sorted_segments(start, cnt, num_leaves, n)
+        pos = jnp.arange(n, dtype=jnp.int32)[None, :]
+        inside = (lo[:, None] <= pos) & (pos < hi[:, None])
+        leaf_of_pos = jnp.sum(
+            jnp.where(inside, leaf_sorted[:, None], 0), axis=0)
+        # back to ORIGINAL row order: one scatter per tree
+        leaf_id = jnp.zeros(n, jnp.int32).at[row_ord[:n]].set(
+            leaf_of_pos, unique_indices=True)
+        bits = jax.lax.bitcast_convert_type(shrunk, jnp.int32)
+        leaves = jnp.arange(shrunk.shape[0], dtype=jnp.int32)
+        output_delta = jax.lax.bitcast_convert_type(
+            jnp.sum(jnp.where(leaf_id[None, :] == leaves[:, None],
+                              bits[:, None], 0), axis=0), jnp.float32)
+    return leaf_id, output_delta
 
 
 @instrumented_jit(program="grow_tree_ordered",
@@ -767,23 +822,12 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
             leaf_parent=leaf_i32[:, _LI["parent"]],
             leaf_depth=leaf_i32[:, _LI["depth"]],
         )
+        seg_start, seg_cnt = leaf_i32[:, _LI["start"]], leaf_i32[:, _LI["cnt"]]
+
+    leaf_id, output_delta = leaf_delta(seg_start, seg_cnt, num_leaves,
+                                       shrunk, row_ord, N)
 
     with jax.named_scope("leaf_delta"):
-        # Per-position leaf assignment from the contiguous segments: the
-        # leaf owning position p is the one with the largest seg_start <= p.
-        leaf_iota = jnp.arange(L, dtype=jnp.int32)
-        live = (leaf_iota < num_leaves) & (leaf_i32[:, _LI["cnt"]] > 0)
-        sv = jnp.where(live, leaf_i32[:, _LI["start"]], jnp.int32(N))
-        sv_sorted, leaf_sorted = jax.lax.sort((sv, leaf_iota), num_keys=1,
-                                              is_stable=True)
-        pos = jnp.arange(N, dtype=jnp.int32)
-        seg = jnp.searchsorted(sv_sorted, pos, side="right") - 1
-        leaf_of_pos = leaf_sorted[seg]
-        # back to ORIGINAL row order: one scatter per tree
-        leaf_id = jnp.zeros(N, jnp.int32).at[row_ord[:N]].set(
-            leaf_of_pos, unique_indices=True)
-        output_delta = shrunk[leaf_id]
-
         if params.compact_inactive:
             # zero-weight rows never entered a segment: route them through
             # the tree like the reference's out-of-bag AddPredictionToScore

@@ -6,10 +6,11 @@ delta matches bit-for-bit."""
 
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from lightgbm_tpu.ops.grow import GrowParams, grow_tree
-from lightgbm_tpu.ops.ordered_grow import grow_tree_ordered
+from lightgbm_tpu.ops.ordered_grow import grow_tree_ordered, leaf_delta
 
 
 def _data(n=20000, f=6, seed=0, cat_feature=False):
@@ -435,3 +436,102 @@ def test_ordered_on_bundles_grows_the_cached_growers_trees(kind, other):
     off = np.abs(np.asarray(got[0].leaf_value)[:n] - truth) \
         / np.maximum(np.abs(truth), 1e-3)
     assert off.max() < 1e-5
+
+
+def _leaf_delta_by_search(start, cnt, num_leaves, shrunk, row_ord, n):
+    """The form ``leaf_delta`` replaced (PR 37), kept as its oracle: the
+    live starts sorted with their leaves, a binary search of every
+    position, the leaf gathered from the table, one scatter to row order
+    and the value gathered by the leaf."""
+    leaf_iota = jnp.arange(start.shape[0], dtype=jnp.int32)
+    live = (leaf_iota < num_leaves) & (cnt > 0)
+    sv = jnp.where(live, start, jnp.int32(n))
+    sv_sorted, leaf_sorted = jax.lax.sort((sv, leaf_iota), num_keys=1,
+                                          is_stable=True)
+    pos = jnp.arange(n, dtype=jnp.int32)
+    seg = jnp.searchsorted(sv_sorted, pos, side="right") - 1
+    leaf_id = jnp.zeros(n, jnp.int32).at[row_ord[:n]].set(
+        leaf_sorted[seg], unique_indices=True)
+    return leaf_id, shrunk[leaf_id]
+
+
+def _segments(rng, n, live, cuts=None):
+    """``live`` segments that tile [0, n), in a shuffled leaf order."""
+    if cuts is None:
+        cuts = np.sort(rng.choice(np.arange(1, n), live - 1, replace=False))
+    start = np.concatenate([[0], cuts]).astype(np.int32)
+    cnt = np.diff(np.concatenate([start, [n]])).astype(np.int32)
+    order = rng.permutation(live)
+    return start[order], cnt[order]
+
+
+def _leaf_delta_case(case, L, rng):
+    """(start, cnt, num_leaves, shrunk, row_ord, n) of one case."""
+    n = 5003 if case == "ragged_n" else 4096
+    shrunk = rng.normal(size=L).astype(np.float32)
+    num_leaves = L
+    start, cnt = _segments(rng, n, L)
+    if case == "stopped_early":
+        # growth stopped: the table's tail holds what was never written
+        num_leaves = max(1, L // 3)
+        start, cnt = _segments(rng, n, num_leaves)
+        start = np.concatenate([start, rng.randint(0, n, L - num_leaves)])
+        cnt = np.concatenate([cnt, rng.randint(1, n, L - num_leaves)])
+    elif case == "empty_leaves":
+        # live leaves that hold no row (a bag's), at starts of others
+        full = max(1, L - L // 4)
+        s_, c_ = _segments(rng, n, full)
+        where = rng.permutation(L)
+        start, cnt = np.zeros(L, np.int64), np.zeros(L, np.int64)
+        start[where[:full]], cnt[where[:full]] = s_, c_
+        start[where[full:]] = rng.choice(s_, L - full)
+    elif case == "one_leaf":
+        num_leaves = 1
+        start, cnt = np.zeros(L, np.int64), np.zeros(L, np.int64)
+        cnt[0] = n
+    elif case == "one_row_segments":
+        # every leaf but the last a single row
+        start, cnt = _segments(rng, n, L, cuts=np.arange(1, L))
+    elif case == "ends":
+        # one row at position 0, one row at position n - 1
+        inner = np.sort(rng.choice(np.arange(2, n - 1), max(L - 3, 0),
+                                   replace=False))
+        cuts = np.concatenate([[1], inner, [n - 1]])[:L - 1] if L > 2 \
+            else np.array([n - 1])
+        start, cnt = _segments(rng, n, L, cuts=cuts)
+    elif case == "signed_zero_and_extreme":
+        shrunk[L // 2] = -3e38
+        shrunk[0], shrunk[-1] = -0.0, 3e38
+    row_ord = rng.permutation(n)
+    if case == "permuted_row_ord":
+        # the grower's lane: the order reversed, the lane longer than n
+        row_ord = np.concatenate([np.arange(n)[::-1], np.arange(n, n + 64)])
+    return (jnp.asarray(start, jnp.int32), jnp.asarray(cnt, jnp.int32),
+            jnp.int32(num_leaves), jnp.asarray(shrunk),
+            jnp.asarray(row_ord, jnp.int32), n)
+
+
+@pytest.mark.parametrize("L", [2, 31, 63, 255, 256])
+@pytest.mark.parametrize("case", [
+    "all_live", "stopped_early", "empty_leaves", "one_leaf",
+    "one_row_segments", "ends", "ragged_n", "signed_zero_and_extreme",
+    "permuted_row_ord"])
+def test_leaf_delta_selects_what_the_search_found(case, L):
+    """``leaf_delta`` (dense compares against the sorted segment starts
+    and the leaf numbers) against the binary search and table gathers it
+    replaced: the leaf of every row and its value BIT for bit, ``-0.0``
+    and 3e38 too."""
+    args = _leaf_delta_case(case, L, np.random.RandomState(L))
+    want_leaf, want_delta = _leaf_delta_by_search(*args)
+    got_leaf, got_delta = jax.jit(leaf_delta, static_argnums=5)(*args)
+    assert got_leaf.dtype == jnp.int32 and got_delta.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(got_leaf),
+                                  np.asarray(want_leaf))
+    np.testing.assert_array_equal(np.asarray(got_delta).view(np.int32),
+                                  np.asarray(want_delta).view(np.int32))
+    if case == "one_leaf":
+        assert not np.asarray(got_leaf).any()
+    if case == "signed_zero_and_extreme":
+        got = np.asarray(got_delta)
+        assert np.signbit(got[np.asarray(got_leaf) == 0]).all()
+        assert (got[np.asarray(got_leaf) == L - 1] == np.float32(3e38)).all()

@@ -296,6 +296,77 @@ def test_ordered_grower_text_carries_the_phase_paths(spec, monkeypatch):
     assert kernels, sorted(pm["phases"])[:20]
     assert pm["ops_unscoped"] == 0, pm["unscoped_op_names"]
     assert pm["inserted"], "the chip's compiler inserts copies here"
+    # leaf reconstruction selects by compares (PR 37): the one-hot's
+    # reduction sits under ``leaf_delta`` and no search loop does
+    rebuilt = {k for k, ph in pm["phases"].items() if ph == "leaf_delta"}
+    assert [k for k in rebuilt
+            if (instrs[k]["op_name"] or "").endswith("/reduce_sum")]
+    assert not [k for k in rebuilt if instrs[k]["opcode"] == "while"]
+    assert not [k for k, r in instrs.items()
+                if "searchsorted" in (r["op_name"] or "")]
+
+
+def _result_elements(text):
+    """``{instruction: [elements of each array of its result]}`` of a
+    compiled text (a tuple result has several)."""
+    from lightgbm_tpu.obs import devtrace
+    out = {}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line)
+        if m:
+            rest = line[m.end():]
+            shape = rest[:len(rest) - len(devtrace._after_shape(rest))]
+            out[m.group(1)] = [
+                int(np.prod([int(d) for d in dims.split(",") if d]))
+                for dims in re.findall(r"\[([\d,]*)\]", shape)]
+    return out
+
+
+@pytest.mark.parametrize("n,leaves,keep", [
+    (12_582_912, 255, "delta"), (12_582_912, 255, "both"),
+    (11_010_048, 63, "delta")])
+def test_leaf_delta_neither_searches_nor_gathers_rows(spec, n, leaves, keep):
+    """``ops/ordered_grow.py leaf_delta`` alone at the one-hot cell's and
+    cell 1's rows and leaves, as the fused round uses it (``delta``: the
+    leaf id dropped) and as the per-stage path does (``both``).  Until PR
+    37 the phase was ``searchsorted``'s scan, a ``while`` of 8 steps that
+    each gathered all N positions from the table of starts, and two table
+    gathers more: ten gathers at 8.2 ns a row, 1,186 ms of a 2,313 ms
+    round (PERF.md).  Now: no loop, no gather, the two ``[L, N]``
+    compares alive only inside their reductions' fusions, ONE scatter
+    either way, and the only sorts the L pairs' and the one the compiler
+    makes of that scatter."""
+    from lightgbm_tpu.obs import devtrace
+    from lightgbm_tpu.ops.ordered_grow import leaf_delta
+
+    def fn(*args):
+        out = leaf_delta(*args, n)
+        return out[1] if keep == "delta" else out
+    compiled = jax.jit(fn).lower(
+        spec((leaves,), jnp.int32), spec((leaves,), jnp.int32),
+        spec((), jnp.int32), spec((leaves,), jnp.float32),
+        spec((n,), jnp.int32)).compile()
+    text = compiled.as_text()
+    instrs = devtrace.parse_hlo(text)["instructions"]
+    elements = _result_elements(text)
+    fused = {c for r in instrs.values() if r["opcode"] == "fusion"
+             for _, c in r["called"]}
+    scoped = [r["op_name"] for r in instrs.values() if r["located"]]
+    assert scoped and all("/leaf_delta/" in name for name in scoped)
+    opcodes = [r["opcode"] for r in instrs.values()]
+    assert "while" not in opcodes and "gather" not in opcodes
+    assert opcodes.count("scatter") == 1
+    # the one-hots over the segments and over the leaves live inside a
+    # fusion each and nowhere else
+    assert len({r["comp"] for k, r in instrs.items() if r["comp"] in fused
+                and max(elements[k], default=0) == n * leaves}) == 2
+    assert not [k for k, r in instrs.items() if r["comp"] not in fused
+                and max(elements[k], default=0) > n]
+    assert compiled.memory_analysis().temp_size_in_bytes < 6 * 4 * n
+    sorts = sorted((elements[k][0], instrs[k]["op_name"].rsplit("/")[-1])
+                   for k, r in instrs.items() if r["opcode"] == "sort")
+    assert sorts in ([(leaves, "sort"), (n, "scatter")],
+                     [(leaves, "sort")]), sorts
 
 
 def test_ordered_grower_builds_nothing_row_major_for_the_histogram(
@@ -441,10 +512,12 @@ def test_pack_words_is_a_small_program(spec, columns):
 # reads the cache's parent row once, behind a barrier, where three
 # consumers each had their own slice of it (3,090); PR 36 searches the
 # integer sums (ops/split.py find_best_split_sums: prefix sums as two
-# products, both sides combined, a split record of both sides' sums).
+# products, both sides combined, a split record of both sides' sums:
+# 3,580); PR 37 selects a position's leaf by compares where
+# ``searchsorted`` looped and gathered (3,376).
 # Whoever changes the serial grower knowingly changes this number with
 # it.
-SERIAL_INSTRUCTIONS = 3580
+SERIAL_INSTRUCTIONS = 3376
 COLLECTIVES = ("all-reduce", "all-reduce-start", "reduce-scatter",
                "all-gather", "all-gather-start", "all-to-all",
                "collective-permute", "collective-permute-start")
