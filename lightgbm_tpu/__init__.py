@@ -9,6 +9,10 @@ inside shard_map.
 
 __version__ = "0.1.0"
 
+import time as _time
+# the set-up account's origin (obs/setup.py): before the first import
+_T0_WALL, _T0_PERF = _time.time(), _time.perf_counter()
+
 from .config import Config  # noqa: F401
 from .io import BinnedDataset, BinMapper, Metadata  # noqa: F401
 from .basic import Booster, Dataset  # noqa: F401
@@ -26,6 +30,11 @@ try:
     _PLOTTING = ["plot_importance", "plot_metric", "plot_tree"]
 except ImportError:  # matplotlib not installed
     _PLOTTING = []
+
+obs.setup.open_account(_T0_WALL, _T0_PERF)
+with obs.span("Setup::import", start=_T0_PERF):
+    pass                    # everything above: jax's import, where this
+                            # package is what imports jax first
 
 __all__ = ["Dataset", "Booster", "Config",
            "train", "train_delta", "cv", "CVBooster",

@@ -91,6 +91,11 @@ class Dataset:
     def construct(self) -> "Dataset":
         if self._binned is not None:
             return self
+        from . import obs
+        with obs.span("Dataset::construct"):
+            return self._construct()
+
+    def _construct(self) -> "Dataset":
         if self.reference is not None:
             ref = self.reference.construct()._binned
         else:
@@ -495,9 +500,12 @@ class Booster:
                 # .train pushes the full params dict the same way)
                 train_set._update_params(
                     {"linear_tree": params["linear_tree"]})
-            train_set.construct()
-            self.config = Config({**train_set.params, **params})
-            self._booster = create_boosting(self.config, train_set._binned)
+            from . import obs
+            with obs.span("Booster::init"):
+                train_set.construct()
+                self.config = Config({**train_set.params, **params})
+                self._booster = create_boosting(self.config,
+                                                train_set._binned)
             self._train_set = train_set
         elif model_file is not None:
             with open(model_file) as fh:

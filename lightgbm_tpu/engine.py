@@ -50,6 +50,10 @@ def train(params, train_set, num_boost_round=100,
     checksum and fall back to the previous one."""
     params = dict(params or {})
     events_file = events_file or params.get("events_file") or None
+    # the job's set-up account (obs/setup.py): the one opened at package
+    # import while that is still open, so a Dataset.construct that ran
+    # before this call is inside it; a later job's opens here
+    obs.setup.ensure_open()
     # -- persistent XLA compile cache (utils/compile_cache.py): applied
     # BEFORE any device work so the training programs themselves are
     # covered — repeated/resumed runs load executables from disk instead

@@ -9,6 +9,7 @@ device during training; metrics pull them once per eval.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import io
 import os
@@ -242,6 +243,7 @@ class _DeviceData:
         obs.devprof.transfer("h2d", "dataset",
                              h2d_bytes + int(init.nbytes),
                              transfers=h2d_xfers + 1)
+        obs.setup.placed(h2d_bytes + int(init.nbytes))
 
     def host_score(self, dtype=np.float64) -> np.ndarray:
         """[num_models, num_data] host copy of the score cache with the
@@ -578,6 +580,7 @@ class GBDT:
     _cum_comm_bytes = 0
     _cum_comm_calls = 0
     _bag_cnt = 0                  # rows in the current bagging draw
+    _first_round_done = False     # GBDT::first_round, the set-up account
     _pending_iter_idx = -1        # iteration index of _pending_iter
     # -- fault tolerance (docs/FAULT_TOLERANCE.md) ----------------------
     _nan_policy = "none"          # none | fail_fast | skip_tree
@@ -604,7 +607,8 @@ class GBDT:
         self.label_idx = 0
         self.sigmoid = (config.sigmoid if config.objective == "binary" else -1.0)
         if train_set is not None:
-            self._setup(train_set, objective)
+            with obs.span("GBDT::setup"):
+                self._setup(train_set, objective)
 
     # ------------------------------------------------------------------
     def _setup(self, train_set: BinnedDataset, objective) -> None:
@@ -1703,8 +1707,18 @@ class GBDT:
         # round_scope splits the span's wall time into host vs device
         # shares from the device-seconds estimate accumulated inside it
         # (no-op unless devprof is on — the span itself never syncs)
-        with obs.devprof.round_scope(), obs.span("GBDT::iteration"):
-            return self._train_one_iter_impl(grad, hess)
+        # a booster's first round holds the step's compile or cache load;
+        # when it returns the job's set-up is over (obs/setup.py).  The
+        # round's own span stays the outer one: a round is a trace root
+        first, self._first_round_done = not self._first_round_done, True
+        try:
+            with obs.devprof.round_scope(), obs.span("GBDT::iteration"), \
+                    (obs.span("GBDT::first_round") if first
+                     else contextlib.nullcontext()):
+                return self._train_one_iter_impl(grad, hess)
+        finally:
+            if first:
+                obs.setup.close(self._telemetry)
 
     # -- distributed desync detection ----------------------------------
     def _maybe_check_consistency(self) -> bool:

@@ -47,11 +47,22 @@ one end-to-end number.  Pieces:
   and of a reduced device window (``--device-trace``).
 - ``compile_ledger``: process-wide account of every XLA compilation —
   program name, abstract input shapes, wall seconds, whether the
-  persistent cache served it — captured by
-  ``instrumented_jit`` at the repo's own jit entry points, feeding
+  persistent cache served it, and the seconds split by stage
+  (``trace_s``, ``lower_s``, ``backend_s``, ``cache_read_s``,
+  ``other_s``, from the public ``jax.monitoring`` listeners) — captured
+  by ``instrumented_jit`` at the repo's own jit entry points, feeding
   ``compile_count``/``compile_seconds`` registry series and an
   append-only ``compile_ledger.jsonl``
-  (``LIGHTGBM_TPU_COMPILE_LEDGER``/``compile_ledger_file``).
+  (``LIGHTGBM_TPU_COMPILE_LEDGER``/``compile_ledger_file``).  The
+  compilations no instrumented call made are counted beside it
+  (``compile_unledgered_count``/``compile_unledgered_seconds``).
+- ``setup``: the set-up account — from package import to the end of a
+  job's first round, every ``obs.span`` with its start, end and parent
+  and every compilation's stages under the span that was open; reduced
+  once at close to self seconds by span, the uncovered share and the
+  stage sums by program (``obs.setup_account()``, the events stream's
+  one ``setup`` record, the ``setup_*`` gauges, ``obs-report --setup``;
+  docs/OBSERVABILITY.md §Set-up account).
 - ``memwatch``: HBM watermark gauges (live/peak device bytes, per span
   phase) sampled at span boundaries; off by default
   (``memwatch``/``LIGHTGBM_TPU_MEMWATCH``).
@@ -69,10 +80,11 @@ one end-to-end number.  Pieces:
   (``trace_events_file``/``LIGHTGBM_TPU_TRACE_EVENTS``).
 """
 
-from . import devcaps, devprof, devtrace, drift  # noqa: F401
+from . import devcaps, devprof, devtrace, drift, setup  # noqa: F401
 from .compile_ledger import (InstrumentedJit, abstract_shapes,  # noqa: F401
                              instrumented_jit)
-from .events import SCHEMA_VERSION, EventRecorder, read_events  # noqa: F401
+from .events import (SCHEMA_VERSION, EventRecorder,  # noqa: F401
+                     read_events, read_setup)
 from .phases import (DEVICE_PARENT, DEVICE_PHASES,  # noqa: F401
                      HOST_PHASES, JITTED_HOST_PHASES,
                      TRANSFER_PHASES, span_series)
@@ -82,6 +94,7 @@ from .registry import (DEFAULT_BYTE_BUCKETS,  # noqa: F401
                        get_counter, get_gauge, get_histogram,
                        histogram_quantile, inc, merge, observe, reset,
                        restore, set_gauge, snapshot)
+from .setup import setup_account  # noqa: F401
 from .spans import span, timed  # noqa: F401
 from .trace import TraceCapture  # noqa: F401
 from .tracing import TRACER  # noqa: F401
@@ -116,7 +129,8 @@ __all__ = [
     "DEFAULT_TIME_BUCKETS", "DEFAULT_BYTE_BUCKETS",
     "snapshot", "merge", "reset", "restore",
     "span", "timed", "span_series", "labeled_name", "split_series",
-    "EventRecorder", "read_events", "SCHEMA_VERSION",
+    "EventRecorder", "read_events", "read_setup", "SCHEMA_VERSION",
+    "setup", "setup_account",
     "TraceCapture",
     "instrumented_jit", "InstrumentedJit", "abstract_shapes",
     "TRACER", "trace_span", "trace_begin", "trace_end", "trace_link",

@@ -27,6 +27,28 @@ This module is that account:
 - every event says whether the persistent compilation cache served it
   (``cache_hit``, from jax's own monitoring events), so cold and warm
   compile seconds separate.
+- every event splits its ``seconds`` by stage, from the public
+  ``jax.monitoring`` listeners (one duration and one time-span listener
+  beside the cache-hit one, registered at the first instrumented
+  dispatch or the first span of a set-up account, never at import):
+  ``trace_s`` (jaxpr tracing), ``lower_s`` (jaxpr to MLIR),
+  ``backend_s`` (the backend compile, or on a cache hit the whole
+  cached path: key, read, deserialisation), ``cache_read_s`` (inside
+  ``backend_s``; on a hit only), ``saved_s`` (what jax says the hit
+  saved), ``other_s`` (``seconds`` less the first three: executable
+  load, argument placement, dispatch) and ``modules`` (backend compiles
+  inside the call).  What the listeners hear on the calling thread
+  while the compiling call runs belongs to that event; a stage nested
+  inside another (an inner jit traced inside the outer trace, an eager
+  operation compiled while tracing) counts once, under the outer.
+- compilations OUTSIDE an instrumented call (eager ``jax.numpy`` on
+  device arrays, an un-instrumented ``jax.jit``) are heard by the same
+  listeners and kept as a bounded table by jax's ``fun_name``
+  (``unledgered()``), the counter ``compile_unledgered_count`` and the
+  histogram ``compile_unledgered_seconds`` (its sum is the seconds).
+  No event is written for them and ``events()`` does not grow.
+- while a set-up account is open (obs/setup.py) each event's stages
+  enter it as ``Compile::*`` spans under the host span that was open.
 - while an ``obs.TraceCapture`` is armed (``trace_dir``), a compile
   event — or the first dispatch inside the window of a program compiled
   earlier — also exports the program's PHASE MAP: the compiled text
@@ -51,7 +73,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
-from . import devprof, devtrace, registry, trace
+from . import devprof, devtrace, registry, setup, trace
 
 ENV_PATH = "LIGHTGBM_TPU_COMPILE_LEDGER"
 
@@ -64,24 +86,170 @@ _events: List[Dict[str, Any]] = []
 _dropped = 0
 _path: Optional[str] = os.environ.get(ENV_PATH, "").strip() or None
 
-# persistent-cache hits seen by jax's monitoring events; the listener is
-# registered at the first instrumented dispatch, not at import
-_cache_hits = 0
+# jax.monitoring events heard (jax/_src/dispatch.py, compiler.py,
+# compilation_cache.py); the listeners are registered by ``listen()``,
+# not at import
+_STAGE_OF = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_SAVED = "/jax/compilation_cache/compile_time_saved_sec"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS = "/jax/compilation_cache/cache_misses"
+
 _listening = False
+_tls = threading.local()
+# stages a thread may hold outside an instrumented call before a backend
+# compile hands them on: traces that never compile (``jax.eval_shape``)
+# must not pile up.  A call's own stages are not capped: lowering a large
+# program reports thousands of nested traces AFTER the outer trace, and
+# dropping the oldest there would drop the stage itself
+_MAX_HELD = 4096
+# compilations outside any instrumented call, by jax's fun_name:
+# [count, backend_s, trace_s + lower_s]
+_unledgered: Dict[str, List[float]] = {}
 
 
-def _count_cache_hits() -> int:
+class _Stages:
+    """The compile stages one thread has heard, flat and in order of
+    their ends: ``(stage, start, end, cache_read_s)`` on jax's wall
+    clock.  jax reports a nested stage before the one that holds it (an
+    inner jit's trace inside the outer trace, an eager operation
+    compiled while tracing), so a stage that arrives absorbs every
+    earlier one that starts inside it: the partition stays flat and no
+    second is counted twice.  A cache read comes as a duration from
+    inside a backend span, and belongs to the next one to end."""
+
+    __slots__ = ("spans", "hits", "misses", "saved_s", "read_s", "mark",
+                 "cap")
+
+    def __init__(self, cap: Optional[int] = None):
+        self.cap = cap
+        self.spans: List[tuple] = []
+        self.hits = self.misses = 0
+        self.saved_s = self.read_s = 0.0
+        self.mark = 0.0         # end of what was already handed on
+
+    def add(self, stage: str, start: float, end: float) -> None:
+        start = max(start, self.mark)
+        spans, read_s = self.spans, 0.0
+        while spans and spans[-1][1] >= start:
+            spans.pop()
+        if self.cap is not None and len(spans) >= self.cap:
+            del spans[0]
+        if stage == "backend":
+            read_s, self.read_s = self.read_s, 0.0
+        spans.append((stage, start, end, read_s))
+
+    def totals(self):
+        """``({stage: seconds}, cache_read_s, backend compiles)`` of the
+        flat partition."""
+        tot = {"trace": 0.0, "lower": 0.0, "backend": 0.0}
+        read_s, modules = 0.0, 0
+        for stage, start, end, read in self.spans:
+            tot[stage] += end - start
+            read_s += read
+            modules += stage == "backend"
+        return tot, read_s, modules
+
+    def fields(self, seconds: float) -> Dict[str, Any]:
+        """The ledger entry's stage fields for a call of ``seconds``."""
+        tot, read_s, modules = self.totals()
+        out = {k + "_s": round(v, 6) for k, v in tot.items()}
+        out["other_s"] = round(max(seconds - sum(tot.values()), 0.0), 6)
+        out["modules"] = modules
+        if self.hits:
+            out["cache_read_s"] = round(read_s, 6)
+            out["saved_s"] = round(self.saved_s, 6)
+        return out
+
+
+def _heard() -> _Stages:
+    """Where this thread's events go: the instrumented call in flight,
+    else the thread's own account of unledgered compilations."""
+    st = getattr(_tls, "call", None)
+    if st is None:
+        st = getattr(_tls, "ambient", None)
+        if st is None:
+            st = _tls.ambient = _Stages(cap=_MAX_HELD)
+    return st
+
+
+def _on_event(event: str, **_kw) -> None:
+    if event == _CACHE_HIT:
+        _heard().hits += 1
+    elif event == _CACHE_MISS:
+        _heard().misses += 1
+
+
+def _on_duration(event: str, duration: float, **_kw) -> None:
+    # the two cache durations come with no span of their own
+    if event == _CACHE_READ:
+        _heard().read_s += duration
+    elif event == _CACHE_SAVED:
+        _heard().saved_s += duration
+
+
+def _on_span(event: str, start: float, end: float, fun_name: str = "",
+             **_kw) -> None:
+    stage = _STAGE_OF.get(event)
+    if stage is None:
+        return
+    t_in = time.perf_counter()
+    call = getattr(_tls, "call", None)
+    st = call if call is not None else _heard()
+    st.add(stage, start, end)
+    if stage == "backend" and call is None:
+        _note_unledgered(st, str(fun_name))
+    acct = setup.ACTIVE
+    if acct is not None:
+        acct.overhead_s += time.perf_counter() - t_in
+
+
+def _note_unledgered(st: _Stages, name: str) -> None:
+    """A backend compile outside every instrumented call ends a module:
+    it and the trace and lowering heard before it on this thread go to
+    the table, the two registry series and the open set-up account."""
+    tot, _, _ = st.totals()
+    st.mark = st.spans[-1][2]
+    st.spans.clear()
+    st.hits = st.misses = 0
+    st.saved_s = st.read_s = 0.0
+    backend_s, front_s = tot["backend"], tot["trace"] + tot["lower"]
+    registry.inc("compile_unledgered_count")
+    registry.observe("compile_unledgered_seconds", backend_s + front_s)
+    with _lock:
+        setup.bump(_unledgered, name, backend_s, front_s)
+    acct = setup.ACTIVE
+    if acct is not None:
+        acct.unledgered(name, backend_s, front_s)
+
+
+def listen() -> None:
+    """Register the three ``jax.monitoring`` listeners, once a process."""
     global _listening
-    if not _listening:
-        import jax
-
-        def on_event(event: str, **_kw) -> None:
-            global _cache_hits
-            if event == "/jax/compilation_cache/cache_hits":
-                _cache_hits += 1
-        jax.monitoring.register_event_listener(on_event)
+    if _listening:
+        return
+    with _lock:
+        if _listening:
+            return
         _listening = True
-    return _cache_hits
+    import jax
+    jax.monitoring.register_event_listener(_on_event)
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+    jax.monitoring.register_event_time_span_listener(_on_span)
+
+
+def unledgered() -> Dict[str, Dict[str, float]]:
+    """Compilations no instrumented call made, by jax's ``fun_name``:
+    ``{name: {count, backend_s, trace_lower_s}}`` (bounded: past
+    ``setup.MAX_NAMES`` names the rest share one row)."""
+    with _lock:
+        return {k: {"count": int(v[0]), "backend_s": round(v[1], 6),
+                    "trace_lower_s": round(v[2], 6)}
+                for k, v in _unledgered.items()}
 
 
 def configure(path: Optional[str] = None) -> Optional[str]:
@@ -145,7 +313,8 @@ def summary(k: int = 5) -> Dict[str, Any]:
 def record(program: str, shapes: str, seconds: float,
            cost: Optional[Dict[str, Any]] = None,
            cache_hit: Optional[bool] = None,
-           phase_map: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
+           phase_map: Optional[Dict[str, Any]] = None,
+           stages: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
     """Append one compile event; feeds the registry series and the JSONL
     sink.  Called by the instrumented jits — safe to call directly for
     compilations detected by other means.  ``cost`` is the program's
@@ -154,7 +323,8 @@ def record(program: str, shapes: str, seconds: float,
     reported nothing — so ledger consumers see one schema.
     ``cache_hit``: the persistent cache served the executable (None:
     not known).  ``phase_map``: the counts of ``_export_phase_map``,
-    present only when a map was built."""
+    present only when a map was built.  ``stages``: the split of
+    ``seconds`` the listeners heard (``_Stages.fields``)."""
     global _dropped
     registry.inc("compile_count")
     registry.inc("compile_count_" + _sanitize(program))
@@ -170,6 +340,8 @@ def record(program: str, shapes: str, seconds: float,
         "output_bytes": cost.get("output_bytes"),
         "cache_hit": cache_hit,
     }
+    if stages:
+        ev.update(stages)
     if phase_map:
         ev.update(phase_map)
     with _lock:
@@ -400,9 +572,14 @@ class InstrumentedJit:
         structs = (_shape_structs(args, kwargs)
                    if capture is not None else None)
         before = self._cache_size()
-        hits = _count_cache_hits()
+        listen()
+        outer, heard = getattr(_tls, "call", None), _Stages()
+        _tls.call = heard
         t0 = time.perf_counter()
-        out = self._call_guarded(*args, **kwargs)
+        try:
+            out = self._call_guarded(*args, **kwargs)
+        finally:
+            _tls.call = outer
         dt = time.perf_counter() - t0
         compiled = self._cache_size() > before
         pm = None
@@ -414,8 +591,12 @@ class InstrumentedJit:
                 cost = _cost_analysis(self._fn, args, kwargs)
                 if cost:
                     devprof.note_cost(self.program, cost)
-            record(self.program, abstract_shapes(args, kwargs), dt,
-                   cost=cost, cache_hit=_cache_hits > hits, phase_map=pm)
+            ev = record(self.program, abstract_shapes(args, kwargs), dt,
+                        cost=cost, cache_hit=heard.hits > 0, phase_map=pm,
+                        stages=heard.fields(dt))
+            acct = setup.ACTIVE
+            if acct is not None:
+                acct.compiled(ev, heard.spans, heard.misses)
         return out, compiled
 
     def __call__(self, *args, **kwargs):

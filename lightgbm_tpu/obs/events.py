@@ -18,15 +18,21 @@ the start of the device work for *i+1*, and eval callbacks for *i* run
 before ``update(i+1)``).  ``close()`` drains whatever is still pending
 (the final iteration), so callers must flush the booster pipeline before
 closing — ``engine.train`` does this for recorders it owns.
+
+Schema 2 adds ONE record with no iteration number, ahead of the first
+iteration's: ``{"schema": 2, "setup": {...}}``, the job's set-up account
+(obs/setup.py), written when the first round returns.  ``read_events``
+passes over it (it returns the per-iteration records, as before);
+``read_setup`` returns it.
 """
 
 from __future__ import annotations
 
 import json
 import math
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Optional
 
-SCHEMA_VERSION = 1
+SCHEMA_VERSION = 2
 
 
 def _json_default(o):
@@ -118,13 +124,22 @@ class EventRecorder:
         for old in sorted(k for k in self._pending if k < it):
             self._commit(old)
 
+    def write_setup(self, account: Dict[str, Any]) -> None:
+        """The job's set-up account (obs/setup.py), written at once: the
+        first round has just returned and its own record commits only on
+        advance, so this one lands ahead of it."""
+        self._write({"schema": SCHEMA_VERSION, "setup": account})
+
     # -- sink ------------------------------------------------------------
     def _commit(self, it: int) -> None:
         rec = self._pending.pop(it)
         line = {"schema": SCHEMA_VERSION, "iter": it}
-        if self._rank is not None:
-            line["rank"] = self._rank
         line.update(rec)
+        self._write(line)
+
+    def _write(self, line: Dict[str, Any]) -> None:
+        if self._rank is not None:
+            line = {**line, "rank": self._rank}
         ok = self._fh.write(
             json.dumps(_sanitize(line), default=_json_default) + "\n")
         if not ok:
@@ -159,8 +174,7 @@ class EventRecorder:
         self.close()
 
 
-def read_events(path: str) -> List[Dict[str, Any]]:
-    """Parse an events file back into a list of dicts (schema round-trip)."""
+def _read_lines(path: str) -> List[Dict[str, Any]]:
     out: List[Dict[str, Any]] = []
     with open(path) as fh:
         for line in fh:
@@ -168,3 +182,17 @@ def read_events(path: str) -> List[Dict[str, Any]]:
             if line:
                 out.append(json.loads(line))
     return out
+
+
+def read_events(path: str) -> List[Dict[str, Any]]:
+    """Parse an events file back into its per-iteration records (schema
+    round-trip).  The one record without an iteration number, the
+    set-up account, is passed over: ``read_setup`` returns it."""
+    return [rec for rec in _read_lines(path) if "iter" in rec]
+
+
+def read_setup(path: str) -> Optional[Dict[str, Any]]:
+    """The set-up account of an events file (the newest, where several
+    jobs appended to one file); None where the file holds none."""
+    found = [rec["setup"] for rec in _read_lines(path) if "setup" in rec]
+    return found[-1] if found else None

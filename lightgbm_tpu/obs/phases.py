@@ -37,6 +37,20 @@ file path without importing the package (and its jax dependency).
 """
 
 HOST_PHASES = frozenset({
+    # set-up, from package import to the end of the first round: the
+    # set-up account's tree (obs/setup.py).  None of these may start
+    # with ``Bin::``: the one-hot cell's ``bin_sparse_s`` sums every
+    # ``phase_seconds_bin_*`` series (tests/test_phase_lint.py)
+    "Setup::import",      # lightgbm_tpu/__init__, top to bottom (jax's
+                          # import where the package imports it first)
+    "Dataset::construct",  # the whole of Dataset.construct, parent of
+                          # the Bin::* five
+    "Booster::init",      # Booster.__init__ with a train set, parent of
+    "GBDT::setup",        # GBDT._setup: objective init, bundles, the
+                          # device placement, the grower and the step
+    "GBDT::first_round",  # the whole of a booster's first
+                          # GBDT::iteration, just inside it: the
+                          # train_step compile or cache load is in it
     "Bin::bundle",        # EFB bundle planning over the mapper sample
                           # (io/bundling.py, docs/SPARSE.md)
     "Bin::linear_fit",    # per-stage batched leaf ridge solve

@@ -41,6 +41,11 @@ Two sibling inputs ride the same CLI (docs/OBSERVABILITY.md):
   the directory's newest trace afresh when the file is not there): ms a
   round per named device phase, inserted copies, unattributed events,
   idle gaps by host span (docs/OBSERVABILITY.md §Device trace capture);
+- ``--setup`` prints the set-up account the events file carries (its
+  one ``setup`` record, obs/setup.py): the span tree from package
+  import to the end of the first round with self seconds, the compile
+  stages by program, the ten heaviest compilations outside the ledger
+  (docs/OBSERVABILITY.md §Set-up account);
 - ``--drift`` prints the drift observatory's per-model offender table
   (PSI / missing-rate delta per feature, score PSI, window trajectory,
   sustained offenders).  Positional files may be registry-snapshot JSON
@@ -56,7 +61,7 @@ import os
 import sys
 from typing import Any, Dict, List, Optional, Sequence
 
-from .events import read_events
+from .events import read_events, read_setup
 
 
 def _merge_by_iter(evs: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
@@ -624,9 +629,18 @@ def device_trace_report(trace_dir: str) -> Dict[str, Any]:
     return rep
 
 
+def setup_report(path: str) -> Dict[str, Any]:
+    """``--setup``: the events file's set-up account."""
+    acct = read_setup(path)
+    if acct is None:
+        raise ValueError(f"no set-up record in {path}")
+    return acct
+
+
 def main(argv: Optional[Sequence[str]] = None) -> int:
     """CLI entry: ``python -m lightgbm_tpu obs-report <events.jsonl ...>
     [--format=json|table] [--top=K] [--compile=<ledger.jsonl>]``,
+    ``obs-report --setup <events.jsonl>``,
     ``obs-report --traces <trace.json ...>``,
     ``obs-report --profile [<registry_snapshot.json ...>]``,
     ``obs-report --device-trace <trace_dir>``, or
@@ -639,6 +653,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     profile_mode = False
     drift_mode = False
     device_trace_mode = False
+    setup_mode = False
     paths: List[str] = []
     for tok in argv:
         if tok.startswith("--format="):
@@ -660,6 +675,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             drift_mode = True
         elif tok == "--device-trace":
             device_trace_mode = True
+        elif tok == "--setup":
+            setup_mode = True
         elif tok.startswith("-"):
             print(f"obs-report: unknown flag {tok!r}", file=sys.stderr)
             return 2
@@ -669,6 +686,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         print("usage: python -m lightgbm_tpu obs-report <events.jsonl ...> "
               "[--format=json|table] [--top=K] "
               "[--compile=<compile_ledger.jsonl>]\n"
+              "       python -m lightgbm_tpu obs-report --setup "
+              "<events.jsonl> [--format=json|table]\n"
               "       python -m lightgbm_tpu obs-report --traces "
               "<trace_events.json ...> [--format=json|table] [--top=K]\n"
               "       python -m lightgbm_tpu obs-report --profile "
@@ -688,6 +707,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     try:
         if device_trace_mode:
             rep = device_trace_report(paths[0])
+        elif setup_mode:
+            rep = setup_report(paths[0])
         elif drift_mode:
             rep = drift_summary_from_files(paths, top_k=top_k)
         elif profile_mode:
@@ -707,6 +728,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     elif device_trace_mode:
         from . import devtrace
         print(devtrace.render(rep))
+    elif setup_mode:
+        from . import setup
+        print(setup.render(rep))
     elif drift_mode:
         print(render_drift_table(rep))
     elif profile_mode:

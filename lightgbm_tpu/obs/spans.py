@@ -34,6 +34,12 @@ default, both gated on one module-attribute read):
   record many-to-one coalesce edges.
 - memwatch (obs/memwatch.py): when enabled, span exit samples the HBM
   watermark gauges under the span's phase name.
+
+And one that is on from package import to the end of a job's first
+round and off after it (obs/setup.py): while a set-up account is open a
+span also appends ``(name, start, end, parent)`` to it, parent being the
+enclosing open span on this thread.  With no account open that is one
+module-attribute read.
 """
 
 from __future__ import annotations
@@ -43,7 +49,8 @@ import time
 from contextlib import contextmanager
 from typing import Callable, Optional, Sequence
 
-from . import devprof, devtrace, memwatch, phases, registry, tracing
+from . import (compile_ledger, devprof, devtrace, memwatch, phases,
+               registry, setup, tracing)
 
 
 # span names are a small fixed set (the phase taxonomy); memoize the
@@ -78,10 +85,14 @@ class _SpanHandle:
 
 @contextmanager
 def span(name: str, buckets: Optional[Sequence[float]] = None,
-         reg: Optional[registry.Registry] = None):
+         reg: Optional[registry.Registry] = None,
+         start: Optional[float] = None):
     """Time this block into the ``span_series(name)`` wall-time
-    histogram, the profiler's trace (``lgbt:<name>``) and the timetag
-    accumulator when that mode is on."""
+    histogram, the profiler's trace (``lgbt:<name>``), the timetag
+    accumulator when that mode is on and the set-up account while one is
+    open.  ``start``: a ``time.perf_counter`` reading the span counts
+    from in place of now (the package's import began before ``obs``
+    could be imported)."""
     import jax
     from ..utils import timetag
     r = reg if reg is not None else registry.REGISTRY
@@ -91,7 +102,14 @@ def span(name: str, buckets: Optional[Sequence[float]] = None,
     if tracing.TRACER.enabled:
         handle.trace = tracing.TRACER.begin(name)
         token = tracing.push(handle.trace)
-    t0 = time.perf_counter()
+    t0 = time.perf_counter() if start is None else start
+    acct = setup.ACTIVE
+    if acct is not None:
+        if start is None:
+            # hear the compilations of set-up from its first live span
+            # on (never at import, where ``start`` is given)
+            compile_ledger.listen()
+        idx = acct.enter(name, t0)
     try:
         with jax.profiler.TraceAnnotation(devtrace.HOST_SPAN_PREFIX + name):
             yield handle
@@ -101,6 +119,8 @@ def span(name: str, buckets: Optional[Sequence[float]] = None,
             # mode's perturbation shows up in its own profile
             devprof.sync(handle.value, source=name)
         dt = time.perf_counter() - t0
+        if acct is not None:
+            acct.exit(idx, t0 + dt)
         r.observe(_series(name), dt, buckets)
         if serialize:
             timetag.add(name, dt)

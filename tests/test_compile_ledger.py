@@ -208,3 +208,152 @@ def test_obs_report_compile_section(tmp_path):
     assert rep["programs"]["grow_tree"]["seconds"] == pytest.approx(180.75)
     assert rep["slowest"][0] == {"program": "grow_tree",
                                  "shapes": "u8[28,100]", "seconds": 120.5}
+
+
+# -- compile stages and unledgered compilations (ISSUE 38) ------------------
+
+STAGES = ("trace_s", "lower_s", "backend_s", "other_s")
+
+
+@pytest.fixture
+def persistent_cache(tmp_path):
+    """jax's persistent compilation cache in ``tmp_path``, taking every
+    program however small and fast; the process's own settings back
+    afterwards."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    old = {n: getattr(jax.config, n) for n in names}
+    cc.reset_cache()
+    for n, v in zip(names, (str(tmp_path / "cache"), 0.0, -1)):
+        jax.config.update(n, v)
+    yield
+    cc.reset_cache()
+    for n, v in old.items():
+        jax.config.update(n, v)
+
+
+def _toy(x):
+    return jnp.sin(x) @ x.T + jnp.where(x > 0, x, -x).sum()
+
+
+def test_compile_event_splits_its_seconds_by_stage(persistent_cache):
+    """Cold: trace, lowering and backend compile heard from
+    ``jax.monitoring`` on the calling thread, ``other_s`` the rest; the
+    same program from the persistent cache: ``cache_hit`` and
+    ``cache_read_s`` inside ``backend_s``."""
+    import jax
+    fn = obs.instrumented_jit(_toy, program="t_stages")
+    e0 = len(compile_ledger.events())
+    fn(jnp.ones((32, 32)))
+    fn(jnp.ones((32, 32)))                 # warm: records nothing
+    (cold,) = compile_ledger.events()[e0:]
+    assert cold["program"] == "t_stages" and cold["cache_hit"] is False
+    for f in STAGES:
+        assert cold[f] >= 0.0, f
+    assert cold["trace_s"] > 0 and cold["lower_s"] > 0 \
+        and cold["backend_s"] > 0
+    assert sum(cold[f] for f in STAGES) == pytest.approx(
+        cold["seconds"], abs=0.05, rel=0.02)
+    assert cold["modules"] == 1
+    assert "cache_read_s" not in cold and "saved_s" not in cold
+
+    jax.clear_caches()
+    fn(jnp.ones((32, 32)))
+    warm = compile_ledger.events()[-1]
+    assert warm["program"] == "t_stages" and warm["cache_hit"] is True
+    assert 0.0 < warm["cache_read_s"] <= warm["backend_s"]
+    assert "saved_s" in warm
+    assert sum(warm[f] for f in STAGES) == pytest.approx(
+        warm["seconds"], abs=0.05, rel=0.02)
+    # the old fields keep their names and meaning
+    for ev in (cold, warm):
+        assert {"program", "shapes", "seconds", "t", "cache_hit"} <= set(ev)
+
+
+def test_unledgered_compile_is_counted_and_not_recorded():
+    """An un-instrumented ``jax.jit`` compiled between two instrumented
+    ones: in the table and the two registry series, not in
+    ``events()``."""
+    import jax
+    a = obs.instrumented_jit(lambda x: x * 5 - 1, program="t_before")
+    b = obs.instrumented_jit(lambda x: x * 7 - 2, program="t_after")
+
+    def t_unseen(x):
+        return (x * 11).sum() - 3
+    a(jnp.ones(9))
+    e0 = len(compile_ledger.events())
+    n0 = obs.get_counter("compile_unledgered_count")
+    h0 = (obs.get_histogram("compile_unledgered_seconds")
+          or {"count": 0, "sum": 0.0})
+    row0 = compile_ledger.unledgered().get("jit(t_unseen)", {"count": 0})
+    jax.jit(t_unseen)(jnp.ones(9))
+    assert len(compile_ledger.events()) == e0
+    assert obs.get_counter("compile_unledgered_count") == n0 + 1
+    h1 = obs.get_histogram("compile_unledgered_seconds")
+    assert h1["count"] == h0["count"] + 1 and h1["sum"] > h0["sum"]
+    row = compile_ledger.unledgered()["jit(t_unseen)"]
+    assert row["count"] == row0["count"] + 1
+    assert row["backend_s"] > 0 and row["trace_lower_s"] > 0
+    b(jnp.ones(9))
+    assert [e["program"] for e in compile_ledger.events()[e0:]] == \
+        ["t_after"]
+    assert obs.get_counter("compile_unledgered_count") == n0 + 1
+
+
+@pytest.mark.parametrize("case", ["nested_trace", "eager_in_trace",
+                                  "cache_read", "siblings",
+                                  "traces_while_lowering"])
+def test_stage_spans_stay_a_flat_partition(case):
+    """jax reports a nested stage before the one that holds it; a stage
+    that arrives absorbs what started inside it, so no second counts
+    twice; a cache read belongs to the backend span that ends next."""
+    st = compile_ledger._Stages()
+    if case == "nested_trace":
+        st.add("trace", 10.2, 10.3)         # an inner jit, traced inside
+        st.add("trace", 10.5, 10.6)
+        st.add("trace", 10.0, 11.0)         # the outer trace ends last
+        got = st.fields(1.5)
+        assert got["trace_s"] == 1.0 and got["other_s"] == 0.5
+    elif case == "eager_in_trace":
+        for stage, s, e in (("trace", 10.1, 10.2), ("lower", 10.2, 10.3),
+                            ("backend", 10.3, 10.6), ("trace", 10.0, 11.0),
+                            ("lower", 11.0, 11.5), ("backend", 11.5, 13.0)):
+            st.add(stage, s, e)
+        got = st.fields(3.25)
+        assert (got["trace_s"], got["lower_s"], got["backend_s"]) == \
+            (1.0, 0.5, 1.5)
+        assert got["modules"] == 1 and got["other_s"] == 0.25
+    elif case == "cache_read":
+        st.hits, st.saved_s = 1, 40.0
+        st.add("trace", 10.0, 10.5)
+        st.read_s += 0.75                   # heard inside the backend span
+        st.add("backend", 10.5, 12.0)
+        got = st.fields(2.0)
+        assert got["backend_s"] == 1.5 and got["cache_read_s"] == 0.75
+        assert got["saved_s"] == 40.0
+        assert st.spans[-1] == ("backend", 10.5, 12.0, 0.75)
+    elif case == "traces_while_lowering":
+        # lowering a large program reports thousands of nested traces
+        # AFTER the outer trace has ended (the chip's ``train_step``):
+        # none of them may push the trace itself out
+        st.add("trace", 10.0, 11.0)
+        for k in range(3 * compile_ledger._MAX_HELD):
+            st.add("trace", 11.1 + k * 1e-4, 11.1 + (k + 0.5) * 1e-4)
+        st.add("lower", 11.0, 13.0)
+        st.add("backend", 13.0, 14.0)
+        got = st.fields(4.0)
+        assert (got["trace_s"], got["lower_s"], got["backend_s"]) == \
+            (1.0, 2.0, 1.0)
+        # outside a call the held stages are bounded
+        amb = compile_ledger._Stages(cap=8)
+        for k in range(100):
+            amb.add("trace", float(k), k + 0.5)
+        assert len(amb.spans) == 8
+    else:
+        st.add("backend", 10.0, 11.0)       # two modules, one after the
+        st.add("backend", 11.0, 13.0)       # other: a kernel's, the step's
+        got = st.fields(3.0)
+        assert got["backend_s"] == 3.0 and got["modules"] == 2
