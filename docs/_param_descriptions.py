@@ -227,8 +227,30 @@ DESC = {
     "max_drop": "dart: max dropped trees per iteration",
     "uniform_drop": "dart: uniform dropping",
     "xgboost_dart_mode": "dart: xgboost normalization mode",
-    "top_rate": "goss: large-gradient keep ratio",
-    "other_rate": "goss: small-gradient sample ratio",
+    "top_rate": ("goss: large-gradient keep ratio: exactly "
+                 "int(top_rate x N) rows kept by abs(g x h) (summed over "
+                 "classes), equal scores by the lower row index; no round "
+                 "samples before int(1 / learning_rate). The draw is a stage "
+                 "of the fused round: one train_step for every round, the "
+                 "exact top set by the goss_sample kernel (no sort), the "
+                 "kept rows moved to the front by the partition kernel and "
+                 "the others routed through the tree (ops/goss.py, "
+                 "ops/ordered_grow.py); the per-stage path is left for a "
+                 "custom fobj, LGBT_NO_FUSED_STEP and a multi-process "
+                 "learner"),
+    "other_rate": ("goss: small-gradient sample ratio: every other real "
+                   "row kept with probability other_rate / (1 - top_rate), "
+                   "its gradient and hessian amplified by (1 - top_rate) / "
+                   "other_rate. The draw is stateless: row r of iteration i "
+                   "under bagging_seed s is kept where fmix32(r * "
+                   "0x9E3779B9 + fmix32(s ^ fmix32(i + 0x9E3779B9))) >> 8 < "
+                   "round(other_rate / (1 - top_rate) * 2^24), in 32-bit "
+                   "unsigned arithmetic (fmix32: MurmurHash3's finaliser), "
+                   "so the same rows come out under any row padding or "
+                   "sharding and a resumed run needs no generator state. "
+                   "Earlier versions drew from a jax.random key split every "
+                   "sampled round: the rows differ from theirs, and their "
+                   "snapshots' goss key is read and ignored"),
     "top_k": "voting-parallel: candidates per shard",
     "output_model": "model save path (train)",
     "input_model": "model load path (predict / continued training)",
@@ -238,7 +260,7 @@ DESC = {
     "bin_construct_sample_cnt": "rows sampled for bin boundary construction",
     "min_data_in_bin": "minimum rows per histogram bin",
     "data_random_seed": "binning/partition seed",
-    "bagging_seed": "bagging seed",
+    "bagging_seed": "bagging seed; also keys GOSS's draw (other_rate)",
     "feature_fraction_seed": "feature subsample seed",
     "drop_seed": "dart dropout seed",
     "has_header": "data files have a header line",

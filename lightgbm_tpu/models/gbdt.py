@@ -28,7 +28,8 @@ from ..io.dataset import BinnedDataset
 from ..ops.bundle import BundleDecode
 from ..metric import Metric, create_metric
 from ..objective import ObjectiveFunction, create_objective
-from ..ops import ordered_grow
+from ..ops import goss as goss_ops, ordered_grow
+from ..ops.goss import GossParams
 from ..ops.grow import (GrowParams, SerialComm, grow_tree, pack_tree_arrays,
                         unpack_tree_arrays)
 from ..ops.predict import (predict_binned_forest,
@@ -449,9 +450,11 @@ def _grower(kind: str, params: GrowParams):
 
 
 def _round_step(objective, num_class: int, guard: bool, grow,
-                linear: Optional[LinearParams] = None):
+                linear: Optional[LinearParams] = None,
+                goss: Optional[GossParams] = None):
     """One fused boosting iteration as a PURE function of device arrays:
-    gradients -> per-class grow -> score update -> packed host vectors.
+    gradients -> [row draw] -> per-class grow -> score update -> packed
+    host vectors.
     ``grow`` is a grower of ``_grower``'s signature; every per-dataset
     array is an ARGUMENT (closed over, the labels were compiled in as a
     constant: 168 MB at 42M rows, on every device, and the persistent
@@ -464,14 +467,28 @@ def _round_step(objective, num_class: int, guard: bool, grow,
     vectors.  ``linear=None`` leaves the trace — and the registry key —
     byte-identical to the pre-linear program.
 
+    ``goss`` (models/goss.py) draws the round's rows from the gradients
+    (ops/goss.py ``sample``, phase ``goss/sample``): ``row_weight`` comes
+    in as the real-row mask, ``sample`` is the round's int32 state
+    (iteration, seed, whether it samples, ``top_cnt``) and the drawn
+    ``(row_weight, factor)`` come back as the step's fourth output.  One
+    program serves the rounds that sample and those that do not;
+    ``goss=None`` leaves the trace as it was.
+
     Every operation traced here sits under one leaf phase of
     obs/phases.py ROUND_PHASES (the growers scope their own), so a
     profiler window reduces to named phases (obs/devtrace.py)."""
     def step_fn(score, feat_masks, row_weight, lr, view, num_bin, is_cat,
-                grad_arrays, raw=None):
+                grad_arrays, raw=None, sample=None):
         with jax.named_scope("gradients"):
             grad, hess = objective.gradients_with(grad_arrays, score)
             ok = (_all_finite(grad, hess) if guard else jnp.asarray(True))
+        drawn = None
+        if goss is not None:
+            drawn = row_weight, factor = goss_ops.sample(
+                grad, hess, row_weight, sample, goss)
+            with jax.named_scope("goss/sample"):
+                grad, hess = grad * factor, hess * factor
         outs = []
         for cls in range(num_class):
             with jax.named_scope("gradients"):
@@ -491,30 +508,31 @@ def _round_step(objective, num_class: int, guard: bool, grow,
                 if linear is not None:
                     packed += pack_linear(coeff, feat, fb)
             outs.append((packed, ta, delta) + lin)
-        return score, outs, ok
+        return score, outs, ok, drawn
     return step_fn
 
 
 def _shared_train_step(objective, num_class: int, guard: bool, kind: str,
                        params: GrowParams, donate: bool,
-                       linear: Optional[LinearParams] = None):
+                       linear: Optional[LinearParams] = None,
+                       goss: Optional[GossParams] = None):
     key = ("train_step", objective.program_key(), num_class, guard, kind,
-           params, donate, linear)
+           params, donate, linear, goss)
     holder = objective.program_holder()
 
     def make():
         body = _round_step(holder, num_class, guard, _grower(kind, params),
-                           linear)
+                           linear, goss)
 
         # the registry's program keeps its flat argument list: the order
         # of a program's parameters is part of its compiled text, and
         # with it of the persistent cache's key
         def step_fn(score, feat_masks, row_weight, lr, bins, num_bin,
                     is_cat, grad_arrays, bins_rm, bins_words, bundle,
-                    raw=None):
+                    raw=None, sample=None):
             return body(score, feat_masks, row_weight, lr,
                         _HistView(bins, bins_rm, bins_words, bundle),
-                        num_bin, is_cat, grad_arrays, raw)
+                        num_bin, is_cat, grad_arrays, raw, sample)
         return step_fn
     return _shared_jit(
         key, make, program="train_step",
@@ -527,7 +545,7 @@ def _shared_train_step(objective, num_class: int, guard: bool, kind: str,
 
 def _shared_linear_fit(linear: LinearParams):
     """Shared jitted program for the PER-STAGE path's batched leaf fit
-    (GOSS, custom fobj, LGBT_NO_FUSED_STEP — the fused path inlines
+    (custom fobj, LGBT_NO_FUSED_STEP — the fused path inlines
     fit_leaf_models into train_step instead).  Keyed on the static
     LinearParams alone: every per-dataset array travels as an argument,
     so rebuilt boosters reuse the compiled program."""
@@ -542,6 +560,11 @@ def _shared_linear_fit(linear: LinearParams):
 
 
 _PACK_LINEAR = obs.instrumented_jit(pack_linear, program="pack_tree")
+
+# the per-stage path's GOSS draw (custom fobj, LGBT_NO_FUSED_STEP, a
+# multi-process learner): the fused round's own stage, alone
+_GOSS_DRAW = obs.instrumented_jit(goss_ops.sample, program="goss_draw",
+                                  static_argnames=("params",))
 
 
 class GBDT:
@@ -580,6 +603,9 @@ class GBDT:
     _cum_comm_bytes = 0
     _cum_comm_calls = 0
     _bag_cnt = 0                  # rows in the current bagging draw
+    # -- GOSS (models/goss.py, ops/goss.py; None = no draw by gradients) -
+    _goss: Optional[GossParams] = None
+    _goss_factor = None           # the last round's amplification [N]
     _first_round_done = False     # GBDT::first_round, the set-up account
     _pending_iter_idx = -1        # iteration index of _pending_iter
     # -- fault tolerance (docs/FAULT_TOLERANCE.md) ----------------------
@@ -1117,8 +1143,8 @@ class GBDT:
         # them out of the leaf-ordered layout so tree cost tracks the
         # subsample (gbdt.cpp:271-278's bag-subset dataset switch).
         # GOSS qualifies only when it can actually sample (top+other < 1);
-        # its 1/learning_rate warmup rounds still pay the compaction sort
-        # on an all-active mask — accepted, the steady state dominates.
+        # its 1/learning_rate warmup rounds compact an all-active mask
+        # (one partition of N rows), so that one program serves both.
         goss_samples = (cfg.boosting_type == "goss"
                         and (cfg.top_rate + cfg.other_rate) < 1.0)
         subsampled = (goss_samples
@@ -1498,10 +1524,30 @@ class GBDT:
     def _gradients(self) -> Tuple[jax.Array, jax.Array]:
         return self._grad_fn(self.train_data.score)
 
-    def _transform_host_gradients(self, grad, hess):
-        """Hook for subclasses that post-process gradients regardless of
-        their source (GOSS sampling/amplification); identity here."""
-        return grad, hess
+    def _goss_round(self) -> Optional[jax.Array]:
+        """This round's state of the GOSS draw (ops/goss.py ``sample``):
+        int32 iteration, ``bagging_seed``'s bits, whether the round
+        samples (not in the first ``1 / learning_rate`` rounds,
+        goss.hpp:129, nor where ``top_rate`` keeps no row or the two
+        rates keep them all) and ``top_cnt``; None where the booster does
+        not sample by gradients."""
+        g = self._goss
+        if g is None:
+            return None
+        n = self.num_data
+        top_cnt, other_cnt = int(g.top_rate * n), int(g.other_rate * n)
+        warmup = int(1.0 / max(self.config.learning_rate, 1e-12))
+        sampled = (self.iter_ >= warmup and top_cnt > 0
+                   and top_cnt + other_cnt < n)
+        # telemetry: the draw is this round's "bag" (a*N + b*N rows)
+        self._bag_cnt = top_cnt + other_cnt if sampled else n
+        if sampled:
+            obs.inc("bagging_draws")
+            obs.inc("goss_sampled_rounds")
+        seed = int(self.config.bagging_seed) & 0xFFFFFFFF
+        return jnp.asarray(
+            [self.iter_, seed - (1 << 32) if seed >= 1 << 31 else seed,
+             int(sampled), top_cnt], jnp.int32)
 
     def _make_train_step(self):
         """One fused jit for a full boosting iteration on the standard
@@ -1528,22 +1574,22 @@ class GBDT:
         jit = _shared_train_step(self.objective, self.num_class, guard,
                                  self._grower_kind, self.grow_params,
                                  donate=not guard and self._donation_on(),
-                                 linear=self._linear)
+                                 linear=self._linear, goss=self._goss)
         num_bin, is_cat = self.num_bin, self.is_cat
         grad_arrays = self._grad_arrays
         raw = self.train_data.raw if self._linear is not None else None
 
-        def args(score, feat_masks, row_weight, lr, view):
+        def args(score, feat_masks, row_weight, lr, view, sample=None):
             return (score, feat_masks, row_weight, lr, view.bins,
                     num_bin, is_cat, grad_arrays, view.bins_rm,
-                    view.bins_words, view.bundle, raw)
+                    view.bins_words, view.bundle, raw, sample)
 
-        def step(*a):
-            return jit(*args(*a))
+        def step(*a, sample=None):
+            return jit(*args(*a, sample))
         # same passthrough InstrumentedJit gives the distributed step:
         # callers inspect the lowered program (chip_smoke.py looks for
         # the Pallas custom call in the compiled text)
-        step.lower = lambda *a: jit.lower(*args(*a))
+        step.lower = lambda *a, sample=None: jit.lower(*args(*a, sample))
         return step
 
     def _make_train_step_local(self, guard: bool):
@@ -1554,13 +1600,14 @@ class GBDT:
         refuses ``linear_tree`` under distributed training."""
         step_fn = obs.instrumented_jit(
             _round_step(self.objective.program_holder(), self.num_class,
-                        guard, self._grow_fn),
+                        guard, self._grow_fn, goss=self._goss),
             program="train_step")
         data = (self.num_bin, self.is_cat, self._grad_arrays)
 
-        def step(*a):
-            return step_fn(*a, *data)
-        step.lower = lambda *a: step_fn.lower(*a, *data)
+        def step(*a, sample=None):
+            return step_fn(*a, *data, None, sample)
+        step.lower = lambda *a, sample=None: step_fn.lower(*a, *data, None,
+                                                           sample)
         return step
 
     # -- pipelined host materialization --------------------------------
@@ -1875,18 +1922,16 @@ class GBDT:
         if self._trace is not None:
             self._trace.iter_begin(it, sync=self.train_data.score)
         # The fused step computes gradients INSIDE the jit and never calls
-        # the _gradients / _transform_host_gradients hooks, so it only
-        # applies when this instance uses the base implementations of ALL
-        # per-round hooks (GOSS sampling/amplification and custom boosters
-        # override them and need the per-stage path; _bagging_mask is
+        # the _gradients hook, so it only applies when this instance uses
+        # the base implementations of the per-round hooks (a custom booster
+        # that overrides one needs the per-stage path; _bagging_mask is
         # checked too, conservatively, so any hook override routes through
-        # the path that visibly runs every hook).  LGBT_NO_FUSED_STEP=1/
-        # true also forces per-stage (same results; smaller XLA programs
-        # for compile-constrained setups).
+        # the path that visibly runs every hook).  GOSS's draw is a stage
+        # of both paths.  LGBT_NO_FUSED_STEP=1/true also forces per-stage
+        # (same results; smaller XLA programs for compile-constrained
+        # setups).
         fused = (grad is None and hess is None
                  and type(self)._gradients is GBDT._gradients
-                 and type(self)._transform_host_gradients
-                 is GBDT._transform_host_gradients
                  and type(self)._bagging_mask is GBDT._bagging_mask
                  and jax.process_count() == 1  # multihost grow fn is a
                  # host-side bridge (globalize_grow_fn), not jit-traceable
@@ -1917,14 +1962,17 @@ class GBDT:
             # standard objective: ONE device dispatch for the whole round
             with obs.span("GBDT::bagging"):
                 row_weight = self._bagging_mask(self.iter_)
+                sample = self._goss_round()
             if self._train_step is None:
                 self._train_step = self._make_train_step()
             feat_masks = self._feature_masks_all()
             with obs.span("GBDT::tree") as tt:
-                self.train_data.score, outs, gh_ok = self._train_step(
+                self.train_data.score, outs, gh_ok, drawn = self._train_step(
                     self.train_data.score, feat_masks, row_weight, lr_dev,
-                    view)
+                    view, sample=sample)
                 tt.sync(self.train_data.score)
+            if drawn is not None:
+                self._row_weight, self._goss_factor = drawn
             if guard:
                 ok_gh, ok_sc = jax.device_get(
                     (gh_ok, _all_finite(self.train_data.score)))
@@ -1950,10 +1998,10 @@ class GBDT:
                         tt.sync(vdeltas)
                     cur.append((packed, delta, vdeltas))
         else:
-            # per-stage path: custom fobj, GOSS-style _gradients hooks, or
-            # LGBT_NO_FUSED_STEP.  Gradients BEFORE the bagging mask:
-            # GOSS._gradients draws this round's sample and the mask read
-            # must see it (gbdt.cpp Bagging-before-Boosting ordering).
+            # per-stage path: custom fobj, overridden _gradients hooks, a
+            # multi-process learner or LGBT_NO_FUSED_STEP.  Gradients
+            # BEFORE the row draw: GOSS draws this round's sample from them
+            # (gbdt.cpp Bagging-before-Boosting ordering).
             with obs.span("GBDT::boosting") as tt:
                 if grad is None or hess is None:
                     grad, hess = self._gradients()
@@ -1968,10 +2016,6 @@ class GBDT:
                         # row_weight keeps it out of every tree)
                         w = ((0, 0), (0, self._padded_rows - grad.shape[1]))
                         grad, hess = jnp.pad(grad, w), jnp.pad(hess, w)
-                    # GOSS-style subclasses sample/amplify host-provided
-                    # gradients too (the reference Bagging step is
-                    # objective-agnostic)
-                    grad, hess = self._transform_host_gradients(grad, hess)
                 tt.sync((grad, hess))
             if guard and not bool(_all_finite(grad, hess)):
                 # caught BEFORE growing: the poisoned round skips the
@@ -1979,6 +2023,15 @@ class GBDT:
                 poisoned = "gradients/hessians"
             with obs.span("GBDT::bagging"):
                 row_weight = self._bagging_mask(self.iter_)
+                sample = self._goss_round()
+                if sample is not None:
+                    # the GOSS draw is objective-agnostic (the reference's
+                    # Bagging step): host-provided gradients sample too
+                    row_weight, self._goss_factor = _GOSS_DRAW(
+                        grad, hess, row_weight, sample, params=self._goss)
+                    self._row_weight = row_weight
+                    grad = grad * self._goss_factor
+                    hess = hess * self._goss_factor
             classes = range(self.num_class) if poisoned is None else ()
             for cls in classes:
                 feat_mask = self._feature_mask()
@@ -2131,8 +2184,8 @@ class GBDT:
     # Crash-safe snapshot/resume state hooks (lightgbm_tpu/snapshot.py).
     # Everything ``init_model`` continued training DISCARDS lives here:
     # score caches, RNG streams, bag state, best-iteration bookkeeping.
-    # Subclasses with extra mutable state (DART drop weights, GOSS
-    # sampling key) extend both hooks.
+    # Subclasses with extra mutable state (DART drop weights) extend both
+    # hooks; GOSS's draw is a function of the iteration and needs none.
 
     def snapshot_state(self) -> Dict:
         """Full resumable training state, host-side.  Flushes the

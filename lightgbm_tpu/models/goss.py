@@ -6,19 +6,21 @@ gradient amplification x (1-a)/b (goss.hpp:79-124); no sampling during the
 first 1/learning_rate iterations (goss.hpp:129); bagging combination is
 forbidden (checked at config time).
 
-TPU formulation: instead of the reference's per-thread ArgMaxAtK partition,
-the threshold is the (top_cnt)-th largest |g*h| from one device sort, and
-the random keep/amplify decision is a vectorized mask.  Amplification is
-applied to gradients AND hessians (like the reference, goss.hpp:108-118)
-while the 0/1 row mask keeps leaf counts meaning true row counts.
+The draw is a stage of the fused round (models/gbdt.py ``_round_step``,
+ops/goss.py ``sample``): the exact top set by the ``goss_sample`` kernel,
+the rest by a stateless function of (``bagging_seed``, iteration, row), so
+unsampled and sampled rounds run one compiled ``train_step`` and a
+snapshot needs no generator state.  The grower then compacts the kept
+rows by the partition kernel and routes the others through the tree
+(ops/ordered_grow.py).  Amplification is applied to gradients AND
+hessians (like the reference, goss.hpp:108-118) while the 0/1 row mask
+keeps leaf counts meaning true row counts.
 """
 
 from __future__ import annotations
 
-import jax
-import jax.numpy as jnp
-import numpy as np
-
+from .. import obs
+from ..ops.goss import GossParams
 from ..utils import log
 from .gbdt import GBDT
 
@@ -28,91 +30,13 @@ class GOSS(GBDT):
 
     def __init__(self, config, train_set, objective=None):
         super().__init__(config, train_set, objective)
-        self.top_rate = float(config.top_rate)
-        self.other_rate = float(config.other_rate)
-        if self.top_rate + self.other_rate >= 1.0:
+        self._goss = GossParams(
+            float(config.top_rate), float(config.other_rate),
+            over_devices=getattr(self, "_mesh", None) is not None)
+        if self._goss.top_rate + self._goss.other_rate >= 1.0:
             log.warning("top_rate + other_rate >= 1.0 in GOSS: no sampling")
-        self._goss_key = jax.random.PRNGKey(config.bagging_seed)
-
-    # GBDT.train_one_iter drives these hooks: _gradients() (objective
-    # path) and _transform_host_gradients() (custom-fobj / C API path)
-    # both run the GOSS draw, so sampling happens regardless of where the
-    # gradients come from (the reference's Bagging step is
-    # objective-agnostic, goss.hpp); _bagging_mask serves the mask back.
-    def _gradients(self):
-        grad, hess = super()._gradients()
-        return self._transform_host_gradients(grad, hess)
-
-    def _transform_host_gradients(self, grad, hess):
-        warmup = int(1.0 / max(self.config.learning_rate, 1e-12))
-        if self.iter_ < warmup:
-            # all real rows active (row-bucket pad rows stay at weight 0)
-            self._row_weight = self._ones_weight
-            self._bag_cnt = self.num_data
-            return grad, hess
-        mask, grad, hess = self._sample(grad, hess)
-        self._row_weight = mask
-        # telemetry: the GOSS draw is this round's "bag" (a*N + b*N rows)
-        top_cnt = int(self.top_rate * self.num_data)
-        other_cnt = int(self.other_rate * self.num_data)
-        kept = top_cnt + other_cnt
-        self._bag_cnt = kept if 0 < top_cnt and kept < self.num_data \
-            else self.num_data
-        from .. import obs
-        obs.inc("bagging_draws")
-        return grad, hess
-
-    def _bagging_mask(self, iter_):
-        return self._row_weight
-
-    # -- crash-safe snapshot/resume (lightgbm_tpu/snapshot.py) -----------
-    # _row_weight/_bag_cnt ride in the base state; only the sampling key
-    # is GOSS-specific (the warmup gate derives from iter_).
-    def snapshot_state(self):
-        state = super().snapshot_state()
-        state["goss"] = {"key": np.asarray(self._goss_key)}
-        return state
-
-    def restore_state(self, state):
-        super().restore_state(state)
-        g = state.get("goss")
-        if g is None:
-            log.fatal("snapshot has no GOSS state; it was not taken from "
-                      "a goss booster")
-        self._goss_key = jnp.asarray(g["key"], jnp.uint32)
-
-    def _sample(self, grad, hess):
-        n = self.num_data
-        top_cnt = int(self.top_rate * n)
-        other_cnt = int(self.other_rate * n)
-        if top_cnt + other_cnt >= n or top_cnt == 0:
-            return self._ones_weight, grad, hess
-        # gradients arrive at the padded row-bucket shape; pad rows must
-        # never be drawn (their gradients are real numbers computed off a
-        # zero label), so they rank at -inf and are masked from the
-        # random keep below.  Direct callers (tests) may pass bare [K, N]
-        # gradients — bring them up to the bucket first.
-        np_rows = self._padded_rows
-        if grad.shape[1] < np_rows:
-            w = ((0, 0), (0, np_rows - grad.shape[1]))
-            grad, hess = jnp.pad(grad, w), jnp.pad(hess, w)
-        # |g * h| summed over classes (goss.hpp:90: multiclass sums classes)
-        score = jnp.abs(grad * hess).sum(axis=0)
-        score = jnp.where(self._real_rows, score, -jnp.inf)
-        # EXACTLY top_cnt rows kept (ArgMaxAtK, goss.hpp:79-124): rank by
-        # score with row index as the tie-break, not a >= threshold test —
-        # low-entropy gradients (many equal |g*h|) would otherwise keep
-        # every tie of the top_cnt-th score and overshoot a*N
-        # (round-2 VERDICT weak #8).
-        order = jnp.argsort(-score, stable=True)
-        rank = jnp.zeros(np_rows, jnp.int32).at[order].set(
-            jnp.arange(np_rows, dtype=jnp.int32), unique_indices=True)
-        self._goss_key, sub = jax.random.split(self._goss_key)
-        rand = jax.random.uniform(sub, (np_rows,))
-        keep_prob = self.other_rate / max(1e-12, 1.0 - self.top_rate)
-        is_top = rank < top_cnt
-        is_other_kept = (~is_top) & (rand < keep_prob) & self._real_rows
-        mask = (is_top | is_other_kept).astype(jnp.float32)
-        amp = (1.0 - self.top_rate) / max(self.other_rate, 1e-12)
-        factor = jnp.where(is_other_kept, amp, 1.0)
-        return mask, grad * factor[None, :], hess * factor[None, :]
+        if train_set is not None:
+            obs.set_gauge("goss_top_rows",
+                          int(self._goss.top_rate * self.num_data))
+            obs.set_gauge("goss_other_rows",
+                          int(self._goss.other_rate * self.num_data))

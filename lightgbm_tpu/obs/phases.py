@@ -103,8 +103,7 @@ HOST_PHASES = frozenset({
 # _round_step, ops/ordered_grow.py, ops/leafhist.py).
 ROUND_PHASES = (
     "gradients",          # objective gradients, weighting, root sums
-    "layout",             # digit quantisation, word packing, the
-                          # compaction sort
+    "layout",             # digit quantisation, word packing
     "exchange/root",      # data-parallel shards only (parallel/comm.py
                           # HistExchange): the scales' max and the
                           # root's sums and histogram over the shards
@@ -137,7 +136,8 @@ ROUND_PHASES = (
                           # segment starts sorted, every position's leaf
                           # selected by compares against them, scattered
                           # back to row order, the value selected by the
-                          # leaf; the out-of-bag walk
+                          # leaf; rows outside a sample routed node by
+                          # node (ops/ordered_grow.py route_rows)
     "score_update",
     "pack_tree",
 )
@@ -164,8 +164,19 @@ BUNDLE_PHASES = (
                               # ``find_split``'s place
 )
 
+# What a round that samples rows adds (boosting=goss, bagging); a round
+# over all rows has neither.
+SAMPLE_PHASES = (
+    "goss/sample",            # |g * h|, the goss_sample kernel's exact top
+                              # set and stateless draw (ops/goss.py), the
+                              # amplification of the drawn rows' gradients
+    "split/compact",          # the kept rows to the front of the root
+                              # window by the segment_partition kernel, the
+                              # lanes written back (ops/ordered_grow.py)
+)
+
 DEVICE_PHASES = frozenset(ROUND_PHASES) | frozenset(RANK_PHASES) \
-    | frozenset(BUNDLE_PHASES) | frozenset({
+    | frozenset(BUNDLE_PHASES) | frozenset(SAMPLE_PHASES) | frozenset({
     # ops/grow.py (cached and parallel learners): the reference's three
     "hist",
     "split",
@@ -180,7 +191,8 @@ DEVICE_PHASES = frozenset(ROUND_PHASES) | frozenset(RANK_PHASES) \
 
 DEVICE_PARENT = {
     **{p: "GBDT::tree"
-       for p in ROUND_PHASES + RANK_PHASES + BUNDLE_PHASES},
+       for p in ROUND_PHASES + RANK_PHASES + BUNDLE_PHASES
+       + SAMPLE_PHASES},
     "hist": "GBDT::tree",
     "split": "GBDT::tree",
     "bin_lookup": "Predict::forest",

@@ -200,10 +200,11 @@ class GrowParams(NamedTuple):
     min_gain_to_split: float = 0.0
     max_depth: int = -1
     # bagging/GOSS: physically move zero-weight rows behind the active
-    # segment once per tree so every window/sort/histogram cost tracks the
-    # SUBSAMPLE, not N (gbdt.cpp:271-278's smaller-dataset switch); their
-    # score deltas come from a tree walk like the reference's out-of-bag
-    # AddPredictionToScore.  Only the leaf-ordered grower honors it.
+    # segment once per tree so every window/partition/histogram cost
+    # tracks the SUBSAMPLE, not N (gbdt.cpp:271-278's smaller-dataset
+    # switch); their score deltas come from routing them through the tree
+    # like the reference's out-of-bag AddPredictionToScore.  Only the
+    # leaf-ordered grower honors it.
     compact_inactive: bool = False
 
     def split_params(self) -> SplitParams:

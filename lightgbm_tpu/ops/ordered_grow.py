@@ -63,8 +63,9 @@ PERF.md, "Carried over", dead ends): XLA row gathers run ~12-200 ns/row
 (lowered per-index), so permutation-only layouts that gather payloads on
 demand are 2x SLOWER end-to-end; the
 12-operand sort is a comparison sort (11.7 ns a row slot at 16M rows,
-half of a round at 10.5M: PERF.md, PR 31) and stays where nothing
-measures it: the once-a-tree bagging compaction below.
+half of a round at 10.5M: PERF.md, PR 31), and no longer compacts a
+bag or a GOSS sample either: the partition kernel does, on one bit a
+row.
 
 Per-step bookkeeping (SplitInfo/LeafSplits, serial_tree_learner.cpp:
 167-224) lives in three PACKED buffers so a step issues ~12 indexed
@@ -276,7 +277,8 @@ def sorted_segments(start, cnt, num_leaves, n: int):
     return lo, hi, leaf_sorted
 
 
-def leaf_delta(start, cnt, num_leaves, shrunk, row_ord, n: int):
+def leaf_delta(start, cnt, num_leaves, shrunk, row_ord, n: int,
+               outside=None):
     """Per-row leaf id and leaf value from the contiguous segments:
     ``(leaf_id, output_delta)``, both ``[n]`` in ORIGINAL row order.
 
@@ -294,7 +296,11 @@ def leaf_delta(start, cnt, num_leaves, shrunk, row_ord, n: int):
     a v5e (1,186 of a 2,313 ms round at 12.6M rows), where both selects
     together are under 1 ns a row and the scatter 6 to 7 (PERF.md, PR 37;
     tools/probe_leaf_delta.py times the forms).  The ``[L, n]`` compares
-    are fused into their reductions and never materialised."""
+    are fused into their reductions and never materialised.
+
+    ``outside``: ``(in_sample, leaf)`` ``[n]`` each, where rows outside
+    a compacted sample get their leaf (``route_rows``) before the value
+    is selected."""
     with jax.named_scope("leaf_delta"):
         lo, hi, leaf_sorted = sorted_segments(start, cnt, num_leaves, n)
         pos = jnp.arange(n, dtype=jnp.int32)[None, :]
@@ -304,12 +310,52 @@ def leaf_delta(start, cnt, num_leaves, shrunk, row_ord, n: int):
         # back to ORIGINAL row order: one scatter per tree
         leaf_id = jnp.zeros(n, jnp.int32).at[row_ord[:n]].set(
             leaf_of_pos, unique_indices=True)
+        if outside is not None:
+            leaf_id = jnp.where(outside[0], leaf_id, outside[1])
         bits = jax.lax.bitcast_convert_type(shrunk, jnp.int32)
         leaves = jnp.arange(shrunk.shape[0], dtype=jnp.int32)
         output_delta = jax.lax.bitcast_convert_type(
             jnp.sum(jnp.where(leaf_id[None, :] == leaves[:, None],
                               bits[:, None], 0), axis=0), jnp.float32)
     return leaf_id, output_delta
+
+
+def route_rows(lanes, tree, num_leaves, is_cat, bundle, n: int):
+    """Every row's leaf ``[n]`` int32 by walking the tree node by node
+    over the bin word ``lanes`` in ROW order: a row's place is a node
+    (``>= 0``) or ``~leaf``; node k (a node's children are made after it)
+    sends the rows that stand at it to its children by its split column's
+    byte, read from the one lane that holds it.  Dense: one lane read and
+    the places read and written a node, about 12 bytes a row, with no row
+    gather (a gather walk of the depth costs 12 to 35 ns a row a level;
+    PERF.md, "Dead ends").  The lane is picked by a chain of two-way
+    ``lax.cond``s, as the grower's size class is: an N-way conditional
+    copies what it threads."""
+    def node(k, at):
+        f = jnp.maximum(tree.split_feature[k], 0)
+        col = f if bundle is None else bundle.col[f]
+        word, byte, tbin = col // 4, col % 4, tree.split_bin[k]
+
+        def through(lane):
+            def go(at):
+                fcol = (lane[:n] >> (8 * byte)) & 0xFF
+                if bundle is not None:
+                    # the member's own bin, as split/decode reads it
+                    off, width = bundle.off[f], bundle.width[f]
+                    mine = (fcol >= off) & (fcol < off + width)
+                    fcol = jnp.where(
+                        off > 0, jnp.where(mine, fcol - off + 1, 0), fcol)
+                right = jnp.where(is_cat[f], fcol != tbin, fcol > tbin)
+                to = jnp.where(right, tree.right_child[k],
+                               tree.left_child[k])
+                return jnp.where(at == k, to, at)
+            return go
+        for i, lane in enumerate(lanes):
+            at = jax.lax.cond(word == i, through(lane), lambda a: a, at)
+        return at
+
+    at = jnp.full((n,), jnp.where(num_leaves > 1, 0, -1), jnp.int32)
+    return ~jax.lax.fori_loop(0, num_leaves - 1, node, at)
 
 
 @instrumented_jit(program="grow_tree_ordered",
@@ -414,26 +460,28 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
             row_ord = jnp.arange(N + PAD, dtype=jnp.int32)
 
     if params.compact_inactive:
-        # one stable sort per tree (over the REAL N rows only — the
-        # window pad stays put) moves zero-weight rows behind the active
-        # segment: every later window, partition, and histogram then
-        # costs O(subsample), not O(N) — the reference's bag-subset
-        # dataset switch (gbdt.cpp:271-278)
-        with jax.named_scope("layout"):
-            bag_key = (row_weight <= 0.0).astype(jnp.uint8)
-            ops0 = (bag_key,) + tuple(w[:N] for w in bins_w) \
-                + tuple(w[:N] for w in dig_w) + (row_ord[:N],)
-            sorted0 = jax.lax.sort(ops0, num_keys=1, is_stable=True)
+        # the kept rows to the front of the root window, in their order,
+        # every other row behind them: ONE partition of the largest window
+        # class by the kernel that partitions every split's window (the
+        # pad slots past N hold no kept row and stay where they are).
+        # Every later window, partition and histogram then costs by the
+        # sample, not by N: the reference's bag-subset dataset switch
+        # (gbdt.cpp:271-278).  The lanes in row order stay for routing
+        # the rows outside the sample (``route_rows``).
+        with jax.named_scope("split/compact"):
+            rows_w = bins_w
+            active = row_weight > 0.0
+            kept = jnp.pad(active, (0, PAD - N))
+            parts = partition.stable_partition(
+                tuple(w[:PAD] for w in bins_w + dig_w + (row_ord,)), kept)
 
             def _splice(full, head):
                 return jax.lax.dynamic_update_slice(full, head, (0,))
-            bins_w = tuple(_splice(f, h)
-                           for f, h in zip(bins_w, sorted0[1:1 + W]))
+            bins_w = tuple(_splice(f, h) for f, h in zip(bins_w, parts[:W]))
             dig_w = tuple(_splice(f, h)
-                          for f, h in zip(dig_w,
-                                          sorted0[1 + W:1 + W + DW]))
-            row_ord = _splice(row_ord, sorted0[-1])
-            root_cnt = jnp.sum((row_weight > 0.0).astype(jnp.int32))
+                          for f, h in zip(dig_w, parts[W:W + DW]))
+            row_ord = _splice(row_ord, parts[-1])
+            root_cnt = jnp.sum(active.astype(jnp.int32))
 
     def hist_window(bw_tuple, dw_tuple, off, scnt, Psz: int):
         """[F, 9, B] digit sums over the scnt rows from ``off`` of the
@@ -824,22 +872,14 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
         )
         seg_start, seg_cnt = leaf_i32[:, _LI["start"]], leaf_i32[:, _LI["cnt"]]
 
+    outside = None
+    if params.compact_inactive:
+        # the rows outside the sample never entered a segment: their leaf
+        # by the tree itself, the reference's out-of-bag
+        # AddPredictionToScore (gbdt.cpp UpdateScore)
+        with jax.named_scope("leaf_delta"):
+            outside = (row_weight > 0.0, route_rows(
+                rows_w, tree, num_leaves, is_cat, bundle, N))
     leaf_id, output_delta = leaf_delta(seg_start, seg_cnt, num_leaves,
-                                       shrunk, row_ord, N)
-
-    with jax.named_scope("leaf_delta"):
-        if params.compact_inactive:
-            # zero-weight rows never entered a segment: route them through
-            # the tree like the reference's out-of-bag AddPredictionToScore
-            # (gbdt.cpp UpdateScore; cost ~ actual tree depth via the
-            # while walk in ops/predict.py)
-            from .predict import predict_binned_tree
-            pval, pleaf = predict_binned_tree(
-                tree.split_feature, tree.split_bin,
-                is_cat[jnp.maximum(tree.split_feature, 0)],
-                tree.left_child, tree.right_child, shrunk, bins, L,
-                bundle=bundle)
-            active = row_weight > 0.0
-            leaf_id = jnp.where(active, leaf_id, pleaf)
-            output_delta = jnp.where(active, output_delta, pval)
+                                       shrunk, row_ord, N, outside)
     return tree, leaf_id, output_delta

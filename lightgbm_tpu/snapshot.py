@@ -17,7 +17,8 @@ What a snapshot holds (``GBDT.snapshot_state`` + subclass hooks):
   buffer would re-order float additions),
 - bagging PRNG key + the live row-weight mask and bag count,
 - the feature-fraction RNG state,
-- DART drop-RNG/tree-weight state and the GOSS sampling key,
+- DART drop-RNG/tree-weight state (GOSS's draw is a function of the
+  iteration and needs none; an older snapshot's ``goss`` key is ignored),
 - engine-side eval history (``evals_result``) and best-iteration
   bookkeeping,
 - the obs counter account (``lightgbm_tpu/obs``), restored so telemetry

@@ -266,12 +266,17 @@ def test_goss_keeps_exactly_top_cnt_on_ties():
                   "top_rate": 0.2, "other_rate": 0.0,
                   "num_leaves": 4, "min_data_in_leaf": 10})
     ds = BinnedDataset.from_matrix(X, y, max_bin=16, min_data_in_leaf=10)
+    from lightgbm_tpu.ops import goss as goss_ops
     g = GOSS(cfg, ds)
+    g.iter_ = 100                       # past the 1/learning_rate warmup
     # all gradients identical in magnitude -> every row ties
-    grad = jnp.ones((1, n), jnp.float32)
-    hess = jnp.ones((1, n), jnp.float32)
-    mask, _, _ = g._sample(grad, hess)
+    grad = jnp.ones((1, g._padded_rows), jnp.float32)
+    hess = jnp.ones((1, g._padded_rows), jnp.float32)
+    mask, _ = goss_ops.sample(grad, hess, g._ones_weight, g._goss_round(),
+                              g._goss)
     assert int(np.count_nonzero(np.asarray(mask))) == int(0.2 * n)
+    # the lowest row indices win the ties, and no pad row is drawn
+    assert np.asarray(mask)[:int(0.2 * n)].all()
 
 
 def test_goss_samples_with_custom_fobj():

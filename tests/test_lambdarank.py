@@ -297,7 +297,7 @@ def test_slab_tables_count_what_they_pad():
     assert snap["rank_pair_slots"] >= 2 * pairs
 
 
-def test_three_rounds_pass_the_ranking_cells_own_comparison():
+def test_three_rounds_pass_the_ranking_cells_own_comparison(monkeypatch):
     """Three rounds of ``lightgbm_tpu.train`` with ``group=`` at the
     ranking cell's configuration (136 columns, 255 leaves, the source's
     settings) against the plain ranking reference, through the comparison
@@ -312,8 +312,12 @@ def test_three_rounds_pass_the_ranking_cells_own_comparison():
         if p not in sys.path:
             sys.path.insert(0, p)
     import run as bench_run
-    from harness import correct_rank, data_rank, result
+    from harness import correct_rank, data_rank, reference, result
     import lightgbm_tpu as lgb
+    # the comparison puts the ranking objective in the binary reference's
+    # place for the process; the sparse cell's tests, which may run in the
+    # same worker after this one, need the binary one back
+    monkeypatch.setattr(reference, "objective", reference.objective)
 
     cell = bench_run.load_cell("mslr30k-lambdarank-train")
     cfg = cell["config_file"]

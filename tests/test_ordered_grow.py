@@ -139,24 +139,34 @@ def test_ordered_with_bagging_weights():
                                   np.asarray(leaf_ref))
 
 
-def test_compact_inactive_matches_riding():
+@pytest.mark.parametrize("data", ["plain", "bundled"])
+def test_compact_inactive_matches_riding(data):
     """compact_inactive=True (bagging compaction, gbdt.cpp:271-278) must
     produce the identical tree AND identical leaf routing / deltas for
-    EVERY row — active rows via segments, zero-weight rows via the
-    out-of-bag tree walk."""
-    bins, num_bin, is_cat, feat_mask, g, h, w = _data(n=9000, cat_feature=True)
+    EVERY row — active rows via segments after the partition kernel's
+    compaction, zero-weight rows routed node by node through the tree
+    (``route_rows``; on EFB columns by the split member's decode)."""
+    dec = None
+    if data == "bundled":
+        _, y, ds = _bundled(9000, 3, "numeric_member")
+        (bins, num_bin, is_cat, feat_mask, g, h, _, _), dec = \
+            _grow_args(ds, y)
+        base = GrowParams(num_leaves=31, max_bin=255, min_data_in_leaf=0,
+                          min_sum_hessian_in_leaf=5.0)
+    else:
+        bins, num_bin, is_cat, feat_mask, g, h, _ = _data(
+            n=9000, cat_feature=True)
+        base = GrowParams(num_leaves=15, max_bin=32, min_data_in_leaf=20,
+                          min_sum_hessian_in_leaf=1.0)
     rng = np.random.RandomState(5)
     w = jnp.asarray((rng.uniform(size=9000) < 0.35).astype(np.float32))
-    base = GrowParams(num_leaves=15, max_bin=32, min_data_in_leaf=20,
-                      min_sum_hessian_in_leaf=1.0)
-    bins_rm = jnp.asarray(np.ascontiguousarray(np.asarray(bins).T))
     lr = jnp.float32(0.1)
     t_ref, leaf_ref, delta_ref = grow_tree_ordered(
-        bins, num_bin, is_cat, feat_mask, g, h, w, lr, base,
-        bins_rm=bins_rm)
+        bins, num_bin, is_cat, feat_mask, g, h, w, lr, base, bundle=dec)
     t_cmp, leaf_cmp, delta_cmp = grow_tree_ordered(
         bins, num_bin, is_cat, feat_mask, g, h, w, lr,
-        base._replace(compact_inactive=True), bins_rm=bins_rm)
+        base._replace(compact_inactive=True), bundle=dec)
+    assert int(t_ref.num_leaves) > 8
     assert int(t_cmp.num_leaves) == int(t_ref.num_leaves)
     for field in ("split_feature", "split_bin", "left_child", "right_child",
                   "leaf_count", "leaf_parent", "leaf_depth"):

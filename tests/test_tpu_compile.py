@@ -565,6 +565,108 @@ def test_partition_output_reaches_its_consumers_as_it_lies(
                 if instrs[r]["opcode"] == "fusion"]
 
 
+@pytest.mark.parametrize("rows", [4096, 1 << 20, 12_582_912])
+def test_goss_sample_kernel_compiles(spec, rows):
+    """GOSS's exact top-a select (ops/goss.py): the smallest block, a
+    million rows and the one-hot cell's padded 12,582,912, one
+    ``pallas_call`` of 8 value passes, the index passes and the write
+    pass over blocks of keys, the counts in vector accumulators and the
+    picks in SMEM, under its own name."""
+    from lightgbm_tpu.ops import goss
+    _assert_kernel_compiles(
+        lambda k, c, d: goss.goss_sample(k, c, d, keep_thr=1 << 21,
+                                         amp=8.0),
+        spec((rows,), jnp.int32), spec((), jnp.int32), spec((), jnp.int32),
+        name="goss_sample")
+
+
+def _goss_round_text(spec, monkeypatch, n, f, leaves, bundled=0):
+    """Compiled text of a GOSS round's device part at ``n`` rows: the
+    draw (ops/goss.py ``sample``) and the leaf-ordered grower with the
+    kept rows compacted (``compact_inactive``), the word lanes handed
+    over as ``train_step`` hands them, packed once a dataset."""
+    from lightgbm_tpu.ops import goss
+    from lightgbm_tpu.ops.bundle import BundleDecode
+    from lightgbm_tpu.ops.grow import GrowParams
+    from lightgbm_tpu.ops.ordered_grow import grow_tree_ordered, lane_pad
+    from lightgbm_tpu.utils import device
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    key = ("goss", n, f, leaves, bundled)
+    if key not in _TEXTS:
+        feats = bundled or f
+        i32 = lambda *shape: spec(shape, jnp.int32)
+        bundle = BundleDecode(
+            col=i32(feats), off=i32(feats), width=i32(feats),
+            slot_map=i32(feats, B), default_bin=i32(feats),
+            col_feat=i32(f), slot_feat=i32(f, B), multi=i32(0)) \
+            if bundled else None
+        params = GrowParams(num_leaves=leaves, max_bin=B, min_data_in_leaf=0,
+                            compact_inactive=True)
+
+        def round_(grad, hess, real, state, bins, num_bin, is_cat, mask,
+                   lanes, bundle):
+            w, factor = goss.sample(grad[None], hess[None], real, state,
+                                    goss.GossParams(0.2, 0.1))
+            with jax.named_scope("goss/sample"):
+                g, h = grad * factor, hess * factor
+            return grow_tree_ordered(bins, num_bin, is_cat, mask, g, h, w,
+                                     jnp.float32(0.1), params,
+                                     bins_words=lanes, bundle=bundle)
+        f32 = spec((n,), jnp.float32)
+        _TEXTS[key] = jax.jit(round_).lower(
+            f32, f32, f32, spec((4,), jnp.int32), spec((f, n), jnp.uint8),
+            spec((feats,), jnp.int32), spec((feats,), jnp.bool_),
+            spec((feats,), jnp.bool_),
+            (spec((n + lane_pad(n),), jnp.int32),) * -(-f // 4),
+            bundle).compile().as_text()
+    return _TEXTS[key]
+
+
+@pytest.mark.parametrize("n,f,leaves,bundled", [
+    (32768, 4, 7, 0), (262144, 60, 255, 4228)])
+def test_goss_round_holds_no_row_sort_and_copies_no_whole_lane(
+        spec, monkeypatch, n, f, leaves, bundled):
+    """A GOSS round's device part at 32,768 rows (three size classes) and
+    at the one-hot cell's widths (60 EFB columns of 4,228 features, 255
+    leaves, 262,144 rows).  The draw was once ``jnp.argsort`` of
+    N scores with a rank scatter, and the grower compacted the kept rows
+    with a 12-operand ``lax.sort`` of N rows (one instance compiled in
+    125 to 150 s on the chip) and walked the others down the tree by row
+    gathers.  Now: the ``goss_sample`` kernel under ``goss/sample``, the
+    partition kernel under ``split/compact``, no sort but the L segment
+    starts' and none at all of N rows, no gather of rows, no operation
+    outside a phase, and nothing copies a whole lane in the grow loop or
+    the routing state of the rows outside the sample anywhere."""
+    from lightgbm_tpu.obs import devtrace
+    from lightgbm_tpu.ops.ordered_grow import lane_pad
+    text = _goss_round_text(spec, monkeypatch, n, f, leaves, bundled)
+    pm = devtrace.phase_map(text)
+    instrs = devtrace.parse_hlo(text)["instructions"]
+    assert pm["ops_unscoped"] == 0, pm["unscoped_op_names"]
+    kernels = {(pm["phases"].get(k), k.split(".")[0]) for k in instrs
+               if k.startswith(("goss_sample", "segment_partition"))}
+    assert {("goss/sample", "goss_sample"), ("split/compact",
+            "segment_partition"), ("split/sort", "segment_partition")} \
+        == kernels, kernels
+    elements = _result_elements(text)
+    sorts = [(k, pm["phases"].get(k), elements[k]) for k, r in instrs.items()
+             if r["opcode"] == "sort"]
+    assert [ph for _, ph, _ in sorts] == ["leaf_delta"], sorts
+    assert max(sorts[0][2]) == leaves
+    assert not [k for k, r in instrs.items() if r["opcode"] == "gather"
+                and max(elements[k], default=0) >= n]
+    lane = n + lane_pad(n)
+    assert _whole_in_grow_loop(text, lane) == []
+    # a copy into or out of ``S(1)`` is the compiler staging a small
+    # buffer in on-chip memory, not the trap
+    kind = dict(re.findall(r"%([\w.\-]+) = (\S+) ", text))
+    copies = re.compile(
+        rf"%[\w.\-]+ = (s32\[(?:{n}|{lane})\]\S*) copy\(%([\w.\-]+)\)")
+    whole = [m.group(0) for m in map(copies.search, text.splitlines())
+             if m and "S(" not in m.group(1) + kind.get(m.group(2), "")]
+    assert len(whole) <= 1, whole       # the parent program's one
+
+
 # grow_tree_ordered at 32,768 x 4, 7 leaves, for the described v5e: 3,282
 # for the program of PR 29 and, the exchange hook in place, of PR 30;
 # PR 31 put the partition kernel, its mask and the slices of its output
