@@ -34,8 +34,9 @@ TPU-shaped design, three pieces:
    byte, an exact zero-byte test, and the int32 block read as int8
    ``[4 B, T]``; it is contracted with the nine digit planes over the
    rows (``A x B^T`` in int8, int32 sums), so nothing is transposed and
-   nothing row-major is built in HBM.  The segment's rows are masked in
-   the kernel from two prefetched scalars.  2.6 ns a row slot at 28
+   nothing row-major is built in HBM.  The segment comes as two
+   prefetched scalars: a grid step that holds none of its rows does no
+   work, the steps at its two ends mask the rest.  2.6 ns a row slot at 28
    features and 255 bins on a v5e (PERF.md, PR 33), in a loop whose code
    does not grow with the window.
 
@@ -258,48 +259,57 @@ def _lanes_hist_kernel(seg_ref, *refs, w, bb, nsub):
 
     first = seg_ref[0]
     stop = first + seg_ref[1]
-    k = SUB_BLOCKS * LANE
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
-    plane = jax.lax.broadcasted_iota(jnp.int32, (PLANES, k), 0)
-    # the bin index in every byte of a word, bins along sublanes
-    bins4 = jax.lax.broadcasted_iota(jnp.int32, (bb, k), 0) * 0x01010101
+    rows = nsub * LANE
 
-    def rows_of(ref, j0):
-        # rows along lanes: row r of the step is element (r // 128,
-        # r % 128) of a lane's block, so a sub-block is one row of it
-        return jnp.concatenate(
-            [ref[pl.ds(j0 + u, 1), :] for u in range(SUB_BLOCKS)], axis=1)
+    # a step that holds no row of the segment does no work (its blocks
+    # are still fetched: the index maps stay plain, since a map that
+    # computes is lowered once a lane); one body, the mask below trims
+    # the steps at the segment's two ends
+    @pl.when((i * rows < stop) & ((i + 1) * rows > first))
+    def _step():
+        k = SUB_BLOCKS * LANE
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+        plane = jax.lax.broadcasted_iota(jnp.int32, (PLANES, k), 0)
+        # the bin index in every byte of a word, bins along sublanes
+        bins4 = jax.lax.broadcasted_iota(jnp.int32, (bb, k), 0) * 0x01010101
 
-    def group(g, carry):
-        j0 = g * SUB_BLOCKS
-        row = (i * nsub + j0) * LANE + lane
-        inside = (row >= first) & (row < stop)
-        d = [jnp.where(inside, rows_of(ref, j0), 0) for ref in dig_refs]
-        # digit k is byte k % 4 of word k // 4; the truncation to int8
-        # gives the signed digit back.  Planes 12 to 15 repeat word 2 and
-        # planes 9 to 11 are its empty bytes: their sums are never read
-        word = jnp.where(plane < 4, d[0], jnp.where(plane < 8, d[1], d[2]))
-        planes = (word >> ((plane & 3) * 8)).astype(jnp.int8)   # [16, k]
-        for wi, ref in enumerate(bin_refs):
-            # four features' one-hots at once: a byte of ``t`` is zero
-            # where that feature's bin is the sublane's, and the exact
-            # zero-byte test leaves 0x01 there and 0x00 elsewhere (no
-            # carry crosses a byte: 0x7F + 0x7F < 0x100)
-            t = bins4 ^ rows_of(ref, j0)                        # [bb, k]
-            y = (t & 0x7F7F7F7F) + 0x7F7F7F7F
-            y = ~(y | t | 0x7F7F7F7F)
-            onehot = pltpu.bitcast(jax.lax.shift_right_logical(y, 7),
-                                   jnp.int8)                    # [4 bb, k]
-            # row 4 * bin + byte of the one-hot is byte ``byte`` of the
-            # word in row ``bin``; contracted over the lanes of both
-            # operands (A x B^T), so nothing is transposed
-            out_ref[wi] += jax.lax.dot_general(
-                planes, onehot, dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.int32)               # [16, 4 bb]
-        return carry
-    # a loop, not an unrolled step: the code of an instance does not grow
-    # with its rows (the grow program holds two dozen instances)
-    jax.lax.fori_loop(0, nsub // SUB_BLOCKS, group, 0)
+        def rows_of(ref, j0):
+            # rows along lanes: row r of the step is element (r // 128,
+            # r % 128) of a lane's block, so a sub-block is one row of it
+            return jnp.concatenate(
+                [ref[pl.ds(j0 + u, 1), :] for u in range(SUB_BLOCKS)], axis=1)
+
+        def group(g, carry):
+            j0 = g * SUB_BLOCKS
+            row = (i * nsub + j0) * LANE + lane
+            inside = (row >= first) & (row < stop)
+            d = [jnp.where(inside, rows_of(ref, j0), 0) for ref in dig_refs]
+            # digit k is byte k % 4 of word k // 4; the truncation to int8
+            # gives the signed digit back.  Planes 12 to 15 repeat word 2
+            # and planes 9 to 11 are its empty bytes: their sums are never
+            # read
+            word = jnp.where(plane < 4, d[0], jnp.where(plane < 8, d[1], d[2]))
+            planes = (word >> ((plane & 3) * 8)).astype(jnp.int8)  # [16, k]
+            for wi, ref in enumerate(bin_refs):
+                # four features' one-hots at once: a byte of ``t`` is zero
+                # where that feature's bin is the sublane's, and the exact
+                # zero-byte test leaves 0x01 there and 0x00 elsewhere (no
+                # carry crosses a byte: 0x7F + 0x7F < 0x100)
+                t = bins4 ^ rows_of(ref, j0)                        # [bb, k]
+                y = (t & 0x7F7F7F7F) + 0x7F7F7F7F
+                y = ~(y | t | 0x7F7F7F7F)
+                onehot = pltpu.bitcast(jax.lax.shift_right_logical(y, 7),
+                                       jnp.int8)                # [4 bb, k]
+                # row 4 * bin + byte of the one-hot is byte ``byte`` of
+                # the word in row ``bin``; contracted over the lanes of
+                # both operands (A x B^T), so nothing is transposed
+                out_ref[wi] += jax.lax.dot_general(
+                    planes, onehot, dimension_numbers=(((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.int32)           # [16, 4 bb]
+            return carry
+        # a loop, not an unrolled step: the code of an instance does not
+        # grow with its rows (the grow program holds two dozen instances)
+        jax.lax.fori_loop(0, nsub // SUB_BLOCKS, group, 0)
 
 
 def digit_histogram_lanes(bin_lanes, dig_lanes, first, scnt, num_features: int,
@@ -311,9 +321,11 @@ def digit_histogram_lanes(bin_lanes, dig_lanes, first, scnt, num_features: int,
     bin_lanes: ceil(F / 4) [P] int32 lanes, four bin codes a word
     (ops/ordered_grow.py ``pack_u8_words``); dig_lanes: the 3 [P] int32
     lanes of the nine int8 digits.  P a multiple of ``step_rows``, or a
-    smaller multiple of 1,024.  The kernel visits all P rows and zeroes the
-    digits of every row outside the segment itself, so its time goes
-    with P and its sums with the segment."""
+    smaller multiple of 1,024.  Only the grid steps that hold a row of
+    the segment do work, and they zero the digits of every row outside
+    it, so the time goes with the segment rounded up to whole steps (the
+    other steps still fetch their blocks) and the sums with the
+    segment."""
     w = len(bin_lanes)
     rows = bin_lanes[0].shape[0]
     nsub = min(step_rows, rows) // LANE

@@ -663,7 +663,9 @@ def grow_tree_ordered(bins, num_bin, is_cat, feat_mask, grad, hess,
 
             # smaller child's histogram from its CONTIGUOUS slice; pad to
             # P/8 when the child is small enough (splits are often very
-            # unbalanced — a fixed P/2 pad wastes up to 4x kernel work).
+            # unbalanced).  The kernel computes only the grid steps that
+            # hold the child's rows, so the P/8 window saves the slices
+            # and the block fetches of the P/2 one, not its compute.
             # Measured dead ends (PERF.md, "Carried over"): a dynamic-grid
             # packed-word kernel runs 3x slower per row (Mosaic keeps all
             # one-hot temporaries live under a dynamic grid, forcing tiny

@@ -138,15 +138,14 @@ LANES_CASES = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(LANES_CASES))
-def test_lanes_kernel_is_the_scatter_of_the_unpacked_rows(case):
-    """``digit_histogram_lanes`` (interpreted) over a window of the word
-    lanes, cut as ``ordered_grow.hist_window`` cuts it, equals
-    ``digit_histogram_scatter`` of the unpacked rows with the rows outside
-    the segment masked, exactly."""
+def _lanes_against_scatter(total, psz, f, max_bin, off, scnt, step, fill,
+                           seed):
+    """``digit_histogram_lanes`` (interpreted) over the ``psz``-row window
+    of random word lanes that ``ordered_grow.hist_window`` cuts for the
+    segment ``[off, off + scnt)``, and ``digit_histogram_scatter`` of the
+    unpacked rows with the rows outside the segment masked."""
     from lightgbm_tpu.ops import ordered_grow as og
-    total, psz, f, max_bin, off, scnt, step, fill = LANES_CASES[case]
-    rng = np.random.RandomState(len(case))
+    rng = np.random.RandomState(seed)
     bins = rng.randint(0, max_bin, size=(total, f)).astype(np.uint8)
     digits = rng.randint(-128, 128, size=(total, 9)).astype(np.int8)
     if fill == "bin_ends":
@@ -171,6 +170,48 @@ def test_lanes_kernel_is_the_scatter_of_the_unpacked_rows(case):
         jnp.asarray(bins), jnp.asarray(np.where(inside[:, None], digits, 0)),
         max_bin)
     assert got.shape == (f, 9, max_bin) and got.dtype == jnp.int32
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    return np.asarray(got), np.asarray(want)
+
+
+@pytest.mark.parametrize("case", sorted(LANES_CASES))
+def test_lanes_kernel_is_the_scatter_of_the_unpacked_rows(case):
+    """``digit_histogram_lanes`` (interpreted) over a window of the word
+    lanes, cut as ``ordered_grow.hist_window`` cuts it, equals
+    ``digit_histogram_scatter`` of the unpacked rows with the rows outside
+    the segment masked, exactly."""
+    total, psz, f, max_bin, off, scnt, step, fill = LANES_CASES[case]
+    got, want = _lanes_against_scatter(total, psz, f, max_bin, off, scnt,
+                                       step, fill, seed=len(case))
+    np.testing.assert_array_equal(got, want)
     if scnt:
-        assert np.asarray(got).any()
+        assert got.any()
+
+
+# (rows of the lanes, window rows, off, scnt, step rows): grid steps of
+# 1,024 rows, the kernel's smallest, but for the one short step
+SKIP_CASES = {
+    "segment_inside_one_step": (4096, 4096, 1100, 700, 1024),
+    "segment_on_step_boundaries": (4096, 4096, 1024, 2048, 1024),
+    "segment_spans_three_steps": (5120, 5120, 1500, 2200, 1024),
+    "window_ends_with_the_lanes": (6144, 4096, 4000, 1500, 1024),
+    "scnt_zero": (4096, 4096, 1500, 0, 1024),
+    "scnt_is_the_window": (4096, 4096, 0, 4096, 1024),
+    "one_short_step": (8192, 4096, 5000, 3000, lh.STEP_ROWS),
+}
+
+
+@pytest.mark.parametrize("features", [28, 136])
+@pytest.mark.parametrize("case", sorted(SKIP_CASES))
+def test_lanes_kernel_skips_the_steps_outside_the_segment(case, features):
+    """With the grid steps that hold no row of the segment skipped, the
+    sums are still the scatter's of the segment's rows, exactly: at 7 and
+    34 bin words, with the segment inside one step, on step boundaries
+    (the two steps beside it skipped by the strict compares), across
+    three steps, in a window that ends with the lanes (``first`` > 0),
+    empty (zeros), the whole window, and in a window shorter than one
+    step."""
+    total, psz, off, scnt, step = SKIP_CASES[case]
+    got, want = _lanes_against_scatter(total, psz, features, 255, off, scnt,
+                                       step, "random", seed=features)
+    np.testing.assert_array_equal(got, want)
+    assert got.any() == (scnt > 0)

@@ -81,19 +81,23 @@ def test_digit_histogram_kernel_compiles(spec):
         name="digit_histogram")
 
 
-@pytest.mark.parametrize("rows", [4096, 1 << 20])
-def test_lanes_histogram_kernel_compiles(spec, rows):
-    """The ordered grower's histogram kernel at the cells' width (7 bin
-    word lanes and 3 digit word lanes at 28 features), at the smallest
-    child window and at one of a million rows: the lanes read as they
-    lie, the segment's two scalars prefetched, four features' one-hots
-    from one word (the int32 block read as int8), the int8 contraction
-    over the lanes of both operands, under the row-major kernel's name."""
+@pytest.mark.parametrize("rows,features", [
+    (4096, F), (1 << 20, F), (1 << 20, 136)],
+    ids=["4096", "1048576", "1048576-136"])
+def test_lanes_histogram_kernel_compiles(spec, rows, features):
+    """The ordered grower's histogram kernel at the Higgs cells' width (7
+    bin word lanes and 3 digit word lanes at 28 features) at the smallest
+    child window and at one of a million rows, and at the ranking cell's
+    136 features (34 bin words), the widest: the lanes read as they lie,
+    the segment's two scalars prefetched, the grid steps outside the
+    segment skipped, four features' one-hots from one word (the int32
+    block read as int8), the int8 contraction over the lanes of both
+    operands, under the row-major kernel's name."""
     from lightgbm_tpu.ops import leafhist
-    w = -(-F // 4)
+    w = -(-features // 4)
     _assert_kernel_compiles(
         lambda first, scnt, *ls: leafhist.digit_histogram_lanes(
-            ls[:w], ls[w:], first, scnt, F, B),
+            ls[:w], ls[w:], first, scnt, features, B),
         spec((), jnp.int32), spec((), jnp.int32),
         *[spec((rows,), jnp.int32)] * (w + 3), name="digit_histogram")
 
